@@ -258,14 +258,17 @@ def test_cli_batch_validation(tmp_path):
                   "--batch-output-files=c", "--sp=4"])
 
 
-def test_cli_compile_cache(tmp_path):
-    """--compile-cache: the flag configures the persistent XLA cache
-    (in-process verification — this process's jit memo means tiny
-    graphs may not hit disk) and the run is output-identical."""
+def test_cli_places_the_one_compile_cache(tmp_path, monkeypatch):
+    """The driver places the persistent XLA cache by the package's
+    one rule (utils/compile_cache: JAX_COMPILATION_CACHE_DIR if set,
+    else the fixed <checkout>/.jax_cache) — no flag, no private knob —
+    and repeat runs are output-identical."""
     import jax
 
+    from ziria_tpu.utils import compile_cache
+
+    monkeypatch.delenv(compile_cache.ENV, raising=False)
     src = os.path.join(EXAMPLES, "fir.zir")
-    cache = tmp_path / "xla_cache"
     xs = (100 * np.sin(np.arange(200) / 5)).astype(np.int32)
     outs = []
     for k in range(2):
@@ -277,9 +280,11 @@ def test_cli_compile_cache(tmp_path):
             f"--src={src}", "--input=file",
             f"--input-file-name={inf}", "--input-file-mode=dbg",
             "--output=file", f"--output-file-name={outf}",
-            "--output-file-mode=dbg", "--backend=jit",
-            f"--compile-cache={cache}"])
+            "--output-file-mode=dbg", "--backend=jit"])
         assert rc == 0
         outs.append(outf.read_text())
     assert outs[0] == outs[1]
-    assert jax.config.jax_compilation_cache_dir == str(cache)
+    assert jax.config.jax_compilation_cache_dir \
+        == compile_cache.checkout_dir()
+    with pytest.raises(SystemExit):
+        cli_main(["--compile-cache=/nowhere", "--list-progs"])

@@ -253,7 +253,7 @@ def test_guarded_watchdog_cuts_hang_and_retries():
 def test_classify_error():
     assert resilience.classify_error(ValueError("nope")) == "fatal"
     assert resilience.classify_error(
-        RuntimeError("UNAVAILABLE: tunnel flap")) == "transient"
+        RuntimeError("UNAVAILABLE: link flap")) == "transient"
     assert resilience.classify_error(
         RuntimeError("RESOURCE_EXHAUSTED: hbm")) == "transient"
     assert resilience.classify_error(
@@ -487,7 +487,7 @@ class _Unpullable:
     surfaces at np.asarray."""
 
     def __array__(self, *a, **k):
-        raise RuntimeError("UNAVAILABLE: tunnel died mid-execution")
+        raise RuntimeError("UNAVAILABLE: link died mid-execution")
 
 
 def test_async_pull_failure_rescans_chunk(corpus):
@@ -872,3 +872,215 @@ def test_cli_chaos_flags_scope_env(tmp_path, monkeypatch):
         cli_main(["--prog=scramble", "--chaos", "justasite"])
     with pytest.raises(SystemExit, match="--chaos"):
         cli_main(["--prog=scramble", "--chaos", "s:explode:every=2"])
+
+
+# ------------------------------- the compiler is not a run-time fault
+#
+# ISSUE 22: an error raised while a program is traced, lowered or
+# compiled is a defect of the program, on this backend, every time.
+# It propagates out of every guarded site as itself — never retried,
+# never classified transient, never answered with the eager/oracle/
+# staged twin — while a fault of a program that DID compile still
+# retries and degrades as above. The guard tells them apart by WHEN,
+# not by type: `resilience.compile_ahead` compiles a jitted program
+# before its first guarded attempt.
+
+import jax  # noqa: E402
+
+from ziria_tpu.phy.wifi import rx as _rx  # noqa: E402
+from ziria_tpu.runtime import serve  # noqa: E402
+
+
+def _untraceable():
+    """A jitted program that fails the way rx.py's shard_map did
+    under jax 0.9: a TypeError out of the tracer."""
+    def f(*_a):
+        raise TypeError("scan body carry input and output must have "
+                        "equal types")
+    return jax.jit(f)
+
+
+class _Refused:
+    """A jitted program the chip's compiler refuses: it lowers, then
+    compile() raises what XLA/Mosaic raise — a JaxRuntimeError whose
+    status reads like a retryable one (a kernel over its fast-memory
+    limit says RESOURCE_EXHAUSTED)."""
+
+    def lower(self, *_a, **_k):
+        return self
+
+    def compile(self):
+        raise jax.errors.JaxRuntimeError(
+            "RESOURCE_EXHAUSTED: Ran out of memory in memory space "
+            "vmem while compiling the kernel")
+
+    def __call__(self, *_a):
+        raise AssertionError("a refused program was dispatched")
+
+
+class _RunFails:
+    """A program that compiles and then fails when it RUNS, with the
+    very error type a compile failure carries."""
+
+    def lower(self, *_a, **_k):
+        return self
+
+    def compile(self):
+        return self
+
+    def __call__(self, *_a):
+        raise jax.errors.JaxRuntimeError(
+            "INTERNAL: device halted mid-execution")
+
+
+BROKEN = {"trace": (_untraceable, TypeError),
+          "compile": (_Refused, jax.errors.JaxRuntimeError)}
+
+
+def _site_single_scan(corpus, bad, _mp):
+    sr = framebatch.StreamReceiver(**GEO)
+    sr._jit1 = bad
+    try:
+        sr.push(corpus[0])
+        sr.flush()
+    finally:
+        assert not sr.stats.degraded
+
+
+def _site_single_decode(corpus, bad, mp):
+    mp.setattr(_rx, "_jit_stream_decode", lambda *a, **k: bad)
+    sr = framebatch.StreamReceiver(**GEO)
+    try:
+        sr.push(corpus[0])
+        sr.flush()
+    finally:
+        assert not sr.stats.degraded
+
+
+def _site_fleet_scan(corpus, bad, _mp):
+    msr = framebatch.MultiStreamReceiver(4, **GEO)
+    msr._jit1 = bad
+    try:
+        msr.push_many(list(corpus[3]))
+        msr.flush()
+    finally:
+        assert not msr.stats.degraded
+
+
+def _site_fleet_decode(corpus, bad, mp):
+    mp.setattr(_rx, "_jit_stream_decode_multi", lambda *a, **k: bad)
+    msr = framebatch.MultiStreamReceiver(4, **GEO)
+    try:
+        msr.push_many(list(corpus[3]))
+        msr.flush()
+    finally:
+        assert not msr.stats.degraded
+
+
+def _site_serve_step(corpus, bad, _mp):
+    """The acceptance surface: it raises out of ServeRuntime.step."""
+    srv = serve.ServeRuntime(serve.ServeConfig(
+        n_lanes=4, chunk_len=CHUNK, frame_len=FRAME_LEN,
+        max_frames_per_chunk=K, check_fcs=True))
+    srv._rx._jit1 = bad
+    assert srv.connect("c0").admitted
+    assert srv.submit("c0", corpus[3][0][:CHUNK]).accepted
+    try:
+        srv.step()
+    finally:
+        assert not srv._rx.stats.degraded
+
+
+def _site_link_fused(_corpus, bad, mp):
+    mp.setattr(link, "_jit_fused_link", lambda *a, **k: bad)
+    rng = np.random.default_rng(77)
+    psdus = [rng.integers(0, 256, n).astype(np.uint8) for n in LENS]
+    link.loopback_many(psdus, MBPS_ALL, snr_db=SNRS, cfo=CFO,
+                       delay=DELAY, seed=11, fused=True)
+
+
+def _site_link_sweep(_corpus, bad, mp):
+    mp.setattr(link, "_jit_sweep_ber", lambda *a, **k: bad)
+    rng = np.random.default_rng(9)
+    psdus = rng.integers(0, 256, (B_SWEEP, NB_SWEEP)).astype(np.uint8)
+    link.sweep_ber(psdus, SWEEP_RATES, (-2.0, 8.0), (7,))
+
+
+GUARDED_SITES = {
+    "rx.stream_chunk": _site_single_scan,
+    "rx.stream_decode": _site_single_decode,
+    "rx.stream_chunk_multi": _site_fleet_scan,
+    "rx.stream_decode_multi": _site_fleet_decode,
+    "serve.step": _site_serve_step,
+    "link.fused": _site_link_fused,
+    "link.sweep": _site_link_sweep,
+}
+
+
+@pytest.mark.parametrize("site", sorted(GUARDED_SITES))
+@pytest.mark.parametrize("kind", sorted(BROKEN))
+def test_trace_and_compile_errors_propagate(corpus, monkeypatch,
+                                            kind, site):
+    make, exc = BROKEN[kind]
+    with telemetry.collect() as reg:
+        with dispatch.count_dispatches() as d:
+            with pytest.raises(exc):
+                GUARDED_SITES[site](corpus, make(), monkeypatch)
+    snap = reg.snapshot()
+    # not retried, not counted fatal, not fallen back, not degraded
+    for c in ("resilience.retries", "resilience.fatal",
+              "resilience.fallbacks", "resilience.degraded",
+              "resilience.async_rescans", "link.fused_degraded",
+              "link.sweep_degraded"):
+        assert snap.get(c, 0) == 0, (c, snap[c])
+    # and no twin ran in its place
+    assert not [s for s in d.counts if s.endswith(".eager")], d.counts
+
+
+def test_runtime_failure_of_a_compiled_program_still_degrades(corpus):
+    """Same exception TYPE as a compile failure, raised when the
+    compiled program runs: contained exactly as before — the fleet
+    degrades to its eager twin and the frames stay bit-identical."""
+    _s, _st, _fc, streams, _fs, res_c = corpus
+    with telemetry.collect() as reg:
+        msr = framebatch.MultiStreamReceiver(4, **GEO)
+        msr._jit1 = _RunFails()
+        got = msr.push_many(list(streams)) + msr.flush()
+    per = [[] for _ in range(4)]
+    for i, fr in got:
+        per[i].append(fr)
+    for i in range(4):
+        _same_frames(per[i], res_c[i])
+    assert msr.stats.degraded
+    snap = reg.snapshot()
+    assert snap["resilience.fatal"] >= 1
+    assert snap["resilience.degraded"] == 1
+
+
+def test_compile_ahead_compiles_once_and_the_dispatch_reuses_it():
+    """`compile_ahead` pays the ONE compile: the guarded jit call that
+    follows finds the executable in the callable's own cache (no
+    second XLA compile), and a second compile_ahead is a memo hit."""
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda name, _d, **_k: compiles.append(name)
+        if name.endswith("backend_compile_duration") else None)
+
+    @jax.jit
+    def prog(x, n):
+        return (x * 3 + n).sum()
+
+    x, n = np.arange(7, dtype=np.float32), np.int32(2)
+    resilience.compile_ahead(prog, x, n)
+    assert len(compiles) == 1
+    with dispatch.count_dispatches() as d:
+        out = resilience.guarded("once", prog, x, n)
+    assert float(out) == float((x * 3 + 2).sum())
+    resilience.compile_ahead(prog, x, n)
+    assert len(compiles) == 1, "the dispatch or the memo re-compiled"
+    assert d.counts["once"] == 1
+    # a new argument geometry is a new program
+    resilience.compile_ahead(prog, np.arange(9, dtype=np.float32), n)
+    assert len(compiles) == 2
+    # a plain callable has nothing to compile
+    resilience.compile_ahead(lambda: None)

@@ -171,7 +171,7 @@ def test_dispatch_pin_at_s1(corpus):
 def test_sharded_fleet_on_suite_mesh_bit_identical(corpus):
     # the dp-mesh path: the SAME fleet with its stream axis sharded
     # over the suite's 8 virtual devices (one stream per device,
-    # shard_map via the compat shim) — identical per-device program,
+    # jax.shard_map) — identical per-device program,
     # streams independent, so results are bit-identical lane for lane
     # and the dispatch pin is unchanged
     from ziria_tpu.parallel.batch import frame_mesh

@@ -345,3 +345,58 @@ def test_bad_geometry_rejected():
         link.stream_many([], [], snr_db=10.0)
     stream, starts = link.stream_many([], [], tail=600)
     assert stream.shape == (600, 2) and starts.size == 0
+
+
+# ------------------------------ windows that hold more than one frame
+#
+# PR 22's first chip run: at the MTU capture bucket (65 536 samples) a
+# window holds up to a dozen short high-rate frames, and the
+# per-window acquisition decoded whichever frame's LTS correlated
+# best — every geometry above holds ONE frame per window, so nothing
+# here had ever seen it. The window's frame is the one it starts at.
+
+
+def test_locate_frame_takes_the_first_frame_not_the_loudest():
+    import jax.numpy as jnp
+
+    from ziria_tpu.ops import sync
+
+    rng = np.random.default_rng(7)
+    psdus = [rng.integers(0, 256, N_BYTES).astype(np.uint8)
+             for _ in range(2)]
+    stream, starts = link.stream_many(
+        psdus, [54, 6], snr_db=30.0, cfo=1e-4, delay=60, seed=9,
+        add_fcs=True, tail=FRAME_LEN)
+    cap = np.array(stream[starts[0]: starts[0] + 4096], copy=True)
+    second = starts[1] - starts[0]
+    assert second + 400 < 4096          # both preambles in the capture
+    cap[second:] *= 2.0                 # ...and the second one louder
+    found, start, _eps = sync.locate_frame(jnp.asarray(cap))
+    assert bool(found) and int(start) == 0
+
+
+def test_chunk_scan_reads_every_frame_of_a_many_frame_window(corpus):
+    """The chunk scan at a window four times the frame: each owned
+    window's acquisition locks to ITS frame (offset 0 in the window)
+    and reads that frame's RATE and LENGTH — the eight rates, in the
+    order sent. XLA only (SIGNAL is decoded in the scan), no Pallas."""
+    import jax
+    import jax.numpy as jnp
+
+    stream, starts = corpus[0], corpus[1]
+    win, chunk = 4 * FRAME_LEN, 16 * FRAME_LEN
+    assert stream.shape[0] <= chunk and len(starts) <= 8
+    scan = jax.jit(lambda c, v: rx.stream_chunk_graph(
+        c, v, jnp.int32(-192), v, 8, win, 8)[:10])
+    padded = np.zeros((chunk, 2), np.float32)
+    padded[:stream.shape[0]] = stream
+    own, got, overflow, found, fstart, _eps, rb, ln, pk, _nv = (
+        np.asarray(o) for o in scan(jnp.asarray(padded),
+                                    jnp.int32(stream.shape[0])))
+    assert not overflow and own.sum() == len(starts)
+    assert list(got[own]) == list(starts)
+    assert found[own].all() and pk[own].all()
+    assert list(fstart[own]) == [0] * len(starts)
+    assert [int(b) for b in rb[own]] == \
+        [RATES[m].signal_bits for m in sorted(RATES)]
+    assert set(ln[own]) == {N_BYTES + 4}

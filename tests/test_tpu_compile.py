@@ -1,0 +1,294 @@
+"""Ask the chip's compiler, without the chip.
+
+libtpu is installed next to the CPU-only jax the suite runs on, and it
+compiles for a chip that is DESCRIBED (``v5e:2x2``) rather than
+attached. So the programs the served receive path dispatches — and
+the Pallas kernels inside them — are lowered and compiled here for
+one TPU v5e exactly as the chip would compile them: a Mosaic
+rejection, a fast-memory overflow or a program that does not fit HBM
+fails THIS file instead of surfacing on the first chip run.
+
+What it cannot show: nothing executes, so no result and no time.
+
+Discipline (one process may hold libtpu; xdist workers each import
+every test file): the topology is described inside a module-scoped
+fixture, after a test of this file has started — never at import, in
+a ``skipif`` or in ``parametrize`` arguments — and every sharding
+and shape is built from it in a fixture or a test. The persistent
+compile cache is off around the compiles (a described-device entry
+can be written but never read back). The program picks interpret
+mode from the live backend, which is the CPU here, so each test
+steers ``interpret=False`` itself (explicit argument on the kernels,
+monkeypatch on the programs) and builds a FRESH jit through the
+factory's ``__wrapped__`` so no interpret=False trace lands in a
+cache another test file shares.
+"""
+
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ziria_tpu.ops import viterbi_pallas as vp
+from ziria_tpu.phy.wifi import rx as _rx
+from ziria_tpu.phy.wifi.params import RATES
+from ziria_tpu.utils.geometry import DEFAULT
+
+LANES = vp.LANES
+
+#: the served MTU geometry (chip_smoke.py): the power-of-two capture
+#: bucket holding a 1500-byte PSDU at 6 Mbit/s, its chunk, S = K = 8
+MTU = dict(s=8, k=8, chunk_len=131072, frame_len=65536)
+DFLT = dict(s=DEFAULT.n_streams, k=DEFAULT.max_frames_per_chunk,
+            chunk_len=DEFAULT.chunk_len, frame_len=DEFAULT.frame_len)
+
+
+def _sym_bucket(frame_len: int) -> int:
+    return DEFAULT.sym_bucket(
+        max(1, (frame_len - _rx.FRAME_DATA_START) // 80))
+
+
+#: trellis steps of the rate-agnostic decode at the MTU symbol bucket
+T_MTU = _sym_bucket(MTU["frame_len"]) * _rx.MAX_DBPS
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:     # noqa: BLE001 - any cause is a skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One described v5e device, with the persistent compile cache
+    off for the life of this file's compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def on_chip(monkeypatch):
+    """Steer the programs' backend-derived interpret choice to the
+    chip's (the live backend here is the CPU)."""
+    monkeypatch.setattr(vp, "_interpret_default", lambda: False)
+
+
+def _shapes(sharding, *specs):
+    return [jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+            for shape, dt in specs]
+
+
+def _compile(fn, *shapes, **static):
+    t0 = time.perf_counter()
+    exe = fn.lower(*shapes, **static).compile()
+    print(f"compiled in {time.perf_counter() - t0:.1f}s; "
+          f"{exe.memory_analysis()}")
+    return exe
+
+
+def _assert_mosaic(exe, at_least: int = 1):
+    n = exe.as_text().count("tpu_custom_call")
+    assert n >= at_least, \
+        f"{n} tpu_custom_call(s) in the compiled program, wanted " \
+        f">= {at_least}: the kernel lowered in interpret mode"
+
+
+# ------------------------------------------------------------ main path
+
+
+def test_acs_f32_radix2_kernel_at_mtu_trellis(one_chip):
+    llr = jax.ShapeDtypeStruct((1, T_MTU, 2, LANES), jnp.float32,
+                               sharding=one_chip)
+    _assert_mosaic(_compile(vp._acs_tiles, llr, interpret=False,
+                            metric_dtype="float32", radix=2))
+
+
+def test_traceback_kernel_at_mtu_trellis(one_chip):
+    dec = jax.ShapeDtypeStruct((1, T_MTU, 8, LANES), jnp.uint8,
+                               sharding=one_chip)
+    met = jax.ShapeDtypeStruct((1, vp.N_STATES, LANES), jnp.float32,
+                               sharding=one_chip)
+    _assert_mosaic(_compile(vp._traceback_tiles, dec, met,
+                            interpret=False))
+
+
+def _decode_shapes(geo, sharding):
+    nsb = _sym_bucket(geo["frame_len"])
+    need = _rx.FRAME_DATA_START + 80 * nsb
+    sk = (geo["s"], geo["k"])
+    tab = jax.ShapeDtypeStruct(sk, jnp.int32, sharding=sharding)
+    segs = jax.ShapeDtypeStruct(sk + (need, 2), jnp.float32,
+                                sharding=sharding)
+    return nsb, (segs, tab, tab, tab, tab)
+
+
+def _chunk_shapes(geo, sharding):
+    s = geo["s"]
+    vec = jax.ShapeDtypeStruct((s,), jnp.int32, sharding=sharding)
+    chunks = jax.ShapeDtypeStruct((s, geo["chunk_len"], 2),
+                                  jnp.float32, sharding=sharding)
+    return chunks, vec, vec, vec
+
+
+@pytest.mark.parametrize("geo", [DFLT, MTU], ids=["default", "mtu"])
+def test_decode_program_compiles_with_both_kernels(one_chip, on_chip,
+                                                   geo):
+    """Dispatch 2 of the fleet chunk-step at the served geometry: the
+    f32 radix-2 ACS and the traceback are both Mosaic kernels in the
+    compiled program, and it fits one chip."""
+    nsb, shapes = _decode_shapes(geo, one_chip)
+    dec = _rx._jit_stream_decode_multi.__wrapped__(
+        nsb, None, None, 2, None, "dp", False, False)
+    _assert_mosaic(_compile(dec, *shapes), at_least=2)
+
+
+def _chunk_scan(geo):
+    return _rx._jit_stream_chunk_multi.__wrapped__(
+        geo["k"], geo["frame_len"], _sym_bucket(geo["frame_len"]),
+        DEFAULT.threshold, DEFAULT.min_run, DEFAULT.dead_zone, None,
+        "dp")
+
+
+def test_chunk_scan_program_compiles_at_default_geometry(one_chip):
+    exe = _compile(_chunk_scan(DFLT), *_chunk_shapes(DFLT, one_chip))
+    assert exe.memory_analysis().temp_size_in_bytes < (8 << 30)
+
+
+def test_chunk_scan_program_compiles_at_mtu_geometry(one_chip):
+    """Dispatch 1 at the served MTU geometry. It took 147 s here (133 s
+    on the chip machine) while `ops/sync`'s sliding-window conv ran at
+    the TPU's DEFAULT precision — the compiler spent minutes on that
+    conv over 131072 samples x 8 streams — and 18 s once it asked for
+    HIGHEST (PR 22), which is what lets it stay in tier-1."""
+    exe = _compile(_chunk_scan(MTU), *_chunk_shapes(MTU, one_chip))
+    assert exe.memory_analysis().temp_size_in_bytes < (8 << 30)
+
+
+def test_sharded_programs_compile_for_four_chips(topo, one_chip,
+                                                 on_chip):
+    """The path across chips (`ServeConfig(shard=True)`), compiled for
+    the described 2x2 host before `chip_smoke.py --four-chips` spends
+    four chips on it: both programs under shard_map over a 4-device dp
+    mesh, stream axis sharded, each device's program free of
+    collectives (streams are independent) and the kernels Mosaic."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from ziria_tpu.parallel.batch import lane_sharding
+
+    mesh = Mesh(np.array(topo.devices), ("dp",))
+    assert mesh.size == 4
+
+    def placed(shapes):
+        return [jax.ShapeDtypeStruct(
+            a.shape, a.dtype,
+            sharding=lane_sharding(mesh, len(a.shape))) for a in shapes]
+
+    nsb, dec_shapes = _decode_shapes(DFLT, None)
+    scan = _rx._jit_stream_chunk_multi.__wrapped__(
+        DFLT["k"], DFLT["frame_len"], nsb, DEFAULT.threshold,
+        DEFAULT.min_run, DEFAULT.dead_zone, mesh, "dp")
+    dec = _rx._jit_stream_decode_multi.__wrapped__(
+        nsb, None, None, 2, mesh, "dp", False, False)
+    exes = [_compile(scan, *placed(_chunk_shapes(DFLT, None))),
+            _compile(dec, *placed(dec_shapes))]
+    _assert_mosaic(exes[1], at_least=2)
+    for exe in exes:
+        text = exe.as_text()
+        for op in ("all-reduce", "all-gather", "all-to-all",
+                   "collective-permute"):
+            assert op not in text, f"{op} in a per-stream program"
+
+
+@pytest.mark.parametrize("which", ["chunk_scan", "decode"])
+def test_served_programs_contract_floats_at_full_precision(which):
+    """A TPU's DEFAULT matmul/conv precision rounds f32 operands to
+    bfloat16. The first chip run (PR 22) lost the SIGNAL field's RATE
+    bits to it through the DFT-by-matmul FFT while every CPU test
+    passed, because on the CPU the default IS full precision. So: no
+    float contraction of the two served programs is lowered at
+    DEFAULT precision (read off the StableHLO; needs no compiler)."""
+    import re
+    if which == "chunk_scan":
+        fn, shapes = _chunk_scan(DFLT), _chunk_shapes(DFLT, None)
+    else:
+        nsb, shapes = _decode_shapes(DFLT, None)
+        fn = _rx._jit_stream_decode_multi.__wrapped__(
+            nsb, None, None, 2, None, "dp", False, False)
+    loose = [ln.strip()[:200] for ln in
+             fn.lower(*shapes).as_text().splitlines()
+             if re.search(r"stablehlo\.(dot_general|dot|convolution)\b",
+                          ln)
+             and "f32" in ln and "HIGHEST" not in ln]
+    assert not loose, loose[:3]
+
+
+# ----------------------------------------------- the off-by-default levers
+#
+# What the chip's compiler says to every kernel variant the Geometry
+# can switch on (all off by default, utils/geometry.py). A variant the
+# chip cannot compile cannot be priced on it (ROADMAP S2/D2), so each
+# is held to compiling here. Before PR 22 the five non-default
+# (metric, radix) pairs were all refused: radix 4 by Mosaic's gather
+# rule and then its mask-register reshape (`_interleave_dec1`), the
+# int metrics by the traceback's int32 argmax ("Only float32 is
+# supported") — both repaired in a line or three.
+
+
+@pytest.mark.parametrize("metric,radix", [
+    ("float32", 4), ("int16", 2), ("int16", 4), ("int8", 2),
+    ("int8", 4)])
+def test_acs_variant_decode_compiles(one_chip, metric, radix):
+    """ACS + traceback (`_decode_tiles`) per (metric, radix) at the
+    MTU trellis."""
+    dt = jnp.float32 if metric == "float32" else jnp.int16
+    llr = jax.ShapeDtypeStruct((1, T_MTU, 2, LANES), dt,
+                               sharding=one_chip)
+    _assert_mosaic(_compile(vp._decode_tiles, llr, interpret=False,
+                            metric_dtype=metric, radix=radix),
+                   at_least=2)
+
+
+def test_fused_known_rate_decode_compiles(one_chip):
+    """The known-rate fused front (demap/deinterleave/depuncture as
+    an in-kernel prologue) at 6 Mbit/s over the MTU symbol bucket."""
+    rate = RATES[6]
+    n_sym = _sym_bucket(MTU["frame_len"])
+
+    def f(data, gain, nbits):
+        return vp.viterbi_decode_batch_fused(
+            data, gain, rate, nbits_real=nbits, radix=2,
+            interpret=False)
+
+    b = MTU["s"] * MTU["k"]
+    _assert_mosaic(_compile(jax.jit(f), *_shapes(
+        one_chip, ((b, n_sym, 48, 2), jnp.float32),
+        ((b, 48), jnp.float32), ((b,), jnp.int32))), at_least=2)
+
+
+def test_fused_rate_switched_decode_compiles(one_chip):
+    """The rate-switched fused front keeps a whole frame's symbols per
+    block ((1, n_sym_p, 96, 128) f32 = 50 MB at symbol bucket 1024);
+    the compiler takes it at one lane tile."""
+    n_sym = _sym_bucket(MTU["frame_len"])
+
+    def f(data, gain, ridx, nbits):
+        return vp.viterbi_decode_mixed_fused(
+            data, gain, ridx, nbits, radix=2, interpret=False)
+
+    b = MTU["s"] * MTU["k"]
+    _assert_mosaic(_compile(jax.jit(f), *_shapes(
+        one_chip, ((b, n_sym, 48, 2), jnp.float32),
+        ((b, 48), jnp.float32), ((b,), jnp.int32),
+        ((b,), jnp.int32))), at_least=2)

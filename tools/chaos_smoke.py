@@ -91,7 +91,7 @@ def main() -> int:
 
     # 5. classification: retry only what may heal
     assert rz.classify_error(
-        RuntimeError("UNAVAILABLE: tunnel")) == "transient"
+        RuntimeError("UNAVAILABLE: link")) == "transient"
     assert rz.classify_error(
         RuntimeError("INVALID_ARGUMENT: shape")) == "fatal"
 

@@ -1060,13 +1060,7 @@ FORI_MIN_COUNT = 24
 
 def _tracing() -> bool:
     """True when called under a jax trace (jit/vmap/scan staging)."""
-    try:
-        from jax._src.core import trace_state_clean
-    except ImportError:       # public alias in some jax versions
-        try:
-            from jax.core import trace_state_clean  # type: ignore
-        except ImportError:
-            return False
+    from jax._src.core import trace_state_clean
     return not trace_state_clean()
 
 

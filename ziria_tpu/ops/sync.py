@@ -40,7 +40,8 @@ def _sliding_sum(x, w: int):
     k = jnp.ones((w,), x.dtype)
 
     def conv1(col):
-        return jnp.convolve(col, k, mode="valid")
+        # "highest": the TPU's default conv precision is bfloat16
+        return jnp.convolve(col, k, mode="valid", precision="highest")
 
     if x.ndim == 1:
         return conv1(x)
@@ -154,6 +155,22 @@ def lts_pair_metric(samples, limit=None):
     return jnp.where(jnp.arange(pair.shape[0]) < lim - 127, pair, -1.0)
 
 
+def _align_lts(pair, crossing, align_back: int = 32,
+               align_span: int = 416):
+    """Exact frame start for the plateau that crosses the STS
+    threshold at ``crossing``: the two-peak LTS argmax within
+    ``[crossing - align_back, crossing - align_back + align_span)``
+    minus the 192-sample preamble offset. The ONE alignment rule of
+    the per-capture oracle (:func:`locate_frame`) and the K-frame
+    chunk scan (:func:`locate_frames`): a local window keeps the
+    frames of one capture from stealing each other's peaks."""
+    pidx = jnp.arange(pair.shape[0])
+    lo = crossing - align_back
+    local = jnp.where((pidx >= lo) & (pidx < lo + align_span),
+                      pair, -1.0)
+    return jnp.argmax(local).astype(jnp.int32) - 192
+
+
 def locate_frame(samples, limit=None, window: int = 48,
                  threshold: float = 0.75):
     """Locate and align a frame in a sample stream: STS detection
@@ -183,17 +200,22 @@ def locate_frame(samples, limit=None, window: int = 48,
     n = x.shape[0]
     lim = n if limit is None else limit
 
-    # STS detection gate (the coarse start is superseded by the LTS
+    # STS detection gate: the first plateau crossing says WHICH frame
+    # of the capture this is (its exact start comes from the LTS
     # timing below)
-    detected, _coarse = detect_packet(x, window, threshold, limit=limit)
+    detected, coarse = detect_packet(x, window, threshold, limit=limit)
 
     # LTS timing: cross-correlate with the known long symbol; the two
     # LTS peaks are 64 apart; first LTS starts at frame_start + 192.
     # The peak-pick is capped the same way as the detect gate (the
-    # shared metric masks out-of-cap positions to -1 sentinels).
+    # shared metric masks out-of-cap positions to -1 sentinels) and
+    # LOCAL to the detected crossing — `locate_frames`' alignment rule
+    # at K=1. A global pick is the same peak while the capture holds
+    # one frame; a 65 536-sample MTU window holds up to a dozen short
+    # high-rate frames, and the global pick then decoded whichever of
+    # them correlated best, not the one the window starts at (PR 22).
     pair = lts_pair_metric(x, limit=lim)
-    lts1 = jnp.argmax(pair).astype(jnp.int32)
-    frame_start = jnp.maximum(lts1 - 192, 0)
+    frame_start = jnp.maximum(_align_lts(pair, coarse), 0)
 
     # CFO from the aligned preamble: coarse (lag-16 STS, wide range)
     # then fine (lag-64 LTS, 4x resolution) on the coarse-corrected
@@ -250,10 +272,10 @@ def locate_frames(samples, k: int, limit=None, window: int = 48,
        two-peak argmax within ``[d - align_back, d - align_back +
        align_span)`` of its crossing ``d`` minus the 192-sample
        preamble offset. The restriction to a local window is what
-       keeps K frames from stealing each other's peaks — and with one
-       frame in the chunk it picks the same global peak
-       :func:`locate_frame` does (the K=1 oracle relationship;
-       :func:`detect_packet` is the matching single-crossing gate).
+       keeps K frames from stealing each other's peaks — the same
+       :func:`_align_lts` rule :func:`locate_frame` applies to its one
+       crossing (the K=1 oracle relationship; :func:`detect_packet`
+       is the matching single-crossing gate).
 
     ``limit`` (static or traced) caps both the plateau gate and the
     peak-pick to positions a LIMIT-length capture would evaluate,
@@ -296,15 +318,8 @@ def locate_frames(samples, k: int, limit=None, window: int = 48,
     overflow = jnp.any(rem)
 
     pair = lts_pair_metric(x, limit=lim)
-    pidx = jnp.arange(pair.shape[0])
-
-    def align(di):
-        lo = di - align_back
-        local = jnp.where((pidx >= lo) & (pidx < lo + align_span),
-                          pair, -1.0)
-        return jnp.argmax(local).astype(jnp.int32) - 192
-
-    starts = jax.vmap(align)(d)
+    starts = jax.vmap(
+        lambda di: _align_lts(pair, di, align_back, align_span))(d)
     starts = jnp.where(found, starts, jnp.int32(-1))
     return found, starts, overflow
 

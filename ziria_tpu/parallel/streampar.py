@@ -45,7 +45,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from ziria_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ziria_tpu.backend.lower import lower

@@ -889,6 +889,10 @@ def sweep_ber(psdus, rates_mbps: Sequence[int],
         return out[:, 0] if profiles_key is None else out
 
     from ziria_tpu.runtime import resilience
+    # the sweep compiles OUTSIDE the guard (the dispatch wrapper hides
+    # the jitted callable from guarded()): a program the compiler
+    # refuses raises here, it never degrades to the loop
+    resilience.compile_ahead(sweep_fn, bits_d, snr_d, seed_d, errbuf)
     try:
         # guarded (runtime/resilience): transient failures retry to
         # the identical counts (pure graph, fixed keys); a fatal one

@@ -98,7 +98,7 @@ def _exp_taps(n: int, decay: float, phase_step: float) -> Tuple:
 
 #: the named profile registry, flat -> severe delay spread plus the
 #: non-FIR physical faults. docs/robustness.md carries the
-#: kind -> seam -> gate taxonomy row for each.
+#: kind -> seam -> gate table row for each.
 CHANNEL_PROFILES = {
     # the identity anchor: today's AWGN+CFO+delay channel, untouched
     "flat": ChannelProfile("flat"),

@@ -1079,8 +1079,8 @@ def _jit_stream_decode(n_sym_bucket: int, viterbi_window: int = None,
 # over the flattened (S*K) lane axis), so an entire fleet of streams
 # still runs on TWO compiled programs and <= 2 dispatches per
 # chunk-step — Ziria's `|>>>|` stage placement re-expressed as a mesh
-# axis. With a `mesh`, both programs wrap in `shard_map` (via the
-# utils/compat shim) over the dp stream axis: an identical per-device
+# axis. With a `mesh`, both programs wrap in `jax.shard_map` over the
+# dp stream axis: an identical per-device
 # program per shard of streams, no collectives (streams are
 # independent), multihost-ready through parallel/multihost.build_mesh.
 
@@ -1112,7 +1112,7 @@ def _jit_stream_chunk_multi(k: int, win_len: int, n_sym_bucket: int,
     detector params, mesh) — stream count and chunk length retrace per
     shape, so a fleet of uniform chunk-steps compiles ONCE. With a
     `mesh`, the graph wraps in shard_map over the leading stream axis
-    (`parallel/batch.stream_specs` placement, compat shim): each
+    (`parallel/batch.stream_specs` placement): each
     device runs the identical per-shard program over its S/n streams.
     `mesh` is part of the lru key (a Mesh hashes by device layout), so
     sharded and unsharded fleets never share a trace."""
@@ -1124,13 +1124,16 @@ def _jit_stream_chunk_multi(k: int, win_len: int, n_sym_bucket: int,
     if mesh is None:
         return jax.jit(f)
     from ziria_tpu.parallel.batch import stream_specs
-    from ziria_tpu.utils.compat import shard_map
     # outputs: own/starts (S,K), overflow (S,), 7x per-lane (S,K)
     # scalars, segs (S,K,need_b,2) — every one leads with the stream
-    # axis, so the specs are rank-driven
-    return jax.jit(shard_map(
+    # axis, so the specs are rank-driven. check_vma=False: the
+    # detector's scan starts its carry from an unvarying constant and
+    # returns it varying over dp, which the varying-axes check refuses
+    # to trace; nothing here is replicated, as in the decode twin
+    return jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=stream_specs((3, 1, 1, 1), axis),
-        out_specs=stream_specs((2, 2, 1) + (2,) * 7 + (4,), axis)))
+        out_specs=stream_specs((2, 2, 1) + (2,) * 7 + (4,), axis),
+        check_vma=False))
 
 
 @lru_cache(maxsize=None)
@@ -1164,12 +1167,10 @@ def _jit_stream_decode_multi(n_sym_bucket: int, viterbi_window: int = None,
     if mesh is None:
         return jax.jit(f)
     from ziria_tpu.parallel.batch import stream_specs
-    from ziria_tpu.utils.compat import shard_map
-    # check_vma=False (compat: check_rep on this image's jax): the
-    # Pallas ACS inside the decode has no replication rule; nothing
-    # here is replicated anyway — every operand leads with the
-    # sharded stream axis
-    return jax.jit(shard_map(
+    # check_vma=False: the Pallas ACS inside the decode has no
+    # replication rule; nothing here is replicated anyway — every
+    # operand leads with the sharded stream axis
+    return jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=stream_specs((4, 2, 2, 2, 2), axis),
         out_specs=stream_specs((3, 2), axis), check_vma=False))
 

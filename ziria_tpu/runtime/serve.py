@@ -1092,6 +1092,27 @@ class ClientSpec(NamedTuple):
     mode: str = "ok"
 
 
+def synth_payloads(n_sessions: int, frames_per_session: int,
+                   n_bytes: int, seed: int) -> Tuple[List, List]:
+    """What :func:`synth_load` transmits: ``(psdus_per, rates_per)``,
+    per session the seeded PSDU byte arrays and their rates (session
+    *i*, frame *j* rides the ``(i + j)``-th of the eight rates). Its
+    own function so a checker can compare the frames a server hands
+    back with the bytes that were sent (chip_smoke.py)."""
+    from ziria_tpu.phy.wifi.params import RATES
+
+    rng = np.random.default_rng(seed)
+    rates_all = sorted(RATES)
+    psdus_per, rates_per = [], []
+    for i in range(n_sessions):
+        rates = [rates_all[(i + j) % len(rates_all)]
+                 for j in range(frames_per_session)]
+        rates_per.append(rates)
+        psdus_per.append([rng.integers(0, 256, n_bytes)
+                          .astype(np.uint8) for _ in rates])
+    return psdus_per, rates_per
+
+
 def synth_load(n_sessions: int, frames_per_session: int = 3,
                n_bytes: int = 12, snr_db: float = 30.0,
                seed: int = 0, add_fcs: bool = True,
@@ -1108,20 +1129,13 @@ def synth_load(n_sessions: int, frames_per_session: int = 3,
     seed. Imports jax (through the PHY) — the jax-free smoke uses its
     own stub traffic instead."""
     from ziria_tpu.phy import link
-    from ziria_tpu.phy.wifi.params import RATES
 
     if arrival is None:
         arrival = link.ArrivalSpec()
     misbehave = dict(misbehave or {})
-    rng = np.random.default_rng(seed)
-    rates_all = sorted(RATES)
-    psdus_per, rates_per = [], []
-    for i in range(n_sessions):
-        rates = [rates_all[(i + j) % len(rates_all)]
-                 for j in range(frames_per_session)]
-        rates_per.append(rates)
-        psdus_per.append([rng.integers(0, 256, n_bytes)
-                          .astype(np.uint8) for _ in rates])
+    psdus_per, rates_per = synth_payloads(n_sessions,
+                                          frames_per_session, n_bytes,
+                                          seed)
     # channel_profile (name / per-stream list / None -> the
     # ZIRIA_CHANNEL_PROFILE default) rides stream_many_multi's
     # per-stream physical channel: the serving load generator can
@@ -1316,6 +1330,8 @@ def main(argv=None) -> int:
             parse_profile_spec(args.channel_profile)
         except ValueError as e:
             raise SystemExit(f"--channel-profile: {e}")
+    from ziria_tpu.utils import compile_cache
+    compile_cache.place()       # before the first compile (the load's TX)
     clients = synth_load(args.sessions, args.frames, seed=args.seed,
                          misbehave=misbehave, tail=args.frame_len,
                          channel_profile=args.channel_profile)

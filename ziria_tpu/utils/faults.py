@@ -4,7 +4,7 @@ runtime (docs/robustness.md).
 A streaming fleet that must survive "millions of users" meets bad
 input and flaky devices as a matter of course: a NaN slab from a
 misbehaving client, a truncated push from a dropped socket, a
-transient ``XlaRuntimeError`` when the device tunnel flaps, a dispatch
+transient ``XlaRuntimeError`` when the device link flaps, a dispatch
 that simply hangs. None of those are reproducible on demand — so this
 module makes them reproducible: :func:`inject` activates a
 :class:`FaultPlan` for a scope (telemetry-style activation: a module
@@ -74,7 +74,7 @@ import numpy as np
 _LOCK = threading.Lock()            # guards (de)activation only
 _PLANS: Tuple["FaultPlan", ...] = ()
 
-#: the injectable fault classes (docs/robustness.md taxonomy)
+#: the injectable fault classes (the docs/robustness.md table)
 KINDS = ("nan_slab", "truncate", "transient", "fatal", "delay", "hang",
          "io_torn", "io_enospc", "channel")
 
