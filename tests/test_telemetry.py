@@ -368,8 +368,8 @@ def test_dispatchcount_concurrent_per_instance_locks():
 
 
 def test_disabled_path_overhead_pinned():
-    """The hot paths carry record()/timed()/record_gauge()/span()
-    permanently; with nothing active each call must stay in the
+    """The hot paths carry record()/timed()/record_gauge()/span()/
+    span(name, args) permanently; with nothing active each call must stay in the
     no-allocation fast path. Pinned as a generous wall bound (CI boxes
     are noisy): 50k disabled calls in well under a second — a
     regression to lock-taking or event building blows this by orders
@@ -389,10 +389,18 @@ def test_disabled_path_overhead_pinned():
         with dispatch.timed("x"):
             pass
     t_timed = time.perf_counter() - t0
+    # the fleet receiver's step-keyed spans (ISSUE 25) build their
+    # args at the site: a small dict, one truthiness check, a generator
+    t0 = time.perf_counter()
+    for i in range(n):
+        with telemetry.span("x", {"step": i, "bytes": 8}):
+            pass
+    t_span = time.perf_counter() - t0
     # ~0.1-0.3 µs/call measured; the pin is 20x that
     assert t_record / n < 5e-6, f"record() disabled: {t_record/n:.2e}s"
     assert t_gauge / n < 5e-6, f"record_gauge() disabled: {t_gauge/n:.2e}s"
     assert t_timed / n < 2e-5, f"timed() disabled: {t_timed/n:.2e}s"
+    assert t_span / n < 2e-5, f"span(args) disabled: {t_span/n:.2e}s"
 
 
 # ------------------------------------------------------------- CLI knob

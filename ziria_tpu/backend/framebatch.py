@@ -36,6 +36,7 @@ variants, not one per group size.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 from typing import Any, List, NamedTuple, Optional, Sequence
 
@@ -596,18 +597,29 @@ def _stream_geometry(r) -> dict:
             "fused_demap": bool(r.fused_demap)}
 
 
-def _pull_chunk(outs):
+def _span(spec):
+    """``telemetry.span(*spec)`` for the fleet receiver, which names
+    the blocking pulls it shares with the single-stream receiver;
+    nothing for the latter (``spec`` None)."""
+    from ziria_tpu.utils import telemetry
+    return telemetry.span(*spec) if spec else contextlib.nullcontext()
+
+
+def _pull_chunk(outs, span=None):
     """Materialize a chunk scan's per-lane scalars on the host. On an
     ASYNC backend a runtime failure mid-execution surfaces HERE, at
     the first host pull, not inside the guarded dispatch — callers
     wrap this and re-run the chunk through the guarded path when it
     throws (the launched results are lost either way). `segs` stays
-    device-resident for the decode dispatch."""
+    device-resident for the decode dispatch. ``span`` (name, args)
+    is opened around the blocking pulls alone."""
     (own, starts, overflow, found, fstart, _eps, rb, ln, pk, nv,
      segs) = outs
-    return (np.asarray(own), np.asarray(starts), np.asarray(overflow),
-            np.asarray(found), np.asarray(fstart), np.asarray(rb),
-            np.asarray(ln), np.asarray(pk), np.asarray(nv), segs)
+    with _span(span):
+        return (np.asarray(own), np.asarray(starts),
+                np.asarray(overflow), np.asarray(found),
+                np.asarray(fstart), np.asarray(rb), np.asarray(ln),
+                np.asarray(pk), np.asarray(nv), segs)
 
 
 def _record_degraded(entered: bool) -> None:
@@ -621,14 +633,16 @@ def _record_degraded(entered: bool) -> None:
         telemetry.count("resilience.degraded")
 
 
-def _guarded_decode(r, label: str, dec, *args):
+def _guarded_decode(r, label: str, dec, *args, pull_span=None):
     """The ONE guarded decode dispatch + SYNCHRONOUS host pull
     (single-stream and fleet receivers share it): an async runtime
     failure surfaces at the pull, after the dispatch returned, so the
     pull lives inside the same containment — one guarded re-dispatch,
     then None, with the receiver marked degraded so the caller (and
     the rest of the stream) runs the oracle twin. Returns (clear,
-    crc) as host arrays, or None."""
+    crc) as host arrays, or None. ``pull_span`` (name, args) is
+    opened around the blocking pull alone, with the pulled ``bytes``
+    added to its args."""
     from ziria_tpu.runtime import resilience
     from ziria_tpu.utils import telemetry
 
@@ -641,8 +655,11 @@ def _guarded_decode(r, label: str, dec, *args):
                                             policy=r._policy)
         except resilience.DispatchFailed:
             break
+        spec = pull_span and (pull_span[0], dict(
+            pull_span[1], bytes=int(clear.nbytes + crc.nbytes)))
         try:
-            return np.asarray(clear, np.uint8), np.asarray(crc)
+            with _span(spec):
+                return np.asarray(clear, np.uint8), np.asarray(crc)
         except Exception:        # noqa: BLE001 - async pull loss
             if attempt:
                 break
@@ -1405,6 +1422,11 @@ class MultiStreamReceiver:
         self._watermarks = [0] * self.s
         self._seen = [set() for _ in range(self.s)]
         self._pending = None   # (offsets, active, arrs, valid, outs)
+        # a chunk-step's id is `_chunk_steps` at its launch; it rides
+        # BESIDE the pending tuple (whose positions other code reads)
+        # and tags every span of that step, launch and drain alike
+        self._pending_step = None
+        self._drain_step = None
         self._inflight = 0
         self._chunk_steps = 0
         self._overflow_chunks = 0
@@ -1551,6 +1573,8 @@ class MultiStreamReceiver:
         ``slabs`` is a length-S sequence, or a ``{stream_id: slab}``
         dict for sparse arrival; an unknown stream id raises a named
         KeyError."""
+        from ziria_tpu.utils import telemetry
+
         if self._flushed:
             raise RuntimeError("push after flush")
         if isinstance(slabs, dict):
@@ -1562,8 +1586,9 @@ class MultiStreamReceiver:
                     f"{self.s} streams need {self.s} slabs, "
                     f"got {len(slabs)}")
             items = list(enumerate(slabs))
-        for i, s in items:
-            self._ingest(i, s)
+        with telemetry.span("rx.fleet.ingest", {"lanes": len(items)}):
+            for i, s in items:
+                self._ingest(i, s)
         return self._pump()
 
     def flush(self) -> List:
@@ -1580,8 +1605,7 @@ class MultiStreamReceiver:
         if active:
             out += self._step(active, flushing=True)
         if self._pending is not None:
-            pend, self._pending = self._pending, None
-            out += self._drain(pend)
+            out += self._drain(self._swap_pending())
         return out
 
     # -- per-lane lifecycle (the serving runtime's lane recycle) --------
@@ -1602,8 +1626,16 @@ class MultiStreamReceiver:
         ``(stream, StreamFrame)`` pairs; safe to call any time."""
         if self._pending is None:
             return []
-        pend, self._pending = self._pending, None
-        return self._drain(pend)
+        return self._drain(self._swap_pending())
+
+    def _swap_pending(self, new=None, step=None):
+        """Take the in-flight chunk-step out, putting ``new`` in its
+        place, and its id with it: the id launched as ``step`` waits
+        in ``_pending_step`` and is ``_drain_step`` while that step
+        drains, one tick later."""
+        pend, self._pending = self._pending, new
+        self._drain_step, self._pending_step = self._pending_step, step
+        return pend
 
     def _pending_touches(self, stream: int) -> bool:
         return self._pending is not None and stream in self._pending[1]
@@ -1701,36 +1733,42 @@ class MultiStreamReceiver:
         """Build one stacked chunk-step over the `active` streams
         (idle lanes ride zeros behind `valid == 0`), launch it, and
         advance the active streams' host carries."""
-        from ziria_tpu.utils import dispatch
+        from ziria_tpu.utils import dispatch, telemetry
 
-        arrs = np.zeros((self.s, self.chunk_len, 2), np.float32)
-        valid = np.zeros(self.s, np.int32)
-        own_lo = np.zeros(self.s, np.int32)
-        own_hi = np.zeros(self.s, np.int32)
-        adv = {}
-        for i in active:
-            t = self._tails[i]
-            if flushing:
-                v = t.shape[0]
-                arrs[i, :v] = t
-                valid[i] = own_hi[i] = v
-                adv[i] = v
-            else:
-                arrs[i] = t[:self.chunk_len]
-                valid[i] = self.chunk_len
-                own_hi[i] = self.stride
-                adv[i] = self.stride
-            # a quarantined stream rides behind the existing valid-
-            # mask: its chunk advances (samples consumed) but the
-            # detector sees zero valid samples — healthy lanes are
-            # untouched by construction (per-lane graphs under vmap),
-            # and the <= 2-dispatch budget is preserved
-            if self._health[i].step(self._dirty[i]):
-                valid[i] = 0
-            self._dirty[i] = False
-            # the stream's FIRST chunk owns head-truncated preambles
-            # (start clamps to 0), exactly the single-stream rule
-            own_lo[i] = -192 if self._offsets[i] == 0 else 0
+        with telemetry.span("rx.fleet.stack", {
+                "step": self._chunk_steps, "active": len(active),
+                "samples": sum(self._tails[i].shape[0] if flushing
+                               else self.chunk_len for i in active)}):
+            arrs = np.zeros((self.s, self.chunk_len, 2), np.float32)
+            valid = np.zeros(self.s, np.int32)
+            own_lo = np.zeros(self.s, np.int32)
+            own_hi = np.zeros(self.s, np.int32)
+            adv = {}
+            for i in active:
+                t = self._tails[i]
+                if flushing:
+                    v = t.shape[0]
+                    arrs[i, :v] = t
+                    valid[i] = own_hi[i] = v
+                    adv[i] = v
+                else:
+                    arrs[i] = t[:self.chunk_len]
+                    valid[i] = self.chunk_len
+                    own_hi[i] = self.stride
+                    adv[i] = self.stride
+                # a quarantined stream rides behind the existing
+                # valid-mask: its chunk advances (samples consumed)
+                # but the detector sees zero valid samples — healthy
+                # lanes are untouched by construction (per-lane graphs
+                # under vmap), and the <= 2-dispatch budget is
+                # preserved
+                if self._health[i].step(self._dirty[i]):
+                    valid[i] = 0
+                self._dirty[i] = False
+                # the stream's FIRST chunk owns head-truncated
+                # preambles (start clamps to 0), exactly the
+                # single-stream rule
+                own_lo[i] = -192 if self._offsets[i] == 0 else 0
         offs = list(self._offsets)          # snapshot BEFORE advancing
         res = self._launch(arrs, valid, own_lo, own_hi, active, offs)
         for i in active:
@@ -1759,10 +1797,14 @@ class MultiStreamReceiver:
         previous chunk-step — the single-stream double buffer, per
         fleet step: step t's transfer and compute are in flight while
         the host blocks on step t-1's scalars."""
-        from ziria_tpu.utils import dispatch, programs
+        from ziria_tpu.utils import dispatch, programs, telemetry
 
-        chunk_args = (self._put(arrs), self._put(valid),
-                      self._put(own_lo), self._put(own_hi))
+        step = self._chunk_steps
+        with telemetry.span("rx.fleet.put", {
+                "step": step, "bytes": arrs.nbytes + valid.nbytes
+                + own_lo.nbytes + own_hi.nbytes}):
+            chunk_args = (self._put(arrs), self._put(valid),
+                          self._put(own_lo), self._put(own_hi))
         programs.note_site("rx.stream_chunk_multi", self._jit1,
                            *chunk_args)
         outs = self._scan_dispatch(chunk_args)
@@ -1780,9 +1822,9 @@ class MultiStreamReceiver:
         dispatch.record_gauge(
             "rx.degraded_mode",
             1.0 if (self._degraded or self._scan_degraded) else 0.0)
-        pend, self._pending = self._pending, (
+        pend = self._swap_pending((
             offs, list(active), arrs, valid.copy(), own_lo.copy(),
-            own_hi.copy(), outs)
+            own_hi.copy(), outs), step)
         return self._drain(pend) if pend is not None else []
 
     def _scan_dispatch(self, chunk_args):
@@ -1816,36 +1858,109 @@ class MultiStreamReceiver:
         host integer decision tree per active stream, and emit —
         dispatching the step's ONE flattened fleet decode when ANY
         stream has a decodable lane (the all-noise fast path skips it
-        for the whole fleet)."""
+        for the whole fleet). Every span carries the drained step's
+        id (`_swap_pending`)."""
         from ziria_tpu.phy.wifi import rx as _rx
-        from ziria_tpu.phy.wifi.params import N_SERVICE_BITS, RATES
-        from ziria_tpu.utils import dispatch, programs
+        from ziria_tpu.phy.wifi.params import N_SERVICE_BITS
+        from ziria_tpu.utils import programs, telemetry
 
+        step = self._drain_step
         offs, active, arrs, valids, own_lo, own_hi, outs = pend
+
+        def pull(o):
+            return _pull_chunk(o, ("rx.fleet.pull_scan", {
+                "step": step, "bytes": sum(
+                    int(x.nbytes) for x in o[:5] + o[6:10])}))
         try:
             (own, starts, overflow, found, fstart, rb, ln, pk, nv,
-             segs) = _pull_chunk(outs)
+             segs) = pull(outs)
         except Exception:    # noqa: BLE001 - async loss, re-dispatch
             (own, starts, overflow, found, fstart, rb, ln, pk, nv,
-             segs) = _pull_chunk(self._rescan(arrs, valids, own_lo,
-                                              own_hi))
+             segs) = pull(self._rescan(arrs, valids, own_lo, own_hi))
         self._inflight -= 1
         self._overflow_chunks += int(overflow[active].sum())
 
-        allcands = []        # (stream, abs_start, row j) in emit order
-        for i in active:
-            off = offs[i]
-            self._watermarks[i] = off
-            self._seen[i], cands = _chunk_candidates(
-                self._seen[i], off, own[i], starts[i], self.k)
-            allcands += [(i, abs_start, j) for abs_start, j in cands]
+        with telemetry.span("rx.fleet.classify", {"step": step}):
+            allcands = []    # (stream, abs_start, row j) in emit order
+            for i in active:
+                off = offs[i]
+                self._watermarks[i] = off
+                self._seen[i], cands = _chunk_candidates(
+                    self._seen[i], off, own[i], starts[i], self.k)
+                allcands += [(i, abs_start, j) for abs_start, j in cands]
+            if not self._degraded:
+                emit, lanes, slots, tables = self._classify(
+                    allcands, found, fstart, rb, ln, pk, nv)
         if self._degraded:
             # compiled fleet decode already failed for good: the
             # per-capture oracle twin serves every window
             return self._decode_oracle(allcands, starts, arrs, valids)
 
-        emit = {}            # (stream, abs_start) -> RxResult
-        lanes = []           # (stream, abs_start, row j, rate, n_sym, lb)
+        got = None
+        if lanes:
+            # what the decode is asked for against what it computes:
+            # every one of its S x K lanes runs the whole symbol bucket
+            useful = sum(lane[4] for lane in lanes)
+            padded = self.s * self.k * self.n_sym_bucket
+            telemetry.count("rx.decode_symbols", useful,
+                            labels={"kind": "useful"})
+            telemetry.count("rx.decode_symbols", padded,
+                            labels={"kind": "padded"})
+            with telemetry.span("rx.fleet.decode", {
+                    "step": step, "lanes": len(lanes),
+                    "useful_symbols": useful,
+                    "padded_symbols": padded}):
+                dec = _rx._jit_stream_decode_multi(
+                    self.n_sym_bucket, self.viterbi_window,
+                    self.viterbi_metric, self.viterbi_radix,
+                    self.mesh, self.axis, self.sco_track,
+                    self.fused_demap)
+                dec_args = (segs,) + tuple(self._put(t) for t in tables)
+                programs.note_site("rx.stream_decode_multi", dec,
+                                   *dec_args)
+                got = _guarded_decode(
+                    self, "rx.stream_decode_multi", dec, *dec_args,
+                    pull_span=("rx.fleet.pull_decode", {"step": step}))
+            if got is None:
+                # degrade the WHOLE fleet's decode to the per-capture
+                # oracle (bit-identical by the pinned contract), this
+                # chunk-step included — healthy lanes keep flowing
+                return self._decode_oracle(allcands, starts, arrs,
+                                           valids)
+        with telemetry.span("rx.fleet.emit", {
+                "step": step, "frames": len(emit) + len(lanes)}):
+            if got is not None:
+                clear, crc = got
+                for i, sl in slots.items():
+                    for pos, (abs_start, m, lb) in enumerate(sl):
+                        psdu = clear[i, pos][
+                            N_SERVICE_BITS: N_SERVICE_BITS + 8 * lb]
+                        emit[(i, abs_start)] = _rx.RxResult(
+                            True, m, lb, psdu,
+                            bool(crc[i, pos]) if self.check_fcs
+                            else None)
+            out = []
+            for key in sorted(emit):
+                i, abs_start = key
+                out.append((i, StreamFrame(abs_start, emit[key])))
+                self._emitted[i] += 1
+        if out:
+            telemetry.count("rx.stream_frames", len(out),
+                            total=sum(self._emitted))
+        return out
+
+    def _classify(self, allcands, found, fstart, rb, ln, pk, nv):
+        """The host integer decision tree over a chunk-step's owned
+        candidates. Returns ``(emit, lanes, slots, tables)``: results
+        already final keyed (stream, abs_start); the decodable lanes
+        as (stream, abs_start, row j, rate, n_sym, length); per stream
+        their (abs_start, rate, length) in table order; and the four
+        (S, K) int32 tables the decode takes (row, rate index, data
+        bits, PSDU bits), None when nothing decodes."""
+        from ziria_tpu.phy.wifi import rx as _rx
+        from ziria_tpu.phy.wifi.params import RATES
+
+        emit, lanes, slots = {}, [], {}
         for i, abs_start, j in allcands:
             avail = int(nv[i, j]) - int(fstart[i, j])
             res, ok = _rx._classify_acquire(
@@ -1856,58 +1971,25 @@ class MultiStreamReceiver:
             else:
                 lanes.append((i, abs_start, j, ok[0], ok[1],
                               int(ln[i, j])))
-        if lanes:
-            # (S, K) row tables, zero-filled past each stream's real
-            # lanes (ridx 0 / nbits 0 = a full-erasure pad decode —
-            # discarded, like every pad lane here); row 0 is safe for
-            # idle streams because segs always holds K rows per stream
-            rows = np.zeros((self.s, self.k), np.int32)
-            ridx = np.zeros((self.s, self.k), np.int32)
-            nbits = np.zeros((self.s, self.k), np.int32)
-            npsdu = np.zeros((self.s, self.k), np.int32)
-            slots = {}
-            for i, abs_start, j, m, n_sym, lb in lanes:
-                sl = slots.setdefault(i, [])
-                pos = len(sl)
-                sl.append((abs_start, m, lb))
-                rows[i, pos] = j
-                ridx[i, pos] = _rx.RATE_INDEX[m]
-                nbits[i, pos] = n_sym * RATES[m].n_dbps
-                npsdu[i, pos] = 8 * lb
-            dec = _rx._jit_stream_decode_multi(
-                self.n_sym_bucket, self.viterbi_window,
-                self.viterbi_metric, self.viterbi_radix,
-                self.mesh, self.axis, self.sco_track,
-                self.fused_demap)
-            dec_args = (segs, self._put(rows), self._put(ridx),
-                        self._put(nbits), self._put(npsdu))
-            programs.note_site("rx.stream_decode_multi", dec, *dec_args)
-            got = _guarded_decode(self, "rx.stream_decode_multi",
-                                  dec, *dec_args)
-            if got is None:
-                # degrade the WHOLE fleet's decode to the per-capture
-                # oracle (bit-identical by the pinned contract), this
-                # chunk-step included — healthy lanes keep flowing
-                return self._decode_oracle(allcands, starts, arrs,
-                                           valids)
-            clear, crc = got
-            for i, sl in slots.items():
-                for pos, (abs_start, m, lb) in enumerate(sl):
-                    psdu = clear[i, pos][
-                        N_SERVICE_BITS: N_SERVICE_BITS + 8 * lb]
-                    emit[(i, abs_start)] = _rx.RxResult(
-                        True, m, lb, psdu,
-                        bool(crc[i, pos]) if self.check_fcs else None)
-        out = []
-        for key in sorted(emit):
-            i, abs_start = key
-            out.append((i, StreamFrame(abs_start, emit[key])))
-            self._emitted[i] += 1
-        if out:
-            from ziria_tpu.utils import telemetry
-            telemetry.count("rx.stream_frames", len(out),
-                            total=sum(self._emitted))
-        return out
+        if not lanes:
+            return emit, lanes, slots, None
+        # (S, K) row tables, zero-filled past each stream's real
+        # lanes (ridx 0 / nbits 0 = a full-erasure pad decode —
+        # discarded, like every pad lane here); row 0 is safe for
+        # idle streams because segs always holds K rows per stream
+        rows = np.zeros((self.s, self.k), np.int32)
+        ridx = np.zeros((self.s, self.k), np.int32)
+        nbits = np.zeros((self.s, self.k), np.int32)
+        npsdu = np.zeros((self.s, self.k), np.int32)
+        for i, abs_start, j, m, n_sym, lb in lanes:
+            sl = slots.setdefault(i, [])
+            pos = len(sl)
+            sl.append((abs_start, m, lb))
+            rows[i, pos] = j
+            ridx[i, pos] = _rx.RATE_INDEX[m]
+            nbits[i, pos] = n_sym * RATES[m].n_dbps
+            npsdu[i, pos] = 8 * lb
+        return emit, lanes, slots, (rows, ridx, nbits, npsdu)
 
     def _decode_oracle(self, allcands, starts, arrs, valids) -> List:
         """The fleet's per-capture decode twin (degraded mode): each
@@ -1922,24 +2004,27 @@ class MultiStreamReceiver:
         from ziria_tpu.utils import telemetry
 
         out: List = []
-        for i, abs_start, j in sorted(allcands,
-                                      key=lambda c: (c[0], c[1])):
-            s = int(starts[i, j])
-            win = arrs[i][s: min(s + self.frame_len, int(valids[i]))]
-            try:
-                res = _rx.receive(
-                    win, check_fcs=self.check_fcs,
-                    viterbi_window=self.viterbi_window,
-                    viterbi_metric=self.viterbi_metric,
-                    viterbi_radix=self.viterbi_radix,
-                    sco_track=self.sco_track)
-            except Exception:    # noqa: BLE001 - counted containment
-                self._lane_blowups += 1
-                self._health[i].blowup()
-                telemetry.count("resilience.lane_blowups")
-                continue
-            out.append((i, StreamFrame(abs_start, res)))
-            self._emitted[i] += 1
+        with telemetry.span("rx.fleet.emit", {
+                "step": self._drain_step, "frames": len(allcands)}):
+            for i, abs_start, j in sorted(allcands,
+                                          key=lambda c: (c[0], c[1])):
+                s = int(starts[i, j])
+                win = arrs[i][s: min(s + self.frame_len,
+                                     int(valids[i]))]
+                try:
+                    res = _rx.receive(
+                        win, check_fcs=self.check_fcs,
+                        viterbi_window=self.viterbi_window,
+                        viterbi_metric=self.viterbi_metric,
+                        viterbi_radix=self.viterbi_radix,
+                        sco_track=self.sco_track)
+                except Exception:  # noqa: BLE001 - counted containment
+                    self._lane_blowups += 1
+                    self._health[i].blowup()
+                    telemetry.count("resilience.lane_blowups")
+                    continue
+                out.append((i, StreamFrame(abs_start, res)))
+                self._emitted[i] += 1
         if out:
             telemetry.count("rx.stream_frames", len(out),
                             total=sum(self._emitted))

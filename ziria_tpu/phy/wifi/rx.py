@@ -480,13 +480,18 @@ def decode_data_mixed(frames, rate_idx, n_bits_real, n_sym_bucket: int,
     t_max = n_sym_bucket * MAX_DBPS
     rate_idx = jnp.asarray(rate_idx, jnp.int32)
     n_bits_real = jnp.asarray(n_bits_real, jnp.int32)
+    # `rx.decode.front` / `.viterbi` / `.back` name the stages in the
+    # device trace (docs/observability.md): metadata, no program change
     if fused_demap_enabled(fused_demap) \
             and _fused_front_applies(viterbi_window, viterbi_metric):
-        data, gain = jax.vmap(
-            lambda f: _front_symbols(f, n_sym_bucket, sco_track))(frames)
-        bits = viterbi_pallas.viterbi_decode_mixed_fused(
-            data, gain, rate_idx, n_bits_real, radix=viterbi_radix,
-            interpret=interpret)
+        with jax.named_scope("rx.decode.front"):
+            data, gain = jax.vmap(
+                lambda f: _front_symbols(f, n_sym_bucket,
+                                         sco_track))(frames)
+        with jax.named_scope("rx.decode.viterbi"):
+            bits = viterbi_pallas.viterbi_decode_mixed_fused(
+                data, gain, rate_idx, n_bits_real, radix=viterbi_radix,
+                interpret=interpret)
     else:
         def _branch(rate):
             def f(frame):
@@ -494,24 +499,28 @@ def decode_data_mixed(frames, rate_idx, n_bits_real, n_sym_bucket: int,
                 return jnp.pad(dep, ((0, t_max - dep.shape[0]), (0, 0)))
             return f
 
-        branches = [_branch(RATES[m]) for m in RATE_MBPS_ORDER]
-        dep = jax.vmap(
-            lambda f, r: jax.lax.switch(r, branches, f))(frames, rate_idx)
-        # rows at/after each lane's true bit count become erasures
-        # (covers both the in-rate bucket pad and the cross-rate pad
-        # to MAX_DBPS)
-        t = jnp.arange(t_max)
-        dep = jnp.where((t[None, :] < n_bits_real[:, None])[..., None],
-                        dep, 0.0)
-        bits = viterbi_pallas.viterbi_decode_batch_opt(
-            dep, window=viterbi_window, metric_dtype=viterbi_metric,
-            radix=viterbi_radix, interpret=interpret)
+        with jax.named_scope("rx.decode.front"):
+            branches = [_branch(RATES[m]) for m in RATE_MBPS_ORDER]
+            dep = jax.vmap(
+                lambda f, r: jax.lax.switch(r, branches, f))(
+                    frames, rate_idx)
+            # rows at/after each lane's true bit count become
+            # erasures (covers both the in-rate bucket pad and the
+            # cross-rate pad to MAX_DBPS)
+            t = jnp.arange(t_max)
+            dep = jnp.where(
+                (t[None, :] < n_bits_real[:, None])[..., None], dep, 0.0)
+        with jax.named_scope("rx.decode.viterbi"):
+            bits = viterbi_pallas.viterbi_decode_batch_opt(
+                dep, window=viterbi_window, metric_dtype=viterbi_metric,
+                radix=viterbi_radix, interpret=interpret)
 
     def _descramble(b):
         seed = scramble.recover_seed(b[:7])
         return scramble.descramble_bits(b, seed)
 
-    return jax.vmap(_descramble)(bits)
+    with jax.named_scope("rx.decode.back"):
+        return jax.vmap(_descramble)(bits)
 
 
 def crc_psdu_many_graph(clear_b, n_psdu_bits):
@@ -525,8 +534,10 @@ def crc_psdu_many_graph(clear_b, n_psdu_bits):
     link inlines it after the decode."""
     from ziria_tpu.ops.crc import check_crc32_masked
 
-    return jax.vmap(check_crc32_masked)(
-        clear_b[:, N_SERVICE_BITS:], jnp.asarray(n_psdu_bits, jnp.int32))
+    with jax.named_scope("rx.decode.back"):
+        return jax.vmap(check_crc32_masked)(
+            clear_b[:, N_SERVICE_BITS:],
+            jnp.asarray(n_psdu_bits, jnp.int32))
 
 
 @lru_cache(maxsize=None)
@@ -1007,29 +1018,38 @@ def stream_chunk_graph(chunk, chunk_valid, own_lo, own_hi, k: int,
     # surplus frame THIS chunk owns (never a silent drop), at the cost
     # of flagging deferred frames in a 224-sample sliver past the
     # bound — the conservative side for a widen-K diagnostic.
-    found, starts, overflow = sync.locate_frames(
-        chunk, k, limit=chunk_valid, threshold=threshold,
-        min_run=min_run, dead_zone=dead_zone,
-        overflow_limit=own_hi + 224)
-    own = found & (starts >= own_lo) & (starts < own_hi)
-    starts = jnp.where(own, jnp.maximum(starts, 0), starts)
-    # tail-pad before slicing: a final-chunk start may sit within
-    # win_len of the chunk end (the stream genuinely ends there, so
-    # the window's zero tail is exactly the oracle slice's bucket
-    # pad); clamping the slice instead would silently shift the lane
-    safe = jnp.clip(starts, 0, chunk.shape[0])
-    chunk_pad = jnp.pad(chunk, ((0, win_len), (0, 0)))
-    wins = jax.vmap(lambda s: jax.lax.dynamic_slice(
-        chunk_pad, (s, jnp.int32(0)), (win_len, 2)))(safe)
-    nv = jnp.clip(jnp.asarray(chunk_valid, jnp.int32) - safe,
-                  0, win_len).astype(jnp.int32)
-    lim = _stream_bucket_graph(nv, win_len)
-    f2, fstart, eps, rb, ln, pk = jax.vmap(acquire_frame_graph)(
-        wins, nv, lim)
-    need_b = FRAME_DATA_START + 80 * n_sym_bucket
-    wins_pad = jnp.pad(wins, ((0, 0), (0, need_b), (0, 0)))
-    segs = jax.vmap(lambda xi, s, e, a: gather_segment_graph(
-        xi, s, e, a, n_sym_bucket))(wins_pad, fstart, eps, nv - fstart)
+    #
+    # The four `rx.scan.*` scopes name the stages in the device trace
+    # (docs/observability.md); they are metadata and change no program.
+    with jax.named_scope("rx.scan.locate"):
+        found, starts, overflow = sync.locate_frames(
+            chunk, k, limit=chunk_valid, threshold=threshold,
+            min_run=min_run, dead_zone=dead_zone,
+            overflow_limit=own_hi + 224)
+    with jax.named_scope("rx.scan.window"):
+        own = found & (starts >= own_lo) & (starts < own_hi)
+        starts = jnp.where(own, jnp.maximum(starts, 0), starts)
+        # tail-pad before slicing: a final-chunk start may sit within
+        # win_len of the chunk end (the stream genuinely ends there,
+        # so the window's zero tail is exactly the oracle slice's
+        # bucket pad); clamping the slice instead would silently
+        # shift the lane
+        safe = jnp.clip(starts, 0, chunk.shape[0])
+        chunk_pad = jnp.pad(chunk, ((0, win_len), (0, 0)))
+        wins = jax.vmap(lambda s: jax.lax.dynamic_slice(
+            chunk_pad, (s, jnp.int32(0)), (win_len, 2)))(safe)
+        nv = jnp.clip(jnp.asarray(chunk_valid, jnp.int32) - safe,
+                      0, win_len).astype(jnp.int32)
+        lim = _stream_bucket_graph(nv, win_len)
+    with jax.named_scope("rx.scan.acquire"):
+        f2, fstart, eps, rb, ln, pk = jax.vmap(acquire_frame_graph)(
+            wins, nv, lim)
+    with jax.named_scope("rx.scan.gather"):
+        need_b = FRAME_DATA_START + 80 * n_sym_bucket
+        wins_pad = jnp.pad(wins, ((0, 0), (0, need_b), (0, 0)))
+        segs = jax.vmap(lambda xi, s, e, a: gather_segment_graph(
+            xi, s, e, a, n_sym_bucket))(wins_pad, fstart, eps,
+                                        nv - fstart)
     return own, starts, overflow, f2, fstart, eps, rb, ln, pk, nv, segs
 
 
@@ -1116,13 +1136,13 @@ def _jit_stream_chunk_multi(k: int, win_len: int, n_sym_bucket: int,
     device runs the identical per-shard program over its S/n streams.
     `mesh` is part of the lru key (a Mesh hashes by device layout), so
     sharded and unsharded fleets never share a trace."""
-    def f(chunks, valid, own_lo, own_hi):
+    def stream_chunk_multi(chunks, valid, own_lo, own_hi):
         return multi_stream_chunk_graph(chunks, valid, own_lo, own_hi,
                                         k, win_len, n_sym_bucket,
                                         threshold, min_run, dead_zone)
 
     if mesh is None:
-        return jax.jit(f)
+        return jax.jit(stream_chunk_multi)
     from ziria_tpu.parallel.batch import stream_specs
     # outputs: own/starts (S,K), overflow (S,), 7x per-lane (S,K)
     # scalars, segs (S,K,need_b,2) — every one leads with the stream
@@ -1131,7 +1151,8 @@ def _jit_stream_chunk_multi(k: int, win_len: int, n_sym_bucket: int,
     # returns it varying over dp, which the varying-axes check refuses
     # to trace; nothing here is replicated, as in the decode twin
     return jax.jit(jax.shard_map(
-        f, mesh=mesh, in_specs=stream_specs((3, 1, 1, 1), axis),
+        stream_chunk_multi, mesh=mesh,
+        in_specs=stream_specs((3, 1, 1, 1), axis),
         out_specs=stream_specs((2, 2, 1) + (2,) * 7 + (4,), axis),
         check_vma=False))
 
@@ -1153,8 +1174,9 @@ def _jit_stream_decode_multi(n_sym_bucket: int, viterbi_window: int = None,
     its single-stream K-lane decode). Decode-mode knobs (including
     the resolved ``fused_demap``, LAST for the R1 lint demo) and the
     mesh are cache keys, as in every jit factory here."""
-    def f(segs, rows, ridx, nbits, npsdu):
-        sel = jax.vmap(lambda sg, r: sg[r])(segs, rows)
+    def stream_decode_multi(segs, rows, ridx, nbits, npsdu):
+        with jax.named_scope("rx.decode.select"):
+            sel = jax.vmap(lambda sg, r: sg[r])(segs, rows)
         s, kk = rows.shape
         clear = decode_data_mixed(
             sel.reshape((s * kk,) + sel.shape[2:]), ridx.reshape(-1),
@@ -1165,13 +1187,14 @@ def _jit_stream_decode_multi(n_sym_bucket: int, viterbi_window: int = None,
         return (clear.reshape(s, kk, -1), crc.reshape(s, kk))
 
     if mesh is None:
-        return jax.jit(f)
+        return jax.jit(stream_decode_multi)
     from ziria_tpu.parallel.batch import stream_specs
     # check_vma=False: the Pallas ACS inside the decode has no
     # replication rule; nothing here is replicated anyway — every
     # operand leads with the sharded stream axis
     return jax.jit(jax.shard_map(
-        f, mesh=mesh, in_specs=stream_specs((4, 2, 2, 2, 2), axis),
+        stream_decode_multi, mesh=mesh,
+        in_specs=stream_specs((4, 2, 2, 2, 2), axis),
         out_specs=stream_specs((3, 2), axis), check_vma=False))
 
 

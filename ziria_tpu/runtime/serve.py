@@ -611,18 +611,19 @@ class ServeRuntime:
         journaled (the next public call), so a snapshot in between
         can carry them as the rider."""
         out = []
-        for lane, fr in pairs:
-            sid = self._lane_sid.get(lane)
-            if sid is None:            # pragma: no cover - drained
-                continue               # lanes are emptied before free
-            s = self._sessions[sid]
-            s.frames += 1
-            if s.frames <= s.dedupe_until:
-                self._count("serve.deduped")
-                continue
-            s.unacked.append((s.frames, fr))
-            self._pending_marks[sid] = s.frames
-            out.append((sid, fr))
+        with telemetry.span("serve.emit", {"frames": len(pairs)}):
+            for lane, fr in pairs:
+                sid = self._lane_sid.get(lane)
+                if sid is None:        # pragma: no cover - drained
+                    continue           # lanes are emptied before free
+                s = self._sessions[sid]
+                s.frames += 1
+                if s.frames <= s.dedupe_until:
+                    self._count("serve.deduped")
+                    continue
+                s.unacked.append((s.frames, fr))
+                self._pending_marks[sid] = s.frames
+                out.append((sid, fr))
         if out:
             self._count("serve.frames", len(out))
         return out
@@ -658,20 +659,23 @@ class ServeRuntime:
         decodable this tick."""
         if self._drained:
             raise RuntimeError("step after drain")
-        self._flush_marks()
-        out = self._take_spill()
-        out += self._shed_expired()
-        self._admit_waiting()
-        push = {}
-        for lane, sid in self._lane_sid.items():
-            take = self._take_staged(self._sessions[sid],
-                                     self.cfg.chunk_len)
-            if take is not None:
-                push[lane] = take
-        if push:
-            out += self._push(push)
-        out += self._maybe_snapshot()
-        self._gauges()
+        with telemetry.span("serve.step",
+                            {"sessions": len(self._sessions)}):
+            self._flush_marks()
+            out = self._take_spill()
+            out += self._shed_expired()
+            self._admit_waiting()
+            push = {}
+            with telemetry.span("serve.stage"):
+                for lane, sid in self._lane_sid.items():
+                    take = self._take_staged(self._sessions[sid],
+                                             self.cfg.chunk_len)
+                    if take is not None:
+                        push[lane] = take
+            if push:
+                out += self._push(push)
+            out += self._maybe_snapshot()
+            self._gauges()
         return out
 
     # -- durability: snapshots + recovery -------------------------------

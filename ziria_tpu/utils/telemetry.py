@@ -425,8 +425,10 @@ def span(name: str, args: Optional[dict] = None):
     from timestamps + tid, the Chrome trace model). Free when no trace
     is active. When an active trace was built with
     ``annotate_device=True``, the block also runs under
-    ``jax.profiler.TraceAnnotation(name)`` so a concurrent device
-    profile shows the same label."""
+    ``jax.profiler.TraceAnnotation(name, **args)`` so a concurrent
+    device profile shows the same label, with ``args`` (ints, floats
+    or short strings, all known when the span opens) as the event's
+    stats."""
     traces = _TRACES
     if not traces:
         yield
@@ -441,7 +443,7 @@ def span(name: str, args: Optional[dict] = None):
     if any(t.annotate_device for t in traces):
         cls = _annotation_cls()
         if cls is not None:
-            ann = cls(name)
+            ann = cls(name, **args) if args else cls(name)
             ann.__enter__()
     t0 = time.perf_counter()
     try:
