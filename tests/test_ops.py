@@ -2,6 +2,7 @@
 SURVEY.md §4: generate ground truth from an obvious loop implementation,
 compare the vectorized TPU path against it)."""
 
+import jax
 import numpy as np
 import pytest
 
@@ -84,6 +85,21 @@ def test_scrambler_sequence_period_127_and_balance():
     assert seq.shape == (127,)
     # maximal-length sequence: 64 ones, 63 zeros
     assert seq.sum() == 64
+
+
+def test_scrambler_sequence_of_every_seed_is_the_lfsrs():
+    """One period is an XOR of unit-seed periods (no loop): all 128
+    seeds, one at a time and under vmap, against the bit-serial LFSR."""
+    seeds = np.array([[(s >> k) & 1 for k in range(7)]
+                      for s in range(128)], np.uint8)
+    want = np.stack([scramble.np_lfsr_sequence_127(s) for s in seeds])
+    got = np.asarray(jax.vmap(scramble.lfsr_sequence_127)(seeds))
+    assert_stream_eq(got, want)
+    one = jax.jit(scramble.lfsr_sequence_127)
+    for s in (0, 1, 0x5D, 0x7F):
+        assert_stream_eq(np.asarray(one(seeds[s])), want[s])
+    assert "scan" not in str(jax.make_jaxpr(scramble.lfsr_sequence_127)(
+        seeds[1]))
 
 
 def test_seed_recovery():

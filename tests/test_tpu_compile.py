@@ -24,6 +24,7 @@ factory's ``__wrapped__`` so no interpret=False trace lands in a
 cache another test file shares.
 """
 
+import re
 import time
 
 import jax
@@ -211,6 +212,15 @@ def test_sharded_programs_compile_for_four_chips(topo, one_chip,
             assert op not in text, f"{op} in a per-stream program"
 
 
+def _loose_contractions(stablehlo_text: str):
+    """Float contractions not lowered at HIGHEST precision (the rule
+    `benchmark/harness/checks.loose_contractions` copied from here)."""
+    return [ln.strip()[:200] for ln in stablehlo_text.splitlines()
+            if re.search(r"stablehlo\.(dot_general|dot|convolution)\b",
+                         ln)
+            and "f32" in ln and "HIGHEST" not in ln]
+
+
 @pytest.mark.parametrize("which", ["chunk_scan", "decode"])
 def test_served_programs_contract_floats_at_full_precision(which):
     """A TPU's DEFAULT matmul/conv precision rounds f32 operands to
@@ -219,19 +229,59 @@ def test_served_programs_contract_floats_at_full_precision(which):
     passed, because on the CPU the default IS full precision. So: no
     float contraction of the two served programs is lowered at
     DEFAULT precision (read off the StableHLO; needs no compiler)."""
-    import re
     if which == "chunk_scan":
         fn, shapes = _chunk_scan(DFLT), _chunk_shapes(DFLT, None)
     else:
         nsb, shapes = _decode_shapes(DFLT, None)
         fn = _rx._jit_stream_decode_multi.__wrapped__(
             nsb, None, None, 2, None, "dp", False, False)
-    loose = [ln.strip()[:200] for ln in
-             fn.lower(*shapes).as_text().splitlines()
-             if re.search(r"stablehlo\.(dot_general|dot|convolution)\b",
-                          ln)
-             and "f32" in ln and "HIGHEST" not in ln]
+    loose = _loose_contractions(fn.lower(*shapes).as_text())
     assert not loose, loose[:3]
+
+
+def _while_locations(lowered):
+    """The location of every `stablehlo.while` of a lowered program
+    (its name stack carries the `jax.named_scope`s it was traced in)."""
+    found = []
+
+    def walk(op):
+        for region in op.regions:
+            for block in region.blocks:
+                for inner in block.operations:
+                    if inner.operation.name == "stablehlo.while":
+                        found.append(str(inner.operation.location))
+                    walk(inner.operation)
+
+    walk(lowered.compiler_ir("stablehlo").operation)
+    return found
+
+
+def test_served_decode_at_mtu_geometry_checks_the_fcs_without_a_loop():
+    """The FCS check was a byte-serial `while` of 27 646 dependent
+    steps under `rx.decode.back`, 35.9 ms of the 83 ms decode on the
+    chip (ledger, PR 25). It is two XOR-reductions and a look-up now
+    (`ops/crc.check_crc32_masked`), and the descrambler's period an
+    XOR of constants: the scope lowers to no loop at the served size,
+    and to no float contraction the chip would round (no compiler)."""
+    from ziria_tpu.ops import crc
+    nsb, shapes = _decode_shapes(MTU, None)
+    dec = _rx._jit_stream_decode_multi.__wrapped__(
+        nsb, None, None, 2, None, "dp", False, False)
+    lowered = dec.lower(*shapes)
+    whiles = _while_locations(lowered)
+    assert not [w[:200] for w in whiles if "rx.decode.back" in w]
+    # what is left is the two Pallas kernels, interpreted on the CPU
+    assert all("viterbi_pallas" in w for w in whiles), whiles
+    assert not _loose_contractions(lowered.as_text())
+
+    def old(data, n):           # the scan it replaced, same scope
+        with jax.named_scope("rx.decode.back"):
+            return jax.vmap(crc.crc32_bytes_masked)(data, n)
+
+    seen = _while_locations(jax.jit(old).lower(
+        jax.ShapeDtypeStruct((4, 16), jnp.uint8),
+        jax.ShapeDtypeStruct((4,), jnp.int32)))
+    assert len(seen) == 1 and "rx.decode.back" in seen[0], seen
 
 
 # ----------------------------------------------- the off-by-default levers

@@ -296,12 +296,13 @@ def _mixed_decode_tail(acqs, padded, segs, n_sym_b: int,
     common bucket over the still-device-resident decode output
     (previously a hidden host `check_crc32` dispatch PER LANE), then
     the per-lane PSDU slice. CRC booleans are bit-identical to the
-    per-lane path (`ops/crc.check_crc32_masked` is the same table
-    scan, masked). `acqs` is [(i, acq)] for the real lanes (acq needs
-    .rate_mbps/.n_sym/.length_bytes — both the host `_Acquired` and
-    batched `_LaneAcq` shapes qualify); `padded` is THE pad_lanes
-    list the caller built `segs` from — passed in, not recomputed, so
-    the ridx/nbits rows can never disagree with the segment rows."""
+    per-lane path (`ops/crc.check_crc32_masked`, loop-free, gives the
+    table scan's verdicts). `acqs` is [(i, acq)] for the real lanes
+    (acq needs .rate_mbps/.n_sym/.length_bytes — both the host
+    `_Acquired` and batched `_LaneAcq` shapes qualify); `padded` is
+    THE pad_lanes list the caller built `segs` from — passed in, not
+    recomputed, so the ridx/nbits rows can never disagree with the
+    segment rows."""
     import jax.numpy as jnp
 
     from ziria_tpu.ops.viterbi import _check_radix

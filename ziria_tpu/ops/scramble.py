@@ -7,10 +7,12 @@ frame; the same primitive with an all-ones seed generates the 127-bit
 pilot-polarity sequence.
 
 TPU-native design: x^7+x^4+1 is primitive, so every nonzero seed
-generates the same maximal-length 127-bit sequence at some phase. We
-scan the LFSR for exactly 127 steps (tiny), then *tile* the period over
-the frame and XOR — one fused elementwise op over the whole bit stream
-instead of a per-bit sequential loop. Seed recovery for the descrambler
+generates the same maximal-length 127-bit sequence at some phase. The
+LFSR is linear over GF(2), so one period is the XOR of the seven
+unit-seed periods (a constant, built at import) that the seed's bits
+select — no dependent step; we *tile* the period over the frame and
+XOR — one fused elementwise op over the whole bit stream instead of a
+per-bit sequential loop. Seed recovery for the descrambler
 is a 128-row precomputed table match (the SERVICE field's first 7 bits
 are zero, so the received first 7 bits expose the sequence phase) —
 AutoLUT-style precomputation (SURVEY.md §2.1).
@@ -18,11 +20,10 @@ AutoLUT-style precomputation (SURVEY.md §2.1).
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ziria_tpu.utils.bits import uint_to_bits
+from ziria_tpu.utils.bits import uint_to_bits, xor_reduce
 
 
 def np_lfsr_sequence_127(seed_bits: np.ndarray) -> np.ndarray:
@@ -38,6 +39,14 @@ def np_lfsr_sequence_127(seed_bits: np.ndarray) -> np.ndarray:
     return np.array(out, np.uint8)
 
 
+#: the period each single seed bit generates, (7, 127): by linearity
+#: a seed's period is the XOR of the rows its bits select. Not a
+#: 128-row table indexed by the seed: that gather read row 0 on the
+#: TPU inside `tx.encode_many_graph`'s vmapped switch (PERF.md, PR 27).
+_UNIT_PERIODS = np.stack([np_lfsr_sequence_127(row)
+                          for row in np.eye(7, dtype=np.uint8)])
+
+
 def lfsr_sequence_127(seed_bits) -> jnp.ndarray:
     """One period (127 bits) of the scrambler sequence from a 7-bit seed.
 
@@ -46,14 +55,7 @@ def lfsr_sequence_127(seed_bits) -> jnp.ndarray:
     x7(t) XOR x4(t); state shifts with that bit fed back into x1.
     """
     seed_bits = jnp.asarray(seed_bits, jnp.uint8)
-
-    def step(s, _):
-        fb = s[6] ^ s[3]  # x7 xor x4
-        s = jnp.concatenate([fb[None], s[:6]])
-        return s, fb
-
-    _, seq = jax.lax.scan(step, seed_bits, None, length=127)
-    return seq
+    return xor_reduce(seed_bits[:, None] & jnp.asarray(_UNIT_PERIODS), (0,))
 
 
 def scramble_bits(bits, seed_bits) -> jnp.ndarray:

@@ -321,8 +321,8 @@ def _jit_fused_link(rows: int, bit_bucket: int, sym_bucket: int,
     XLA program. A profiled link is STILL one dispatch: the profile's
     taps/SCO/drift/bursts trace into the channel stage as per-lane
     constants (callers pass RESOLVED names — jaxlint R1). The CRC
-    flags are always computed (a ~200-byte masked scan per lane —
-    noise next to the Viterbi), so one compile serves both
+    flags are always computed (`ops/crc.check_crc32_masked`: two
+    XOR-reductions and a look-up per lane), so one compile serves both
     ``check_fcs`` modes."""
     need_b = rx.FRAME_DATA_START + 80 * sym_bucket
 

@@ -10,6 +10,7 @@ Bit order follows the reference's wire convention: within a byte, bit 0
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -52,6 +53,13 @@ def uint_to_bits(vals, k: int, xp=jnp, msb_first: bool = False):
     if msb_first:
         idx = idx[::-1]
     return ((vals[..., None] >> idx) & 1).astype(xp.uint8)
+
+
+def xor_reduce(x, axes):
+    """XOR of an unsigned integer array over ``axes``: the sum of a
+    GF(2) product whose rows are packed into words."""
+    return jax.lax.reduce(x, np.zeros((), x.dtype), jax.lax.bitwise_xor,
+                          axes)
 
 
 def np_bytes_to_bits(data):
