@@ -166,6 +166,53 @@ def test_useful_and_padded_symbols_from_the_frames_sent(runs):
         == sum(len(r) for r in RATE_SETS)
 
 
+def test_classify_counts_the_lanes_owned_and_those_acquired(runs):
+    _srv, spans, _traced, _plain, _built = runs
+    cls = _named(spans, "rx.fleet.classify")
+    assert all(set(e["args"]) == {"step", "candidates", "acquired"}
+               and all(isinstance(v, int) for v in e["args"].values())
+               for e in cls)
+    # a clean load: every lane the scan owned, its window acquired
+    assert all(e["args"]["candidates"] == e["args"]["acquired"]
+               for e in cls)
+    assert sum(e["args"]["candidates"] for e in cls) \
+        == sum(len(r) for r in RATE_SETS)
+    # the same lanes the decode and the emit of that step then count
+    lanes = {e["args"]["step"]: e["args"]["lanes"]
+             for e in _named(spans, "rx.fleet.decode")}
+    assert all(e["args"]["acquired"] == lanes.get(e["args"]["step"], 0)
+               for e in cls)
+
+
+def test_window_wider_than_the_acquisition_head_loses_no_lane():
+    """The same count where it says something: a window twice
+    `rx._acquire_head` (the suite geometry's window IS the head), all
+    eight rates over two streams with CFO and noise. Every owned lane
+    is acquired from its window's head and decodes clean."""
+    frame_len = 2 * rx._acquire_head(1 << 16)
+    rng = np.random.default_rng(20260928)
+    streams, want = [], []
+    for i, rates in enumerate(([6, 12, 24, 48], [9, 18, 36, 54])):
+        psdus = [rng.integers(0, 256, N_BYTES).astype(np.uint8)
+                 for _ in rates]
+        st, starts = link.stream_many(
+            psdus, rates, snr_db=30.0, cfo=1e-4, delay=60 + 500 * i,
+            seed=90 + i, add_fcs=True, tail=frame_len)
+        streams.append(st)
+        want.append(list(starts))
+    with telemetry.tracing() as tr:
+        got, stats = framebatch.receive_streams(
+            streams, multi=True, chunk_len=4 * frame_len,
+            frame_len=frame_len, max_frames_per_chunk=K, check_fcs=True)
+    assert [[f.start for f in r] for r in got] == want
+    assert all(f.result.ok and f.result.crc_ok for r in got for f in r)
+    cls = [e["args"] for e in tr.events()
+           if e["name"] == "rx.fleet.classify"]
+    assert len(cls) == stats.chunk_steps >= 1
+    assert all(a["candidates"] == a["acquired"] for a in cls)
+    assert sum(a["candidates"] for a in cls) == 8
+
+
 def test_bytes_on_put_and_pulls_redo_the_shape_arithmetic(runs):
     srv, spans, _traced, _plain, _built = runs
     bucket = srv._rx.n_sym_bucket

@@ -111,6 +111,39 @@ def test_fleet_bit_identical_to_s_independent_receivers(corpus):
     assert res_m[2] == [] and res_m[6] == []
 
 
+def test_lanes_the_chunk_does_not_own_are_masked_host_side(
+        corpus, monkeypatch):
+    """Whatever the window acquisition says of a lane the chunk scan
+    did not own (a pad lane, a deferred or a previous chunk's frame:
+    since PR 30 it reads only the window's head there, and garbage is
+    garbage), the host never looks: every such lane made to report a
+    found, parity-clean 16-byte frame at 6 Mbit/s, and the fleet
+    emits what it emitted."""
+    import jax.numpy as jnp
+
+    from ziria_tpu.phy.wifi.params import RATES
+
+    streams, _starts, res_m, _st, _d, _ro, _so, _do = corpus
+    real = framebatch.MultiStreamReceiver._drain
+    forged = []
+
+    def drain(self, pend):
+        outs = list(pend[6])
+        own = np.asarray(outs[0])
+        forged.append(int((~own).sum()))
+        for at, val in ((3, True), (4, 0), (6, RATES[6].signal_bits),
+                        (7, N_BYTES + 4), (8, True)):
+            a = np.asarray(outs[at])
+            outs[at] = jnp.asarray(np.where(own, a, val).astype(a.dtype))
+        return real(self, pend[:6] + (tuple(outs),))
+
+    monkeypatch.setattr(framebatch.MultiStreamReceiver, "_drain", drain)
+    res, _stats = framebatch.receive_streams(streams, multi=True, **GEO)
+    assert sum(forged) > 0
+    for i in range(S):
+        _same_frames(res[i], res_m[i])
+
+
 def test_straddling_frame_decoded_exactly_once_in_fleet(corpus):
     streams, starts, res_m, _st, _d, _ro, _so, _do = corpus
     assert [f.start for f in res_m[1]] == list(starts[1])

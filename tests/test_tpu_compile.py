@@ -239,6 +239,31 @@ def test_served_programs_contract_floats_at_full_precision(which):
     assert not loose, loose[:3]
 
 
+def test_chunk_scan_at_mtu_geometry_acquires_over_the_window_head():
+    """The per-window acquisition ran its detector and its four LTS
+    convolutions over all 65 536 samples of 64 windows to re-derive a
+    start that lies in each window's first few hundred (36 ms of the
+    522 ms chunk-step on the chip: PERF.md, PR 30). It reads the
+    window's head now (`rx._acquire_head`): at the served geometry no
+    convolution of the S x K window batch is window-long any more,
+    and the chunk-level ones are still there (no compiler)."""
+    s, k = MTU["s"], MTU["k"]
+    text = _chunk_scan(MTU).lower(*_chunk_shapes(MTU, None)).as_text()
+    outs = [tuple(int(d) for d in m.groups()) for m in re.finditer(
+        r"stablehlo\.convolution.*-> tensor<(\d+)x1x(\d+)xf32>", text)]
+    assert len(outs) == 6, outs
+    head = _rx._acquire_head(MTU["frame_len"])
+    # the chunk scan: STS sums over S and 2S rows, LTS over S
+    chunk = MTU["chunk_len"]
+    assert sorted(o for o in outs if o[1] >= chunk - 128) == [
+        (s, chunk - 63), (s, chunk + 63), (2 * s, chunk - 63)]
+    # the acquisition: the same three over S x K windows, head-long
+    # and nothing else (they were frame_len - 63 and + 63 long)
+    assert head < MTU["frame_len"] - 128
+    assert sorted(o for o in outs if o[0] % (s * k) == 0) == [
+        (s * k, head - 63), (s * k, head + 63), (2 * s * k, head - 63)]
+
+
 def _while_locations(lowered):
     """The location of every `stablehlo.while` of a lowered program
     (its name stack carries the `jax.named_scope`s it was traced in)."""

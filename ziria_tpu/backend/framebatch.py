@@ -1881,7 +1881,13 @@ class MultiStreamReceiver:
         self._inflight -= 1
         self._overflow_chunks += int(overflow[active].sum())
 
-        with telemetry.span("rx.fleet.classify", {"step": step}):
+        # what the scan owned against what its window acquisition found
+        # there: equal on a clean stream (the acquisition reads only
+        # each window's head, `rx._acquire_head`, and loses nothing)
+        owned = own[active]
+        with telemetry.span("rx.fleet.classify", {
+                "step": step, "candidates": int(owned.sum()),
+                "acquired": int((owned & found[active]).sum())}):
             allcands = []    # (stream, abs_start, row j) in emit order
             for i in active:
                 off = offs[i]

@@ -155,8 +155,19 @@ def lts_pair_metric(samples, limit=None):
     return jnp.where(jnp.arange(pair.shape[0]) < lim - 127, pair, -1.0)
 
 
-def _align_lts(pair, crossing, align_back: int = 32,
-               align_span: int = 416):
+# The local alignment window of `_align_lts`, and what it reads: the
+# first LTS starts LTS_OFFSET samples into a frame, and each value of
+# `lts_pair_metric` reads LTS_PAIR_SPAN samples. Named because
+# `phy/wifi/rx._acquire_head` derives from them how much of a window
+# cut AT a frame start its acquisition can read.
+ALIGN_BACK = 32
+ALIGN_SPAN = 416
+LTS_OFFSET = 192
+LTS_PAIR_SPAN = 128
+
+
+def _align_lts(pair, crossing, align_back: int = ALIGN_BACK,
+               align_span: int = ALIGN_SPAN):
     """Exact frame start for the plateau that crosses the STS
     threshold at ``crossing``: the two-peak LTS argmax within
     ``[crossing - align_back, crossing - align_back + align_span)``
@@ -168,7 +179,7 @@ def _align_lts(pair, crossing, align_back: int = 32,
     lo = crossing - align_back
     local = jnp.where((pidx >= lo) & (pidx < lo + align_span),
                       pair, -1.0)
-    return jnp.argmax(local).astype(jnp.int32) - 192
+    return jnp.argmax(local).astype(jnp.int32) - LTS_OFFSET
 
 
 def locate_frame(samples, limit=None, window: int = 48,
@@ -241,8 +252,8 @@ def locate_frame(samples, limit=None, window: int = 48,
 
 def locate_frames(samples, k: int, limit=None, window: int = 48,
                   threshold: float = 0.75, min_run: int = 33,
-                  dead_zone: int = 320, align_back: int = 32,
-                  align_span: int = 416, overflow_limit=None):
+                  dead_zone: int = 320, align_back: int = ALIGN_BACK,
+                  align_span: int = ALIGN_SPAN, overflow_limit=None):
     """Locate up to ``k`` frame starts in a multi-frame sample chunk:
     top-K STS plateau extraction with dead-zone suppression, each
     candidate LTS-aligned by a local peak-pick. Returns

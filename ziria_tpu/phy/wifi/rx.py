@@ -980,6 +980,34 @@ def _stream_bucket_graph(n_valid, cap: int):
     return b
 
 
+def _acquire_head(win_len: int) -> int:
+    """How many samples of a window cut AT an aligned frame start its
+    acquisition reads (`stream_chunk_graph` step 4): the chunk scan
+    found the start already, so `acquire_frame_graph` re-derives it
+    from the window's head and everything it computes further in is
+    discarded by its own first-crossing `argmax` and local peak mask.
+
+    `sync._align_lts` puts the start at most LTS_OFFSET + ALIGN_BACK
+    (224) below the plateau crossing it was given, so the window's
+    FIRST crossing lies at or below 224. Its peak-pick then reads
+    `pair` below crossing - ALIGN_BACK + ALIGN_SPAN (608: ALIGN_BACK
+    cancels), each value from LTS_PAIR_SPAN samples: 736 for the
+    timing. The start it finds is below 608 - LTS_OFFSET (416), and
+    the CFO head (320) and SIGNAL head (FRAME_DATA_START) are sliced
+    there: 816. The larger, as a power of two (1024 today), clipped to
+    the window: one line for every geometry, and
+    `tests/test_rx_acquire_head.py` pins it against `sync`'s
+    constants. A lane whose first crossing lies further in (a false
+    plateau that ended before the start it aligned) reads as not
+    found, where a whole-window scan locked onto a later frame."""
+    from ziria_tpu.utils.dispatch import pow2_ceil
+
+    pick_end = sync.LTS_OFFSET + sync.ALIGN_SPAN
+    timing = pick_end + sync.LTS_PAIR_SPAN
+    heads = pick_end - sync.LTS_OFFSET + FRAME_DATA_START
+    return min(pow2_ceil(max(timing, heads)), win_len)
+
+
 def stream_chunk_graph(chunk, chunk_valid, own_lo, own_hi, k: int,
                        win_len: int, n_sym_bucket: int,
                        threshold: float = 0.75, min_run: int = 33,
@@ -1005,7 +1033,10 @@ def stream_chunk_graph(chunk, chunk_valid, own_lo, own_hi, k: int,
        oracle would see for `stream[max(start,0) : +win_len]`).
     4. the vmapped per-window acquisition (detect gate, LTS timing,
        CFO, SIGNAL decode) with per-lane true counts and own-bucket
-       detector caps, and
+       detector caps, over the window's first `_acquire_head` samples
+       — all it reads of a window that starts at its frame; a whole-
+       window scan there located every frame a second time, 36 ms of
+       a chunk-step at 64 windows x 65 536 (chip runs, PR 30) — and
     5. gather+derotate of every window's data region at the ONE static
        symbol bucket (garbage on failed lanes, masked host-side).
 
@@ -1027,7 +1058,7 @@ def stream_chunk_graph(chunk, chunk_valid, own_lo, own_hi, k: int,
         found, starts, overflow = sync.locate_frames(
             chunk, k, limit=chunk_valid, threshold=threshold,
             min_run=min_run, dead_zone=dead_zone,
-            overflow_limit=own_hi + 224)
+            overflow_limit=own_hi + sync.LTS_OFFSET + sync.ALIGN_BACK)
     with jax.named_scope("rx.scan.window"):
         own = found & (starts >= own_lo) & (starts < own_hi)
         starts = jnp.where(own, jnp.maximum(starts, 0), starts)
@@ -1044,8 +1075,10 @@ def stream_chunk_graph(chunk, chunk_valid, own_lo, own_hi, k: int,
                       0, win_len).astype(jnp.int32)
         lim = _stream_bucket_graph(nv, win_len)
     with jax.named_scope("rx.scan.acquire"):
+        # nv stays the window's true count: `found`'s avail gate reads it
+        head = _acquire_head(win_len)
         f2, fstart, eps, rb, ln, pk = jax.vmap(acquire_frame_graph)(
-            wins, nv, lim)
+            wins[:, :head], nv, jnp.minimum(lim, head))
     with jax.named_scope("rx.scan.gather"):
         need_b = FRAME_DATA_START + 80 * n_sym_bucket
         wins_pad = jnp.pad(wins, ((0, 0), (0, need_b), (0, 0)))
