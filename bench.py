@@ -1257,7 +1257,7 @@ def _child_main(run_id):
     # ISSUE 20 tentpole evidence: the rate-switched fused decode on
     # the mixed/stream path — identity-gated (lane-for-lane vs the
     # unfused mixed trellis, radix 2 and 4) with the analytical
-    # cost_of(_jit_stream_decode) bytes_accessed delta fused vs
+    # cost_of(_jit_stream_decode_multi) bytes_accessed delta fused vs
     # unfused at the suite-shared geometry. On CPU the fused sps pays
     # interpret-mode dispatch overhead for the in-kernel 8-rate front
     # (the win is priced by the bytes delta until a chip run
@@ -1456,7 +1456,7 @@ def _child_main(run_id):
         ev = _load_rx_dispatch_bench().streaming_stats(
             n_frames=8 if cpu else 16, trace_path=trace_path)
         chunk_lat = ev.get("latency_ms_streaming", {}).get(
-            "rx.stream_chunk", {})
+            "rx.stream_chunk_multi", {})
         note(f"streaming rx: {ev['frames']} frames / "
              f"{ev['chunks']} chunks, "
              f"{ev['dispatches_percapture']} dispatches -> "

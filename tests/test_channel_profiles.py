@@ -481,7 +481,7 @@ def test_hostile_stream_and_channel_chaos_contained():
     # chaos grammar: per-slab channel corruption at the push seam
     (clean,), _ = _std_streams(1, None, seed=33)
     specs, cseed = faults.parse_chaos_spec(
-        "seed=5;rx.push:channel:profile=severe,every=2")
+        "seed=5;rx.push.s0:channel:profile=severe,every=2")
     sr2 = framebatch.StreamReceiver(chunk_len=CHUNK,
                                     frame_len=FRAME_LEN,
                                     max_frames_per_chunk=K,

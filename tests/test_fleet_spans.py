@@ -202,7 +202,7 @@ def test_window_wider_than_the_acquisition_head_loses_no_lane():
         want.append(list(starts))
     with telemetry.tracing() as tr:
         got, stats = framebatch.receive_streams(
-            streams, multi=True, chunk_len=4 * frame_len,
+            streams, chunk_len=4 * frame_len,
             frame_len=frame_len, max_frames_per_chunk=K, check_fcs=True)
     assert [[f.start for f in r] for r in got] == want
     assert all(f.result.ok and f.result.crc_ok for r in got for f in r)
@@ -275,19 +275,28 @@ def test_annotation_takes_args_as_keywords(monkeypatch):
     assert seen == []
 
 
-def test_single_stream_receiver_names_no_fleet_span():
+def test_single_stream_receiver_names_the_fleets_spans():
+    """A lone stream is a fleet of one: `receive_stream` runs the
+    same named steps, one set per chunk-step, keyed by step id."""
     rng = np.random.default_rng(5)
     st, _ = link.stream_many(
         [rng.integers(0, 256, N_BYTES).astype(np.uint8)], [24],
         snr_db=30.0, cfo=1e-4, delay=60, seed=9, add_fcs=True,
         tail=FRAME_LEN)
     with telemetry.tracing() as tr:
-        frames, _stats = framebatch.receive_stream(
+        frames, stats = framebatch.receive_stream(
             st, chunk_len=CHUNK, frame_len=FRAME_LEN,
             max_frames_per_chunk=K, check_fcs=True)
     assert [f.result.ok for f in frames] == [True]
-    assert not any(e["name"].startswith("rx.fleet.")
-                   for e in tr.events())
+    steps = {}
+    for e in tr.events():
+        if e["name"].startswith("rx.fleet."):
+            steps.setdefault(e["name"], []).append(e["args"]["step"])
+    want = list(range(stats.chunks))
+    for name in ("rx.fleet.stack", "rx.fleet.put", "rx.fleet.pull_scan",
+                 "rx.fleet.classify", "rx.fleet.emit"):
+        assert steps[name] == want, (name, steps)
+    assert len(steps["rx.fleet.decode"]) == 1     # the one frame
 
 
 # ------------------------------------------- names inside the programs

@@ -166,8 +166,8 @@ def test_default_geometry_compiles_nothing_new(corpus):
     the already-compiled geometry adds ZERO programs to any streaming
     cache and emits bit-identical frames."""
     stream, starts, got_legacy = corpus
-    with dispatch.no_recompile(rx._jit_stream_chunk,
-                               rx._jit_stream_decode):
+    with dispatch.no_recompile(rx._jit_stream_chunk_multi,
+                               rx._jit_stream_decode_multi):
         got_geo, _ = framebatch.receive_stream(
             stream, streaming=True, check_fcs=True, geometry=GEO)
     assert [f.start for f in got_geo] == list(starts)
@@ -182,8 +182,8 @@ def test_stream_receiver_ctor_geometry_equals_legacy_kwargs(corpus):
     # (= compile keys + checkpoint identity) included
     r_geo = framebatch.StreamReceiver(geometry=GEO, check_fcs=True)
     r_old = framebatch.StreamReceiver(**LEGACY_KW)
-    assert framebatch._stream_geometry(r_geo) == \
-        framebatch._stream_geometry(r_old)
+    assert framebatch._stream_geometry(r_geo.fleet) == \
+        framebatch._stream_geometry(r_old.fleet)
     # explicit per-knob args still override the geometry object
     r_mix = framebatch.StreamReceiver(geometry=GEO, chunk_len=8192,
                                       check_fcs=True)

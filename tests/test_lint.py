@@ -166,7 +166,6 @@ def test_r1_reflags_dropped_cache_key_param_in_real_rx_factory():
 
 
 @pytest.mark.parametrize("factory", ["_jit_decode_data_mixed",
-                                     "_jit_stream_decode",
                                      "_jit_stream_decode_multi"])
 def test_r1_guards_fused_demap_key_in_mixed_decode_factories(factory):
     """ISSUE 20 satellite: every MIXED-decode jit factory now carries

@@ -90,8 +90,7 @@ def _tier1_driver():
         tail=FRAME_LEN)
     framebatch.receive_streams(streams, chunk_len=CHUNK,
                                frame_len=FRAME_LEN,
-                               max_frames_per_chunk=K, check_fcs=True,
-                               multi=True)
+                               max_frames_per_chunk=K, check_fcs=True)
 
 
 @pytest.fixture(scope="module")
@@ -123,9 +122,7 @@ def test_driver_covers_the_streaming_and_batched_factories(report):
     # factories the tier-1 driver exercises must all map back to a
     # noted program; the full-driver CLI covers the rest (slow test)
     uncovered = set(report["uncovered"])
-    for fq in ("ziria_tpu.phy.wifi.rx._jit_stream_chunk",
-               "ziria_tpu.phy.wifi.rx._jit_stream_decode",
-               "ziria_tpu.phy.wifi.rx._jit_stream_chunk_multi",
+    for fq in ("ziria_tpu.phy.wifi.rx._jit_stream_chunk_multi",
                "ziria_tpu.phy.wifi.rx._jit_stream_decode_multi",
                "ziria_tpu.phy.wifi.rx._jit_decode_data_mixed",
                "ziria_tpu.phy.wifi.rx._jit_acquire_many",
@@ -148,12 +145,11 @@ def test_factory_discovery_is_ast_driven():
     names = {f"{f['module']}.{f['name']}" for f in facs}
     # the jit factories of the tree are found by the R1 convention —
     # and table/kernel lru_caches (no jit in the body) are NOT
-    assert "ziria_tpu.phy.wifi.rx._jit_stream_chunk" in names
     assert "ziria_tpu.phy.wifi.rx._jit_stream_chunk_multi" in names
     assert "ziria_tpu.phy.wifi.rx._jit_stream_decode_multi" in names
     assert "ziria_tpu.phy.link._jit_fused_link" in names
     assert "ziria_tpu.ops.interleave.interleave_perm" not in names
-    assert len(facs) >= 18
+    assert len(facs) >= 16
 
 
 # ------------------------------------------------------------- cost pins
@@ -169,24 +165,26 @@ def _pin_check(cost, pin):
 
 
 def test_stream_chunk_cost_pinned():
-    # rx.stream_chunk_graph behind _jit_stream_chunk at the canonical
-    # (K=8, 1024-window, 8-symbol) geometry on the 4096-sample chunk
-    fn = rx._jit_stream_chunk(K, FRAME_LEN, SYM_B)
+    # rx.stream_chunk_graph behind _jit_stream_chunk_multi at the
+    # canonical (K=8, 1024-window, 8-symbol) geometry on the
+    # 4096-sample chunk, one stream wide
+    fn = rx._jit_stream_chunk_multi(K, FRAME_LEN, SYM_B)
     S, i32 = jax.ShapeDtypeStruct, jnp.int32
-    cost = P.cost_of(fn, S((CHUNK, 2), jnp.float32), S((), i32),
-                     S((), i32), S((), i32))
+    cost = P.cost_of(fn, S((1, CHUNK, 2), jnp.float32), S((1,), i32),
+                     S((1,), i32), S((1,), i32))
     _pin_check(cost, STREAM_CHUNK_PIN)
 
 
 def test_stream_decode_cost_pinned():
-    # _jit_stream_decode (row-select + mixed decode + masked CRC) at
-    # the same geometry; a dropped carry re-evaluating the decode
-    # would ~double both pinned numbers
+    # _jit_stream_decode_multi (row-select + mixed decode + masked
+    # CRC) at the same geometry; a dropped carry re-evaluating the
+    # decode would ~double both pinned numbers
     need_b = rx.FRAME_DATA_START + 80 * SYM_B
-    fn = rx._jit_stream_decode(SYM_B, None, None, 2)
+    fn = rx._jit_stream_decode_multi(SYM_B, None, None, 2)
     S, i32 = jax.ShapeDtypeStruct, jnp.int32
-    cost = P.cost_of(fn, S((K, need_b, 2), jnp.float32), S((K,), i32),
-                     S((K,), i32), S((K,), i32), S((K,), i32))
+    cost = P.cost_of(fn, S((1, K, need_b, 2), jnp.float32),
+                     S((1, K), i32), S((1, K), i32), S((1, K), i32),
+                     S((1, K), i32))
     _pin_check(cost, STREAM_DECODE_PIN)
 
 
@@ -198,13 +196,13 @@ def test_stream_decode_fused_cost_pinned_below_unfused():
     # (e.g. a bank re-materialized per chunk) fails tier-1 loudly
     need_b = rx.FRAME_DATA_START + 80 * SYM_B
     S, i32 = jax.ShapeDtypeStruct, jnp.int32
-    avals = (S((K, need_b, 2), jnp.float32), S((K,), i32),
-             S((K,), i32), S((K,), i32), S((K,), i32))
-    cost_u = P.cost_of(rx._jit_stream_decode(SYM_B, None, None, 2),
-                       *avals)
+    avals = (S((1, K, need_b, 2), jnp.float32), S((1, K), i32),
+             S((1, K), i32), S((1, K), i32), S((1, K), i32))
+    cost_u = P.cost_of(
+        rx._jit_stream_decode_multi(SYM_B, None, None, 2), *avals)
     cost_f = P.cost_of(
-        rx._jit_stream_decode(SYM_B, None, None, 2, False, True),
-        *avals)
+        rx._jit_stream_decode_multi(SYM_B, None, None, 2,
+                                    fused_demap=True), *avals)
     _pin_check(cost_f, STREAM_DECODE_FUSED_PIN)
     assert cost_f["bytes_accessed"] < cost_u["bytes_accessed"], (
         cost_f, cost_u)
@@ -223,8 +221,7 @@ def test_note_site_is_free_when_idle():
 
 def test_site_costs_join_on_dispatch_labels(report):
     labels = {r["label"] for r in report["programs"]}
-    for lbl in ("rx.stream_chunk", "rx.stream_decode",
-                "rx.stream_chunk_multi", "rx.stream_decode_multi",
+    for lbl in ("rx.stream_chunk_multi", "rx.stream_decode_multi",
                 "rx.decode_mixed", "rx.crc_many", "rx.acquire_many",
                 "tx.encode_many"):
         assert lbl in labels, sorted(labels)

@@ -94,8 +94,7 @@ def corpus():
         s_psdus, s_rates, snr_db=30.0, cfo=1e-4, delay=60, seed=33,
         add_fcs=True, tail=FRAME_LEN,
         gaps=[[9000], None, None, None])
-    res_c, st_c = framebatch.receive_streams(streams, multi=True,
-                                             **GEO)
+    res_c, st_c = framebatch.receive_streams(streams, **GEO)
     for i in range(4):
         assert [f.start for f in res_c[i]] == list(fstarts[i])
     return stream, starts, frames_c, streams, fstarts, res_c
@@ -105,7 +104,8 @@ def corpus():
 
 
 def test_fault_plan_deterministic_replay():
-    specs = (faults.FaultSpec("rx.stream_chunk", "transient", every=3),
+    specs = (faults.FaultSpec("rx.stream_chunk_multi", "transient",
+                              every=3),
              faults.FaultSpec("rx.push.s*", "nan_slab", calls=(1,)))
 
     def run():
@@ -113,7 +113,7 @@ def test_fault_plan_deterministic_replay():
         with faults.inject(*specs, seed=7) as plan:
             for i in range(9):
                 try:
-                    faults.maybe_fail("rx.stream_chunk")
+                    faults.maybe_fail("rx.stream_chunk_multi")
                 except faults.InjectedTransientError:
                     fired.append(i)
             a = np.ones((16, 2), np.float32)
@@ -132,7 +132,7 @@ def test_fault_plan_deterministic_replay():
     assert np.array_equal(np.isnan(s1[1]), np.isnan(s2[1]))
     # inactive outside the scope
     assert not faults.active()
-    faults.maybe_fail("rx.stream_chunk")      # no-op, no raise
+    faults.maybe_fail("rx.stream_chunk_multi")  # no-op, no raise
 
 
 def test_fault_spec_validation_and_truncate():
@@ -159,11 +159,11 @@ def test_fault_spec_validation_and_truncate():
 
 def test_parse_chaos_spec_and_env(monkeypatch):
     specs, seed = faults.parse_chaos_spec(
-        "seed=3;rx.stream_chunk:transient:every=7;"
+        "seed=3;rx.stream_chunk_multi:transient:every=7;"
         "rx.push.s*:nan_slab:calls=1+4,frac=0.5")
     assert seed == 3
-    assert specs[0] == faults.FaultSpec("rx.stream_chunk", "transient",
-                                        every=7)
+    assert specs[0] == faults.FaultSpec("rx.stream_chunk_multi",
+                                        "transient", every=7)
     assert specs[1].calls == (1, 4) and specs[1].fraction == 0.5
     # a bare spec fires every call
     (sp,), _ = faults.parse_chaos_spec("link.fused:fatal")
@@ -284,7 +284,7 @@ def test_disabled_path_overhead_pinned():
     arr = np.ones((4, 2), np.float32)
     t0 = time.perf_counter()
     for _ in range(n):
-        faults.maybe_fail("rx.stream_chunk")
+        faults.maybe_fail("rx.stream_chunk_multi")
     t_fail = time.perf_counter() - t0
     t0 = time.perf_counter()
     for _ in range(n):
@@ -332,7 +332,7 @@ def test_sanitize_counts_and_quarantines():
     bad[5, 0] = np.inf
     sr.push(bad)
     assert sr.stats.sanitized == 2 and sr.stats.quarantines == 1
-    assert sr._health.quarantined
+    assert sr.fleet.quarantined(0)
 
 
 # ----------------------------------------------------- lane quarantine
@@ -423,7 +423,8 @@ def test_quarantine_rejoin_after_clean_chunks():
 
 def test_transient_scan_fault_retries_to_identical_frames(corpus):
     stream, starts, frames_c, *_ = corpus
-    spec = faults.FaultSpec("rx.stream_chunk", "transient", every=2)
+    spec = faults.FaultSpec("rx.stream_chunk_multi", "transient",
+                            every=2)
     with telemetry.collect() as reg:
         with faults.inject(spec) as plan:
             frames, stats = framebatch.receive_stream(stream, **GEO)
@@ -437,7 +438,8 @@ def test_transient_scan_fault_retries_to_identical_frames(corpus):
 
 def test_fatal_decode_fault_degrades_to_oracle_identical(corpus):
     stream, starts, frames_c, *_ = corpus
-    spec = faults.FaultSpec("rx.stream_decode", "fatal", every=1)
+    spec = faults.FaultSpec("rx.stream_decode_multi", "fatal",
+                            every=1)
     with telemetry.collect() as reg:
         with dispatch.count_dispatches() as d:
             with faults.inject(spec) as plan:
@@ -456,7 +458,8 @@ def test_fatal_decode_fault_degrades_to_oracle_identical(corpus):
 
 def test_fatal_scan_fault_degrades_to_eager_identical(corpus):
     stream, starts, frames_c, *_ = corpus
-    spec = faults.FaultSpec("rx.stream_chunk", "fatal", calls=(1,))
+    spec = faults.FaultSpec("rx.stream_chunk_multi", "fatal",
+                            calls=(1,))
     with dispatch.count_dispatches() as d:
         with faults.inject(spec) as plan:
             frames, stats = framebatch.receive_stream(stream, **GEO)
@@ -464,12 +467,12 @@ def test_fatal_scan_fault_degrades_to_eager_identical(corpus):
     _same_frames(frames, frames_c)
     assert stats.degraded
     # the eager twin is its own instrumented site
-    assert d.counts["rx.stream_chunk.eager"] >= 1
+    assert d.counts["rx.stream_chunk_multi.eager"] >= 1
 
 
 def test_injected_hang_cut_by_watchdog_identical(corpus):
     stream, starts, frames_c, *_ = corpus
-    spec = faults.FaultSpec("rx.stream_chunk", "hang", calls=(1,),
+    spec = faults.FaultSpec("rx.stream_chunk_multi", "hang", calls=(1,),
                             delay_s=5.0)
     t0 = time.perf_counter()
     with faults.inject(spec):
@@ -500,9 +503,8 @@ def test_async_pull_failure_rescans_chunk(corpus):
         sr = framebatch.StreamReceiver(**GEO)
         frames = sr.push(stream)
         # sabotage the in-flight chunk's device handles
-        off, arr, valid, own_hi, _outs = sr._pending
-        sr._pending = (off, arr, valid, own_hi,
-                       tuple(_Unpullable() for _ in range(11)))
+        sr.fleet._pending = sr.fleet._pending[:6] + (
+            tuple(_Unpullable() for _ in range(11)),)
         frames += sr.flush()
     _same_frames(frames, frames_c)
     assert not sr.stats.degraded     # the rescan's compiled path won
@@ -535,8 +537,7 @@ def test_multi_transient_and_fatal_fleet_recovery(corpus):
              faults.FaultSpec("rx.stream_decode_multi", "fatal",
                               calls=(0,)))
     with faults.inject(*specs) as plan:
-        res, stats = framebatch.receive_streams(streams, multi=True,
-                                                **GEO)
+        res, stats = framebatch.receive_streams(streams, **GEO)
     assert plan.total_fired == 2
     for i in range(4):
         _same_frames(res[i], res_c[i])
@@ -660,14 +661,14 @@ def test_checkpoint_preserves_quarantine_and_degraded_state():
     bad = np.zeros((16, 2), np.float32)
     bad[3] = np.nan
     sr.push(bad)
-    sr._mark_degraded(scan=False)
+    sr.fleet._mark_degraded(scan=False)
     state, _ = sr.checkpoint()
     sr2 = framebatch.StreamReceiver(sanitize=True, checkpoint=state,
                                     **GEO)
-    assert sr2._health.quarantined and sr2._dirty
+    assert sr2.fleet.quarantined(0) and sr2.fleet._dirty[0]
     assert sr2.stats.quarantines == 1
     assert sr2.stats.sanitized == sr.stats.sanitized == 1
-    assert sr2.stats.degraded and sr2._degraded
+    assert sr2.stats.degraded and sr2.fleet._degraded
 
 
 def test_raw_carry_without_geometry_refuses_restore(corpus):
@@ -677,7 +678,7 @@ def test_raw_carry_without_geometry_refuses_restore(corpus):
     stream, *_ = corpus
     sr = framebatch.StreamReceiver(**GEO)
     sr.push(stream[:CHUNK // 2])
-    blob = resilience.checkpoint_carry(sr.carry, seen=sr._seen)
+    blob = resilience.checkpoint_carry(sr.carry, seen=sr.fleet._seen[0])
     with pytest.raises(resilience.CarryCheckpointError,
                        match="lacks geometry fields"):
         framebatch.StreamReceiver(checkpoint=blob, **GEO)
@@ -707,6 +708,91 @@ def test_plain_oracle_propagates_decode_blowups(corpus, monkeypatch):
     assert frames == []                     # dropped, loudly counted
     assert sr2.stats.lane_blowups >= 2
     assert sr2.stats.quarantines >= 1       # blowup_limit=2 reached
+
+
+def test_fleet_plain_oracle_propagates_and_contains(corpus,
+                                                    monkeypatch):
+    """The same boundary, per lane of the fleet (where the rule
+    lives): ``streaming=False`` decodes the same owned windows through
+    per-capture `rx.receive` — identical frames, no compiled decode
+    dispatched — a blowup there propagates in the plain oracle, and
+    under sanitize=True is dropped, counted and charged to ITS lane's
+    health while the lane-mate keeps its frames."""
+    _s, _st, _fc, streams, fstarts, res_c = corpus
+    from ziria_tpu.phy.wifi import rx as _rx
+
+    def run(**kw):
+        msr = framebatch.MultiStreamReceiver(2, **kw, **GEO)
+        got = msr.push_many(list(streams[:2])) + msr.flush()
+        return [[f for i, f in got if i == lane]
+                for lane in range(2)], msr
+
+    with dispatch.count_dispatches() as d:
+        per, _msr = run(streaming=False)
+    assert "rx.stream_decode_multi" not in d.counts
+    assert d.counts["rx.sync"] >= sum(len(p) for p in per) >= 4
+    for lane in range(2):
+        _same_frames(per[lane], res_c[lane])    # == streaming=True
+
+    real = _rx.receive
+    heads = [streams[1][s: s + 64] for s in fstarts[1]]
+
+    def boom(win, **kw):                        # lane 1's frames only
+        if any(np.array_equal(win[:64], h) for h in heads):
+            raise RuntimeError("genuine decoder defect")
+        return real(win, **kw)
+
+    monkeypatch.setattr(_rx, "receive", boom)
+    with pytest.raises(RuntimeError, match="genuine decoder defect"):
+        run(streaming=False)
+    per, msr = run(streaming=False, sanitize=True)
+    _same_frames(per[0], res_c[0])              # lane-mate untouched
+    assert per[1] == []                         # dropped, loudly
+    assert msr.stats.lane_blowups == len(heads) == 2
+    assert msr._health[1].quarantines == 1      # blowup_limit=2
+    assert msr._health[0].quarantines == 0
+    assert not msr.stats.degraded
+
+
+def test_parent_format_checkpoint_restores_into_the_face(corpus):
+    """`StreamReceiver(checkpoint=...)` takes up the WHOLE single-
+    stream rider — `restore_stream` alone leaves the degraded flags,
+    the containment counters and the emitted count to the old runtime
+    — so a blob in the format every single-stream receiver has
+    written restores with those values in `stats`, resumes degraded
+    (the oracle twin decodes the rest) and emits bit-identical
+    subsequent frames; and what `checkpoint()` writes is that same
+    nine-key rider."""
+    stream, _starts, frames_c, *_ = corpus
+    cut = stream.shape[0] // 2
+    sr1 = framebatch.StreamReceiver(**GEO)
+    first = sr1.push(stream[:cut])
+    blob, drained = sr1.checkpoint()
+    first += drained
+    st = resilience.restore_carry(blob)
+    assert set(st.state) == {
+        "quarantined", "clean", "blowups", "quarantines", "dirty",
+        "sanitized", "lane_blowups", "degraded", "scan_degraded"}
+    assert st.emitted == len(first)
+    parent_blob = resilience.checkpoint_carry(
+        st, seen=st.seen, geometry=st.geometry,
+        state=dict(st.state, sanitized=5, lane_blowups=3,
+                   degraded=True))
+    sr2 = framebatch.StreamReceiver(checkpoint=parent_blob, **GEO)
+    assert sr2.stats.sanitized == 5 and sr2.stats.lane_blowups == 3
+    assert sr2.stats.degraded and sr2.fleet._degraded
+    assert not sr2.fleet._scan_degraded
+    assert sr2.stats.frames == sr2.carry.emitted == len(first)
+    # the face's own checkpoint carries the counters on
+    st2 = resilience.restore_carry(sr2.checkpoint()[0]).state
+    assert (st2["sanitized"], st2["lane_blowups"], st2["degraded"]) \
+        == (5, 3, True)
+    with dispatch.count_dispatches() as d:
+        rest = sr2.push(stream[cut:]) + sr2.flush()
+    assert d.counts["rx.stream_chunk_multi"] >= 1
+    assert "rx.stream_decode_multi" not in d.counts
+    _same_frames(first + rest, frames_c)
+    assert sr2.stats.frames == len(frames_c) and len(rest) >= 1
 
 
 def test_checkpoint_geometry_mismatch_rejected(corpus):
@@ -748,7 +834,7 @@ def test_checkpoint_restore_quarantined_and_degraded_emissions(corpus):
         bad = np.zeros((16, 2), np.float32)
         bad[3] = np.nan
         out = sr.push(bad)                   # -> quarantined
-        sr._mark_degraded(scan=False)        # -> decode oracle twin
+        sr.fleet._mark_degraded(scan=False)  # -> decode oracle twin
         if split is None:
             out += sr.push(stream)
         else:
@@ -758,7 +844,7 @@ def test_checkpoint_restore_quarantined_and_degraded_emissions(corpus):
             sr = framebatch.StreamReceiver(
                 sanitize=True, rejoin_after=2, checkpoint=blob,
                 **GEO)
-            assert sr._health.quarantined and sr._degraded
+            assert sr.fleet.quarantined(0) and sr.fleet._degraded
             out += sr.push(stream[split:])
         out += sr.flush()
         return out, sr.stats
@@ -792,7 +878,7 @@ def test_cross_product_blob_restores_into_fleet_lane(corpus):
         bad = np.zeros((16, 2), np.float32)
         bad[3] = np.nan
         out = sr.push(bad)
-        sr._mark_degraded(scan=False)
+        sr.fleet._mark_degraded(scan=False)
         out += sr.push(stream[:split] if split else stream)
         return sr, out
 
@@ -939,7 +1025,7 @@ BROKEN = {"trace": (_untraceable, TypeError),
 
 def _site_single_scan(corpus, bad, _mp):
     sr = framebatch.StreamReceiver(**GEO)
-    sr._jit1 = bad
+    sr.fleet._jit1 = bad
     try:
         sr.push(corpus[0])
         sr.flush()
@@ -948,7 +1034,7 @@ def _site_single_scan(corpus, bad, _mp):
 
 
 def _site_single_decode(corpus, bad, mp):
-    mp.setattr(_rx, "_jit_stream_decode", lambda *a, **k: bad)
+    mp.setattr(_rx, "_jit_stream_decode_multi", lambda *a, **k: bad)
     sr = framebatch.StreamReceiver(**GEO)
     try:
         sr.push(corpus[0])
@@ -1007,8 +1093,8 @@ def _site_link_sweep(_corpus, bad, mp):
 
 
 GUARDED_SITES = {
-    "rx.stream_chunk": _site_single_scan,
-    "rx.stream_decode": _site_single_decode,
+    "face:rx.stream_chunk_multi": _site_single_scan,
+    "face:rx.stream_decode_multi": _site_single_decode,
     "rx.stream_chunk_multi": _site_fleet_scan,
     "rx.stream_decode_multi": _site_fleet_decode,
     "serve.step": _site_serve_step,

@@ -1,12 +1,12 @@
 """Multi-stream fleet receiver (backend/framebatch.receive_streams +
 MultiStreamReceiver + rx._jit_stream_chunk_multi/_jit_stream_decode_multi):
 S concurrent I/Q streams' chunks stacked on a leading stream axis
-through stream-axis-vmapped twins of the two compiled streaming
-programs — <= 2 device dispatches per CHUNK-STEP independent of S —
-with every emitted frame bit-identical, lane for lane and RxResult
-field for field, to S independent single-stream `StreamReceiver`s
-(and hence, transitively, to per-capture `rx.receive` over the
-slice — the PR 5 contract).
+through the two compiled streaming programs — <= 2 device dispatches
+per CHUNK-STEP independent of S — with every emitted frame
+bit-identical, lane for lane and RxResult field for field, to that
+stream received ALONE (`receive_stream`: a fleet of one) and hence,
+transitively, to per-capture `rx.receive` over the slice — the PR 5
+contract.
 
 Budget discipline (the tier-1 870 s cutoff is real): ONE module
 fixture pays the S=8 fleet compiles at the suite-shared streaming
@@ -50,8 +50,8 @@ def corpus():
     """An 8-stream fleet load: all 8 rates spread across the streams,
     one stream whose second frame straddles its chunk boundary, one
     all-noise stream, one EMPTY stream, ragged lengths — plus one
-    fleet pass and one S-independent-receivers oracle pass, both
-    under dispatch counters."""
+    fleet pass and one pass of every stream alone (S fleets of one),
+    both under dispatch counters."""
     rng = np.random.default_rng(20260803)
 
     def psdus(n):
@@ -85,18 +85,18 @@ def corpus():
     assert starts[1][1] == 3800 and starts[1][1] + 480 > CHUNK
 
     with dispatch.count_dispatches() as d_m:
-        res_m, st_m = framebatch.receive_streams(streams, multi=True,
-                                                 **GEO)
+        res_m, st_m = framebatch.receive_streams(streams, **GEO)
+    # the reference: every stream ALONE through `receive_stream`
     with dispatch.count_dispatches() as d_o:
-        res_o, st_o = framebatch.receive_streams(streams, multi=False,
-                                                 **GEO)
+        lone = [framebatch.receive_stream(st, **GEO) for st in streams]
+    res_o, st_o = [r for r, _ in lone], [st for _, st in lone]
     return streams, starts, res_m, st_m, d_m, res_o, st_o, d_o
 
 
 def test_fleet_bit_identical_to_s_independent_receivers(corpus):
     # THE fleet contract: per stream, frame for frame, every emitted
-    # start and RxResult (crc_ok included) equals what a lone
-    # single-stream receiver emits — mixed rates, straddle, noise,
+    # start and RxResult (crc_ok included) equals what that stream
+    # received alone emits — mixed rates, straddle, noise,
     # empty, and ragged lengths all riding one stream axis
     streams, starts, res_m, _st, _d, res_o, _so, _do = corpus
     assert len(res_m) == len(res_o) == S
@@ -138,7 +138,7 @@ def test_lanes_the_chunk_does_not_own_are_masked_host_side(
         return real(self, pend[:6] + (tuple(outs),))
 
     monkeypatch.setattr(framebatch.MultiStreamReceiver, "_drain", drain)
-    res, _stats = framebatch.receive_streams(streams, multi=True, **GEO)
+    res, _stats = framebatch.receive_streams(streams, **GEO)
     assert sum(forged) > 0
     for i in range(S):
         _same_frames(res[i], res_m[i])
@@ -157,17 +157,19 @@ def test_straddling_frame_decoded_exactly_once_in_fleet(corpus):
 def test_dispatches_per_chunk_step_independent_of_s(corpus):
     # the tentpole number at S=8: <= 2 dispatches per CHUNK-STEP
     # (one stacked scan + at most one flattened decode), however many
-    # streams ride the step — vs the oracle's per-stream chunk costs
+    # streams ride the step — vs the per-stream chunk costs of S
+    # fleets of one
     _s, _starts, _rm, st_m, d_m, _ro, st_o, d_o = corpus
     assert st_m.streams == S and st_m.chunk_steps >= 2
     assert d_m.total <= 2 * st_m.chunk_steps, dict(d_m.counts)
     assert d_m.counts["rx.stream_chunk_multi"] == st_m.chunk_steps
     assert d_m.counts["rx.stream_decode_multi"] <= st_m.chunk_steps
-    # the oracle pays one scan per PER-STREAM chunk: strictly more
-    # scans than the fleet's chunk-steps (7 non-empty streams)
-    assert d_o.counts["rx.stream_chunk"] == st_o.chunk_steps
-    assert st_o.chunk_steps > st_m.chunk_steps
-    assert st_m.frames == st_o.frames
+    # alone, every stream pays one scan per chunk of its own: strictly
+    # more scans than the fleet's chunk-steps (7 non-empty streams)
+    lone_chunks = sum(st.chunks for st in st_o)
+    assert d_o.counts["rx.stream_chunk_multi"] == lone_chunks
+    assert lone_chunks > st_m.chunk_steps
+    assert st_m.frames == sum(st.frames for st in st_o)
     # double-buffering still overlaps at fleet scale
     assert d_m.gauges["rx.stream_inflight"] == 2
     assert st_m.max_in_flight == 2
@@ -191,11 +193,10 @@ def test_active_streams_gauge_and_per_stream_carry_rows(corpus):
 
 def test_dispatch_pin_at_s1(corpus):
     # S=1 is the degenerate fleet: same <= 2-per-chunk-step pin, and
-    # bit-identity with the single-stream receiver it wraps
+    # what `receive_stream` (which unwraps its lane 0) emits
     streams, _starts, _rm, _st, _d, res_o, _so, _do = corpus
     with dispatch.count_dispatches() as d1:
-        res_1, st_1 = framebatch.receive_streams(streams[:1],
-                                                 multi=True, **GEO)
+        res_1, st_1 = framebatch.receive_streams(streams[:1], **GEO)
     assert st_1.streams == 1 and st_1.chunk_steps >= 1
     assert d1.total <= 2 * st_1.chunk_steps, dict(d1.counts)
     _same_frames(res_1[0], res_o[0])
@@ -213,7 +214,7 @@ def test_sharded_fleet_on_suite_mesh_bit_identical(corpus):
     mesh = frame_mesh(8)
     with dispatch.count_dispatches() as d_sh:
         res_s, st_s = framebatch.receive_streams(
-            streams, multi=True, mesh=mesh, **GEO)
+            streams, mesh=mesh, **GEO)
     assert d_sh.total <= 2 * st_s.chunk_steps, dict(d_sh.counts)
     for i in range(S):
         _same_frames(res_s[i], res_m[i])
@@ -228,8 +229,7 @@ def test_all_noise_fleet_costs_one_dispatch_per_step(corpus):
     noise = [rng.normal(scale=0.05, size=(2 * CHUNK, 2))
              .astype(np.float32) for _ in range(S)]
     with dispatch.count_dispatches() as d:
-        res, stats = framebatch.receive_streams(noise, multi=True,
-                                                **GEO)
+        res, stats = framebatch.receive_streams(noise, **GEO)
     assert all(r == [] for r in res)
     assert stats.frames == 0 and stats.overflow_chunks == 0
     assert d.total == stats.chunk_steps
@@ -283,52 +283,6 @@ def test_single_stream_carry_exposes_watermark(corpus):
     assert sr.carry.emitted == 2
 
 
-def test_multi_stream_env_knob(monkeypatch):
-    # the CLI's scoped-env pattern: default ON, ZIRIA_MULTI_STREAM=0
-    # forces the S-independent-receivers oracle, an explicit argument
-    # wins; any nonzero lane count means ON
-    monkeypatch.delenv("ZIRIA_MULTI_STREAM", raising=False)
-    assert framebatch.multi_stream_enabled(None)
-    monkeypatch.setenv("ZIRIA_MULTI_STREAM", "0")
-    assert not framebatch.multi_stream_enabled(None)
-    assert framebatch.multi_stream_enabled(True)
-    monkeypatch.setenv("ZIRIA_MULTI_STREAM", "8")
-    assert framebatch.multi_stream_enabled(None)
-    assert not framebatch.multi_stream_enabled(False)
-
-
-def test_cli_multi_stream_flag_scopes_env(tmp_path, monkeypatch):
-    """--multi-stream S writes ZIRIA_MULTI_STREAM for the invocation
-    only (the scoped-env pattern): a pre-existing value is restored
-    after main() returns, and --no-multi-stream maps to the "0"
-    force-off value."""
-    import os
-
-    from ziria_tpu.runtime.buffers import StreamSpec, write_stream
-    from ziria_tpu.runtime.cli import build_parser, main as cli_main
-
-    args = build_parser().parse_args(["--multi-stream", "4"])
-    assert args.multi_stream == 4
-    args = build_parser().parse_args(["--no-multi-stream"])
-    assert args.multi_stream == 0
-
-    inf, outf = tmp_path / "in.dbg", tmp_path / "out.dbg"
-    rng = np.random.default_rng(0)
-    write_stream(StreamSpec(ty="bit", path=str(inf), mode="dbg"),
-                 rng.integers(0, 2, 16).astype(np.uint8))
-    monkeypatch.setenv("ZIRIA_MULTI_STREAM", "0")
-    rc = cli_main([
-        "--prog=scramble",
-        "--input=file", f"--input-file-name={inf}",
-        "--input-file-mode=dbg", "--input-type=bit",
-        "--output=file", f"--output-file-name={outf}",
-        "--output-file-mode=dbg", "--output-type=bit",
-        "--backend=interp", "--multi-stream", "4",
-    ])
-    assert rc == 0
-    assert os.environ.get("ZIRIA_MULTI_STREAM") == "0"   # restored
-
-
 def test_bad_geometry_and_mesh_divisibility_rejected():
     with pytest.raises(ValueError):
         framebatch.MultiStreamReceiver(0, **GEO)
@@ -341,12 +295,6 @@ def test_bad_geometry_and_mesh_divisibility_rejected():
     from ziria_tpu.parallel.batch import frame_mesh
     with pytest.raises(ValueError):
         framebatch.MultiStreamReceiver(5, mesh=frame_mesh(8), **GEO)
-    # a mesh cannot ride the S-independent-receivers oracle: loud, not
-    # a silently unsharded measurement
-    with pytest.raises(ValueError):
-        framebatch.receive_streams(
-            [np.zeros((8, 2), np.float32)], multi=False,
-            mesh=frame_mesh(8), **GEO)
     msr = framebatch.MultiStreamReceiver(2, **GEO)
     with pytest.raises(IndexError):
         msr.push(2, np.zeros((4, 2), np.float32))

@@ -97,8 +97,8 @@ def test_o_chunks_dispatches_vs_o_frames(corpus):
     n = len(starts)
     assert st_s.chunks >= 2                   # the stream really chunks
     assert d_st.total <= 2 * st_s.chunks, dict(d_st.counts)
-    assert d_st.counts["rx.stream_chunk"] == st_s.chunks
-    assert d_st.counts["rx.stream_decode"] <= st_s.chunks
+    assert d_st.counts["rx.stream_chunk_multi"] == st_s.chunks
+    assert d_st.counts["rx.stream_decode_multi"] <= st_s.chunks
     assert d_pc.total >= 3 * n + 1, dict(d_pc.counts)
     # double-buffering really overlapped: chunk i+1 was in flight
     # before chunk i drained (the utils/dispatch gauge)
@@ -266,7 +266,7 @@ def test_all_noise_chunks_cost_one_dispatch_each(corpus):
     assert stats.frames == 0 and stats.overflow_chunks == 0
     # no decodable lane -> the decode dispatch never fires
     assert d.total == stats.chunks
-    assert d.counts.get("rx.stream_decode", 0) == 0
+    assert d.counts.get("rx.stream_decode_multi", 0) == 0
 
 
 def test_push_flush_carry_threads_across_slabs(corpus):
@@ -279,8 +279,8 @@ def test_push_flush_carry_threads_across_slabs(corpus):
     pushes may only RE-DISPATCH the two compiled chunk programs, never
     mint a fresh compile-cache entry."""
     stream, starts, got_s, _st, _d, _gp, _sp, _dp = corpus
-    with dispatch.no_recompile(rx._jit_stream_chunk,
-                               rx._jit_stream_decode):
+    with dispatch.no_recompile(rx._jit_stream_chunk_multi,
+                               rx._jit_stream_decode_multi):
         sr = framebatch.StreamReceiver(**GEO)
         got = []
         cuts = [0, 777, 3000, 4100, 9001, stream.shape[0]]
@@ -295,6 +295,35 @@ def test_push_flush_carry_threads_across_slabs(corpus):
         assert _same_result(a.result, b.result)
     with pytest.raises(RuntimeError):
         sr.push(stream[:8])                   # closed stream
+
+
+def test_stream_receiver_is_a_fleet_of_one(corpus):
+    """A lone stream is lane 0 of a one-lane fleet: `StreamReceiver`
+    holds ONE `MultiStreamReceiver(n_streams=1)` and no chunk
+    lifecycle, compiled program or pending state of its own — what it
+    dispatches are the fleet's two sites, and `rx` has no other
+    streaming programs to dispatch."""
+    stream, _starts, got_s, _st, _d, _gp, _sp, _dp = corpus
+    with dispatch.count_dispatches() as d:
+        sr = framebatch.StreamReceiver(**GEO)
+        got = sr.push(stream) + sr.flush()
+    assert set(d.counts) == {"rx.stream_chunk_multi",
+                             "rx.stream_decode_multi"}
+    assert d.counts["rx.stream_chunk_multi"] == sr.stats.chunks \
+        == sr.fleet.stats.chunk_steps
+    assert type(sr.fleet) is framebatch.MultiStreamReceiver
+    assert sr.fleet.s == 1 and sr.fleet.mesh is None
+    assert [f.start for f in got] == [f.start for f in got_s]
+    for a, b in zip(got, got_s):
+        assert _same_result(a.result, b.result)
+    for gone in ("chunk", "decode"):     # the fleet's two are `_multi`
+        assert not hasattr(rx, "_jit_stream_" + gone)
+    for name in ("_launch", "_scan_dispatch", "_rescan", "_drain",
+                 "_decode_oracle", "_eager_chunk", "_mark_degraded",
+                 "_jit1", "_pending", "_health"):
+        assert hasattr(sr.fleet, name) and not hasattr(sr, name), name
+    for name in ("_note_emitted", "_runtime_state"):
+        assert not hasattr(sr, name), name
 
 
 def test_stream_bucket_graph_matches_host_rule():

@@ -39,13 +39,13 @@ def main() -> int:
     def run_once():
         fired = []
         with faults.inject(
-                faults.FaultSpec("rx.stream_chunk", "transient",
+                faults.FaultSpec("rx.stream_chunk_multi", "transient",
                                  every=3),
                 faults.FaultSpec("rx.push.s*", "nan_slab",
                                  calls=(1,)), seed=7) as plan:
             for i in range(9):
                 try:
-                    faults.maybe_fail("rx.stream_chunk")
+                    faults.maybe_fail("rx.stream_chunk_multi")
                 except faults.InjectedTransientError:
                     fired.append(i)
             a = np.ones((16, 2), np.float32)

@@ -740,12 +740,12 @@ def streaming_stats(n_frames=16, n_bytes=12, snr_db=30.0,
         "dispatch_times_ms_streaming": d_st.times_ms(),
         "dispatch_times_ms_percapture": d_pc.times_ms(),
         # distribution-level per-site latency (telemetry histograms):
-        # "rx.stream_chunk" is the per-chunk p50/p99 the serving
+        # "rx.stream_chunk_multi" is the per-chunk p50/p99 the serving
         # harness will report against SLOs — not a summed mean
         "latency_ms_streaming": _latency_block(reg_st),
         "latency_ms_percapture": _latency_block(reg_pc),
         # per-site roofline from the compiled graphs: achieved GB/s /
-        # GFLOP/s per dispatch site (rx.stream_chunk is the number the
+        # GFLOP/s per dispatch site (rx.stream_chunk_multi: the number the
         # serving work reports against the hardware ceiling)
         "roofline_by_site": roofline_by_site,
         "trace_path": trace_path,
@@ -817,18 +817,20 @@ def multi_stream_stats(n_streams=8, frames_per_stream=4, n_bytes=12,
 
     with programs.observing() as obs:
         with telemetry.collect() as reg_or:
+            def lone():
+                return [framebatch.receive_stream(s, **kw)[0]
+                        for s in streams]
+
             with count_dispatches() as d_or:
-                res_o, st_o = framebatch.receive_streams(
-                    streams, multi=False, **kw)
-            t_or = _timed(lambda: framebatch.receive_streams(
-                streams, multi=False, **kw))
+                res_o = lone()
+            t_or = _timed(lone)
 
         with telemetry.collect() as reg_ml:
             with count_dispatches() as d_ml:
                 res_m, st_m = framebatch.receive_streams(
-                    streams, multi=True, **kw)
+                    streams, **kw)
             t_ml = _timed(lambda: framebatch.receive_streams(
-                streams, multi=True, **kw))
+                streams, **kw))
 
     gate(res_m, res_o, "fleet vs S independent receivers")
     assert all(f.result.ok and f.result.crc_ok
@@ -857,10 +859,10 @@ def multi_stream_stats(n_streams=8, frames_per_stream=4, n_bytes=12,
             continue
         mesh = pbatch.frame_mesh(n)
         res_s, _st_s = framebatch.receive_streams(
-            streams, multi=True, mesh=mesh, **kw)
+            streams, mesh=mesh, **kw)
         gate(res_s, res_m, f"sharded fleet (dp={n})")
         t_n = _timed(lambda _m=mesh: framebatch.receive_streams(
-            streams, multi=True, mesh=_m, **kw))
+            streams, mesh=_m, **kw))
         sps_by_devices[str(n)] = round(n_samples / t_n, 1)
 
     out = {
@@ -945,8 +947,7 @@ def resilience_stats(n_streams=4, frames_per_stream=3, n_bytes=12,
 
     # fault-free reference (also pre-compiles both fleet programs so
     # the chaos pass times recovery, not first-contact compiles)
-    res_c, st_c = framebatch.receive_streams(streams, multi=True,
-                                             **kw)
+    res_c, st_c = framebatch.receive_streams(streams, **kw)
     per_c = res_c
 
     specs = (
@@ -1564,7 +1565,7 @@ def fused_mixed_stats(B=64, n_bytes=100, noise_sigma=0.3, k1=2, k2=6,
     - marginal step time (K-spread) for the unfused and fused mixed
       decode -> `sps_fused_mixed` / `sps_unfused_mixed` (bench.py's
       fused_mixed stage headline);
-    - the observatory's before/after on `rx._jit_stream_decode` at
+    - the observatory's before/after on `rx._jit_stream_decode_multi` at
       the suite-shared geometry: compiled `bytes_accessed` unfused vs
       fused, asserted STRICTLY lower fused (the roofline claim — the
       LLR round-trip leaves the program, the constant bank it buys is
@@ -1648,11 +1649,12 @@ def fused_mixed_stats(B=64, n_bytes=100, noise_sigma=0.3, k1=2, k2=6,
         max(1, (frame_len - rx.FRAME_DATA_START) // 80))
     need_b = rx.FRAME_DATA_START + 80 * sym_b
     S = jax.ShapeDtypeStruct
-    segs = S((stream_k, need_b, 2), np.float32)
-    row = S((stream_k,), np.int32)
+    segs = S((1, stream_k, need_b, 2), np.float32)
+    row = S((1, stream_k), np.int32)
     for name, fused in (("unfused", False), ("fused", True)):
         c = programs.cost_of(
-            rx._jit_stream_decode(sym_b, None, None, 2, False, fused),
+            rx._jit_stream_decode_multi(sym_b, None, None, 2,
+                                        fused_demap=fused),
             segs, row, row, row, row)
         out[f"stream_decode_bytes_{name}"] = c.get("bytes_accessed")
         out[f"stream_decode_flops_{name}"] = c.get("flops")

@@ -36,7 +36,6 @@ variants, not one per group size.
 
 from __future__ import annotations
 
-import contextlib
 import threading
 from typing import Any, List, NamedTuple, Optional, Sequence
 
@@ -388,20 +387,36 @@ def receive_many_device(x_dev, n_lanes: int, check_fcs: bool = False,
 # ------------------------------------------------------ streaming receiver
 #
 # `receive_many` serves a *batch of pre-segmented captures*; the
-# reference runtime serves a *stream* — an unbounded I/Q sample flow
-# with many frames at unknown offsets. `receive_stream` closes that
-# gap: the stream is cut into fixed-size overlapping chunks, each
-# chunk costs AT MOST TWO device dispatches (the fused multi-peak
-# scan `rx.stream_chunk_graph`, then the fixed-geometry mixed-rate
-# decode — skipped entirely on all-noise chunks), and a carried
-# (tail samples, sample offset, frames emitted) state threads across
-# chunks so every frame is owned by exactly one chunk and decodes
-# bit-identically to slicing `stream[start:start+frame_len]` out and
-# calling per-capture `rx.receive` on it. The dispatch loop is
-# double-buffered: chunk i+1's upload+dispatch is issued BEFORE the
-# host blocks on chunk i's scalars, so the host<->device transfer
-# hides behind compute (in-flight depth on the
-# `utils/dispatch.record_gauge("rx.stream_inflight")` gauge).
+# reference runtime serves *streams* — unbounded I/Q sample flows with
+# many frames at unknown offsets, and "millions of users" is MANY of
+# them on one device fleet. ONE receiver closes that gap: the push-
+# driven `MultiStreamReceiver` (`receive_streams` its whole-stream
+# wrapper) cuts each of S independent streams into fixed-size
+# overlapping chunks, stacks one chunk per stream on a leading STREAM
+# AXIS, and runs each stacked chunk-step through the two compiled
+# streaming programs (`rx._jit_stream_chunk_multi`, the fused
+# multi-peak scan `rx.stream_chunk_graph` under one vmap, then
+# `rx._jit_stream_decode_multi`, the fixed-geometry mixed-rate decode
+# over the flattened S*K lanes) — <= 2 dispatches per CHUNK-STEP,
+# independent of S. A carried (tail samples, sample offset, frames
+# emitted) state threads across each stream's chunks, so every frame
+# is owned by exactly one chunk and decodes bit-identically to slicing
+# `stream[start:start+frame_len]` out and calling per-capture
+# `rx.receive` on it. Ragged arrival is handled host-side by a packer:
+# a chunk-step fires only when at least one stream has a full chunk,
+# streams without one ride the step as idle lanes behind a valid-mask
+# (`valid == 0` → the detector caps their positions to nothing), and
+# the all-noise fast path is preserved (a step with zero decodable
+# lanes across the WHOLE fleet skips the decode dispatch entirely).
+# The dispatch loop is double-buffered: step t+1's upload+dispatch is
+# issued BEFORE the host blocks on step t's scalars, so the
+# host<->device transfer hides behind compute (in-flight depth on the
+# `utils/dispatch.record_gauge("rx.stream_inflight")` gauge). The
+# stream axis shards over the dp mesh (`parallel/batch.frame_mesh` /
+# `lane_sharding`, `jax.shard_map` — multihost-ready through
+# `parallel/multihost.build_mesh`, dp being the axis with no
+# steady-state collectives). A single stream is a fleet of one:
+# `StreamReceiver` / `receive_stream` are that fleet's lane 0, unwrapped.
 
 
 def streaming_rx_enabled(streaming: Optional[bool] = None) -> bool:
@@ -433,8 +448,8 @@ class StreamCarry(NamedTuple):
     sample, the frames emitted so far, and the dedupe watermark (the
     offset below which no future chunk can re-own a start — the
     `_seen` set holds only entries at or above it, O(K) per stream).
-    Exposed read-only via :attr:`StreamReceiver.carry` (and per lane
-    via :meth:`MultiStreamReceiver.carry`) for observability and
+    Exposed read-only per lane via :meth:`MultiStreamReceiver.carry`
+    (and :attr:`StreamReceiver.carry`) for observability and
     tests — to continue a stream across slabs, keep pushing into the
     SAME receiver (the carry is its live state, not a detached resume
     token)."""
@@ -445,9 +460,8 @@ class StreamCarry(NamedTuple):
 
 
 def _chunk_candidates(seen, off, own, starts, k: int):
-    """The shared dedupe/ownership core of the streaming drains —
-    single-stream and per-fleet-lane alike, so the two receivers can
-    never drift on the trickiest host logic: prune `seen` to the
+    """The dedupe/ownership core of a lane's drain (the trickiest
+    host logic, kept apart so it reads alone): prune `seen` to the
     watermark `off` (starts are non-decreasing across chunks, so no
     future chunk can re-own a start below it — the receiver holds
     O(K) entries, not one per frame ever emitted), then collect the
@@ -489,9 +503,8 @@ def _slab_array(samples, name: str) -> np.ndarray:
 
 
 class _LaneHealth:
-    """Per-stream quarantine state (shared by the single-stream and
-    fleet receivers so the two can never drift): non-finite input
-    poisons the lane immediately; ``blowup_limit`` repeated per-lane
+    """Per-stream quarantine state: non-finite input poisons the lane
+    immediately; ``blowup_limit`` repeated per-lane
     decode blowups poison it too; a poisoned lane rides behind the
     valid-mask (``valid == 0`` — its chunks scan to nothing, healthy
     lanes untouched by construction) and rejoins after
@@ -549,10 +562,9 @@ _LEGACY_GEOMETRY_DEFAULTS = {"sco_track": False, "fused_demap": False}
 
 
 def _validate_checkpoint(st, mine: dict) -> None:
-    """The ONE checkpoint-geometry gate of every restore surface
-    (``StreamReceiver(checkpoint=...)`` and the fleet's
-    ``restore_stream`` share it, so the two can never drift): refuse
-    a blob whose fingerprint is partial/absent (a raw
+    """The checkpoint-geometry gate of ``restore_stream`` (and so of
+    ``StreamReceiver(checkpoint=...)``, which restores through it):
+    refuse a blob whose fingerprint is partial/absent (a raw
     ``checkpoint_carry`` without geometry must not restore into an
     arbitrary receiver) or disagrees with the restoring receiver."""
     from ziria_tpu.runtime import resilience
@@ -581,9 +593,10 @@ def _validate_checkpoint(st, mine: dict) -> None:
 
 
 def _stream_geometry(r) -> dict:
-    """The ONE checkpoint geometry fingerprint, shared by the single-
-    stream and fleet receivers (so a fleet lane's checkpoint restores
-    into a lone receiver): everything a restoring receiver must match
+    """The checkpoint geometry fingerprint — the fleet width is NOT
+    part of it, so a lane's checkpoint restores into any fleet, a
+    lone `StreamReceiver` included: everything a restoring receiver
+    must match
     for bit-identical resumption — the detector parameters included,
     since different thresholds detect different frame starts."""
     return {"chunk_len": r.chunk_len, "frame_len": r.frame_len,
@@ -598,15 +611,7 @@ def _stream_geometry(r) -> dict:
             "fused_demap": bool(r.fused_demap)}
 
 
-def _span(spec):
-    """``telemetry.span(*spec)`` for the fleet receiver, which names
-    the blocking pulls it shares with the single-stream receiver;
-    nothing for the latter (``spec`` None)."""
-    from ziria_tpu.utils import telemetry
-    return telemetry.span(*spec) if spec else contextlib.nullcontext()
-
-
-def _pull_chunk(outs, span=None):
+def _pull_chunk(outs, span):
     """Materialize a chunk scan's per-lane scalars on the host. On an
     ASYNC backend a runtime failure mid-execution surfaces HERE, at
     the first host pull, not inside the guarded dispatch — callers
@@ -614,9 +619,11 @@ def _pull_chunk(outs, span=None):
     throws (the launched results are lost either way). `segs` stays
     device-resident for the decode dispatch. ``span`` (name, args)
     is opened around the blocking pulls alone."""
+    from ziria_tpu.utils import telemetry
+
     (own, starts, overflow, found, fstart, _eps, rb, ln, pk, nv,
      segs) = outs
-    with _span(span):
+    with telemetry.span(*span):
         return (np.asarray(own), np.asarray(starts),
                 np.asarray(overflow), np.asarray(found),
                 np.asarray(fstart), np.asarray(rb), np.asarray(ln),
@@ -624,26 +631,25 @@ def _pull_chunk(outs, span=None):
 
 
 def _record_degraded(entered: bool) -> None:
-    """The ONE degrade-visibility ritual (both receivers and both
-    link sites share it, so the recording can never drift): the
-    rx.degraded_mode gauge level plus — on entry — the
-    resilience.degraded counter."""
+    """The degrade-visibility ritual: the rx.degraded_mode gauge level
+    plus — on entry — the resilience.degraded counter. A fleet quietly
+    running its slow twin must be visible in a trace, not discovered
+    in a latency graph."""
     from ziria_tpu.utils import dispatch, telemetry
     dispatch.record_gauge("rx.degraded_mode", 1.0 if entered else 0.0)
     if entered:
         telemetry.count("resilience.degraded")
 
 
-def _guarded_decode(r, label: str, dec, *args, pull_span=None):
-    """The ONE guarded decode dispatch + SYNCHRONOUS host pull
-    (single-stream and fleet receivers share it): an async runtime
-    failure surfaces at the pull, after the dispatch returned, so the
-    pull lives inside the same containment — one guarded re-dispatch,
-    then None, with the receiver marked degraded so the caller (and
-    the rest of the stream) runs the oracle twin. Returns (clear,
-    crc) as host arrays, or None. ``pull_span`` (name, args) is
-    opened around the blocking pull alone, with the pulled ``bytes``
-    added to its args."""
+def _guarded_decode(r, label: str, dec, *args, pull_span):
+    """The guarded decode dispatch + SYNCHRONOUS host pull: an async
+    runtime failure surfaces at the pull, after the dispatch
+    returned, so the pull lives inside the same containment — one
+    guarded re-dispatch, then None, with the receiver marked degraded
+    so the caller (and the rest of the stream) runs the oracle twin.
+    Returns (clear, crc) as host arrays, or None. ``pull_span`` (name,
+    args) is opened around the blocking pull alone, with the pulled
+    ``bytes`` added to its args."""
     from ziria_tpu.runtime import resilience
     from ziria_tpu.utils import telemetry
 
@@ -656,10 +662,10 @@ def _guarded_decode(r, label: str, dec, *args, pull_span=None):
                                             policy=r._policy)
         except resilience.DispatchFailed:
             break
-        spec = pull_span and (pull_span[0], dict(
-            pull_span[1], bytes=int(clear.nbytes + crc.nbytes)))
         try:
-            with _span(spec):
+            with telemetry.span(pull_span[0], dict(
+                    pull_span[1],
+                    bytes=int(clear.nbytes + crc.nbytes))):
                 return np.asarray(clear, np.uint8), np.asarray(crc)
         except Exception:        # noqa: BLE001 - async pull loss
             if attempt:
@@ -671,8 +677,7 @@ def _guarded_decode(r, label: str, dec, *args, pull_span=None):
 
 def _gate_finite(arr: np.ndarray, name: str, sanitize: bool,
                  health: "_LaneHealth"):
-    """The ONE non-finite gate behind the shape gate (single-stream
-    and fleet push seams share it, so the two can never drift):
+    """The non-finite gate behind the shape gate of the push seam:
     reject with an error NAMING the stream — or, under
     ``sanitize=True``, zero the poisoned samples and quarantine the
     lane. Returns ``(arr, n_bad)``; the caller owns its own dirty
@@ -695,605 +700,9 @@ def _gate_finite(arr: np.ndarray, name: str, sanitize: bool,
     return arr, n_bad
 
 
-class StreamStats(NamedTuple):
-    chunks: int                # chunk dispatch-1 scans issued
-    frames: int                # StreamFrames emitted
-    overflow_chunks: int       # chunks reporting > K eligible plateaus
-    max_in_flight: int         # high-water chunk dispatches in flight
-    sanitized: int = 0         # non-finite samples zeroed (sanitize=True)
-    quarantines: int = 0       # times the stream entered quarantine
-    lane_blowups: int = 0      # per-window oracle decode blowups caught
-    degraded: bool = False     # a compiled program degraded to its twin
-
-
-class StreamReceiver:
-    """Push-driven streaming receiver: feed arbitrary sample slabs
-    with :meth:`push`, close the stream with :meth:`flush`; both
-    return the :class:`StreamFrame`\\ s that became decodable.
-
-    Geometry: `chunk_len` samples per scan with `frame_len` of
-    overlap between consecutive chunks (`frame_len` must be a
-    power-of-two >= 512 capture bucket covering the longest frame the
-    stream may carry, so a frame starting anywhere in a chunk's OWNED
-    region — the first `chunk_len - frame_len` samples — lies fully
-    inside that chunk). Starts detected in the overlap re-detect
-    fully inside the next chunk and are owned there: every frame is
-    decoded exactly once. Up to `max_frames_per_chunk` frames are
-    extracted per chunk; more raises the chunk's overflow flag
-    (counted in :class:`StreamStats` — reported, never silently
-    dropped; widen K or shorten the chunk).
-    """
-
-    def __init__(self, chunk_len: Optional[int] = None,
-                 frame_len: Optional[int] = None,
-                 max_frames_per_chunk: Optional[int] = None,
-                 check_fcs: bool = False,
-                 threshold: Optional[float] = None,
-                 min_run: Optional[int] = None,
-                 dead_zone: Optional[int] = None,
-                 viterbi_window: int = None,
-                 viterbi_metric: str = None,
-                 viterbi_radix: int = None,
-                 streaming: Optional[bool] = None,
-                 sanitize: bool = False,
-                 max_retries: Optional[int] = None,
-                 watchdog_s: Optional[float] = None,
-                 blowup_limit: int = 2, rejoin_after: int = 3,
-                 checkpoint: Optional[bytes] = None,
-                 sco_track: Optional[bool] = None,
-                 fused_demap: Optional[bool] = None,
-                 geometry: Optional[_geometry.Geometry] = None):
-        from ziria_tpu.ops.viterbi import _check_radix
-        from ziria_tpu.phy.wifi import rx as _rx
-        from ziria_tpu.runtime import resilience
-
-        # ONE declarative geometry supplies every default the caller
-        # leaves None (explicit per-knob args still win); the default
-        # Geometry IS the historical constants, so StreamReceiver()
-        # builds exactly yesterday's receiver — same compiled
-        # programs, same checkpoint fingerprint, same bits.
-        geo = geometry if geometry is not None else _geometry.DEFAULT
-        chunk_len = geo.chunk_len if chunk_len is None else chunk_len
-        frame_len = geo.frame_len if frame_len is None else frame_len
-        max_frames_per_chunk = (geo.max_frames_per_chunk
-                                if max_frames_per_chunk is None
-                                else max_frames_per_chunk)
-        threshold = geo.threshold if threshold is None else threshold
-        min_run = geo.min_run if min_run is None else min_run
-        dead_zone = geo.dead_zone if dead_zone is None else dead_zone
-        viterbi_window = (geo.viterbi_window if viterbi_window is None
-                          else viterbi_window)
-        viterbi_metric = (geo.viterbi_metric if viterbi_metric is None
-                          else viterbi_metric)
-        viterbi_radix = (geo.viterbi_radix if viterbi_radix is None
-                         else viterbi_radix)
-        sco_track = geo.sco_track if sco_track is None else sco_track
-        fused_demap = (geo.fused_demap if fused_demap is None
-                       else fused_demap)
-
-        if frame_len != geo.capture_bucket(frame_len):
-            raise ValueError(
-                f"frame_len {frame_len} is not a power-of-two >= "
-                f"{geo.capture_bucket_min} capture bucket; per-capture "
-                f"receive would pad to {geo.capture_bucket(frame_len)} "
-                f"and the identity contract needs identical geometry")
-        if chunk_len <= frame_len:
-            raise ValueError(
-                f"chunk_len {chunk_len} must exceed the frame_len "
-                f"{frame_len} overlap (the owned region would be empty)")
-        self.chunk_len = int(chunk_len)
-        self.frame_len = int(frame_len)
-        self.stride = self.chunk_len - self.frame_len
-        self.k = int(max_frames_per_chunk)
-        # the largest DATA field a frame_len window can hold, bucketed:
-        # the stream's ONE fixed decode geometry (longer frames are
-        # ACQ_TRUNCATED in both paths — the window cannot hold them)
-        self.n_sym_bucket = geo.sym_bucket(
-            max(1, (self.frame_len - _rx.FRAME_DATA_START) // 80))
-        self.check_fcs = check_fcs
-        self.viterbi_window = viterbi_window
-        self.viterbi_metric = viterbi_metric
-        # resolved ONCE at construction: the radix, sco_track, and
-        # fused_demap are part of the stream's fixed compiled
-        # geometry (decode jit cache key AND the checkpoint
-        # fingerprint — a different decode program emits different
-        # bits)
-        self.viterbi_radix = _check_radix(viterbi_radix)
-        self.sco_track = _rx.sco_track_enabled(sco_track)
-        self.fused_demap = _rx.fused_demap_enabled(fused_demap)
-        self.streaming = streaming_rx_enabled(streaming)
-        # detector params kept for the degraded eager twin (the same
-        # chunk graph run op-by-op when the compiled program fails)
-        self._threshold = float(threshold)
-        self._min_run = int(min_run)
-        self._dead_zone = int(dead_zone)
-        self._jit1 = _rx._jit_stream_chunk(
-            self.k, self.frame_len, self.n_sym_bucket,
-            float(threshold), int(min_run), int(dead_zone))
-        self.sanitize = bool(sanitize)
-        self._policy = resilience.default_policy(
-            max_retries=max_retries, timeout_s=watchdog_s)
-        self._health = _LaneHealth(blowup_limit, rejoin_after)
-        self._dirty = False        # non-finite input since last chunk
-        self._sanitized = 0
-        self._lane_blowups = 0
-        self._degraded = False        # decode program -> oracle twin
-        self._scan_degraded = False   # chunk program -> eager twin
-        self._tail = np.zeros((0, 2), np.float32)
-        self._offset = 0
-        self._emitted = 0
-        self._watermark = 0
-        self._seen = set()
-        self._pending = None       # (offset, host chunk, valid, outs)
-        self._inflight = 0
-        self._chunks = 0
-        self._overflow_chunks = 0
-        self._max_in_flight = 0
-        self._flushed = False
-        if checkpoint is not None:
-            st = resilience.restore_carry(checkpoint)
-            _validate_checkpoint(st, self._geometry())
-            self._tail = np.asarray(st.tail, np.float32)
-            self._offset = int(st.offset)
-            self._emitted = int(st.emitted)
-            self._watermark = int(st.watermark)
-            self._seen = set(st.seen)
-            rs = st.state   # quarantine/degraded runtime state: a
-            #                 quarantined receiver must RESUME
-            #                 quarantined or emissions diverge from
-            #                 the uninterrupted run
-            self._health.quarantined = bool(rs.get("quarantined",
-                                                   False))
-            self._health.clean = int(rs.get("clean", 0))
-            self._health.blowups = int(rs.get("blowups", 0))
-            self._health.quarantines = int(rs.get("quarantines", 0))
-            self._dirty = bool(rs.get("dirty", False))
-            self._sanitized = int(rs.get("sanitized", 0))
-            self._lane_blowups = int(rs.get("lane_blowups", 0))
-            self._degraded = bool(rs.get("degraded", False))
-            self._scan_degraded = bool(rs.get("scan_degraded", False))
-
-    # -- state ----------------------------------------------------------
-
-    @property
-    def carry(self) -> StreamCarry:
-        return StreamCarry(self._tail, self._offset, self._emitted,
-                           self._watermark)
-
-    @property
-    def stats(self) -> StreamStats:
-        return StreamStats(self._chunks, self._emitted,
-                           self._overflow_chunks, self._max_in_flight,
-                           self._sanitized, self._health.quarantines,
-                           self._lane_blowups,
-                           self._degraded or self._scan_degraded)
-
-    def _geometry(self) -> dict:
-        return _stream_geometry(self)
-
-    def _runtime_state(self) -> dict:
-        """The checkpoint's runtime-state rider: quarantine health +
-        degraded flags + containment counters, so a restored receiver
-        keeps behaving exactly as the uninterrupted one would."""
-        return {"quarantined": self._health.quarantined,
-                "clean": self._health.clean,
-                "blowups": self._health.blowups,
-                "quarantines": self._health.quarantines,
-                "dirty": self._dirty,
-                "sanitized": self._sanitized,
-                "lane_blowups": self._lane_blowups,
-                "degraded": self._degraded,
-                "scan_degraded": self._scan_degraded}
-
-    def checkpoint(self):
-        """Serialize the live stream state (runtime/resilience
-        checkpoint blob): the in-flight chunk is DRAINED first — its
-        frames belong to the pre-checkpoint past and are returned
-        alongside, so nothing launched is silently dropped. The blob
-        carries the quarantine/degraded runtime state too. Returns
-        ``(state_bytes, frames)``; a new
-        ``StreamReceiver(checkpoint=state_bytes, ...)`` at the same
-        geometry resumes with bit-identical subsequent emissions."""
-        if self._flushed:
-            raise RuntimeError("checkpoint after flush")
-        out: List[StreamFrame] = []
-        if self._pending is not None:
-            pend, self._pending = self._pending, None
-            out = self._drain(pend)
-        from ziria_tpu.runtime import resilience
-        return resilience.checkpoint_carry(
-            self.carry, seen=self._seen, geometry=self._geometry(),
-            state=self._runtime_state()), out
-
-    # -- the push surface -----------------------------------------------
-
-    def push(self, samples) -> List[StreamFrame]:
-        """Append samples ((n, 2) float pairs) to the stream; scan
-        every full chunk that completes. Returns the frames emitted.
-        Malformed slabs fail loudly at the seam (`_slab_array`);
-        non-finite samples reject — or, with ``sanitize=True``, zero
-        and quarantine the stream (docs/robustness.md)."""
-        if self._flushed:
-            raise RuntimeError("push after flush")
-        from ziria_tpu.utils import dispatch, faults
-
-        arr = _slab_array(samples, "stream")
-        arr, _kinds = faults.corrupt_slab("rx.push", arr)
-        arr, n_bad = _gate_finite(arr, "stream", self.sanitize,
-                                  self._health)
-        if n_bad:
-            self._sanitized += n_bad
-            self._dirty = True
-        if arr.size:
-            self._tail = np.concatenate([self._tail, arr], axis=0)
-
-        out: List[StreamFrame] = []
-        while self._tail.shape[0] >= self.chunk_len:
-            q = self._health.step(self._dirty)
-            self._dirty = False
-            out += self._launch(self._tail[:self.chunk_len],
-                                0 if q else self.chunk_len,
-                                self.stride)
-            self._tail = self._tail[self.stride:]
-            self._offset += self.stride
-            # carry depth after each chunk consumption: with telemetry
-            # active this is a plottable counter track (does the push
-            # cadence keep up with the chunk stride, or does the tail
-            # grow?); a plain high-water mark under count_dispatches
-            dispatch.record_gauge("rx.stream_carry_depth",
-                                  self._tail.shape[0])
-        return out
-
-    def flush(self) -> List[StreamFrame]:
-        """Close the stream: scan the carried tail (zero-padded to the
-        chunk geometry, owning every remaining start) and drain the
-        in-flight chunk. Idempotent."""
-        if self._flushed:
-            return []
-        self._flushed = True
-        out: List[StreamFrame] = []
-        valid = self._tail.shape[0]
-        if valid:
-            q = self._health.step(self._dirty)
-            self._dirty = False
-            arr = np.zeros((self.chunk_len, 2), np.float32)
-            arr[:valid] = self._tail
-            out += self._launch(arr, 0 if q else valid, valid)
-        if self._pending is not None:
-            pend, self._pending = self._pending, None
-            out += self._drain(pend)
-        return out
-
-    # -- chunk lifecycle ------------------------------------------------
-
-    def _launch(self, arr, valid: int, own_hi: int) -> List[StreamFrame]:
-        """Issue chunk upload + scan dispatch, THEN drain the previous
-        chunk: while the host blocks on chunk i-1's scalars, chunk i's
-        transfer and compute are already in flight (the double
-        buffer). Returns chunk i-1's emissions."""
-        import jax
-        import jax.numpy as jnp
-
-        from ziria_tpu.utils import dispatch, programs
-
-        # the stream's FIRST chunk owns head-truncated preambles whose
-        # LTS alignment lands below 0 (clamped to 0 on device, exactly
-        # as per-capture locate_frame clamps); on any later chunk a
-        # negative start is the previous chunk's frame
-        own_lo = -192 if self._offset == 0 else 0
-        dev = jax.device_put(arr)
-        chunk_args = (dev, jnp.int32(valid), jnp.int32(own_lo),
-                      jnp.int32(own_hi))
-        programs.note_site("rx.stream_chunk", self._jit1, *chunk_args)
-        outs = self._scan_dispatch(chunk_args)
-        dispatch.record_gauge(
-            "rx.degraded_mode",
-            1.0 if (self._degraded or self._scan_degraded) else 0.0)
-        dispatch.record_gauge(
-            "rx.quarantined_streams",
-            1.0 if self._health.quarantined else 0.0)
-        self._chunks += 1
-        self._inflight += 1
-        self._max_in_flight = max(self._max_in_flight, self._inflight)
-        dispatch.record_gauge("rx.stream_inflight", self._inflight)
-        pend, self._pending = self._pending, (self._offset, arr, valid,
-                                              own_hi, outs)
-        return self._drain(pend) if pend is not None else []
-
-    def _scan_dispatch(self, chunk_args):
-        """The ONE guarded chunk-scan dispatch (shared by `_launch`
-        and the async-rescan path): the compiled program behind the
-        guard, degrading to the eager twin when it fails for good."""
-        from ziria_tpu.runtime import resilience
-
-        if self._scan_degraded:
-            return self._eager_chunk(*chunk_args)
-        try:
-            return resilience.guarded(
-                "rx.stream_chunk", self._jit1, *chunk_args,
-                policy=self._policy)
-        except resilience.DispatchFailed:
-            self._mark_degraded(scan=True)
-            return self._eager_chunk(*chunk_args)
-
-
-    def _rescan(self, arr, valid: int, off: int, own_hi: int):
-        """Re-run a chunk whose ASYNC results were lost: a runtime
-        failure mid-execution surfaces at the host pull in `_drain`,
-        after the guarded dispatch already returned — the launched
-        results are gone, so the chunk re-dispatches through the same
-        guarded/degraded path (counted as an async rescan)."""
-        import jax
-        import jax.numpy as jnp
-
-        from ziria_tpu.utils import telemetry
-
-        telemetry.count("resilience.async_rescans")
-        own_lo = -192 if off == 0 else 0
-        return self._scan_dispatch(
-            (jax.device_put(arr), jnp.int32(valid),
-             jnp.int32(own_lo), jnp.int32(own_hi)))
-
-    def _drain(self, pend) -> List[StreamFrame]:
-        """Block on a launched chunk's per-lane scalars, run the host
-        integer decision tree, and emit its frames (dispatching the
-        chunk's ONE fixed-geometry decode when any lane is decodable;
-        per-capture `rx.receive` per window in oracle mode)."""
-        from ziria_tpu.phy.wifi import rx as _rx
-        from ziria_tpu.phy.wifi.params import N_SERVICE_BITS, RATES
-        from ziria_tpu.utils import dispatch, programs
-
-        off, arr, valid, own_hi, outs = pend
-        try:
-            (own, starts, overflow, found, fstart, rb, ln, pk, nv,
-             segs) = _pull_chunk(outs)
-        except Exception:    # noqa: BLE001 - async loss, re-dispatch
-            (own, starts, overflow, found, fstart, rb, ln, pk, nv,
-             segs) = _pull_chunk(self._rescan(arr, valid, off,
-                                              own_hi))
-        self._inflight -= 1
-        if bool(overflow):
-            self._overflow_chunks += 1
-
-        self._watermark = off
-        self._seen, cands = _chunk_candidates(self._seen, off, own,
-                                              starts, self.k)
-
-        if not self.streaming or self._degraded:
-            # the per-capture oracle: the SAME detected windows, each
-            # sliced to the host and pushed through `rx.receive` — the
-            # ">= 3 dispatches per frame" path the streaming mode's
-            # identity (and speedup) is measured against, and the
-            # degraded twin when the compiled decode fails for good
-            return self._decode_oracle(cands, starts, arr, valid)
-
-        emit = {}
-        lanes = []                   # (abs_start, lane row, rate, len)
-        for abs_start, j in cands:
-            avail = int(nv[j]) - int(fstart[j])
-            res, ok = _rx._classify_acquire(
-                bool(found[j]), avail, int(rb[j]), int(ln[j]),
-                bool(pk[j]))
-            if ok is None:
-                emit[abs_start] = res
-            else:
-                lanes.append((abs_start, j, ok[0], ok[1], int(ln[j])))
-        if lanes:
-            import jax.numpy as jnp
-
-            # rows always pad to K (lane 0 repeated): ONE compiled
-            # decode geometry serves every chunk of the stream
-            def row_pad(vals):
-                vals = list(vals) + [vals[0]] * (self.k - len(vals))
-                return jnp.asarray(np.asarray(vals, np.int32))
-
-            rows = row_pad([j for _s, j, _m, _n, _lb in lanes])
-            ridx = row_pad([_rx.RATE_INDEX[m] for _s, _j, m, _n, _lb
-                            in lanes])
-            nbits = row_pad([n_sym * RATES[m].n_dbps
-                             for _s, _j, m, n_sym, _lb in lanes])
-            npsdu = row_pad([8 * lb for _s, _j, _m, _n, lb in lanes])
-            dec = _rx._jit_stream_decode(self.n_sym_bucket,
-                                         self.viterbi_window,
-                                         self.viterbi_metric,
-                                         self.viterbi_radix,
-                                         self.sco_track,
-                                         self.fused_demap)
-            programs.note_site("rx.stream_decode", dec, segs, rows,
-                               ridx, nbits, npsdu)
-            got = _guarded_decode(
-                self, "rx.stream_decode", dec, segs, rows, ridx,
-                nbits, npsdu)
-            if got is None:
-                # the compiled decode failed for good (at dispatch OR
-                # at the async host pull): degrade to the per-capture
-                # oracle for this chunk AND the rest of the stream
-                # (bit-identical by the pinned contract)
-                return self._decode_oracle(cands, starts, arr, valid)
-            clear, crc = got
-            for i, (abs_start, _j, m, _n, lb) in enumerate(lanes):
-                psdu = clear[i][N_SERVICE_BITS: N_SERVICE_BITS + 8 * lb]
-                emit[abs_start] = _rx.RxResult(
-                    True, m, lb, psdu,
-                    bool(crc[i]) if self.check_fcs else None)
-        out = [StreamFrame(s, emit[s]) for s in sorted(emit)]
-        self._emitted += len(out)
-        self._note_emitted(len(out))
-        return out
-
-    def _decode_oracle(self, cands, starts, arr,
-                       valid: int) -> List[StreamFrame]:
-        """The per-capture decode twin over the chunk's owned windows
-        — the ``streaming=False`` oracle AND the degraded mode the
-        compiled decode falls back to. Under the resilience opt-ins
-        (``sanitize=True`` or degraded mode) a window whose
-        per-capture receive blows up is counted
-        (`resilience.lane_blowups`), dropped loudly, and charged to
-        the stream's health (repeated blowups quarantine it) — never
-        a crash, never a silent wrong answer. In the PLAIN
-        ``streaming=False`` oracle (no opt-in) exceptions propagate
-        unchanged: a genuine decoder defect must surface, not
-        masquerade as frame loss."""
-        from ziria_tpu.phy.wifi import rx as _rx
-        from ziria_tpu.utils import telemetry
-
-        contain = (self.sanitize or self._degraded
-                   or self._scan_degraded)
-        out: List[StreamFrame] = []
-        for abs_start, j in cands:
-            s = int(starts[j])
-            win = arr[s: min(s + self.frame_len, valid)]
-            try:
-                res = _rx.receive(
-                    win, check_fcs=self.check_fcs,
-                    viterbi_window=self.viterbi_window,
-                    viterbi_metric=self.viterbi_metric,
-                    viterbi_radix=self.viterbi_radix,
-                    sco_track=self.sco_track)
-            except Exception:    # noqa: BLE001 - counted containment
-                if not contain:
-                    raise
-                self._lane_blowups += 1
-                self._health.blowup()
-                telemetry.count("resilience.lane_blowups")
-                continue
-            out.append(StreamFrame(abs_start, res))
-        self._emitted += len(out)
-        self._note_emitted(len(out))
-        return out
-
-    def _eager_chunk(self, dev, valid, own_lo, own_hi):
-        """The degraded scan twin: the SAME chunk graph run op-by-op
-        (eager jax) — no dependence on the failed compiled program.
-        Slower (many small dispatches) but available; labelled
-        ``rx.stream_chunk.eager`` so chaos plans targeting the
-        compiled site never block the fallback."""
-        from ziria_tpu.phy.wifi import rx as _rx
-        from ziria_tpu.utils import dispatch
-
-        with dispatch.timed("rx.stream_chunk.eager"):
-            return _rx.stream_chunk_graph(
-                dev, valid, own_lo, own_hi, self.k, self.frame_len,
-                self.n_sym_bucket, self._threshold, self._min_run,
-                self._dead_zone)
-
-    def _mark_degraded(self, scan: bool) -> None:
-        """Enter degraded mode for one of the two compiled streaming
-        programs: recorded as the ``rx.degraded_mode`` gauge plus a
-        counter — a fleet quietly running its slow twin must be
-        visible in trace_report, not discovered in a latency graph."""
-        if scan:
-            self._scan_degraded = True
-        else:
-            self._degraded = True
-        _record_degraded(True)
-
-    def reset_degraded(self) -> None:
-        """Leave degraded mode (re-probe the compiled programs on the
-        next chunk) — the operator's lever after the underlying fault
-        (a link flap, a wedged device) is known to be fixed."""
-        self._degraded = False
-        self._scan_degraded = False
-        _record_degraded(False)
-
-    def _note_emitted(self, k: int) -> None:
-        """Frames-emitted counter into the telemetry layer (registry
-        increment + cumulative counter track in active traces). Free
-        when nothing is collecting."""
-        if k:
-            from ziria_tpu.utils import telemetry
-            telemetry.count("rx.stream_frames", k, total=self._emitted)
-
-
-def receive_stream(samples, chunk_len: Optional[int] = None,
-                   frame_len: Optional[int] = None,
-                   max_frames_per_chunk: Optional[int] = None,
-                   check_fcs: bool = False,
-                   threshold: Optional[float] = None,
-                   min_run: Optional[int] = None,
-                   dead_zone: Optional[int] = None,
-                   viterbi_window: int = None,
-                   viterbi_metric: str = None,
-                   viterbi_radix: int = None,
-                   streaming: Optional[bool] = None,
-                   sco_track: Optional[bool] = None,
-                   fused_demap: Optional[bool] = None,
-                   geometry: Optional[_geometry.Geometry] = None):
-    """Decode every frame of a long multi-frame sample stream in
-    O(chunks) device dispatches (<= 2 per chunk; 1 for all-noise
-    chunks). Returns ``(frames, stats)``: a position-ordered list of
-    :class:`StreamFrame` — each bit-identical, RxResult field for
-    field including the FCS status, to per-capture
-    ``rx.receive(stream[start : start + frame_len], check_fcs=...)``
-    — and the :class:`StreamStats` (chunks scanned, frames emitted,
-    overflow chunks, in-flight high-water mark).
-
-    ``streaming=False`` (or ``--no-streaming-rx`` /
-    ``ZIRIA_STREAMING_RX=0``) runs the per-capture oracle over the
-    same detected windows (>= 3 dispatches per frame). The convenience
-    wrapper over :class:`StreamReceiver` — push-driven callers (a live
-    capture feed) use the class directly, pushing slabs into one
-    receiver whose :class:`StreamCarry` state threads across chunks
-    internally (visible via ``.carry``). ``geometry`` supplies the
-    default for every knob the caller leaves None (one declarative
-    object; explicit arguments win)."""
-    sr = StreamReceiver(chunk_len=chunk_len, frame_len=frame_len,
-                        max_frames_per_chunk=max_frames_per_chunk,
-                        check_fcs=check_fcs, threshold=threshold,
-                        min_run=min_run, dead_zone=dead_zone,
-                        viterbi_window=viterbi_window,
-                        viterbi_metric=viterbi_metric,
-                        viterbi_radix=viterbi_radix,
-                        streaming=streaming, sco_track=sco_track,
-                        fused_demap=fused_demap, geometry=geometry)
-    frames = sr.push(samples)
-    frames += sr.flush()
-    return frames, sr.stats
-
-
-# ------------------------------------------------- multi-stream receiver
-#
-# `receive_stream` decodes ONE stream per process; "millions of users"
-# is MANY concurrent streams on one device fleet. `receive_streams` +
-# the push-driven `MultiStreamReceiver` stack S independent streams'
-# chunks on a leading STREAM AXIS and run them through the stream-
-# axis-vmapped twins of the two compiled streaming programs
-# (`rx._jit_stream_chunk_multi` / `rx._jit_stream_decode_multi`), so
-# an entire S-stream fleet still runs on TWO compiled programs at
-# <= 2 dispatches per CHUNK-STEP — independent of S. Ragged arrival
-# is handled host-side by a packer: a chunk-step fires only when at
-# least one stream has a full chunk, streams without one ride the
-# step as idle lanes behind a valid-mask (`valid == 0` → the detector
-# caps their positions to nothing), and the all-noise fast path is
-# preserved (a step with zero decodable lanes across the WHOLE fleet
-# skips the decode dispatch entirely). The stream axis shards over
-# the dp mesh (`parallel/batch.frame_mesh` / `lane_sharding`,
-# `jax.shard_map` — multihost-ready through
-# `parallel/multihost.build_mesh`, dp being the axis with no
-# steady-state collectives). Every emitted frame is bit-identical to
-# S separate single-stream `StreamReceiver`s BY CONSTRUCTION: the
-# per-stream chunk boundaries, ownership windows, and per-lane graphs
-# are exactly the single-stream ones — the vmap only adds the axis.
-
-
-def multi_stream_enabled(multi: Optional[bool] = None) -> bool:
-    """The ONE reading of the --multi-stream / ZIRIA_MULTI_STREAM knob
-    (default ON): whether `receive_streams` runs the stream-axis fleet
-    path or falls back to S independent single-stream
-    `StreamReceiver`s (the bit-identity oracle — >= S x the fleet's
-    dispatch count). The env value is the CLI's declared lane count;
-    only ``"0"`` disables."""
-    import os
-
-    if multi is not None:
-        return multi
-    return os.environ.get("ZIRIA_MULTI_STREAM", "1") != "0"
-
-
 class MultiStreamStats(NamedTuple):
     streams: int               # S, the fleet width
-    chunk_steps: int           # fleet scan dispatches issued (oracle
-    #                            mode: per-stream chunks, summed)
+    chunk_steps: int           # fleet scan dispatches issued
     frames: int                # StreamFrames emitted, all streams
     overflow_chunks: int       # per-stream chunk overflow flags raised
     max_in_flight: int         # high-water chunk-steps in flight
@@ -1311,15 +720,25 @@ class MultiStreamReceiver:
     stream), close with :meth:`flush`; all return the
     ``(stream, StreamFrame)`` pairs that became decodable.
 
-    Geometry is the single-stream receiver's (`chunk_len` windows
-    overlapping by `frame_len`, up to `max_frames_per_chunk` frames
-    per chunk per stream), applied PER STREAM: each stream steps
-    through exactly the chunk boundaries a lone `StreamReceiver`
-    would, so lane-for-lane bit-identity with S separate receivers
-    holds by construction. One chunk-step = one stacked
-    (S, chunk_len, 2) upload + ONE vmapped scan dispatch (+ ONE
-    flattened decode dispatch when any stream has a decodable frame),
-    double-buffered like the single-stream loop. `mesh` shards the
+    Geometry, PER STREAM: `chunk_len` samples per scan with
+    `frame_len` of overlap between consecutive chunks (`frame_len`
+    must be a power-of-two >= 512 capture bucket covering the longest
+    frame a stream may carry, so a frame starting anywhere in a
+    chunk's OWNED region — the first `chunk_len - frame_len` samples —
+    lies fully inside that chunk). Starts detected in the overlap
+    re-detect fully inside the next chunk and are owned there: every
+    frame is decoded exactly once. Up to `max_frames_per_chunk` frames
+    are extracted per chunk per stream; more raises the chunk's
+    overflow flag (counted in :attr:`stats` — reported, never silently
+    dropped; widen K or shorten the chunk). Each stream steps through
+    its own chunk boundaries whatever its lane-mates do, and per-lane
+    graphs under vmap are the one-stream graphs, so a lane's frames
+    are bit-identical to that stream received alone — by
+    construction. One chunk-step = one stacked (S, chunk_len, 2)
+    upload + ONE vmapped scan dispatch (+ ONE flattened decode
+    dispatch when any stream has a decodable frame), double-buffered.
+    ``streaming=False`` runs the per-capture oracle over the same
+    detected windows in place of the compiled decode. `mesh` shards the
     stream axis over dp (`S % mesh.size == 0`); per-stream carries
     (:class:`StreamCarry`, dedupe watermark included) are visible via
     :meth:`carry`/:attr:`carries`."""
@@ -1340,14 +759,18 @@ class MultiStreamReceiver:
                  blowup_limit: int = 2, rejoin_after: int = 3,
                  sco_track: Optional[bool] = None,
                  fused_demap: Optional[bool] = None,
-                 geometry: Optional[_geometry.Geometry] = None):
+                 geometry: Optional[_geometry.Geometry] = None,
+                 streaming: bool = True):
         from ziria_tpu.ops.viterbi import _check_radix
         from ziria_tpu.phy.wifi import rx as _rx
         from ziria_tpu.runtime import resilience
 
-        # the declarative-geometry defaults (see StreamReceiver): the
-        # fleet width S rides the same object as the chunk geometry,
-        # so MultiStreamReceiver(geometry=g) builds the whole fleet
+        # ONE declarative geometry supplies every default the caller
+        # leaves None (explicit per-knob args still win); the default
+        # Geometry IS the historical constants — same compiled
+        # programs, same checkpoint fingerprint, same bits. The fleet
+        # width S rides the same object as the chunk geometry, so
+        # MultiStreamReceiver(geometry=g) builds the whole fleet
         geo = geometry if geometry is not None else _geometry.DEFAULT
         n_streams = geo.n_streams if n_streams is None else n_streams
         chunk_len = geo.chunk_len if chunk_len is None else chunk_len
@@ -1390,16 +813,30 @@ class MultiStreamReceiver:
         self.frame_len = int(frame_len)
         self.stride = self.chunk_len - self.frame_len
         self.k = int(max_frames_per_chunk)
+        # the largest DATA field a frame_len window can hold, bucketed:
+        # the fleet's ONE fixed decode geometry (longer frames are
+        # ACQ_TRUNCATED in both paths — the window cannot hold them)
         self.n_sym_bucket = geo.sym_bucket(
             max(1, (self.frame_len - _rx.FRAME_DATA_START) // 80))
         self.check_fcs = check_fcs
         self.viterbi_window = viterbi_window
         self.viterbi_metric = viterbi_metric
+        # resolved ONCE at construction: the radix, sco_track, and
+        # fused_demap are part of the fixed compiled geometry (decode
+        # jit cache key AND the checkpoint fingerprint — a different
+        # decode program emits different bits)
         self.viterbi_radix = _check_radix(viterbi_radix)
         self.sco_track = _rx.sco_track_enabled(sco_track)
         self.fused_demap = _rx.fused_demap_enabled(fused_demap)
+        # False = every owned window through per-capture `rx.receive`
+        # (`_decode_oracle`) in place of the compiled decode. The
+        # caller's word, never the environment's: the served path does
+        # not read ZIRIA_STREAMING_RX (StreamReceiver resolves it)
+        self.streaming = bool(streaming)
         self.mesh = mesh
         self.axis = axis
+        # detector params kept for the degraded eager twin (the same
+        # chunk graph run op-by-op when the compiled program fails)
         self._threshold = float(threshold)
         self._min_run = int(min_run)
         self._dead_zone = int(dead_zone)
@@ -1451,8 +888,7 @@ class MultiStreamReceiver:
 
     def carry(self, stream: int) -> StreamCarry:
         """Stream `stream`'s live :class:`StreamCarry` (tail, offset,
-        emitted, dedupe watermark) — read-only observability, exactly
-        like the single-stream receiver's."""
+        emitted, dedupe watermark) — read-only observability."""
         stream = self._check_stream(stream)
         return StreamCarry(self._tails[stream], self._offsets[stream],
                            self._emitted[stream],
@@ -1493,11 +929,14 @@ class MultiStreamReceiver:
                 "degraded": self._degraded,
                 "scan_degraded": self._scan_degraded}
 
-    def _lane_blob(self, stream: int) -> bytes:
+    def _lane_blob(self, stream: int, **rider) -> bytes:
+        """One lane's checkpoint blob; ``rider`` adds runtime-state
+        keys only the fleet's owner can vouch for per lane."""
         from ziria_tpu.runtime import resilience
         return resilience.checkpoint_carry(
             self.carry(stream), seen=self._seen[stream],
-            geometry=self._geometry(), state=self._lane_state(stream))
+            geometry=self._geometry(),
+            state=dict(self._lane_state(stream), **rider))
 
     def checkpoint(self, stream: int):
         """Serialize one fleet lane's live stream state (the in-flight
@@ -1616,8 +1055,8 @@ class MultiStreamReceiver:
     # evicted one checkpoints it (`checkpoint`), and the freed lane is
     # recycled for the next admitted session (`reset_stream`) or a
     # recovering one (`restore_stream`). None of these disturb the
-    # other lanes: per-lane state is exactly the single-stream
-    # receiver's, and the in-flight chunk-step is drained first only
+    # other lanes: every piece of stream state is per lane, and the
+    # in-flight chunk-step is drained first only
     # when the touched lane actually rides in it — an idle lane's
     # recycle preserves the double buffer.
 
@@ -1686,8 +1125,8 @@ class MultiStreamReceiver:
         eviction-recovery path: a blob from ``checkpoint(i)`` (or a
         lone ``StreamReceiver.checkpoint()``) at the same geometry
         resumes on this lane with bit-identical subsequent emissions
-        (per-lane graphs under vmap ARE the single-stream graphs —
-        the pinned fleet contract). The quarantine rider restores
+        (per-lane graphs under vmap ARE the one-stream graphs — the
+        pinned fleet contract). The quarantine rider restores
         per-lane: a session checkpointed quarantined RESUMES
         quarantined, its lane-mates untouched. The blob's
         degraded/scan_degraded flags deliberately do NOT transfer —
@@ -1767,8 +1206,10 @@ class MultiStreamReceiver:
                     valid[i] = 0
                 self._dirty[i] = False
                 # the stream's FIRST chunk owns head-truncated
-                # preambles (start clamps to 0), exactly the
-                # single-stream rule
+                # preambles whose LTS alignment lands below 0 (clamped
+                # to 0 on device, exactly as per-capture locate_frame
+                # clamps); on any later chunk a negative start is the
+                # previous chunk's frame
                 own_lo[i] = -192 if self._offsets[i] == 0 else 0
         offs = list(self._offsets)          # snapshot BEFORE advancing
         res = self._launch(arrs, valid, own_lo, own_hi, active, offs)
@@ -1795,9 +1236,9 @@ class MultiStreamReceiver:
 
     def _launch(self, arrs, valid, own_lo, own_hi, active, offs) -> List:
         """Issue the stacked upload + scan dispatch, THEN drain the
-        previous chunk-step — the single-stream double buffer, per
-        fleet step: step t's transfer and compute are in flight while
-        the host blocks on step t-1's scalars."""
+        previous chunk-step — the double buffer: step t's transfer
+        and compute are in flight while the host blocks on step t-1's
+        scalars. Returns step t-1's emissions."""
         from ziria_tpu.utils import dispatch, programs, telemetry
 
         step = self._chunk_steps
@@ -1845,8 +1286,11 @@ class MultiStreamReceiver:
             return self._eager_chunk(*chunk_args)
 
     def _rescan(self, arrs, valid, own_lo, own_hi):
-        """Re-run a chunk-step whose ASYNC results were lost at the
-        host pull (the fleet twin of StreamReceiver._rescan)."""
+        """Re-run a chunk-step whose ASYNC results were lost: a
+        runtime failure mid-execution surfaces at the host pull in
+        `_drain`, after the guarded dispatch already returned — the
+        launched results are gone, so the step re-dispatches through
+        the same guarded/degraded path (counted as an async rescan)."""
         from ziria_tpu.utils import telemetry
 
         telemetry.count("resilience.async_rescans")
@@ -1895,12 +1339,14 @@ class MultiStreamReceiver:
                 self._seen[i], cands = _chunk_candidates(
                     self._seen[i], off, own[i], starts[i], self.k)
                 allcands += [(i, abs_start, j) for abs_start, j in cands]
-            if not self._degraded:
+            oracle = not self.streaming or self._degraded
+            if not oracle:
                 emit, lanes, slots, tables = self._classify(
                     allcands, found, fstart, rb, ln, pk, nv)
-        if self._degraded:
-            # compiled fleet decode already failed for good: the
-            # per-capture oracle twin serves every window
+        if oracle:
+            # the per-capture oracle serves every window: asked for
+            # (``streaming=False``), or the compiled fleet decode
+            # already failed for good
             return self._decode_oracle(allcands, starts, arrs, valids)
 
         got = None
@@ -1999,17 +1445,24 @@ class MultiStreamReceiver:
         return emit, lanes, slots, (rows, ridx, nbits, npsdu)
 
     def _decode_oracle(self, allcands, starts, arrs, valids) -> List:
-        """The fleet's per-capture decode twin (degraded mode): each
-        owned window sliced from its stream's host chunk and pushed
-        through per-capture `rx.receive` — the single-stream oracle
-        rule, per lane. A window whose receive blows up is counted,
-        dropped loudly, and charged to ITS stream's health (repeated
-        blowups quarantine that stream; the rest of the fleet keeps
-        flowing). Reached only from degraded mode (this path IS the
-        resilience opt-in), so containment always applies here."""
+        """The per-capture decode twin — the ``streaming=False``
+        oracle AND the degraded mode the compiled decode falls back
+        to: each owned window sliced from its stream's host chunk and
+        pushed through per-capture `rx.receive` (the same detected
+        windows, >= 3 dispatches per frame: the identity contract made
+        runnable). Under the resilience opt-ins (``sanitize=True`` or
+        degraded mode) a window whose receive blows up is counted
+        (`resilience.lane_blowups`), dropped loudly, and charged to
+        ITS stream's health (repeated blowups quarantine that stream;
+        the rest of the fleet keeps flowing) — never a crash, never a
+        silent wrong answer. In the PLAIN ``streaming=False`` oracle
+        (no opt-in) exceptions propagate unchanged: a genuine decoder
+        defect must surface, not masquerade as frame loss."""
         from ziria_tpu.phy.wifi import rx as _rx
         from ziria_tpu.utils import telemetry
 
+        contain = (self.sanitize or self._degraded
+                   or self._scan_degraded)
         out: List = []
         with telemetry.span("rx.fleet.emit", {
                 "step": self._drain_step, "frames": len(allcands)}):
@@ -2026,6 +1479,8 @@ class MultiStreamReceiver:
                         viterbi_radix=self.viterbi_radix,
                         sco_track=self.sco_track)
                 except Exception:  # noqa: BLE001 - counted containment
+                    if not contain:
+                        raise
                     self._lane_blowups += 1
                     self._health[i].blowup()
                     telemetry.count("resilience.lane_blowups")
@@ -2038,10 +1493,13 @@ class MultiStreamReceiver:
         return out
 
     def _eager_chunk(self, chunks, valid, own_lo, own_hi):
-        """The degraded fleet scan: the SAME stream-axis graph run
-        op-by-op (eager vmap, unsharded — results are bit-identical
-        on any mesh, so dropping the mesh in the degraded twin loses
-        throughput, never correctness)."""
+        """The degraded scan twin: the SAME stream-axis graph run
+        op-by-op (eager vmap — no dependence on the failed compiled
+        program; unsharded — results are bit-identical on any mesh,
+        so dropping the mesh in the degraded twin loses throughput,
+        never correctness). Slower (many small dispatches) but
+        available; labelled ``rx.stream_chunk_multi.eager`` so chaos
+        plans targeting the compiled site never block the fallback."""
         from ziria_tpu.phy.wifi import rx as _rx
         from ziria_tpu.utils import dispatch
 
@@ -2052,6 +1510,8 @@ class MultiStreamReceiver:
                 self._dead_zone)
 
     def _mark_degraded(self, scan: bool) -> None:
+        """Enter degraded mode for one of the two compiled programs
+        (`_record_degraded`)."""
         if scan:
             self._scan_degraded = True
         else:
@@ -2059,8 +1519,9 @@ class MultiStreamReceiver:
         _record_degraded(True)
 
     def reset_degraded(self) -> None:
-        """Leave degraded mode (re-probe the compiled fleet programs
-        on the next chunk-step)."""
+        """Leave degraded mode (re-probe the compiled programs on the
+        next chunk-step) — the operator's lever after the underlying
+        fault (a link flap, a wedged device) is known to be fixed."""
         self._degraded = False
         self._scan_degraded = False
         _record_degraded(False)
@@ -2075,8 +1536,7 @@ def receive_streams(streams, chunk_len: Optional[int] = None,
                     dead_zone: Optional[int] = None,
                     viterbi_window: int = None,
                     viterbi_metric: str = None,
-                    viterbi_radix: int = None,
-                    multi: Optional[bool] = None, mesh=None,
+                    viterbi_radix: int = None, mesh=None,
                     axis: str = "dp",
                     sco_track: Optional[bool] = None,
                     fused_demap: Optional[bool] = None,
@@ -2085,56 +1545,175 @@ def receive_streams(streams, chunk_len: Optional[int] = None,
     device dispatches — <= 2 per chunk-step *independent of S*.
     Returns ``(per_stream_frames, stats)``: a per-stream position-
     ordered list of :class:`StreamFrame` (each bit-identical, RxResult
-    field for field, to what a lone single-stream receiver — and hence
-    per-capture ``rx.receive`` over the slice — emits for that
-    stream) and the :class:`MultiStreamStats`.
+    field for field, to what :func:`receive_stream` — and hence
+    per-capture ``rx.receive`` over the slice — emits for that stream
+    alone) and the :class:`MultiStreamStats`.
 
-    ``multi=False`` (or ``--no-multi-stream`` / ``ZIRIA_MULTI_STREAM=0``)
-    runs S independent single-stream :class:`StreamReceiver`\\ s — the
-    bit-identity oracle, >= S x the dispatch count. ``mesh`` shards
-    the stream axis over the dp device mesh
+    ``mesh`` shards the stream axis over the dp device mesh
     (`parallel/batch.frame_mesh`; S must divide it). Push-driven
     callers (live feeds with ragged arrival) use
     :class:`MultiStreamReceiver` directly."""
     s = len(streams)
     if s == 0:
         return [], MultiStreamStats(0, 0, 0, 0, 0, 0)
-    kw = dict(chunk_len=chunk_len, frame_len=frame_len,
-              max_frames_per_chunk=max_frames_per_chunk,
-              check_fcs=check_fcs, threshold=threshold,
-              min_run=min_run, dead_zone=dead_zone,
-              viterbi_window=viterbi_window,
-              viterbi_metric=viterbi_metric,
-              viterbi_radix=viterbi_radix, sco_track=sco_track,
-              fused_demap=fused_demap, geometry=geometry)
-    if not multi_stream_enabled(multi):
-        if mesh is not None:
-            # a sharded-vs-oracle comparison must never silently
-            # measure the wrong configuration: the oracle is S
-            # unsharded single-stream receivers by definition
-            raise ValueError(
-                "mesh sharding needs the fleet path: multi=False / "
-                "ZIRIA_MULTI_STREAM=0 runs S independent single-"
-                "stream receivers, which cannot honor a stream-axis "
-                "mesh")
-        per, chunks, frames, ovf, infl = [], 0, 0, 0, 0
-        for st in streams:
-            got, stats = receive_stream(np.asarray(st, np.float32),
-                                        **kw)
-            per.append(got)
-            chunks += stats.chunks
-            frames += stats.frames
-            ovf += stats.overflow_chunks
-            infl = max(infl, stats.max_in_flight)
-        return per, MultiStreamStats(s, chunks, frames, ovf, infl,
-                                     1 if chunks else 0)
-    msr = MultiStreamReceiver(s, mesh=mesh, axis=axis, **kw)
+    msr = MultiStreamReceiver(
+        s, chunk_len=chunk_len, frame_len=frame_len,
+        max_frames_per_chunk=max_frames_per_chunk, check_fcs=check_fcs,
+        threshold=threshold, min_run=min_run, dead_zone=dead_zone,
+        viterbi_window=viterbi_window, viterbi_metric=viterbi_metric,
+        viterbi_radix=viterbi_radix, mesh=mesh, axis=axis,
+        sco_track=sco_track, fused_demap=fused_demap, geometry=geometry)
     got = msr.push_many([np.asarray(st, np.float32) for st in streams])
     got += msr.flush()
     per = [[] for _ in range(s)]
     for i, fr in got:
         per[i].append(fr)
     return per, msr.stats
+
+
+class StreamStats(NamedTuple):
+    chunks: int                # chunk dispatch-1 scans issued
+    frames: int                # StreamFrames emitted
+    overflow_chunks: int       # chunks reporting > K eligible plateaus
+    max_in_flight: int         # high-water chunk dispatches in flight
+    sanitized: int = 0         # non-finite samples zeroed (sanitize=True)
+    quarantines: int = 0       # times the stream entered quarantine
+    lane_blowups: int = 0      # per-window oracle decode blowups caught
+    degraded: bool = False     # a compiled program degraded to its twin
+
+
+class StreamReceiver:
+    """ONE stream's face over a :class:`MultiStreamReceiver` of one
+    (``.fleet``): feed arbitrary sample slabs with :meth:`push`,
+    close the stream with :meth:`flush`; both return the
+    :class:`StreamFrame`\\ s that became decodable. The chunk
+    lifecycle, the two compiled programs and their degraded twins are
+    the fleet's — a lone stream is lane 0 of a one-lane fleet, with
+    the fleet's geometry rules (`chunk_len` windows overlapping by
+    `frame_len`, up to `max_frames_per_chunk` frames per chunk, more
+    raising the overflow flag counted in :class:`StreamStats`).
+
+    The face owns two keywords and forwards every other to the fleet:
+
+    - ``streaming`` (None = ``--streaming-rx`` / ``ZIRIA_STREAMING_RX``,
+      default on) is resolved HERE and handed down; off runs the
+      per-capture oracle over the same detected windows.
+    - ``checkpoint`` restores a blob of :meth:`checkpoint` (or of a
+      fleet lane's) into lane 0 WHOLE. ``restore_stream`` leaves the
+      old runtime's degraded flags, containment counters and emitted
+      count behind, because a serving fleet has lane-mates to protect;
+      this fleet has none, so the face takes them up and the restored
+      receiver behaves and counts as the uninterrupted one would."""
+
+    _FLEET_ATTRS = ("chunk_len", "frame_len", "stride", "k",
+                    "n_sym_bucket", "check_fcs", "viterbi_window",
+                    "viterbi_metric", "viterbi_radix", "sco_track",
+                    "fused_demap", "streaming", "sanitize")
+
+    def __init__(self, *, streaming: Optional[bool] = None,
+                 checkpoint: Optional[bytes] = None, **fleet_kw):
+        fleet = self.fleet = MultiStreamReceiver(
+            n_streams=1, mesh=None,
+            streaming=streaming_rx_enabled(streaming), **fleet_kw)
+        for name in self._FLEET_ATTRS:  # fixed at construction
+            setattr(self, name, getattr(fleet, name))
+        if checkpoint is not None:
+            from ziria_tpu.runtime import resilience
+
+            fleet.restore_stream(0, checkpoint)   # validates the blob
+            st = resilience.restore_carry(checkpoint)
+            fleet._degraded = bool(st.state.get("degraded", False))
+            fleet._scan_degraded = bool(
+                st.state.get("scan_degraded", False))
+            fleet._sanitized = int(st.state.get("sanitized", 0))
+            fleet._lane_blowups = int(st.state.get("lane_blowups", 0))
+            fleet._retired += int(st.emitted)
+
+    @property
+    def carry(self) -> StreamCarry:
+        return self.fleet.carry(0)
+
+    @property
+    def stats(self) -> StreamStats:
+        st = self.fleet.stats
+        return StreamStats(st.chunk_steps, st.frames, st.overflow_chunks,
+                           st.max_in_flight, st.sanitized,
+                           st.quarantines, st.lane_blowups, st.degraded)
+
+    def push(self, samples) -> List[StreamFrame]:
+        """Append samples ((n, 2) float pairs) to the stream; scan
+        every full chunk that completes. Returns the frames emitted."""
+        return [fr for _i, fr in self.fleet.push(0, samples)]
+
+    def flush(self) -> List[StreamFrame]:
+        """Close the stream: scan the carried tail and drain the
+        in-flight chunk. Idempotent."""
+        return [fr for _i, fr in self.fleet.flush()]
+
+    def checkpoint(self):
+        """Serialize the live stream state: the fleet's lane blob
+        (in-flight chunk DRAINED first, its frames returned alongside)
+        with the containment counters in its rider. Returns
+        ``(state_bytes, frames)``; a new
+        ``StreamReceiver(checkpoint=state_bytes, ...)`` at the same
+        geometry resumes with bit-identical subsequent emissions."""
+        fleet = self.fleet
+        if fleet._flushed:
+            raise RuntimeError("checkpoint after flush")
+        out = [fr for _i, fr in fleet.drain_pending()]
+        return fleet._lane_blob(
+            0, sanitized=fleet._sanitized,
+            lane_blowups=fleet._lane_blowups), out
+
+    def reset_degraded(self) -> None:
+        """Leave degraded mode (`MultiStreamReceiver.reset_degraded`)."""
+        self.fleet.reset_degraded()
+
+
+def receive_stream(samples, chunk_len: Optional[int] = None,
+                   frame_len: Optional[int] = None,
+                   max_frames_per_chunk: Optional[int] = None,
+                   check_fcs: bool = False,
+                   threshold: Optional[float] = None,
+                   min_run: Optional[int] = None,
+                   dead_zone: Optional[int] = None,
+                   viterbi_window: int = None,
+                   viterbi_metric: str = None,
+                   viterbi_radix: int = None,
+                   streaming: Optional[bool] = None,
+                   sco_track: Optional[bool] = None,
+                   fused_demap: Optional[bool] = None,
+                   geometry: Optional[_geometry.Geometry] = None):
+    """Decode every frame of a long multi-frame sample stream in
+    O(chunks) device dispatches (<= 2 per chunk; 1 for all-noise
+    chunks). Returns ``(frames, stats)``: a position-ordered list of
+    :class:`StreamFrame` — each bit-identical, RxResult field for
+    field including the FCS status, to per-capture
+    ``rx.receive(stream[start : start + frame_len], check_fcs=...)``
+    — and the :class:`StreamStats` (chunks scanned, frames emitted,
+    overflow chunks, in-flight high-water mark).
+
+    ``streaming=False`` (or ``--no-streaming-rx`` /
+    ``ZIRIA_STREAMING_RX=0``) runs the per-capture oracle over the
+    same detected windows (>= 3 dispatches per frame). The convenience
+    wrapper over :class:`StreamReceiver` — push-driven callers (a live
+    capture feed) use the class directly, pushing slabs into one
+    receiver whose :class:`StreamCarry` state threads across chunks
+    internally (visible via ``.carry``). ``geometry`` supplies the
+    default for every knob the caller leaves None (one declarative
+    object; explicit arguments win)."""
+    sr = StreamReceiver(chunk_len=chunk_len, frame_len=frame_len,
+                        max_frames_per_chunk=max_frames_per_chunk,
+                        check_fcs=check_fcs, threshold=threshold,
+                        min_run=min_run, dead_zone=dead_zone,
+                        viterbi_window=viterbi_window,
+                        viterbi_metric=viterbi_metric,
+                        viterbi_radix=viterbi_radix,
+                        streaming=streaming, sco_track=sco_track,
+                        fused_demap=fused_demap, geometry=geometry)
+    frames = sr.push(samples)
+    frames += sr.flush()
+    return frames, sr.stats
 
 
 def transmit_many(psdus, rates_mbps, add_fcs: bool = False,

@@ -1088,54 +1088,14 @@ def stream_chunk_graph(chunk, chunk_valid, own_lo, own_hi, k: int,
     return own, starts, overflow, f2, fstart, eps, rb, ln, pk, nv, segs
 
 
-@lru_cache(maxsize=None)
-def _jit_stream_chunk(k: int, win_len: int, n_sym_bucket: int,
-                      threshold: float = 0.75, min_run: int = 33,
-                      dead_zone: int = 320):
-    """ONE compiled chunk scan per (K, window, symbol bucket, detector
-    params) — chunk length retraces per shape; a stream of uniform
-    chunks compiles ONCE and every chunk is a re-dispatch."""
-    def f(chunk, chunk_valid, own_lo, own_hi):
-        return stream_chunk_graph(chunk, chunk_valid, own_lo, own_hi,
-                                  k, win_len, n_sym_bucket, threshold,
-                                  min_run, dead_zone)
-    return jax.jit(f)
-
-
-@lru_cache(maxsize=None)
-def _jit_stream_decode(n_sym_bucket: int, viterbi_window: int = None,
-                       viterbi_metric: str = None,
-                       viterbi_radix: int = None,
-                       sco_track: bool = False,
-                       fused_demap: bool = False):
-    """Dispatch 2 of the streaming chunk: row-select the decodable
-    lanes INSIDE the jit (the segment batch never re-crosses the host
-    link), the one-`lax.switch` mixed-rate decode at the stream's
-    fixed symbol bucket, and the vmapped masked-CRC check. The CRC
-    flags are always computed, so one compile serves both `check_fcs`
-    modes — the fused-link rule: two XOR-reductions and a look-up,
-    0.4 ms at the MTU bucket (as a byte-serial scan the check was
-    35.9 ms of the 83 ms decode, more than the Viterbi; ledger PR 25,
-    my chip runs PR 27). The
-    decode-mode knobs are cache keys (resolved radix/fused values,
-    like every jit factory here); ``fused_demap`` is LAST so the R1
-    lint demo can AST-drop it by position."""
-    def f(segs, rows, ridx, nbits, npsdu):
-        clear = decode_data_mixed(segs[rows], ridx, nbits, n_sym_bucket,
-                                  viterbi_window, viterbi_metric,
-                                  viterbi_radix, sco_track=sco_track,
-                                  fused_demap=fused_demap)
-        return clear, crc_psdu_many_graph(clear, npsdu)
-    return jax.jit(f)
-
-
-# --------------------------------------------------- multi-stream fleet
+# ---------------------------------------------- the streaming programs
 #
-# The S-stream twins of the two streaming programs: S independent I/Q
-# streams' chunks ride a LEADING STREAM AXIS through the same per-lane
-# graphs (`stream_chunk_graph` under one more vmap; the mixed decode
-# over the flattened (S*K) lane axis), so an entire fleet of streams
-# still runs on TWO compiled programs and <= 2 dispatches per
+# The two compiled programs of the streaming receiver
+# (backend/framebatch.MultiStreamReceiver; a lone stream is S = 1): S
+# independent I/Q streams' chunks ride a LEADING STREAM AXIS through
+# the per-lane graphs (`stream_chunk_graph` under one vmap; the mixed
+# decode over the flattened (S*K) lane axis), so an entire fleet of
+# streams runs on TWO compiled programs and <= 2 dispatches per
 # chunk-step — Ziria's `|>>>|` stage placement re-expressed as a mesh
 # axis. With a `mesh`, both programs wrap in `jax.shard_map` over the
 # dp stream axis: an identical per-device
@@ -1147,14 +1107,14 @@ def multi_stream_chunk_graph(chunks, valid, own_lo, own_hi, k: int,
                              win_len: int, n_sym_bucket: int,
                              threshold: float = 0.75, min_run: int = 33,
                              dead_zone: int = 320):
-    """The stream-axis twin of `stream_chunk_graph`: `chunks`
+    """`stream_chunk_graph` over a leading stream axis: `chunks`
     (S, chunk_len, 2) stacked per-stream windows, `valid`/`own_lo`/
     `own_hi` (S,) per-stream scalars (an idle lane rides `valid == 0`
     — the detector's position cap masks it to zero candidates, the
-    valid-mask of the host packer). Per lane, values are the SINGLE-
+    valid-mask of the host packer). Per lane, values are the one-
     stream graph's values by construction — the vmap adds the stream
-    axis, nothing else — which is what makes the fleet bit-identical
-    to S separate receivers."""
+    axis, nothing else — which is what makes a fleet lane bit-
+    identical to that stream received alone."""
     return jax.vmap(
         lambda c, v, lo, hi: stream_chunk_graph(
             c, v, lo, hi, k, win_len, n_sym_bucket, threshold,
@@ -1202,16 +1162,21 @@ def _jit_stream_decode_multi(n_sym_bucket: int, viterbi_window: int = None,
                              axis: str = "dp",
                              sco_track: bool = False,
                              fused_demap: bool = False):
-    """Dispatch 2 of the multi-stream chunk-step: per-stream row-
-    select of the decodable lanes (all inside the jit, over the still
-    device-resident (S, K, ...) segment batch), then the (S*K)-lane
-    FLATTENED mixed-rate decode + masked CRC — one rate-agnostic
-    Pallas Viterbi batch for the whole fleet, every lane riding the
-    same 128-lane tiles (lane values are batch-independent, the
-    pinned receive_many contract, so each lane is bit-identical to
-    its single-stream K-lane decode). Decode-mode knobs (including
-    the resolved ``fused_demap``, LAST for the R1 lint demo) and the
-    mesh are cache keys, as in every jit factory here."""
+    """Dispatch 2 of the chunk-step: per-stream row-select of the
+    decodable lanes (all inside the jit — the still device-resident
+    (S, K, ...) segment batch never re-crosses the host link), then
+    the (S*K)-lane FLATTENED mixed-rate decode + masked CRC — one
+    rate-agnostic Pallas Viterbi batch for the whole fleet, every
+    lane riding the same 128-lane tiles (lane values are batch-
+    independent, the pinned receive_many contract, so each lane is
+    bit-identical to its stream's own K-lane decode). The CRC flags
+    are always computed, so one compile serves both `check_fcs`
+    modes: two XOR-reductions and a look-up, 0.4 ms at the MTU
+    bucket (as a byte-serial scan the check was 35.9 ms of the 83 ms
+    decode, more than the Viterbi; ledger PR 25, chip runs PR 27).
+    Decode-mode knobs (including the resolved ``fused_demap``, LAST
+    for the R1 lint demo) and the mesh are cache keys, as in every
+    jit factory here."""
     def stream_decode_multi(segs, rows, ridx, nbits, npsdu):
         with jax.named_scope("rx.decode.select"):
             sel = jax.vmap(lambda sg, r: sg[r])(segs, rows)

@@ -392,25 +392,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "detected windows (>= 3 dispatches per frame "
                         "— the streaming path's bit-identical "
                         "contract); also via ZIRIA_STREAMING_RX=0")
-    p.add_argument("--multi-stream", dest="multi_stream", type=int,
-                   default=None, metavar="S",
-                   help="S-stream fleet mode for the library stream "
-                        "surface (framebatch.receive_streams / "
-                        "MultiStreamReceiver): S concurrent I/Q "
-                        "streams' chunks stack on a leading stream "
-                        "axis through stream-axis-vmapped twins of "
-                        "the two compiled streaming programs — <= 2 "
-                        "device dispatches per chunk-step independent "
-                        "of S, shardable over the dp device mesh "
-                        "(the default; docs/architecture.md). S=0 "
-                        "disables (same as --no-multi-stream). Also "
-                        "via ZIRIA_MULTI_STREAM=S")
-    p.add_argument("--no-multi-stream", dest="multi_stream",
-                   action="store_const", const=0,
-                   help="force S independent single-stream receivers "
-                        "(the fleet path's bit-identical oracle, "
-                        ">= S x the dispatch count); also via "
-                        "ZIRIA_MULTI_STREAM=0")
     p.add_argument("--fused-link", dest="fused_link",
                    action="store_true", default=None,
                    help="ONE-dispatch fused loopback link "
@@ -571,11 +552,6 @@ def main(argv=None) -> int:
         # (the chunked streaming receiver vs its per-capture oracle)
         overrides["ZIRIA_STREAMING_RX"] = \
             "1" if args.streaming_rx else "0"
-    if args.multi_stream is not None:
-        # framebatch.multi_stream_enabled reads this at call time (the
-        # S-stream fleet vs S independent single-stream receivers);
-        # the value is the declared lane count, "0" disables
-        overrides["ZIRIA_MULTI_STREAM"] = str(args.multi_stream)
     if args.chaos is not None:
         # faults.env_chaos reads this inside _main_run's shell; the
         # scoped write keeps in-process callers from inheriting a

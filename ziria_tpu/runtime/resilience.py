@@ -38,9 +38,10 @@ poisoning the rest of the fleet. This module is that layer:
   (tail samples, offset, emitted count, dedupe watermark — plus the
   live dedupe set and a geometry fingerprint) so a crashed or
   restarted receiver resumes mid-stream with bit-identical subsequent
-  emissions — into a lone ``StreamReceiver(checkpoint=...)``, or
-  into a fleet lane via ``MultiStreamReceiver.restore_stream(i,
-  blob)`` (the serving runtime's eviction-recovery path,
+  emissions — into a fleet lane via
+  ``MultiStreamReceiver.restore_stream(i, blob)``, a lone
+  ``StreamReceiver(checkpoint=...)`` being lane 0 of a fleet of one
+  (the serving runtime's eviction-recovery path,
   docs/serving.md: ``ServeRuntime.evict`` checkpoints a session out,
   ``connect(sid, checkpoint=blob)`` restores it into whatever lane
   frees next).
@@ -346,16 +347,17 @@ def _carry_crc(tail: np.ndarray, scalars: np.ndarray,
 def checkpoint_carry(carry, seen=(), geometry: Optional[dict] = None,
                      state: Optional[dict] = None) -> bytes:
     """Serialize a stream carry (anything with ``tail`` / ``offset`` /
-    ``emitted`` / ``watermark`` fields — ``StreamReceiver.carry``)
+    ``emitted`` / ``watermark`` fields — a receiver's ``carry(i)``)
     plus the dedupe set, a geometry fingerprint, and the receiver's
     runtime ``state`` dict into a compact npz-container blob with a
     CRC32 integrity field over the payload (a torn write fails
     loudly at restore; pre-integrity blobs still load, counted on
-    ``resilience.checkpoint_legacy``). ``StreamReceiver.checkpoint()``
-    and ``MultiStreamReceiver.checkpoint(i)`` are the receiver-level
-    wrappers (they drain the in-flight chunk first, so the blob never
-    silently drops a launched chunk's frames, and they fill ``state``
-    so quarantine/degraded status survives the restart)."""
+    ``resilience.checkpoint_legacy``).
+    ``MultiStreamReceiver.checkpoint(i)`` is the receiver-level
+    wrapper (a lone ``StreamReceiver`` checkpoints through it): it
+    drains the in-flight chunk-step first, so the blob never silently
+    drops a launched chunk's frames, and fills ``state`` so
+    quarantine/degraded status survives the restart."""
     tail = np.asarray(carry.tail, np.float32).reshape(-1, 2)
     scalars = np.asarray([int(carry.offset), int(carry.emitted),
                           int(carry.watermark)], np.int64)

@@ -82,8 +82,8 @@ from ziria_tpu.utils import dispatch, faults, geometry as _geometry, \
     telemetry
 
 # the single source of the fleet-geometry defaults below (jax-free,
-# like this module) — ServeConfig() and StreamReceiver() can never
-# drift apart on chunk_len/frame_len/K/S again
+# like this module) — ServeConfig() and MultiStreamReceiver() can
+# never drift apart on chunk_len/frame_len/K/S
 _GEO = _geometry.DEFAULT
 
 

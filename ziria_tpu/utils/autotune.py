@@ -107,11 +107,12 @@ def stream_chunk_cost(geo: Geometry) -> Dict[str, float]:
 
     n_sym_bucket = geo.sym_bucket(
         max(1, (geo.frame_len - _rx.FRAME_DATA_START) // 80))
-    fn = _rx._jit_stream_chunk(
+    fn = _rx._jit_stream_chunk_multi(
         geo.max_frames_per_chunk, geo.frame_len, n_sym_bucket,
         float(geo.threshold), int(geo.min_run), int(geo.dead_zone))
-    chunk = jax.ShapeDtypeStruct((geo.chunk_len, 2), np.float32)
-    scalar = jax.ShapeDtypeStruct((), np.int32)
+    # a fleet of one: what `receive_stream` dispatches
+    chunk = jax.ShapeDtypeStruct((1, geo.chunk_len, 2), np.float32)
+    scalar = jax.ShapeDtypeStruct((1,), np.int32)
     c = programs.cost_of(fn, chunk, scalar, scalar, scalar)
     owned = geo.chunk_len - geo.frame_len
     return {
@@ -175,7 +176,7 @@ def _chunk_latency_ms(reg) -> Dict[str, float]:
 
     for (name, labels), m in reg.metrics():
         if name == telemetry.DISPATCH_HISTOGRAM and \
-                dict(labels).get("site") == "rx.stream_chunk":
+                dict(labels).get("site") == "rx.stream_chunk_multi":
             s = m.summary(scale=1e3, ndigits=4)
             return {"p50_ms": s.get("p50"), "p99_ms": s.get("p99")}
     return {}

@@ -272,8 +272,8 @@ def cache_growth(*caches):
 
 @contextmanager
 def no_recompile(*caches):
-    """``with no_recompile(rx._jit_stream_chunk): ...`` — assert the
-    block added ZERO entries to each jit-factory ``lru_cache``: the
+    """``with no_recompile(rx._jit_stream_chunk_multi): ...`` — assert
+    the block added ZERO entries to each jit-factory ``lru_cache``: the
     runtime twin of the jaxlint R1 cache-key rule
     (docs/static_analysis.md). The static rule proves every knob IS in
     the key; this proves a steady-state path never mints a fresh key —

@@ -56,7 +56,7 @@ with keys ``every=N`` (fire every Nth call), ``calls=i+j+k`` (explicit
 for delay/hang), ``frac=F`` (slab fraction, for nan_slab/truncate),
 ``profile=NAME`` (a phy/profiles channel-profile name, for the
 ``channel`` kind — default ``hostile``).
-Examples: ``ZIRIA_CHAOS="seed=3;rx.stream_chunk:transient:every=7"``,
+Examples: ``ZIRIA_CHAOS="seed=3;rx.stream_chunk*:transient:every=7"``,
 ``ZIRIA_CHAOS="rx.push.s*:channel:profile=severe,every=2"``.
 """
 

@@ -166,7 +166,7 @@ class Geometry:
     sym_bucket_min: int = 4
     capture_bucket_min: int = 512
     bit_bucket_min: int = 128
-    # detector parameters (part of _jit_stream_chunk's cache key)
+    # detector parameters (part of _jit_stream_chunk_multi's cache key)
     threshold: float = 0.75
     min_run: int = 33
     dead_zone: int = 320

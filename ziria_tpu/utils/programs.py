@@ -388,7 +388,7 @@ def coverage(records: List[Dict],
     a factory is *covered* when some record's traced function either
     is one of the factory's jit targets (``jax.jit(sync_frame)``
     style) or is defined inside the factory
-    (``_jit_stream_chunk.<locals>.f`` style). Returns
+    (``_jit_decode_data_mixed.<locals>.f`` style). Returns
     ``{"covered": [...], "uncovered": [...]}`` of
     ``module.name`` strings — an uncovered factory means the driver
     workloads never exercised it, i.e. a blind spot, not an error."""
@@ -461,24 +461,15 @@ def run_driver() -> None:
     link.loopback_ber_bits(pb, rates[0], 8.0, 7)
     link.sweep_ber(pb, (rates[0],), (8.0,), (7,))
 
-    # streaming receiver: stream_chunk + stream_decode at the suite's
+    # streaming receiver: the two fleet programs (stream_chunk_multi +
+    # stream_decode_multi) over a 2-stream load at the suite's
     # canonical (K=8, 4096-chunk, 1024-window, 8-symbol) geometry
-    stream, _starts = link.stream_many(
-        psdus, rates, snr_db=30.0, cfo=1e-4, delay=60, seed=8,
-        add_fcs=True, tail=1024)
-    framebatch.receive_stream(stream, chunk_len=4096, frame_len=1024,
-                              max_frames_per_chunk=8, check_fcs=True,
-                              streaming=True)
-
-    # multi-stream fleet: the stream-axis twins (stream_chunk_multi +
-    # stream_decode_multi) over a 2-stream load at the same geometry
     streams, _st = link.stream_many_multi(
         [psdus[:1], psdus[1:]], [rates[:1], rates[1:]],
         snr_db=30.0, cfo=1e-4, delay=60, seed=9, add_fcs=True,
         tail=1024)
     framebatch.receive_streams(streams, chunk_len=4096, frame_len=1024,
-                               max_frames_per_chunk=8, check_fcs=True,
-                               multi=True)
+                               max_frames_per_chunk=8, check_fcs=True)
 
 
 def collect_programs(hlo_dump: Optional[str] = None,

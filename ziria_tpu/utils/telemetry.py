@@ -39,7 +39,7 @@ cost is one tuple truthiness check, pinned by
 
 `utils/dispatch.record()/timed()/record_gauge()` are thin emitters
 into whatever is active here, so every instrumented site of the last
-six PRs (``rx.stream_chunk``, ``link.fused``, ``tx.encode_many``, the
+six PRs (``rx.stream_chunk_multi``, ``link.fused``, ``tx.encode_many``, the
 in-flight gauge, ...) inherits tracing and histograms with no changes
 at the site. Activation nests and overlaps freely: each active trace
 and registry sees every event recorded while it is active (the same
@@ -420,7 +420,7 @@ def _annotation_cls():
 
 @contextmanager
 def span(name: str, args: Optional[dict] = None):
-    """``with span("rx.stream_chunk"): ...`` — record the block as one
+    """``with span("rx.stream_chunk_multi"): ...`` — record the block as one
     trace span in every active trace (nesting and thread identity come
     from timestamps + tid, the Chrome trace model). Free when no trace
     is active. When an active trace was built with
