@@ -17,11 +17,13 @@ import pytest
 
 from ziria_tpu.ops import crc
 from ziria_tpu.phy.wifi import rx
-from ziria_tpu.phy.wifi.params import N_SERVICE_BITS
+from ziria_tpu.phy.wifi.params import N_SERVICE_BITS, mixed_trellis_steps
 from ziria_tpu.utils.bits import bits_to_bytes, np_bytes_to_bits, uint_to_bits
 
-#: the served MTU bucket: 1024 symbols x 216 bits, SERVICE in front
-MTU_ROW = 1024 * 216
+#: the served row: the 1024-symbol MTU bucket's bound trellis, 152 x
+#: 216 = 32 832 bits (the longest frame LENGTH can announce), SERVICE
+#: in front; 32 blocks of 1024 and 64 bits of a 33rd
+MTU_ROW = mixed_trellis_steps(1024)
 #: a toy row (4 symbols), not a whole number of 1024-bit blocks
 TOY_ROW = 4 * 216
 
@@ -95,13 +97,14 @@ def test_one_bit_corruption_reports_false_at_every_length(where):
 
 def _mtu_lengths(rng):
     full = (MTU_ROW - N_SERVICE_BITS) // 8
-    return np.concatenate([[4, 5, 1504, full - 1, full],
+    return np.concatenate([[4, 5, 1504, 4095, full - 1, full],
                            rng.integers(4, full + 1, 7)])
 
 
 def test_seeded_lengths_at_the_mtu_bucket():
-    """221 168 message bits in a 221 184-bit row (216 whole blocks):
-    the served shape, against zlib and against the 27 646-step scan."""
+    """Up to 32 816 message bits in a 32 832-bit row (a 4095-byte
+    PSDU among them: the longest legal one): the served shape, against
+    zlib and against the byte-serial scan."""
     rng = np.random.default_rng(2027)
     n_bytes = _mtu_lengths(rng)
     rows = _rows(rng, MTU_ROW, N_SERVICE_BITS, n_bytes)

@@ -16,7 +16,7 @@ import pytest
 from ziria_tpu.backend import framebatch
 from ziria_tpu.phy import link
 from ziria_tpu.phy.wifi import rx
-from ziria_tpu.phy.wifi.params import RATES
+from ziria_tpu.phy.wifi.params import RATES, mixed_trellis_steps
 from ziria_tpu.runtime import serve
 from ziria_tpu.utils import telemetry
 
@@ -154,6 +154,12 @@ def test_useful_and_padded_symbols_from_the_frames_sent(runs):
                for e in decodes)
     assert sum(e["args"]["lanes"] for e in decodes) \
         == sum(len(r) for r in RATE_SETS)
+    # and what the bound trellis ran against the bits that filled it
+    assert sum(e["args"]["useful_bits"] for e in decodes) \
+        == sum(_n_sym(m) * RATES[m].n_dbps
+               for rates in RATE_SETS for m in rates)
+    assert all(e["args"]["trellis_steps"]
+               == S * K * mixed_trellis_steps(bucket) for e in decodes)
     # the same two counts in the registry, for scrape()
     reg = srv.registry
     assert reg.find("rx.decode_symbols", kind="useful").value == want
@@ -223,10 +229,12 @@ def test_bytes_on_put_and_pulls_redo_the_shape_arithmetic(runs):
     assert {e["args"]["bytes"]
             for e in _named(spans, "rx.fleet.pull_scan")} \
         == {S * K * (3 * 1 + 5 * 4) + S}
-    # (S, K, T) uint8 clear bits at 216 bits a symbol + (S, K) bool
+    # (S, K, T) uint8 clear bits, T the bound trellis (216 bits a
+    # symbol at this bucket: it is under 152 symbols) + (S, K) bool
+    assert mixed_trellis_steps(bucket) == bucket * 216
     assert {e["args"]["bytes"]
             for e in _named(spans, "rx.fleet.pull_decode")} \
-        == {S * K * bucket * 216 + S * K}
+        == {S * K * mixed_trellis_steps(bucket) + S * K}
 
 
 def test_with_no_trace_same_frames_and_nothing_built(runs):

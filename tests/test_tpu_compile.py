@@ -34,7 +34,7 @@ from jax.sharding import SingleDeviceSharding
 
 from ziria_tpu.ops import viterbi_pallas as vp
 from ziria_tpu.phy.wifi import rx as _rx
-from ziria_tpu.phy.wifi.params import RATES
+from ziria_tpu.phy.wifi.params import RATES, mixed_trellis_steps
 from ziria_tpu.utils.geometry import DEFAULT
 
 LANES = vp.LANES
@@ -51,8 +51,10 @@ def _sym_bucket(frame_len: int) -> int:
         max(1, (frame_len - _rx.FRAME_DATA_START) // 80))
 
 
-#: trellis steps of the rate-agnostic decode at the MTU symbol bucket
-T_MTU = _sym_bucket(MTU["frame_len"]) * _rx.MAX_DBPS
+#: trellis steps of the rate-agnostic decode at the MTU symbol bucket:
+#: 152 x 216 = 32 832, the longest frame LENGTH can announce (the
+#: bucket at 54 Mbit/s would be 1024 x 216 = 221 184)
+T_MTU = mixed_trellis_steps(_sym_bucket(MTU["frame_len"]))
 
 
 @pytest.fixture(scope="module")
@@ -152,6 +154,26 @@ def test_decode_program_compiles_with_both_kernels(one_chip, on_chip,
     dec = _rx._jit_stream_decode_multi.__wrapped__(
         nsb, None, None, 2, None, "dp", False, False)
     _assert_mosaic(_compile(dec, *shapes), at_least=2)
+
+
+def test_decode_program_runs_the_bound_trellis_at_mtu(one_chip, on_chip):
+    """Lowering only: at the served bucket (1024 symbols) the ACS
+    kernel's LLR operand is 152 x 216 = 32 832 steps long, nothing in
+    the program is as long as the bucket at 54 Mbit/s, and the clear
+    bits come back (S, K, 32 832)."""
+    nsb, shapes = _decode_shapes(MTU, one_chip)
+    assert (nsb, T_MTU) == (1024, 32832)
+    dec = _rx._jit_stream_decode_multi.__wrapped__(
+        nsb, None, None, 2, None, "dp", False, False)
+    low = dec.lower(*shapes)
+    clear, crc = low.out_info
+    assert clear.shape == (MTU["s"], MTU["k"], T_MTU)
+    assert crc.shape == (MTU["s"], MTU["k"])
+    text = low.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert any(f"tensor<1x{T_MTU}x2x{LANES}xf32>" in ln for ln in calls), \
+        [ln[:200] for ln in calls]
+    assert str(nsb * _rx.MAX_DBPS) not in text
 
 
 def _chunk_scan(geo):

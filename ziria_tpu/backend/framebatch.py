@@ -229,7 +229,9 @@ def receive_many(captures: Sequence[Any], check_fcs: bool = False,
        device-resident.
     3. **decode** (`rx.decode_data_mixed`): the one-``lax.switch``
        mixed-rate DATA decode — lanes with DIFFERENT rates share the
-       same device call and the same Pallas Viterbi batch.
+       same device call and the same Pallas Viterbi batch; each row
+       it returns is `params.mixed_trellis_steps(bucket)` bits long
+       (the bucket at 54 Mbit/s, bound by the longest legal frame).
 
     ``batched_acquire=False`` (or env ``ZIRIA_BATCHED_ACQUIRE=0``)
     falls back to the host-driven per-capture acquisition loop (~3
@@ -1306,7 +1308,8 @@ class MultiStreamReceiver:
         for the whole fleet). Every span carries the drained step's
         id (`_swap_pending`)."""
         from ziria_tpu.phy.wifi import rx as _rx
-        from ziria_tpu.phy.wifi.params import N_SERVICE_BITS
+        from ziria_tpu.phy.wifi.params import (N_SERVICE_BITS,
+                                               mixed_trellis_steps)
         from ziria_tpu.utils import programs, telemetry
 
         step = self._drain_step
@@ -1352,7 +1355,9 @@ class MultiStreamReceiver:
         got = None
         if lanes:
             # what the decode is asked for against what it computes:
-            # every one of its S x K lanes runs the whole symbol bucket
+            # each of its S x K lanes is gathered at the whole symbol
+            # bucket and runs the bound trellis (the LENGTH field's
+            # longest frame, `params.mixed_trellis_steps`)
             useful = sum(lane[4] for lane in lanes)
             padded = self.s * self.k * self.n_sym_bucket
             telemetry.count("rx.decode_symbols", useful,
@@ -1362,7 +1367,10 @@ class MultiStreamReceiver:
             with telemetry.span("rx.fleet.decode", {
                     "step": step, "lanes": len(lanes),
                     "useful_symbols": useful,
-                    "padded_symbols": padded}):
+                    "padded_symbols": padded,
+                    "useful_bits": int(tables[2].sum()),
+                    "trellis_steps": self.s * self.k
+                    * mixed_trellis_steps(self.n_sym_bucket)}):
                 dec = _rx._jit_stream_decode_multi(
                     self.n_sym_bucket, self.viterbi_window,
                     self.viterbi_metric, self.viterbi_radix,

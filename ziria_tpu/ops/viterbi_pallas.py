@@ -1131,7 +1131,8 @@ def viterbi_decode_batch_fused(data, gain, rate, n_bits: int = None,
 #: trellis steps per mixed-fused sub-block: gcd of all 8 rates' n_dbps
 MIXED_SUB = 12
 #: trellis steps per mixed-fused grid block (6 sub-blocks; divides
-#: n_sym_bucket * MAX_DBPS for every bucket since 72 | 216)
+#: the fused kernel's n_sym_bucket * MAX_DBPS trellis at every bucket
+#: since 72 | 216, and so the mixed decode's bound one, 152 x 216)
 MIXED_UNROLL = 72
 #: chunks per rate row in the stacked bank: max n_dbps / MIXED_SUB
 MIXED_CHUNKS = 18
@@ -1361,9 +1362,11 @@ def viterbi_decode_mixed_fused(data, gain, rate_idx, nbits_real,
     graph's whole XLA front end, vs 8 per-rate branches unfused);
     gain: (B, 48) |H|^2 weights; rate_idx: (B,) traced indices into
     RATE_MBPS_ORDER; nbits_real: (B,) traced true data-bit counts.
-    Returns (B, n_sym_bucket * MAX_DBPS) raw decoded bits — the same
-    shape/semantics as the unfused mixed trellis, so the descramble
-    tail is shared.
+    Returns (B, n_sym_bucket * MAX_DBPS) raw decoded bits: the whole
+    bucket at 54 Mbit/s. The unfused mixed trellis stops at the
+    longest legal frame (`params.mixed_trellis_steps`), so
+    `rx.decode_data_mixed` slices this output to it before the shared
+    descramble tail; bounding this kernel's own grid is ROADMAP S2's.
 
     float32 metrics only, radix 2 or 4 (the quantized paths scale by
     the whole frame's LLR peak the prologue never materializes;

@@ -45,8 +45,36 @@ MAX_DBPS = max(p.n_dbps for p in RATES.values())     # 216 (54 Mbps)
 N_SERVICE_BITS = 16
 N_TAIL_BITS = 6
 
+# SIGNAL's LENGTH field has 12 bits, so no PSDU exceeds 4095 bytes and
+# no DATA field 32 782 bits: 152 symbols at 54 Mbit/s, and at most
+# 152 * 216 = 32 832 trellis steps at ANY rate (whole symbols: 152 x
+# 216, 171 x 192, 228 x 144, 342 x 96, 456 x 72; 683 x 48 and 911 x 36
+# fall just under)
+LENGTH_FIELD_BITS = 12
+MAX_PSDU_BYTES = (1 << LENGTH_FIELD_BITS) - 1
+MAX_DATA_BITS = N_SERVICE_BITS + 8 * MAX_PSDU_BYTES + N_TAIL_BITS
+MAX_SYM_AT_MAX_DBPS = -(-MAX_DATA_BITS // MAX_DBPS)           # 152
+
 
 def n_symbols(length_bytes: int, rate: RateParams) -> int:
     """Number of DATA OFDM symbols for a PSDU of `length_bytes`."""
     n_bits = N_SERVICE_BITS + 8 * length_bytes + N_TAIL_BITS
     return -(-n_bits // rate.n_dbps)
+
+
+def mixed_trellis_steps(n_sym_bucket: int) -> int:
+    """Trellis length of the rate-agnostic mixed decode at a symbol
+    bucket: the bucket at 54 Mbit/s, bound by the longest DATA field
+    the LENGTH field can announce. The identity (``n_sym_bucket *
+    MAX_DBPS``) for every bucket of 152 symbols or fewer; 32 832 steps,
+    not 221 184, at the served bucket of 1024."""
+    return min(n_sym_bucket, MAX_SYM_AT_MAX_DBPS) * MAX_DBPS
+
+
+def mixed_branch_symbols(n_sym_bucket: int, rate: RateParams) -> int:
+    """DATA symbols the mixed decode's branch for `rate` demaps: the
+    bucket, or the fewer that already hold `mixed_trellis_steps` rows
+    at this rate (1024, 912, 684, 456, 342, 228, 171, 152 of a
+    1024-symbol bucket for 6 ... 54 Mbit/s)."""
+    return min(n_sym_bucket,
+               -(-mixed_trellis_steps(n_sym_bucket) // rate.n_dbps))

@@ -37,7 +37,8 @@ from ziria_tpu.phy.wifi.params import (MAX_DBPS, N_SERVICE_BITS,
                                        N_TAIL_BITS, RATE_INDEX,
                                        RATE_MBPS_ORDER, RateParams,
                                        RATES, SIGNAL_BITS_TO_MBPS,
-                                       n_symbols)
+                                       mixed_branch_symbols,
+                                       mixed_trellis_steps, n_symbols)
 from ziria_tpu.utils.bits import bits_to_uint
 
 FRAME_DATA_START = 400  # 320 preamble + 80 SIGNAL
@@ -185,6 +186,15 @@ def _decode_front(frame, rate: RateParams, n_sym: int,
     (n_sym x 64) matmul-FFT + equalize + pilot track + demap +
     deinterleave + depuncture — everything before the Viterbi."""
     data, gain = _front_symbols(frame, n_sym, sco_track)
+    return _demap_symbols(data, gain, rate)
+
+
+def _demap_symbols(data, gain, rate: RateParams):
+    """`_front_symbols`' (n_sym, 48, 2) symbols and (48,) gains ->
+    depunctured soft LLR pairs (n_sym * n_dbps, 2): demap + deinterleave
+    + depuncture, the rate-dependent half of `_decode_front`. Symbol-
+    local, like the half before it: a prefix of the symbols gives that
+    prefix of the rows."""
     llrs = demap_mod.demap(data, rate.n_bpsc,
                            gain=jnp.broadcast_to(gain, data.shape[:-1]))
     deint = interleave.deinterleave(
@@ -443,18 +453,29 @@ def decode_data_mixed(frames, rate_idx, n_bits_real, n_sym_bucket: int,
     CFO-corrected frames padded to ONE common symbol bucket;
     rate_idx: (B,) int32 indices into RATE_MBPS_ORDER (traced);
     n_bits_real: (B,) int32 true data-bit counts (traced).
-    Returns (B, n_sym_bucket * MAX_DBPS) descrambled bit streams; the
-    caller slices each lane's PSDU.
+    Returns (B, t_max) descrambled bit streams, ``t_max =
+    params.mixed_trellis_steps(n_sym_bucket)``; the caller slices each
+    lane's PSDU out by its length.
 
     Geometry trick that makes one `lax.switch` serve all 8 rates: each
     per-rate branch runs only the CHEAP front end (FFT/equalize/demap/
     deinterleave/depuncture) at its own rate and pads the depunctured
-    LLRs to the bucket's maximal trellis (n_sym_bucket * MAX_DBPS)
-    with zero-LLR erasures — the same "adds no likelihood" argument as
-    the symbol-bucket padding, so the surviving path over each lane's
-    real prefix is exactly its unpadded ML path. The EXPENSIVE Viterbi
-    then runs once, rate-agnostic, over the whole mixed batch through
-    the Pallas kernel with every lane riding the same 128-lane tiles —
+    LLRs to the bucket's maximal trellis with zero-LLR erasures — the
+    same "adds no likelihood" argument as the symbol-bucket padding,
+    so the surviving path over each lane's real prefix is exactly its
+    unpadded ML path. That trellis is the bucket at 54 Mbit/s
+    (n_sym_bucket * MAX_DBPS) up to 152 symbols and stops there: the
+    LENGTH field's 12 bits let no frame fill more than 152 x 216 =
+    32 832 steps at any rate, so past that every row of every lane is
+    an erasure by construction, and dropping erasures after the tail
+    bits changes no bit before them. Each branch likewise demaps only
+    the symbols that can hold t_max steps at its rate
+    (`params.mixed_branch_symbols`), off ONE rate-independent
+    `_front_symbols` over the whole bucket (on the chip 3.2 ms where
+    eight fronts cut to their own symbols read 4.9 and whole-bucket
+    rows sliced after the demap 15.2; PERF.md, PR 32). The EXPENSIVE
+    Viterbi then runs once, rate-agnostic, over the whole mixed batch
+    through the Pallas kernel, every lane riding the same 128-lane tiles:
     mixed traffic no longer fragments the hot kernel's batch. Under
     vmap the switch lowers to a select over the (cheap) front-end
     branches; the per-lane trellis work is never duplicated.
@@ -477,7 +498,7 @@ def decode_data_mixed(frames, rate_idx, n_bits_real, n_sym_bucket: int,
     share stays one kernel. Windowed/quantized modes fall back to the
     (bit-identical) unfused front, exactly like the known-rate path.
     """
-    t_max = n_sym_bucket * MAX_DBPS
+    t_max = mixed_trellis_steps(n_sym_bucket)
     rate_idx = jnp.asarray(rate_idx, jnp.int32)
     n_bits_real = jnp.asarray(n_bits_real, jnp.int32)
     # `rx.decode.front` / `.viterbi` / `.back` name the stages in the
@@ -489,13 +510,23 @@ def decode_data_mixed(frames, rate_idx, n_bits_real, n_sym_bucket: int,
                 lambda f: _front_symbols(f, n_sym_bucket,
                                          sco_track))(frames)
         with jax.named_scope("rx.decode.viterbi"):
+            # the fused kernel still runs the bucket's whole trellis
+            # (ROADMAP S2); its rows past t_max are the same erasures
             bits = viterbi_pallas.viterbi_decode_mixed_fused(
                 data, gain, rate_idx, n_bits_real, radix=viterbi_radix,
-                interpret=interpret)
+                interpret=interpret)[:, :t_max]
     else:
         def _branch(rate):
+            n_sym = mixed_branch_symbols(n_sym_bucket, rate)
+
             def f(frame):
-                dep = _decode_front(frame, rate, n_sym_bucket, sco_track)
+                # the SAME whole-bucket expression in all eight
+                # branches, so XLA computes it once a lane; what
+                # differs by rate starts at the demap, over the
+                # symbols that can hold t_max steps at this rate
+                data, gain = _front_symbols(frame, n_sym_bucket,
+                                            sco_track)
+                dep = _demap_symbols(data[:n_sym], gain, rate)[:t_max]
                 return jnp.pad(dep, ((0, t_max - dep.shape[0]), (0, 0)))
             return f
 
@@ -525,15 +556,16 @@ def decode_data_mixed(frames, rate_idx, n_bits_real, n_sym_bucket: int,
 
 def crc_psdu_many_graph(clear_b, n_psdu_bits):
     """Batched FCS check over the mixed decode's output: for each lane
-    of `clear_b` (B, n_sym_bucket * MAX_DBPS descrambled bit streams)
+    of `clear_b` (B, mixed_trellis_steps(n_sym_bucket) descrambled bits)
     with `n_psdu_bits` (B,) traced true PSDU bit counts, True iff the
     PSDU's trailing 32 bits are the CRC-32 of the rest — ONE vmapped
     loop-free check at the common bucket (`ops/crc.check_crc32_masked`:
     two GF(2) products and a table look-up), boolean-identical lane
     for lane to a host `check_crc32` per lane. The whole row goes in,
-    SERVICE bits masked by position, so the served bucket (1024 x 216
-    bits) is a whole number of the check's blocks. Traced, so the
-    fused loopback link inlines it after the decode."""
+    SERVICE bits masked by position; the check pads it to whole blocks
+    of its own (the served row, 152 x 216 = 32 832 bits, to 33 blocks
+    of 1024). Traced, so the fused loopback link inlines it after the
+    decode."""
     from ziria_tpu.ops.crc import check_crc32_masked
 
     with jax.named_scope("rx.decode.back"):
