@@ -154,6 +154,8 @@ def test_useful_and_padded_symbols_from_the_frames_sent(runs):
                for e in decodes)
     assert sum(e["args"]["lanes"] for e in decodes) \
         == sum(len(r) for r in RATE_SETS)
+    # the lanes filled of the S x K the program runs whatever they hold
+    assert all(e["args"]["slots"] == S * K for e in decodes)
     # and what the bound trellis ran against the bits that filled it
     assert sum(e["args"]["useful_bits"] for e in decodes) \
         == sum(_n_sym(m) * RATES[m].n_dbps

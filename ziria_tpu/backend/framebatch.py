@@ -36,6 +36,7 @@ variants, not one per group size.
 
 from __future__ import annotations
 
+import collections
 import threading
 from typing import Any, List, NamedTuple, Optional, Sequence
 
@@ -556,6 +557,32 @@ class _LaneHealth:
                 self.blowups = 0
             return True
         return False
+
+
+#: length classes of `rx.stream_frames_by_length` (PSDU bytes, FCS
+#: included): an 802.11 ACK or CTS is 14, a TCP ACK under a hundred
+_ACK_BYTES, _SHORT_BYTES = 16, 128
+
+
+def _count_emitted(out, total: int) -> None:
+    """The registry's view of the frames a chunk-step emitted: their
+    number (`total` the receiver's running count, for the trace's
+    counter track), and the decoded ones by length class (a PSDU, FCS
+    included, of at most `_ACK_BYTES`, of at most `_SHORT_BYTES`, or
+    longer), so that a class of frames that stopped coming out shows
+    as a count and not as a rate."""
+    from ziria_tpu.utils import telemetry
+
+    if not out or not telemetry.active():
+        return
+    telemetry.count("rx.stream_frames", len(out), total=total)
+    by_class = collections.Counter(
+        "ack" if fr.result.length_bytes <= _ACK_BYTES
+        else "short" if fr.result.length_bytes <= _SHORT_BYTES
+        else "long" for _i, fr in out if fr.result.ok)
+    for c, n in by_class.items():
+        telemetry.count("rx.stream_frames_by_length", n,
+                        labels={"psdu": c})
 
 
 #: geometry keys that postdate shipped checkpoint blobs, mapped to
@@ -1359,17 +1386,19 @@ class MultiStreamReceiver:
             # bucket and runs the bound trellis (the LENGTH field's
             # longest frame, `params.mixed_trellis_steps`)
             useful = sum(lane[4] for lane in lanes)
-            padded = self.s * self.k * self.n_sym_bucket
+            n_slots = self.s * self.k
+            padded = n_slots * self.n_sym_bucket
             telemetry.count("rx.decode_symbols", useful,
                             labels={"kind": "useful"})
             telemetry.count("rx.decode_symbols", padded,
                             labels={"kind": "padded"})
             with telemetry.span("rx.fleet.decode", {
                     "step": step, "lanes": len(lanes),
+                    "slots": n_slots,
                     "useful_symbols": useful,
                     "padded_symbols": padded,
                     "useful_bits": int(tables[2].sum()),
-                    "trellis_steps": self.s * self.k
+                    "trellis_steps": n_slots
                     * mixed_trellis_steps(self.n_sym_bucket)}):
                 dec = _rx._jit_stream_decode_multi(
                     self.n_sym_bucket, self.viterbi_window,
@@ -1405,9 +1434,7 @@ class MultiStreamReceiver:
                 i, abs_start = key
                 out.append((i, StreamFrame(abs_start, emit[key])))
                 self._emitted[i] += 1
-        if out:
-            telemetry.count("rx.stream_frames", len(out),
-                            total=sum(self._emitted))
+        _count_emitted(out, sum(self._emitted))
         return out
 
     def _classify(self, allcands, found, fstart, rb, ln, pk, nv):
@@ -1495,9 +1522,7 @@ class MultiStreamReceiver:
                     continue
                 out.append((i, StreamFrame(abs_start, res)))
                 self._emitted[i] += 1
-        if out:
-            telemetry.count("rx.stream_frames", len(out),
-                            total=sum(self._emitted))
+        _count_emitted(out, sum(self._emitted))
         return out
 
     def _eager_chunk(self, chunks, valid, own_lo, own_hi):
