@@ -219,10 +219,9 @@ def nest(ops: List[Tuple[str, float, float, str]]) -> List[Op]:
             for i in order]
 
 
-def read(path: str, tr: xplane.DeviceTrace) -> Annotations:
-    """``tr`` is ``xplane.read(path)``: its window, module runs and
-    device 0's ops are taken as they are; this adds the annotations'
-    stats and the ops' scopes."""
+def host_spans(path: str) -> List[Span]:
+    """The main thread's annotation events with their stats, by start:
+    all a trace with no device op in it (a CPU rehearsal) has to read."""
     from jax.profiler import ProfileData
 
     spans: List[Span] = []
@@ -233,6 +232,14 @@ def read(path: str, tr: xplane.DeviceTrace) -> Annotations:
             spans = [Span(e.name, float(e.start_ns),
                           float(e.start_ns) + float(e.duration_ns),
                           dict(e.stats)) for e in evs]
+    return sorted(spans, key=lambda s: s.start)
+
+
+def read(path: str, tr: xplane.DeviceTrace) -> Annotations:
+    """``tr`` is ``xplane.read(path)``: its window, module runs and
+    device 0's ops are taken as they are; this adds the annotations'
+    stats and the ops' scopes."""
+    spans = host_spans(path)
     scopes = op_scopes(path) if tr.ops else {}
     runs = sorted(((m, kind) for kind, evs in tr.modules.items()
                    for m in evs), key=lambda mk: mk[0].start)
@@ -247,8 +254,7 @@ def read(path: str, tr: xplane.DeviceTrace) -> Annotations:
                                "")
         raw.append((o.name, o.start, o.end, scope))
     ops = charge_unnamed(nest(raw), [m for m, _kind in runs])
-    return Annotations(tr.window, sorted(spans, key=lambda s: s.start),
-                       ops, sum_runs(ops, tr.modules))
+    return Annotations(tr.window, spans, ops, sum_runs(ops, tr.modules))
 
 
 def sum_runs(ops: List[Op], modules: Dict[str, List[xplane.Ev]]

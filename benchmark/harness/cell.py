@@ -15,18 +15,25 @@ import os
 import shutil
 import sys
 import time
-from typing import Dict, NamedTuple, Optional
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import numpy as np
 
-from . import checks, counts, load, loop, manifest, peaks, spans, xplane
+from . import annotations, checks, counts, load, loop, manifest, peaks, \
+    spans, xplane
 
 #: closed-loop ticks before the window: the first fills the lanes, the
 #: second launches the first chunk-step, the third drains it through
 #: the decode, the fourth is the first in steady state
 WARM_TICKS = 4
-#: chunk-steps under the profiler in a traced run
-PROFILED_TICKS = 5
+#: chunk-steps the fleet launches under the profiler in a traced run:
+#: a step is launched in one and drained in the next, so five hold the
+#: four whole flights of the first four
+PROFILED_STEPS = 5
+#: and the seconds after which the profiler stops whatever was launched:
+#: a fleet that launches less than once a second is traced for these
+#: and no longer (today's five chunk-steps take 2.2 s)
+PROFILED_SECONDS = 6.0
 #: a tick this long (a chunk-step is about half a second) has its
 #: whereabouts noted by the stall watch
 STALL_S = 2.0
@@ -88,31 +95,38 @@ def warm(rx, on_tpu: bool):
 
 class Profiler:
     """Starts the JAX profiler at the first tick at or after ``at_s``
-    into the window and stops it ``PROFILED_TICKS`` ticks later."""
+    into the window and stops it at the first tick by which the fleet
+    has launched ``PROFILED_STEPS`` more chunk-steps (``launched()`` is
+    its count so far), or ``PROFILED_SECONDS`` later where that comes
+    first. A tick is one call of ``on_tick``: in the open loop most
+    launch nothing once a tick is shorter than the gap between lane
+    fills, so ticks say nothing of how much work the trace holds."""
 
-    def __init__(self, logdir: str, at_s: float):
-        self.logdir, self.at_s = logdir, at_s
-        self.started_tick: Optional[int] = None
+    def __init__(self, logdir: str, at_s: float,
+                 launched: Callable[[], int]):
+        self.logdir, self.at_s, self.launched = logdir, at_s, launched
+        self.started: Optional[Tuple[float, int]] = None  # (t, launched)
         self.done = False
 
     def on_tick(self, tick: int, t: float) -> None:
         import jax
         if self.done:
             return
-        if self.started_tick is None:
+        if self.started is None:
             if t >= self.at_s:
                 opts = jax.profiler.ProfileOptions()
                 opts.python_tracer_level = 1
                 opts.host_tracer_level = 2
                 jax.profiler.start_trace(self.logdir,
                                          profiler_options=opts)
-                self.started_tick = tick
-        elif tick - self.started_tick >= PROFILED_TICKS:
+                self.started = (t, self.launched())
+        elif self.launched() - self.started[1] >= PROFILED_STEPS \
+                or t - self.started[0] >= PROFILED_SECONDS:
             self.close()
 
     def close(self) -> None:
         import jax
-        if self.started_tick is not None and not self.done:
+        if self.started is not None and not self.done:
             jax.profiler.stop_trace()
         self.done = True
 
@@ -223,6 +237,10 @@ def measure(args, inspect=None, traffic=None):
     def consumed() -> int:
         return sum(rx.carry(lane_of[s]).offset for s in sids)
 
+    def in_flight() -> list:
+        """Blocks on the chunk-step in flight, if any: its frames."""
+        return [(srv._lane_sid[ln], fr) for ln, fr in rx.drain_pending()]
+
     # every XLA compile fires this event; the listener cannot be
     # removed, so it counts only while the window is open
     seen = {"live": False, "compiles": 0}
@@ -238,7 +256,8 @@ def measure(args, inspect=None, traffic=None):
     prof = None
     if args.trace:
         shutil.rmtree(trace_dir, ignore_errors=True)
-        prof = Profiler(trace_dir, 0.4 * args.seconds)
+        prof = Profiler(trace_dir, 0.4 * args.seconds,
+                        lambda: rx.stats.chunk_steps)
     at_open: Dict[str, object] = {}
 
     def on_open() -> None:
@@ -267,7 +286,8 @@ def measure(args, inspect=None, traffic=None):
                     slab = traffic["slab_samples"]
                     win = loop.run_closed(
                         srv, sids, laps, stride if slab == "stride" else slab,
-                        args.seconds, WARM_TICKS, **common)
+                        args.seconds, WARM_TICKS, drain=in_flight,
+                        **common)
                 elif traffic["loop"] == "open":
                     rate = traffic["rate_samples_per_s"] / len(sids)
                     arrivals = [load.Arrivals(
@@ -289,9 +309,10 @@ def measure(args, inspect=None, traffic=None):
                 for k, v in d.counts.items()}
         growth = sum(c.cache_info().currsize for c in jits) \
             - at_open["jit_entries"]
-        # the step still in flight: its frames belong to the run
-        tail = [loop.Emitted(win.elapsed_s, session_of(srv._lane_sid[ln]),
-                             fr) for ln, fr in rx.drain_pending()]
+        # the step the open loop left in flight: its frames belong to
+        # the run (the closed loop has drained its own)
+        tail = [loop.Emitted(win.elapsed_s, session_of(sid), fr)
+                for sid, fr in in_flight()]
         stats = rx.stats
         snap = srv.registry.snapshot()
 
@@ -394,6 +415,13 @@ def measure(args, inspect=None, traffic=None):
             "acs_min_bytes": counts.acs_min_bytes(rx.s * rx.k,
                                                   rx.n_sym_bucket),
         }, tr, peaks.peaks_for(dev.device_kind) if on_tpu else {})
+        an = annotations.for_ctx(ctx)
+        prog_spans = an.spans if an is not None \
+            else annotations.host_spans(path)
+        stale = counts.stale(prog_spans, rx.s, rx.k, rx.n_sym_bucket)
+        if stale:
+            raise SystemExit("the program's spans and the benchmark's "
+                             "counts disagree: " + "; ".join(stale))
         for m in cell.per_layer:
             v = m.reduce(ctx, **m.args)
             if v is not None:
@@ -404,6 +432,9 @@ def measure(args, inspect=None, traffic=None):
         say("trace", file=path, busy_s=tr.busy_s, window_s=tr.window_s,
             idle_share=1 - tr.busy_s / tr.window_s,
             runs={k: len(v) for k, v in tr.modules.items()},
+            spans={n: sum(1 for sp in prog_spans if sp.name == n)
+                   for n in (xplane.WINDOW_SPAN, "serve.step",
+                             "rx.fleet.stack", "rx.fleet.emit")},
             scan_ms=[round((e.end - e.start) / 1e6, 2)
                      for e in tr.modules["scan"]])
     else:

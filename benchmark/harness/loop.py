@@ -3,7 +3,8 @@ but a runtime with ``submit``/``step``, a clock and a sleep: a stub
 runtime with a fake clock drives them in the tests.
 
 Closed loop (``saturated``): each tick submits one slab per session and
-calls ``step()``; input is always waiting. Open loop (``paced``): slabs
+calls ``step()``; input is always waiting, and nothing is in flight
+when the window opens or closes. Open loop (``paced``): slabs
 are submitted when they are due, whatever the server is doing, and
 ``step()`` is called whenever something was submitted.
 """
@@ -24,6 +25,7 @@ class Emitted(NamedTuple):
 class Window(NamedTuple):
     t_open: float               # on the loop's clock
     elapsed_s: float            # as measured, to the closing step's end
+                                # (closed loop: its in-flight step's too)
     ticks: int
     consumed: int               # owned samples of its chunk-steps
     emitted: List[Emitted]      # every frame handed back, warm-up too
@@ -63,14 +65,25 @@ def run_closed(srv, sids: Sequence, laps, slab: int, seconds: float,
                clock: Callable[[], float], rec,
                session_of: Callable[[object], int],
                on_open: Callable[[], None] = lambda: None,
-               on_tick: Optional[Callable[[int, float], None]] = None
+               on_tick: Optional[Callable[[int, float], None]] = None,
+               drain: Callable[[], list] = lambda: []
                ) -> Window:
     """Warm-up ticks (set-up), ``on_open()``, then the window: it opens
-    at the start of the first tick after them and closes at the end of
-    the first ``step()`` that finishes at or after ``seconds``."""
+    at the start of the first tick after them and closes once the first
+    ``step()`` that finishes at or after ``seconds`` has returned and
+    the chunk-step it left in flight is done. ``drain()`` blocks on the
+    step in flight and returns its (session, frame) pairs. It is called
+    before the window opens and before it closes, so the time measured
+    is that of the work counted: a step launched in the window and done
+    after it would be samples without their time, and one launched
+    before it time without samples, and the two cancel only where every
+    ``step()`` takes as long as the next (PR 34: in the beacon cell they
+    take 425, 854, 8.5 and 426 ms, and a window that closed on one or
+    the other read 1.5% apart)."""
     pos = [0] * len(sids)
     emitted = _warm_up(srv, sids, laps, pos, slab, warm_ticks, rec,
                        session_of)
+    emitted += [Emitted(-1.0, session_of(sid), fr) for sid, fr in drain()]
     on_open()
     c0, t_open, ticks = consumed(), clock(), 0
     while True:
@@ -83,6 +96,12 @@ def run_closed(srv, sids: Sequence, laps, slab: int, seconds: float,
         if on_tick is not None:
             on_tick(ticks, t)
         if t >= seconds:
+            # the closing step's own blocking, a tick late: timed as
+            # one, so that the steps' time still adds up to the window's
+            with rec.span("bench.step"):
+                out = drain()
+            t = clock() - t_open
+            emitted += [Emitted(t, session_of(sid), fr) for sid, fr in out]
             return Window(t_open, t, ticks, consumed() - c0, emitted,
                           [], [], 0)
 

@@ -8,6 +8,11 @@ its second.
 
     python3 benchmark/sweep.py --workload mtu8.paced --seed 1 --seconds 25 \\
         --rates 300000 500000 700000 900000
+
+``--trace 1`` makes each of them a traced run, whose line holds the
+cell's per-layer metrics: a rate far under the sustained one stands in
+for a program whose tick is shorter than the gap between lane fills
+(PR 34: 100000 samples/s, a fill every 655 ms against a 443 ms tick).
 """
 
 import argparse
@@ -26,13 +31,14 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=1)
     ap.add_argument("--seconds", type=float, default=25.0)
     ap.add_argument("--rates", type=float, nargs="+", required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args(argv)
     base = manifest.load_cell(args.workload, args.rehearse).traffic
     for rate in args.rates:
         line, _compared = cell.measure(
             argparse.Namespace(workload=args.workload, seed=args.seed,
-                               seconds=args.seconds, trace=0,
+                               seconds=args.seconds, trace=args.trace,
                                rehearse=args.rehearse),
             traffic=dict(base, rate_samples_per_s=rate))
         print("[sweep] " + json.dumps(

@@ -1,11 +1,12 @@
-"""The window and delay arithmetic on a stub runtime and a fake clock."""
+"""The window and delay arithmetic on a stub runtime and a fake clock,
+and the traced run's profiled window on the same two loops."""
 
 from collections import namedtuple
 
 import numpy as np
 import pytest
 
-from benchmark.harness import load, loop, spans
+from benchmark.harness import cell, load, loop, spans
 
 Frame = namedtuple("Frame", "start result")
 Result = namedtuple("Result", "accepted")
@@ -35,6 +36,7 @@ class StubRuntime:
         self.pending = []
         self.clock, self.step_s = clock, step_s
         self.fresh = False
+        self.chunk_steps = 0        # step() calls that launched one
 
     def submit(self, sid, slab):
         self.tail[sid] += len(slab)
@@ -44,6 +46,7 @@ class StubRuntime:
     def step(self):
         self.clock.t += self.step_s
         out, self.pending = self.pending, []
+        self.chunk_steps += any(t >= CHUNK for t in self.tail)
         for i in range(len(self.tail)):
             while self.tail[i] >= CHUNK:
                 self.pending += [(i, Frame(s, None)) for s in
@@ -81,6 +84,82 @@ def test_closed_window_counts_whole_steps_over_measured_time():
     assert win.consumed / win.elapsed_s == pytest.approx(800 / 1.2)
     # warm-up frames are kept (for the checks) and stamped before 0
     assert [e.t for e in win.emitted if e.t < 0]
+
+
+class PipelinedStub:
+    """A device that runs one scan of ``scan_s`` a chunk-step, back to
+    back, behind a host that launches step t and then blocks on step
+    t-1 (the double buffer). Every ``every``-th step has frames: its
+    decode is queued behind scan t, so that ``step()`` returns only
+    when scan t is done too, and the next finds nothing to wait for:
+    the beacon cell's ticks of one, two, nought and one scan."""
+
+    def __init__(self, n, clock, scan_s, every):
+        self.n, self.clock, self.scan_s, self.every = n, clock, scan_s, every
+        self.fed = 0
+        self.offset = 0
+        self.launched = 0
+        self.device_free_at = 0.0
+        self.pending = None         # (done at, has frames, first sample)
+
+    def submit(self, sid, slab):
+        self.fed += len(slab)
+        return Result(True)
+
+    def _launch(self):
+        done = max(self.clock.t, self.device_free_at) + self.scan_s
+        self.device_free_at = done
+        self.launched += 1
+        new = (done, self.launched % self.every == 0, self.offset)
+        self.offset += STRIDE
+        return new
+
+    def _wait(self, pend):
+        done, frames, first = pend
+        if frames:                  # the decode runs behind every scan
+            done = self.device_free_at
+        self.clock.t = max(self.clock.t, done)
+        return [(0, Frame(first, None))] if frames else []
+
+    def step(self):
+        prev, self.pending = self.pending, None
+        if self.fed >= self.n * STRIDE:
+            self.fed -= self.n * STRIDE
+            self.pending = self._launch()
+        return self._wait(prev) if prev else []
+
+    def drain(self):
+        prev, self.pending = self.pending, None
+        return self._wait(prev) if prev else []
+
+    def consumed(self):
+        return self.n * self.offset
+
+
+def closed_rate(seconds, drained):
+    clock = FakeClock()
+    srv = PipelinedStub(2, clock, scan_s=0.425, every=4)
+    win = loop.run_closed(srv, [0, 1], laps(2), STRIDE, seconds, 4,
+                          srv.consumed, clock, spans.Recorder(), int,
+                          **({"drain": srv.drain} if drained else {}))
+    return win.consumed / win.elapsed_s, win
+
+
+def test_the_closed_window_times_the_work_it_counts_whatever_its_edge():
+    true = 2 * STRIDE / 0.425
+    # windows that close after a step() of one scan, of two, of none
+    edges = [29.3, 29.5, 29.9, 30.3, 30.7]
+    got = [closed_rate(s, drained=True) for s in edges]
+    assert len({win.ticks for _r, win in got}) > 1
+    for rate, win in got:
+        assert rate == pytest.approx(true, rel=1e-9)
+        assert win.emitted[-1].t <= win.elapsed_s
+    # until PR 34 the step in flight at the close was counted and not
+    # timed, the one in flight at the opening timed and not counted:
+    # they cancel where every step() takes as long as the next, and
+    # here the same five windows read 1.4% apart (B1(d))
+    was = [closed_rate(s, drained=False)[0] for s in edges]
+    assert max(was) / min(was) > 1.01
 
 
 def test_open_loop_times_each_frame_from_its_slabs_due_time():
@@ -129,3 +208,100 @@ def test_lap_slice_wraps():
     got = load.lap_slice(lap, 8, 5)[:, 0]
     assert list(got) == [16, 18, 0, 2, 4]
     assert load.lap_slice(lap, 23, 3)[:, 0].tolist() == [6, 8, 10]
+
+
+# ------------------------------------ the profiled window (cell.Profiler)
+
+
+class Watched:
+    """A ``cell.Profiler`` on a stub runtime's launch count, with the
+    JAX profiler's two calls replaced by a record of them, and every
+    ``on_tick`` noted as (tick, t, launches so far, traced)."""
+
+    def __init__(self, monkeypatch, srv, at_s):
+        import jax
+        self.calls, self.log, self.srv = [], [], srv
+        monkeypatch.setattr(jax.profiler, "start_trace",
+                            lambda *a, **kw: self.calls.append("start"))
+        monkeypatch.setattr(jax.profiler, "stop_trace",
+                            lambda: self.calls.append("stop"))
+        self.prof = cell.Profiler("unused", at_s,
+                                  lambda: srv.chunk_steps)
+
+    def on_tick(self, tick, t):
+        self.prof.on_tick(tick, t)
+        self.log.append((tick, t, self.srv.chunk_steps,
+                         self.prof.started is not None
+                         and not self.prof.done))
+
+    def traced(self):
+        """The (tick, t, launches) the trace started at, and those of
+        the tick that stopped it."""
+        on = [row[:3] for row in self.log if row[3]]
+        after = next(row[:3] for row in self.log if row[0] > on[-1][0])
+        return on[0], after
+
+
+def paced(monkeypatch, fill_s, seconds, step_s=0.001):
+    """The open loop on one session whose lane fills every ``fill_s``
+    (one- and two-sample slabs, so a submit and a ``step()`` every
+    millisecond or two) and a server whose ``step()`` takes ``step_s``."""
+    clock = FakeClock()
+    srv = StubRuntime(1, clock, step_s)
+    w = Watched(monkeypatch, srv, 0.4 * seconds)
+    arr = [load.Arrivals(7, 0, 1, 3, STRIDE / fill_s, 0.0)]
+    win = loop.run_open(srv, [0], laps(1), arr, 2, seconds, STRIDE, CHUNK,
+                        srv.consumed, clock, clock.sleep, spans.Recorder(),
+                        int, on_tick=w.on_tick)
+    w.prof.close()
+    return w, win
+
+
+def test_a_tick_shorter_than_the_fill_gap_is_traced_over_five_launches(
+        monkeypatch):
+    # what S9 leaves of mtu8.paced: a step() of 1 ms, a lane fill (and
+    # so a launch) every 91 ms
+    w, win = paced(monkeypatch, fill_s=0.091, seconds=3.0)
+    (tick0, t0, n0), (tick1, t1, n1) = w.traced()
+    assert t0 >= 1.2 and w.calls == ["start", "stop"]
+    assert n1 - n0 == cell.PROFILED_STEPS
+    assert t1 - t0 == pytest.approx(5 * 0.091, abs=0.091)
+    assert t1 - t0 < cell.PROFILED_SECONDS
+    # hundreds of ticks, all but five of them launched nothing
+    assert tick1 - tick0 > 200
+    # until PR 34 the profiler stopped five TICKS after it started
+    # (PROFILED_TICKS): a window of a few milliseconds with no launch
+    # in it, so no stack -> emit pair, so no chunk_flight_ms, so the
+    # traced line was refused as malformed (PR 26)
+    by_tick = {row[0]: row for row in w.log}
+    five_ticks_on = by_tick[tick0 + 5]
+    assert five_ticks_on[2] - n0 == 0
+    assert five_ticks_on[1] - t0 < 0.02
+
+
+def test_a_fleet_that_hardly_launches_is_traced_for_the_guards_seconds(
+        monkeypatch):
+    # a lane fill every 4 s: the guard ends the trace with one launch
+    w, win = paced(monkeypatch, fill_s=4.0, seconds=20.0)
+    (tick0, t0, n0), (tick1, t1, n1) = w.traced()
+    assert t0 >= 8.0 and w.calls == ["start", "stop"]
+    assert n1 - n0 < cell.PROFILED_STEPS
+    assert cell.PROFILED_SECONDS <= t1 - t0 < cell.PROFILED_SECONDS + 0.1
+    assert win.elapsed_s >= 20.0
+
+
+def test_closed_loop_five_ticks_are_five_launches_the_window_as_it_was(
+        monkeypatch):
+    clock = FakeClock()
+    srv = StubRuntime(2, clock, step_s=0.44)
+    w = Watched(monkeypatch, srv, 0.4 * 10.0)
+    loop.run_closed(srv, [0, 1], laps(2), STRIDE, seconds=10.0,
+                    warm_ticks=3, consumed=srv.consumed, clock=clock,
+                    rec=spans.Recorder(), session_of=int,
+                    on_tick=w.on_tick)
+    (tick0, t0, n0), (tick1, t1, n1) = w.traced()
+    # starts after the first tick to end at or after 4.0 s (the 10th),
+    # stops after five more: what PROFILED_TICKS = 5 gave
+    assert tick0 == 10 and tick1 - tick0 == 5 == n1 - n0
+    assert t1 - t0 == pytest.approx(5 * 0.44)
+    assert w.calls == ["start", "stop"]
