@@ -239,6 +239,35 @@ def test_bytes_on_put_and_pulls_redo_the_shape_arithmetic(runs):
         == {S * K * mixed_trellis_steps(bucket) + S * K}
 
 
+def test_put_names_the_batch_the_detector_convolves_over(runs):
+    """`locate_rows` (PR 35): lanes a device x the blocks
+    `sync.correlate_valid` cuts a chunk-long row into, from the
+    function that picks the fold; static, so the same on every
+    step."""
+    from ziria_tpu.ops import sync
+    _srv, spans, _traced, _plain, _built = runs
+    blocks = sync.fold_blocks(CHUNK - 63)
+    assert blocks == -(-(CHUNK - 63) // sync.FOLD_BLOCK) == 8
+    assert {e["args"]["locate_rows"]
+            for e in _named(spans, "rx.fleet.put")} == {S * blocks}
+
+
+@pytest.mark.parametrize("s,chunk_len,devices,want", [
+    (8, 131072, 1, 2048), (1, 131072, 1, 256), (32, 131072, 1, 8192),
+    (32, 131072, 4, 2048), (8, 1056, 1, 8)])
+def test_locate_rows_by_geometry(s, chunk_len, devices, want):
+    """The served geometry, the lone stream, the 32-lane fleet on one
+    chip and sharded over four (8 lanes a chip: the shape PR 28 lost
+    to), and a chunk too short to fold."""
+    mesh = None
+    if devices > 1:
+        from jax.sharding import Mesh
+        mesh = Mesh(np.array(jax.devices()[:devices]), ("dp",))
+    rcv = framebatch.MultiStreamReceiver(
+        n_streams=s, chunk_len=chunk_len, frame_len=1024, mesh=mesh)
+    assert rcv._locate_rows == want
+
+
 def test_with_no_trace_same_frames_and_nothing_built(runs):
     _srv, _spans, traced, plain, built = runs
     assert built == 0

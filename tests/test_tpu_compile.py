@@ -261,29 +261,48 @@ def test_served_programs_contract_floats_at_full_precision(which):
     assert not loose, loose[:3]
 
 
-def test_chunk_scan_at_mtu_geometry_acquires_over_the_window_head():
+@pytest.mark.parametrize("s", [8, 1, 32])
+def test_chunk_scan_at_mtu_geometry_acquires_over_the_window_head(s):
     """The per-window acquisition ran its detector and its four LTS
     convolutions over all 65 536 samples of 64 windows to re-derive a
     start that lies in each window's first few hundred (36 ms of the
     522 ms chunk-step on the chip: PERF.md, PR 30). It reads the
     window's head now (`rx._acquire_head`): at the served geometry no
-    convolution of the S x K window batch is window-long any more,
-    and the chunk-level ones are still there (no compiler)."""
-    s, k = MTU["s"], MTU["k"]
-    text = _chunk_scan(MTU).lower(*_chunk_shapes(MTU, None)).as_text()
+    convolution of the S x K window batch is window-long any more.
+
+    And the chunk-level ones are no longer `[S, 1, 131 072]`, the
+    shape that was 420.8 ms of every tick at S = 8 (PERF.md, PR 35):
+    `sync.correlate_valid` cuts each row into blocks of
+    `sync.FOLD_BLOCK` outputs, so every chunk-level contraction of the
+    scan has a batch of at least 256 — for the lone stream (S = 1) as
+    for the fleet — while the head-long ones pass through as they
+    were, and every float contraction is HIGHEST (no compiler)."""
+    from ziria_tpu.ops import sync
+
+    k, chunk = MTU["k"], MTU["chunk_len"]
+    geo = dict(MTU, s=s)
+    text = _chunk_scan(geo).lower(*_chunk_shapes(geo, None)).as_text()
+    assert not _loose_contractions(text)
     outs = [tuple(int(d) for d in m.groups()) for m in re.finditer(
         r"stablehlo\.convolution.*-> tensor<(\d+)x1x(\d+)xf32>", text)]
     assert len(outs) == 6, outs
     head = _rx._acquire_head(MTU["frame_len"])
-    # the chunk scan: STS sums over S and 2S rows, LTS over S
-    chunk = MTU["chunk_len"]
-    assert sorted(o for o in outs if o[1] >= chunk - 128) == [
-        (s, chunk - 63), (s, chunk + 63), (2 * s, chunk - 63)]
-    # the acquisition: the same three over S x K windows, head-long
-    # and nothing else (they were frame_len - 63 and + 63 long)
+    # the acquisition: STS sums over 2 S K and S K rows, LTS over S K
+    # (one private function, called four times), head-long, unfolded
+    # (they were frame_len - 63 and + 63 long before PR 30)
     assert head < MTU["frame_len"] - 128
-    assert sorted(o for o in outs if o[0] % (s * k) == 0) == [
-        (s * k, head - 63), (s * k, head + 63), (2 * s * k, head - 63)]
+    assert sync.fold_blocks(head - 63) == 1
+    heads = sorted(o for o in outs if o[1] > sync.FOLD_BLOCK)
+    assert heads == [(s * k, head - 63), (s * k, head - 63),
+                     (2 * s * k, head - 63)]
+    # the chunk scan: the same three, every row cut into 256 blocks
+    blocks = sync.fold_blocks(chunk - 63)
+    assert blocks == sync.fold_blocks(chunk - 63 - 47) == 256
+    assert sync.fold_rows(s, chunk) == s * blocks
+    folded = sorted(o for o in outs if o[1] <= sync.FOLD_BLOCK)
+    assert folded == [(s * blocks, sync.FOLD_BLOCK),
+                      (s * blocks, sync.FOLD_BLOCK),
+                      (2 * s * blocks, sync.FOLD_BLOCK)]
 
 
 def _while_locations(lowered):

@@ -790,6 +790,7 @@ class MultiStreamReceiver:
                  fused_demap: Optional[bool] = None,
                  geometry: Optional[_geometry.Geometry] = None,
                  streaming: bool = True):
+        from ziria_tpu.ops import sync as _sync
         from ziria_tpu.ops.viterbi import _check_radix
         from ziria_tpu.phy.wifi import rx as _rx
         from ziria_tpu.runtime import resilience
@@ -872,6 +873,12 @@ class MultiStreamReceiver:
         self._jit1 = _rx._jit_stream_chunk_multi(
             self.k, self.frame_len, self.n_sym_bucket,
             float(threshold), int(min_run), int(dead_zone), mesh, axis)
+        # the batch the detector's LTS convolutions run over at this
+        # geometry on each device (`rx.fleet.put`'s `locate_rows`):
+        # from the function that picks the fold, so it cannot drift
+        self._locate_rows = _sync.fold_rows(
+            self.s // (mesh.size if mesh is not None else 1),
+            self.chunk_len)
         self.sanitize = bool(sanitize)
         self._policy = resilience.default_policy(
             max_retries=max_retries, timeout_s=watchdog_s)
@@ -1273,7 +1280,8 @@ class MultiStreamReceiver:
         step = self._chunk_steps
         with telemetry.span("rx.fleet.put", {
                 "step": step, "bytes": arrs.nbytes + valid.nbytes
-                + own_lo.nbytes + own_hi.nbytes}):
+                + own_lo.nbytes + own_hi.nbytes,
+                "locate_rows": self._locate_rows}):
             chunk_args = (self._put(arrs), self._put(valid),
                           self._put(own_lo), self._put(own_hi))
         programs.note_site("rx.stream_chunk_multi", self._jit1,

@@ -17,6 +17,72 @@ from ziria_tpu.ops import cplx
 from ziria_tpu.ops.ofdm import LTS_FREQ, N_FFT, lts_time_symbol
 
 
+#: Output samples of one folded block. A one-channel convolution over
+#: few long rows is a shape the TPU crawls on: 64 taps over
+#: [8, 1, 131 072] took 86.9 ms on a v5e (12 M outputs/s) and 0.87 ms
+#: cut into 128 rows or more (1.2 G outputs/s, flat from 16 to 1024
+#: blocks a row); a lone row wants 256 blocks or more. The whole
+#: `locate_frames` at [8, 131 072, 2]: 421.1 ms unfolded, 5.8 ms at
+#: blocks of 128 to 8192 outputs, 6.4 at 1024 (a row of 1024 + 47
+#: tiles badly), 9.6 at 16 384 (chip runs, PR 35: PERF.md section 6).
+FOLD_BLOCK = 512
+
+
+def fold_blocks(n_out: int) -> int:
+    """How many overlapped blocks `correlate_valid` cuts a row of
+    ``n_out`` outputs into: as many as `FOLD_BLOCK` goes into it,
+    rounded up, and 1 (the row passes through unfolded) where the row
+    is at most two blocks long — the acquisition's window heads are
+    already many and short. A function of the row's length alone: it
+    is all a per-lane graph can see of its shape under ``vmap``, and
+    131 072 / 512 = 256 blocks reach the wide shape at any lane
+    count."""
+    return 1 if n_out <= 2 * FOLD_BLOCK else -(-n_out // FOLD_BLOCK)
+
+
+def fold_rows(rows: int, n: int) -> int:
+    """The batch the LTS correlation (`N_FFT` taps) of ``rows`` rows
+    of ``n`` samples hands the convolution: what `rx.fleet.put`
+    reports as ``locate_rows``."""
+    return rows * fold_blocks(n - N_FFT + 1)
+
+
+def correlate_valid(x, taps):
+    """``jnp.convolve(x, taps, mode="valid", precision="highest")`` of
+    one f32 row, value for value, in a shape the chip runs well: THE
+    sliding correlator of this module (`_sliding_sum`'s float path and
+    `lts_pair_metric`), so the per-capture oracle and the chunk scan
+    run the same function.
+
+    x: (n,), taps: (w,), n >= w. Returns (n - w + 1,). A long row is
+    cut into `fold_blocks` blocks of `FOLD_BLOCK` outputs, each with
+    the w - 1 samples of halo its last outputs read, the blocks ride
+    the convolution's batch axis (under ``vmap`` the lane axis merges
+    into the same batch) and their outputs are laid end to end again.
+    The same taps meet the same samples in the same order, so no
+    arithmetic is added, removed or reordered, and a value depends on
+    its own w-sample window alone — never on the block, the offset or
+    the array it landed in. "highest": the TPU's default convolution
+    precision is bfloat16."""
+    import jax
+
+    def conv(row):
+        return jnp.convolve(row, taps, mode="valid", precision="highest")
+
+    w = taps.shape[0]
+    n_out = x.shape[0] - w + 1
+    blocks = fold_blocks(n_out)
+    if blocks == 1:
+        return conv(x)
+    # block b reads x[b * L : (b + 1) * L + w - 1]: its own L samples
+    # and the head of the next block's (w - 1 <= L), zeros past the end
+    size = FOLD_BLOCK
+    body = jnp.pad(x, (0, (blocks + 1) * size - x.shape[0])) \
+        .reshape(blocks + 1, size)
+    rows = jnp.concatenate([body[:-1], body[1:, :w - 1]], axis=1)
+    return jax.vmap(conv)(rows).reshape(-1)[:n_out]
+
+
 def _sliding_sum(x, w: int):
     """Sliding window sums along axis 0: out[k] = sum(x[k:k+w]).
 
@@ -25,9 +91,11 @@ def _sliding_sum(x, w: int):
     window value c[k+w]-c[k] is a catastrophic cancellation once the
     prefix dwarfs the window (measured ~0.2% metric error at 14k
     samples, and host vs stream-sharded results diverged). The conv
-    accumulates only the w local terms, is position-independent — so
-    `parallel/streampar.sliding_parallel` shards bit-compatibly — and
-    a 48-tap conv is nothing on the VPU/MXU.
+    accumulates only the w local terms and is position-independent —
+    so `parallel/streampar.sliding_parallel` shards bit-compatibly and
+    `correlate_valid` may cut the row into blocks. It is cheap only
+    in the right shape: 48 taps over 8 rows of 131 072 took 64 ms on
+    a v5e (ledger, PR 34), which is why the rows are folded.
     """
     import jax
     x = jnp.asarray(x)
@@ -39,14 +107,11 @@ def _sliding_sum(x, w: int):
         return c[w:] - c[:-w]
     k = jnp.ones((w,), x.dtype)
 
-    def conv1(col):
-        # "highest": the TPU's default conv precision is bfloat16
-        return jnp.convolve(col, k, mode="valid", precision="highest")
-
     if x.ndim == 1:
-        return conv1(x)
+        return correlate_valid(x, k)
     flat = x.reshape(x.shape[0], -1)
-    out = jax.vmap(conv1, in_axes=1, out_axes=1)(flat)
+    out = jax.vmap(lambda col: correlate_valid(col, k),
+                   in_axes=1, out_axes=1)(flat)
     return out.reshape((out.shape[0],) + x.shape[1:])
 
 
@@ -144,13 +209,11 @@ def lts_pair_metric(samples, limit=None):
     lts = jnp.asarray(lts_time_symbol())                # (64, 2)
     ref = cplx.conj(lts)[::-1]                          # reversed conj
 
-    def conv1(u, v):
-        return jnp.convolve(u, v, precision="highest")
-
+    conv1 = correlate_valid                             # (n-63,) each
     re = conv1(x[:, 0], ref[:, 0]) - conv1(x[:, 1], ref[:, 1])
     im = conv1(x[:, 0], ref[:, 1]) + conv1(x[:, 1], ref[:, 0])
-    # full conv index 63+k = correlation at lag k
-    c = re[63:n] ** 2 + im[63:n] ** 2                   # (n-63,)
+    # valid conv index k = correlation at lag k
+    c = re ** 2 + im ** 2                               # (n-63,)
     pair = c[:-64] + c[64:]                             # two-peak sum
     return jnp.where(jnp.arange(pair.shape[0]) < lim - 127, pair, -1.0)
 
