@@ -20,7 +20,7 @@ from typing import Callable, Dict, NamedTuple, Optional, Tuple
 import numpy as np
 
 from . import annotations, checks, counts, load, loop, manifest, peaks, \
-    spans, xplane
+    spans, steady, xplane
 
 #: closed-loop ticks before the window: the first fills the lanes, the
 #: second launches the first chunk-step, the third drains it through
@@ -290,9 +290,10 @@ def measure(args, inspect=None, traffic=None):
                         **common)
                 elif traffic["loop"] == "open":
                     rate = traffic["rate_samples_per_s"] / len(sids)
+                    phases = load.phases(args.seed, len(sids), stride / rate)
                     arrivals = [load.Arrivals(
                         args.seed, i, traffic["slab_lo"], traffic["slab_hi"],
-                        rate, stride / rate) for i in range(len(sids))]
+                        rate, phases[i]) for i in range(len(sids))]
                     win = loop.run_open(srv, sids, laps, arrivals, WARM_TICKS,
                                         args.seconds, stride, chunk_len,
                                         sleep=time.sleep, **common)
@@ -375,8 +376,10 @@ def measure(args, inspect=None, traffic=None):
         realtime_sessions=rate / cfg["sample_rate_hz"],
         frames=len(emitted), frames_attempted=frames.attempted,
         dispatches=disp, peak_device_bytes=peak)
-    say("ticks", step_ms=[round(1e3 * x, 1) for x in rec.durations(
-        "bench.step", win.t_open, t_close)])
+    step_ms = [1e3 * x for x in rec.durations("bench.step", win.t_open,
+                                              t_close)]
+    say("ticks", step_ms=[round(x, 1) for x in step_ms])
+    say("steady", **steady.tick_summary(step_ms))
     for began, where in stalls.seen:
         say("stall", at_s=began - win.t_open, where=where)
     e2e = {"setup_s": setup_s, "samples_per_s": rate}
@@ -386,14 +389,20 @@ def measure(args, inspect=None, traffic=None):
         half = len(dl) // 2
         e2e["emit_delay_p50_ms"] = float(np.percentile(dl, 50))
         e2e["emit_delay_p90_ms"] = float(np.percentile(dl, 90))
-        say("paced", delay_samples=len(dl), delay_p50_ms=e2e[
-            "emit_delay_p50_ms"], delay_p90_ms=e2e["emit_delay_p90_ms"],
+        paced = dict(
+            delay_samples=len(dl), delay_p50_ms=e2e["emit_delay_p50_ms"],
+            delay_p90_ms=e2e["emit_delay_p90_ms"],
+            delay_p99_ms=float(np.percentile(dl, 99)),
             delay_p50_first_half_ms=float(np.median(dl[:half])),
             delay_p50_second_half_ms=float(np.median(dl[half:])),
             slabs=len(lt), late_p50_ms=float(np.median(lt)),
             late_max_ms=float(lt.max()), refused=win.refused,
             staged_at_close=sum(s.staged_samples
-                                for s in srv._sessions.values()))
+                                for s in srv._sessions.values()),
+            step_busy_p50_ms=steady.time_weighted_median(step_ms),
+            step_samples=rx.s * stride,
+            stalls=sum(1 for began, _ in stalls.seen if began >= win.t_open))
+        say("paced", **paced)
 
     metrics = {}
     if args.trace:
@@ -403,25 +412,30 @@ def measure(args, inspect=None, traffic=None):
                              f"window closed before the profiler ran")
         tr = xplane.read(path, need_device=on_tpu)
         n_dec = disp.get(checks.SITES[1], 0)
-        ctx = Reduction(rec, (win.t_open, t_close), {
+        tallies = {
             "samples_consumed": win.consumed, "chunk_steps": steps,
             "lanes": rx.s, "stride": stride, "ticks": win.ticks,
             "peak_device_bytes": peak,
             "h2d_bytes": steps * counts.scan_h2d_bytes(rx.s, chunk_len)
-            + n_dec * counts.decode_h2d_bytes(rx.s, rx.k),
-            "d2h_bytes": steps * counts.scan_d2h_bytes(rx.s, rx.k)
-            + n_dec * counts.decode_d2h_bytes(rx.s, rx.k,
-                                              rx.n_sym_bucket),
-            "acs_min_bytes": counts.acs_min_bytes(rx.s * rx.k,
-                                                  rx.n_sym_bucket),
-        }, tr, peaks.peaks_for(dev.device_kind) if on_tpu else {})
+            + n_dec * counts.decode_h2d_bytes(rx.s, rx.k)}
+        ctx = Reduction(rec, (win.t_open, t_close), tallies, tr,
+                        peaks.peaks_for(dev.device_kind) if on_tpu else {})
         an = annotations.for_ctx(ctx)
         prog_spans = an.spans if an is not None \
             else annotations.host_spans(path)
-        stale = counts.stale(prog_spans, rx.s, rx.k, rx.n_sym_bucket)
+        shape = (rx.s, rx.k, rx.n_sym_bucket)
+        stale = counts.stale(prog_spans, *shape)
         if stale:
-            raise SystemExit("the program's spans and the benchmark's "
-                             "counts disagree: " + "; ".join(stale))
+            raise SystemExit("the program's spans report what cannot "
+                             "be: " + "; ".join(stale))
+        # what was pulled and decoded, as the program reports it per
+        # call between its floor and the benchmark's ceiling
+        said = counts.reported(prog_spans, *shape)
+        tallies["d2h_bytes"] = \
+            steps * said[counts.PULL_SCAN] \
+            + n_dec * said[counts.PULL_DECODE]
+        tallies["acs_min_bytes"] = counts.ACS_BYTES_PER_STEP \
+            * said[counts.TRELLIS]
         for m in cell.per_layer:
             v = m.reduce(ctx, **m.args)
             if v is not None:
@@ -450,6 +464,16 @@ def measure(args, inspect=None, traffic=None):
             "device": device}
     if args.trace:
         line["breakdown"] = breakdown
+    if win.delays_s:
+        line["paced"] = paced
+    # every number compared beside its limit: the line's last key, and
+    # the last lines of standard error
+    line["compared"] = {r.name: [checks.plain(r.value), r.limit, r.how]
+                        for r in rows}
+    for r in rows:
+        print(f"compared {r.name} {r.value} {r.how} {r.limit} "
+              f"{'ok' if r.ok else 'NOT OK'}", file=sys.stderr)
+    sys.stderr.flush()
     return line, {r.name: r.value for r in rows}
 
 

@@ -46,6 +46,13 @@ class Compared(NamedTuple):
             else self.value <= self.limit
 
 
+def plain(x):
+    """A compared value as the result line can carry it: a python
+    number, and None for a NaN (which JSON has no word for)."""
+    x = float(x)
+    return None if x != x else int(x) if x == int(x) else x
+
+
 def limits() -> Dict[str, float]:
     with open(os.path.join(os.path.dirname(__file__), "limits.json")) as f:
         return json.load(f)["limits"]

@@ -55,7 +55,9 @@ def closed_tick(srv, sids, laps, pos: List[int], slab: int, rec):
 
 def _warm_up(srv, sids, laps, pos, slab, ticks, rec, session_of):
     """Closed-loop ticks before the window; their frames are kept for
-    the checks, stamped before 0."""
+    the checks, stamped before 0. A count and not a time (PR 36 tried
+    "and 2 s at least": the spread did not follow, and the window's
+    place in the stream stays a function of the seed)."""
     return [Emitted(-1.0, session_of(sid), fr) for _ in range(ticks)
             for sid, fr in closed_tick(srv, sids, laps, pos, slab, rec)]
 

@@ -150,4 +150,13 @@ def problems(root: str = ROOT) -> List[str]:
             elif not _reports(e2e[m["moves"]], c):
                 out.append(f"{m['name']}: cell {c} does not report "
                            f"{m['moves']}")
+    # the bounds against the runs they were derived from
+    # (benchmark/bounds/: a new cell needs no record there), and an
+    # open-loop mix's rate against the sweeps it records (PR 36)
+    from . import bounds
+    out += bounds.problems(man)
+    for mix in sorted({w["traffic"] for w in man["workloads"]}):
+        path = os.path.join(HERE, "traffic", mix + ".json")
+        if os.path.isfile(path):
+            out += bounds.rate_problems(mix, _json(path))
     return out

@@ -166,7 +166,7 @@ def test_open_loop_times_each_frame_from_its_slabs_due_time():
     clock = FakeClock()
     srv = StubRuntime(1, clock, step_s=0.01)
     arr = [load.Arrivals(seed=3, i=0, slab_lo=10, slab_hi=11, rate=100.0,
-                         phase_span_s=0.0)]
+                         phase_s=0.0)]
     win = loop.run_open(srv, [0], laps(1), arr, warm_ticks=2, seconds=3.0,
                         stride=STRIDE, chunk_len=CHUNK,
                         consumed=srv.consumed, clock=clock,
@@ -305,3 +305,35 @@ def test_closed_loop_five_ticks_are_five_launches_the_window_as_it_was(
     assert tick0 == 10 and tick1 - tick0 == 5 == n1 - n0
     assert t1 - t0 == pytest.approx(5 * 0.44)
     assert w.calls == ["start", "stop"]
+
+
+# --------------------- PR 36: the warm-up is a count, the phases even
+
+
+@pytest.mark.parametrize("step_s", [0.5, 0.026, 3.0])
+def test_the_warm_up_is_its_ticks_whatever_a_tick_takes(step_s):
+    """PR 36 tried "and 2 s at least" and dropped it: the number of
+    warm-up ticks then followed the clock, and with it the place in
+    every session's stream at which the window opens."""
+    clock = FakeClock()
+    srv = StubRuntime(2, clock, step_s=step_s)
+    opened = []
+    win = loop.run_closed(srv, [0, 1], laps(2), STRIDE, seconds=1.0,
+                          warm_ticks=4, consumed=srv.consumed, clock=clock,
+                          rec=spans.Recorder(), session_of=int,
+                          on_open=lambda: opened.append(
+                              (clock.t, srv.consumed())))
+    # three of the four ticks found a chunk waiting in both lanes
+    assert opened == [(pytest.approx(4 * step_s), 3 * 2 * STRIDE)]
+    assert win.t_open == opened[0][0]
+    assert win.consumed == win.ticks * 2 * STRIDE
+
+
+@pytest.mark.parametrize("seed", [0, 7, 2 ** 31 + 5, 3600004001])
+def test_even_phases_are_the_same_eighths_dealt_by_the_seed(seed):
+    period = 0.04
+    got = load.phases(seed, 8, period)
+    assert sorted(got) == pytest.approx([j * period / 8 for j in range(8)])
+    assert got == load.phases(seed, 8, period)
+    # another seed deals them in another order (these four all differ)
+    assert got != load.phases(seed + 1, 8, period)

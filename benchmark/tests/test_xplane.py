@@ -85,9 +85,34 @@ def test_breakdown(tr):
     assert gaps and all(s > 0 for _n, s in gaps)
     idle = tr.window_s - tr.busy_s
     assert sum(s for _n, s in gaps) <= idle + 1e-9
-    # charged to python frames of the served path or to our own spans
-    assert any(n.startswith(("$framebatch.py", "$serve.py", "bench.",
-                             "rx.")) for n, _s in gaps)
+    # charged to spans, ours here (PR 24's trace: the program had
+    # none yet), never to a python frame, whose line moves with every
+    # edit (PR 36)
+    assert {n for n, _s in gaps} == {"bench.step", "(gaps under 50 us)"}
+
+
+def test_idle_gaps_are_charged_to_the_programs_spans_serve_among_them():
+    """The trace of PR 25 (the program's spans on): ``serve.*`` are
+    among the host events a gap may be charged to, and the deepest one
+    open takes it."""
+    tr = xplane.read(os.path.join(os.path.dirname(TRACE),
+                                  "tiny_v5e_scoped.xplane.pb"))
+    names = {e.name for e in tr.host}
+    assert {"serve.step", "serve.stage", "serve.emit",
+            "rx.fleet.pull_decode", "bench.tick"} <= names
+    assert not any(n.startswith("$") for n in names)
+    gaps = dict(xplane.idle_gaps(tr))
+    assert max(gaps, key=gaps.get) == "rx.fleet.pull_decode"
+    assert all(xplane.HOST_LABEL.match(n) or n.startswith("(")
+               for n in gaps)
+    # a gap outside every rx.* span but inside step() goes to serve.step
+    step = next(e for e in tr.host if e.name == "serve.step")
+    inner = [e for e in tr.host if e.name != "serve.step"
+             and step.start <= e.start and e.end <= step.end
+             and not e.name.startswith("bench.")]
+    free = step.start + 1.0
+    assert not any(e.start <= free <= e.end for e in inner)
+    assert xplane._label(tr.host, free) == "serve.step"
 
 
 def test_a_trace_without_a_device_is_refused(tmp_path):
