@@ -32,7 +32,9 @@ with, and nothing else: the same seeded load through a sharded
 (``ServeConfig(shard=True)``, 8 lanes over 4 devices) and an unsharded
 runtime at the default ``Geometry()``, frames identical, the chunk
 scan's outputs on four distinct devices, the same nothing-hidden
-checks on both.
+checks on both. Then the fleet the runtime places by itself: 32
+sessions on 32 lanes with NO ``shard`` argument (the rule gives 8
+lanes a chip: a 4-device mesh) against ``shard=False``, same checks.
 
 The printed seconds and bytes are observations for PERF.md, not
 metrics; a CPU rehearsal's are not even that.
@@ -55,6 +57,9 @@ DEFAULT_LOAD = dict(n_sessions=8, frames_per_session=16, n_bytes=48)
 TINY = dict(n_lanes=8, chunk_len=4096, frame_len=1024,
             max_frames_per_chunk=8)
 TINY_LOAD = dict(n_sessions=8, frames_per_session=16, n_bytes=12)
+#: the fleet `--four-chips` lets the runtime place by itself: four
+#: chips x the tuned 8 lanes (`mtu32x4.saturated`'s width)
+WIDE_LANES = 32
 
 RESILIENCE_COUNTERS = ("resilience.fatal", "resilience.fallbacks",
                        "resilience.degraded", "resilience.retries",
@@ -357,6 +362,31 @@ def main(argv=None) -> int:
               f"chunk-scan outputs not on four distinct devices: "
               f"{placed}")
         say("placement", sharded_equals_unsharded=True,
+            chunk_scan_output_devices="|".join(placed[-1]))
+        # 32 lanes, placed by the runtime's own rule: no argument
+        wide = base._replace(n_lanes=WIDE_LANES)
+        check(wide.shard is None, "ServeConfig.shard defaults to None")
+        load = dict(load, n_sessions=WIDE_LANES, frames_per_session=2)
+        clients = serve.synth_load(seed=SEED, tail=base.frame_len,
+                                   **load)
+        sent = sent_frames(load)
+        f32, outs, st32 = serve_once(wide, clients, on_tpu,
+                                     "[32-lanes-by-rule]")
+        check_frames(f32, sent, clients, wide, "[32-lanes-by-rule]")
+        placed = [sorted(str(s.device) for s in o.addressable_shards)
+                  for o in outs]
+        check(all(len(set(p)) == 4 for p in placed),
+              f"32 lanes by the rule: chunk-scan outputs not on four "
+              f"distinct devices: {placed}")
+        f32one, outs1, _s = serve_once(wide._replace(shard=False),
+                                       clients, on_tpu,
+                                       "[32-lanes-1-device]")
+        check(all(len(o.addressable_shards) == 1 for o in outs1),
+              "shard=False left the fleet on more than one device")
+        same_frames(f32, f32one)
+        say("placement-by-rule", lanes=WIDE_LANES,
+            lanes_per_chip=WIDE_LANES // 4,
+            sharded_equals_unsharded=True, chunk_steps=st32.chunk_steps,
             chunk_scan_output_devices="|".join(placed[-1]))
     else:
         geo = TINY if args.rehearse else MTU

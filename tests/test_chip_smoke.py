@@ -60,4 +60,8 @@ def test_four_chip_rehearsal_on_virtual_devices():
     out = r.stdout
     assert "[placement] sharded_equals_unsharded=True" in out
     assert "TFRT_CPU_0|TFRT_CPU_1|TFRT_CPU_2|TFRT_CPU_3" in out
+    # and the 32-lane fleet the runtime places with no argument
+    assert "[placement-by-rule] lanes=32 lanes_per_chip=8 " \
+        "sharded_equals_unsharded=True" in out
+    assert out.count("TFRT_CPU_0|TFRT_CPU_1|TFRT_CPU_2|TFRT_CPU_3") == 2
     assert '"ok"' not in out and '"count": 4' in out

@@ -239,6 +239,28 @@ def test_bytes_on_put_and_pulls_redo_the_shape_arithmetic(runs):
         == {S * K * mixed_trellis_steps(bucket) + S * K}
 
 
+def test_put_and_pulls_say_how_many_devices_they_touch(runs):
+    """ISSUE 37: `rx.fleet.put` carries the mesh size and the fleet
+    width (a per-layer metric divides them: lanes a chip), the two
+    pulls the device-to-host transfers they issue (nine scan scalars,
+    the decode's clear bits and FCS flags, from every device), and the
+    gauge `rx.mesh_devices` is set once, as the receiver is built, in
+    the runtime's own registry. One device here: 8 lanes stay on one
+    chip on any host (tests/test_fleet_placement.py has four)."""
+    srv, spans, _traced, _plain, _built = runs
+    assert srv._rx.mesh is None
+    puts = _named(spans, "rx.fleet.put")
+    assert puts and all(e["args"]["devices"] == 1
+                        and e["args"]["lanes"] == S for e in puts)
+    assert {e["args"]["shards"]
+            for e in _named(spans, "rx.fleet.pull_scan")} == {9}
+    assert {e["args"]["shards"]
+            for e in _named(spans, "rx.fleet.pull_decode")} == {2}
+    g = srv.registry.find(telemetry.GAUGE_METRIC, site="rx.mesh_devices")
+    assert g is not None and g.last == 1.0 and len(g.samples) == 1
+    assert 'ziria_gauge{site="rx.mesh_devices"} 1.0' in srv.scrape()
+
+
 def test_put_names_the_batch_the_detector_convolves_over(runs):
     """`locate_rows` (PR 35): lanes a device x the blocks
     `sync.correlate_valid` cuts a chunk-long row into, from the

@@ -640,23 +640,39 @@ def _stream_geometry(r) -> dict:
             "fused_demap": bool(r.fused_demap)}
 
 
-def _pull_chunk(outs, span):
+def _chunk_scalars(outs):
+    """The nine per-lane arrays the host pulls of a chunk scan's
+    eleven outputs: all but the CFO estimate and the segments, which
+    stay on the device for the decode."""
+    return outs[:5] + outs[6:10]
+
+
+def _start_pull(arrays) -> None:
+    """Send every shard of every array on its way to the host without
+    blocking on any: the reads that follow wait for the slowest device
+    once, not for each transfer in turn (a sharded fleet's nine scan
+    scalars are 36 transfers, its decode's pull 8)."""
+    for x in arrays:
+        x.copy_to_host_async()
+
+
+def _pull_chunk(outs, span, sharded: bool = False):
     """Materialize a chunk scan's per-lane scalars on the host. On an
     ASYNC backend a runtime failure mid-execution surfaces HERE, at
     the first host pull, not inside the guarded dispatch — callers
     wrap this and re-run the chunk through the guarded path when it
     throws (the launched results are lost either way). `segs` stays
     device-resident for the decode dispatch. ``span`` (name, args)
-    is opened around the blocking pulls alone."""
+    is opened around the blocking pulls alone. ``sharded``: the
+    arrays lie over a mesh (`_start_pull` first: a no-op for what
+    `_launch` already sent on its way, the whole of it for a rescan)."""
     from ziria_tpu.utils import telemetry
 
-    (own, starts, overflow, found, fstart, _eps, rb, ln, pk, nv,
-     segs) = outs
+    scalars, segs = _chunk_scalars(outs), outs[10]
     with telemetry.span(*span):
-        return (np.asarray(own), np.asarray(starts),
-                np.asarray(overflow), np.asarray(found),
-                np.asarray(fstart), np.asarray(rb), np.asarray(ln),
-                np.asarray(pk), np.asarray(nv), segs)
+        if sharded:
+            _start_pull(scalars)
+        return tuple(np.asarray(x) for x in scalars) + (segs,)
 
 
 def _record_degraded(entered: bool) -> None:
@@ -695,6 +711,8 @@ def _guarded_decode(r, label: str, dec, *args, pull_span):
             with telemetry.span(pull_span[0], dict(
                     pull_span[1],
                     bytes=int(clear.nbytes + crc.nbytes))):
+                if r.mesh is not None:
+                    _start_pull((clear, crc))
                 return np.asarray(clear, np.uint8), np.asarray(crc)
         except Exception:        # noqa: BLE001 - async pull loss
             if attempt:
@@ -794,6 +812,7 @@ class MultiStreamReceiver:
         from ziria_tpu.ops.viterbi import _check_radix
         from ziria_tpu.phy.wifi import rx as _rx
         from ziria_tpu.runtime import resilience
+        from ziria_tpu.utils import dispatch
 
         # ONE declarative geometry supplies every default the caller
         # leaves None (explicit per-knob args still win); the default
@@ -876,9 +895,12 @@ class MultiStreamReceiver:
         # the batch the detector's LTS convolutions run over at this
         # geometry on each device (`rx.fleet.put`'s `locate_rows`):
         # from the function that picks the fold, so it cannot drift
+        self._n_devices = mesh.size if mesh is not None else 1
         self._locate_rows = _sync.fold_rows(
-            self.s // (mesh.size if mesh is not None else 1),
-            self.chunk_len)
+            self.s // self._n_devices, self.chunk_len)
+        # how many devices the lane axis lies over: set once, the
+        # placement is fixed for the receiver's life
+        dispatch.record_gauge("rx.mesh_devices", self._n_devices)
         self.sanitize = bool(sanitize)
         self._policy = resilience.default_policy(
             max_retries=max_retries, timeout_s=watchdog_s)
@@ -1281,12 +1303,17 @@ class MultiStreamReceiver:
         with telemetry.span("rx.fleet.put", {
                 "step": step, "bytes": arrs.nbytes + valid.nbytes
                 + own_lo.nbytes + own_hi.nbytes,
-                "locate_rows": self._locate_rows}):
+                "locate_rows": self._locate_rows,
+                "devices": self._n_devices, "lanes": self.s}):
             chunk_args = (self._put(arrs), self._put(valid),
                           self._put(own_lo), self._put(own_hi))
         programs.note_site("rx.stream_chunk_multi", self._jit1,
                            *chunk_args)
         outs = self._scan_dispatch(chunk_args)
+        if self.mesh is not None:
+            # the scan's scalars leave each device as its scan ends, a
+            # tick before `_drain` reads them
+            _start_pull(_chunk_scalars(outs))
         self._chunk_steps += 1
         self._inflight += 1
         self._max_in_flight = max(self._max_in_flight, self._inflight)
@@ -1351,9 +1378,12 @@ class MultiStreamReceiver:
         offs, active, arrs, valids, own_lo, own_hi, outs = pend
 
         def pull(o):
+            pulled = _chunk_scalars(o)
             return _pull_chunk(o, ("rx.fleet.pull_scan", {
-                "step": step, "bytes": sum(
-                    int(x.nbytes) for x in o[:5] + o[6:10])}))
+                "step": step,
+                "bytes": sum(int(x.nbytes) for x in pulled),
+                "shards": len(pulled) * self._n_devices}),
+                sharded=self.mesh is not None)
         try:
             (own, starts, overflow, found, fstart, rb, ln, pk, nv,
              segs) = pull(outs)
@@ -1418,7 +1448,8 @@ class MultiStreamReceiver:
                                    *dec_args)
                 got = _guarded_decode(
                     self, "rx.stream_decode_multi", dec, *dec_args,
-                    pull_span=("rx.fleet.pull_decode", {"step": step}))
+                    pull_span=("rx.fleet.pull_decode", {
+                        "step": step, "shards": 2 * self._n_devices}))
             if got is None:
                 # degrade the WHOLE fleet's decode to the per-capture
                 # oracle (bit-identical by the pinned contract), this

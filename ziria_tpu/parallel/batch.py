@@ -44,18 +44,26 @@ def largest_divisor(n: int, cap: int) -> int:
 
 
 def elastic_mesh(n_streams: int, n_devices: Optional[int] = None,
-                 axis: str = "dp") -> Optional[Mesh]:
+                 axis: str = "dp", min_lanes: int = 1) -> Optional[Mesh]:
     """The ELASTIC placement rule (ISSUE 14 failover): build the
     widest dp mesh the surviving device fleet supports for an
     ``n_streams``-lane receiver — the largest divisor of S that fits
-    the visible (or capped) device count. Returns None when that is
+    the visible (or capped) device count and leaves every device at
+    least ``min_lanes`` lanes (the floor the served runtime places
+    by: under the tuned fleet width a chip's decode tile is mostly
+    padding, and the chip is wasted). Returns None when that is
     one device (an unsharded receiver is the correct degenerate
     mesh), so recovery onto a shrunken ``--devices`` — or a machine
     that lost a chip — rebuilds the fleet on whatever is left instead
     of refusing to start."""
+    if min_lanes < 1:
+        raise ValueError(f"need min_lanes >= 1, got {min_lanes}")
+    cap = n_streams // min_lanes
+    if cap <= 1:                # one device, and no backend is asked
+        return None
     avail = len(jax.devices()) if n_devices is None \
         else min(n_devices, len(jax.devices()))
-    m = largest_divisor(n_streams, max(1, avail))
+    m = largest_divisor(n_streams, max(1, min(avail, cap)))
     return None if m <= 1 else frame_mesh(m, axis)
 
 

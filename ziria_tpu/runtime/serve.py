@@ -117,7 +117,10 @@ class ServeConfig(NamedTuple):
     snapshot_keep: int = 2
     journal_segment_records: int = 256
     jitter_seed: int = 0             # retry-after hint jitter seed
-    shard: bool = False              # elastic dp mesh over the lanes
+    # the lane axis over a dp mesh: None places by rule (the widest
+    # mesh that leaves every chip the tuned fleet width), True takes
+    # every chip the lanes divide over, False none
+    shard: Optional[bool] = None
 
     @classmethod
     def from_geometry(cls, geo: "_geometry.Geometry",
@@ -271,8 +274,13 @@ class ServeRuntime:
         self.clock = clock
         self.registry = registry if registry is not None \
             else telemetry.MetricsRegistry()
-        self._rx = receiver if receiver is not None \
-            else self._default_receiver()
+        if receiver is not None:
+            self._rx = receiver
+        else:
+            # what the receiver reports once, as it is built, lands
+            # in this runtime's registry (`rx.mesh_devices`)
+            with telemetry.collect(self.registry):
+                self._rx = self._default_receiver()
         self._free = list(range(self.cfg.n_lanes))
         self._lane_sid: Dict[int, Any] = {}
         self._sessions: Dict[Any, _Session] = {}
@@ -311,13 +319,18 @@ class ServeRuntime:
         from ziria_tpu.backend import framebatch
         c = self.cfg
         mesh = None
-        if c.shard:
+        if c.shard is not False:
             # the ELASTIC placement rule: shard the lane axis over
             # the widest S-divisible mesh the surviving devices
             # support — a recovery onto fewer chips rebuilds the
-            # fleet instead of refusing to start (ISSUE 14)
+            # fleet instead of refusing to start (ISSUE 14). Left to
+            # itself (``shard=None``) the runtime takes a further chip
+            # only where each keeps the tuned fleet width: 8 lanes
+            # stay on one chip on any host, 32 lie over four
             from ziria_tpu.parallel import batch as pbatch
-            mesh = pbatch.elastic_mesh(c.n_lanes)
+            mesh = pbatch.elastic_mesh(
+                c.n_lanes,
+                min_lanes=1 if c.shard else _GEO.n_streams)
         return framebatch.MultiStreamReceiver(
             c.n_lanes, chunk_len=c.chunk_len, frame_len=c.frame_len,
             max_frames_per_chunk=c.max_frames_per_chunk,
