@@ -176,6 +176,7 @@ def serve_once(cfg, clients, on_tpu: bool, tag: str):
     say(f"served{tag}", chunk_steps=st.chunk_steps, frames=st.frames,
         dispatches=n_disp, overflow_chunks=st.overflow_chunks,
         degraded=st.degraded, quarantines=st.quarantines,
+        max_in_flight=st.max_in_flight,
         compiles_after_warmup=n_compiles, wall_s=round(wall, 2),
         s_per_chunk_step=wall / max(1, st.chunk_steps),
         **{c.replace(".", "_"): v for c, v in counters.items()})
@@ -404,6 +405,14 @@ def main(argv=None) -> int:
         check(per_lane >= 5 and st.chunk_steps >= per_lane,
               f"{per_lane} chunk-steps per lane ({st.chunk_steps} fleet "
               f"steps) is under five")
+        # eight lanes outrun their device: the pipeline fills. (The
+        # --four-chips fleets print the depth and are not held to it: a
+        # host slower than its devices hands every step back from a
+        # call that launches nothing, before the next launch.)
+        check(st.max_in_flight == 3,
+              f"the pipeline held {st.max_in_flight} chunk-step(s) in "
+              f"flight at most, not 3: a scan and a decode were never "
+              f"queued behind the step the host waited for")
         check_frames(frames, sent_frames(load), clients, cfg, "")
         check_reference(clients, frames, cfg)
 

@@ -232,17 +232,16 @@ def test_spans_and_gauge_name_the_four_devices(served):
         assert g.last == want and len(g.samples) == 1
 
 
-def test_a_sharded_step_starts_every_copy_before_it_blocks(served):
-    """Only where a mesh is set: the nine scan scalars leave their
-    devices as the step is launched and again (a no-op) where
-    `_pull_chunk` is about to read them, the decode's two arrays before
-    its first read. The one-device path never calls it."""
-    _streams_, _sent, (srv4, _g4, _p4), _one, spans = served
-    assert STARTED["one device"] == []
-    got = STARTED["sharded"]
-    steps = srv4._rx.stats.chunk_steps
+def test_a_step_starts_every_copy_before_it_blocks(served):
+    """On a mesh and on one device alike: the nine scan scalars leave
+    their devices as the step is launched, the decode's two arrays as
+    it is dispatched, each a launch before the host reads them."""
+    _streams_, _sent, (srv4, _g4, _p4), (srv1, _g1, _p1), spans = served
     decodes = sum(1 for e in spans if e["name"] == "rx.fleet.pull_decode")
-    assert got.count(9) == 2 * steps and got.count(2) == decodes >= 1
-    assert set(got) == {9, 2}
-    # launched first: a step's scalars are on their way a tick early
-    assert got[0] == 9
+    for got, srv in ((STARTED["sharded"], srv4),
+                     (STARTED["one device"], srv1)):
+        assert got.count(9) == srv._rx.stats.chunk_steps
+        assert got.count(2) == decodes >= 1
+        assert set(got) == {9, 2}
+        # launched first: a step's scalars are on their way a tick early
+        assert got[0] == 9

@@ -45,7 +45,7 @@ def _n_sym(mbps: int) -> int:
 
 def _serve(streams):
     """Every stream through a fresh ServeRuntime, a stride a session a
-    tick, then the in-flight step. Returns (runtime, emitted pairs)."""
+    tick, then the steps in flight. Returns (runtime, emitted pairs)."""
     srv = serve.ServeRuntime(serve.ServeConfig(
         n_lanes=S, chunk_len=CHUNK, frame_len=FRAME_LEN,
         max_frames_per_chunk=K, check_fcs=True))
@@ -61,8 +61,9 @@ def _serve(streams):
                 srv.submit(f"s{i}", slab)
             out += srv.step()
             pos += SLAB
-        out += [(srv._lane_sid[ln], fr)
-                for ln, fr in srv._rx.drain_pending()]
+        # the two chunk-steps still in flight, through the runtime's
+        # own emit (`serve.emit` counts them like any other)
+        out += srv._emit(srv._rx.drain_pending())
     return srv, out
 
 
@@ -119,7 +120,7 @@ def test_every_span_of_the_table_is_recorded(runs):
                for e in _named(spans, "rx.fleet.ingest"))
 
 
-def test_step_pairs_each_stack_with_one_emit_a_tick_later(runs):
+def test_step_pairs_each_stack_with_one_emit_two_ticks_later(runs):
     srv, spans, _traced, _plain, _built = runs
     stacks = {e["args"]["step"]: e for e in _named(spans, "rx.fleet.stack")}
     emits = [e["args"]["step"] for e in _named(spans, "rx.fleet.emit")]
@@ -130,7 +131,12 @@ def test_step_pairs_each_stack_with_one_emit_a_tick_later(runs):
     def tick_of(e):
         return max(i for i, t in enumerate(ticks) if t["ts"] <= e["ts"])
 
-    for e in _named(spans, "rx.fleet.emit")[:-1]:   # the last: the tail
+    # every tick here launches, so a step's frames come out of the
+    # second launch after its own; the last two are the final drain's
+    for e in _named(spans, "rx.fleet.emit")[:-2]:
+        assert tick_of(e) == tick_of(stacks[e["args"]["step"]]) + 2
+    # its front half (the decode's dispatch) runs a tick before that
+    for e in _named(spans, "rx.fleet.classify")[:-1]:
         assert tick_of(e) == tick_of(stacks[e["args"]["step"]]) + 1
     # every other step-keyed span of a step lies between the two
     for e in spans:
@@ -142,6 +148,7 @@ def test_step_pairs_each_stack_with_one_emit_a_tick_later(runs):
         assert e["args"]["samples"] == S * CHUNK
     # the id rides beside the pending tuple, never inside it
     assert srv._rx._pending is None and srv._rx._pending_step is None
+    assert srv._rx.stats.max_in_flight == min(3, srv._rx.stats.chunk_steps)
 
 
 def test_useful_and_padded_symbols_from_the_frames_sent(runs):
@@ -259,6 +266,29 @@ def test_put_and_pulls_say_how_many_devices_they_touch(runs):
     g = srv.registry.find(telemetry.GAUGE_METRIC, site="rx.mesh_devices")
     assert g is not None and g.last == 1.0 and len(g.samples) == 1
     assert 'ziria_gauge{site="rx.mesh_devices"} 1.0' in srv.scrape()
+
+
+def test_put_and_pulls_say_how_the_pipeline_ran(runs):
+    """ISSUE 40: each pull says whether the device had finished before
+    the host asked (`ready` of `reads`: `decode_ready_share` divides
+    them), each put how many chunk-steps are in flight once its own is
+    launched, the counter `rx.pipeline_advances` which way each half of
+    a step's drain ran, the gauge the depth."""
+    srv, spans, _traced, _plain, _built = runs
+    steps = srv._rx.stats.chunk_steps
+    for name in ("rx.fleet.pull_scan", "rx.fleet.pull_decode"):
+        args = [e["args"] for e in _named(spans, name)]
+        assert args and all(a["reads"] == 1 and a["ready"] in (0, 1)
+                            for a in args)
+    assert sorted(e["args"]["in_flight"]
+                  for e in _named(spans, "rx.fleet.put")) \
+        == [min(3, n + 1) for n in range(steps)]
+    by_how = [srv.registry.find("rx.pipeline_advances", how=how)
+              for how in ("launch", "ready", "drain")]
+    assert sum(c.value for c in by_how if c is not None) == 2 * steps
+    g = srv.registry.find(telemetry.GAUGE_METRIC, site="rx.stream_inflight")
+    assert max(v for _t, v in g.samples) == min(3, steps)
+    assert 'rx_pipeline_advances{how="' in srv.scrape()
 
 
 def test_put_names_the_batch_the_detector_convolves_over(runs):

@@ -50,7 +50,7 @@ def test_the_manifest_is_sound_with_the_new_cell():
     assert cell.traffic["loop"] == "closed"
     names = {m.name for m in cell.per_layer}
     assert {"trellis_fill_share", "slot_fill_share",
-            "emit_ms_per_step"} <= names
+            "emit_ms_per_step", "decode_ready_share"} <= names
     # the two counts that read stale since PR 32 get no new cell
     assert not {"acs_roofline", "d2h_bytes_per_step"} & names
     assert {m["name"] for m in cell.end_to_end} \

@@ -493,6 +493,14 @@ class _Unpullable:
         raise RuntimeError("UNAVAILABLE: link died mid-execution")
 
 
+def _lose_scans(fleet) -> None:
+    """Every chunk-step in flight whose scan the host has not read
+    yet loses its device handles."""
+    for st in fleet._flight:
+        if not st.fronted:
+            st.outs = tuple(_Unpullable() for _ in range(11))
+
+
 def test_async_pull_failure_rescans_chunk(corpus):
     """On an async backend a runtime failure surfaces at the host
     pull, AFTER the guarded dispatch returned — the receiver must
@@ -502,9 +510,8 @@ def test_async_pull_failure_rescans_chunk(corpus):
     with telemetry.collect() as reg:
         sr = framebatch.StreamReceiver(**GEO)
         frames = sr.push(stream)
-        # sabotage the in-flight chunk's device handles
-        sr.fleet._pending = sr.fleet._pending[:6] + (
-            tuple(_Unpullable() for _ in range(11)),)
+        # sabotage the device handles of the scan still in flight
+        _lose_scans(sr.fleet)
         frames += sr.flush()
     _same_frames(frames, frames_c)
     assert not sr.stats.degraded     # the rescan's compiled path won
@@ -516,10 +523,7 @@ def test_async_pull_failure_rescans_fleet_step(corpus):
     with telemetry.collect() as reg:
         msr = framebatch.MultiStreamReceiver(4, **GEO)
         got = msr.push_many([s for s in streams])
-        if msr._pending is not None:
-            offs, active, arrs, valid, olo, ohi, _outs = msr._pending
-            msr._pending = (offs, active, arrs, valid, olo, ohi,
-                            tuple(_Unpullable() for _ in range(11)))
+        _lose_scans(msr)
         got += msr.flush()
     per = [[] for _ in range(4)]
     for i, fr in got:

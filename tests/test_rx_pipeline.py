@@ -1,0 +1,577 @@
+"""The receiver's pipeline (ISSUE 40): three chunk-steps in flight, a
+launch that blocks only on the oldest, a push that launches nothing and
+hands back what is ready.
+
+What comes out never depends on when: over seeded streams, lockstep and
+ragged pushes, one lane and eight, one device and the suite's mesh, the
+pipelined receiver's emissions equal, frame for frame, byte for byte
+and in order, those of the per-capture oracle (``streaming=False``)
+and of the plain numpy reference, wherever `drain_pending`,
+`flush_stream`, `reset_stream` or `checkpoint` / `restore_stream` fall
+between two steps in flight. WHEN is pinned where it is a rule: a
+launch hands back the frames of `_pending`'s chunk-step, the oldest.
+"""
+
+import numpy as np
+import pytest
+
+from benchmark.reference.wifi_rx_ref import np_receive
+from ziria_tpu.backend import framebatch
+from ziria_tpu.phy import link
+from ziria_tpu.runtime import serve
+from ziria_tpu.utils import telemetry
+from ziria_tpu.utils.bits import np_bits_to_bytes
+
+N_BYTES = 12     # +4 FCS = the suite's standard 16-byte on-air PSDU
+CHUNK, FRAME_LEN, K, S = 4096, 1024, 8, 8
+STRIDE = CHUNK - FRAME_LEN
+GEO = dict(chunk_len=CHUNK, frame_len=FRAME_LEN,
+           max_frames_per_chunk=K, check_fcs=True)
+RATES_OF = [6, 9, 12, 18, 24, 36, 48, 54]
+N_FRAMES = 10
+#: samples of every stream pushed before the drain point: three chunks,
+#: the last completed by the last sample, so the push that ends there
+#: launches and leaves two chunk-steps in flight
+P = CHUNK + 2 * STRIDE
+SLAB = 1024
+SCENARIOS = ("none", "drain", "checkpoint", "flush_stream",
+             "reset_stream")
+
+
+def _stream(rng, seed):
+    """One seeded stream of N_FRAMES frames behind a stretch of idle
+    air that keeps every frame off sample P (a lane flushed there
+    closes between two frames)."""
+    psdus = [rng.integers(0, 256, N_BYTES).astype(np.uint8)
+             for _ in range(N_FRAMES)]
+    rates = [RATES_OF[(seed + n) % 8] for n in range(N_FRAMES)]
+    st, starts = link.stream_many(
+        psdus, rates, gaps=rng.integers(1000, 1600, N_FRAMES - 1),
+        snr_db=30.0, cfo=1e-4, delay=60, seed=seed, add_fcs=True,
+        tail=FRAME_LEN)
+    idle = next(d for d in range(0, 3000, 97) if not any(
+        P - FRAME_LEN - 100 < s + d < P + 64 for s in starts))
+    st = np.concatenate([np.zeros((idle, 2), np.float32), st])
+    assert len(st) > P + 2 * STRIDE        # five chunk-steps and a tail
+    return st, [int(s) + idle for s in starts]
+
+
+@pytest.fixture(scope="module")
+def corpus():
+    """Eight seeded streams, the second stream lane S-1 (or 0) takes
+    after a `reset_stream`, and every stream's true frame starts."""
+    rng = np.random.default_rng(20260930)
+    made = [_stream(rng, 400 + i) for i in range(S + 1)]
+    return ([st for st, _ in made[:S]], [sts for _, sts in made[:S]],
+            made[S][0], made[S][1])
+
+
+def _cuts(rng, lo, hi, ragged):
+    """Slab edges of one stream from sample ``lo`` to ``hi``."""
+    if hi <= lo:
+        return [lo]
+    if not ragged:
+        return list(range(lo, hi, SLAB)) + [hi]
+    n = max(1, (hi - lo) // SLAB)
+    inner = sorted(set(int(x) for x in rng.integers(lo + 1, hi, n)))
+    return [lo] + inner + [hi]
+
+
+def _rounds(rng, streams, lo, his, ragged):
+    """Push rounds from ``lo`` to each stream's ``his[i]``: a dict of
+    slabs a round, the LAST round carrying every stream's last slab."""
+    cuts = [_cuts(rng, lo, hi, ragged) for hi in his]
+    n = max((len(c) - 1 for c in cuts), default=0)
+    rounds = []
+    for r in range(n):
+        push = {}
+        for i, c in enumerate(cuts):
+            k = r - (n - (len(c) - 1))     # right-aligned: all end at n
+            if k >= 0:
+                push[i] = streams[i][c[k]: c[k + 1]]
+        rounds.append(push)
+    return rounds
+
+
+def _drive(rx, streams, alt, scenario, ragged, seed, at_point=None):
+    """Phase one (``P`` samples a stream), the scenario's drain point on
+    lane j = S-1, phase two (the rest; lane j's second stream after a
+    reset, nothing after its flush), `flush`. Returns the emissions
+    before the reset took effect and after it."""
+    rng = np.random.default_rng(seed)
+    s = len(streams)
+    j = s - 1
+    before = []
+    for push in _rounds(rng, streams, 0, [P] * s, ragged):
+        before += rx.push_many(push)
+    if at_point is not None:
+        at_point(rx, j)
+    rest = list(streams)
+    his = [len(st) for st in streams]
+    lo = P
+    if scenario == "drain":
+        before += rx.drain_pending()
+        assert rx._pending is None and rx._pending_step is None
+    elif scenario == "checkpoint":
+        blob, out = rx.checkpoint(j)
+        before += out
+        assert rx._pending is None
+        before += rx.restore_stream(j, blob)
+    elif scenario == "flush_stream":
+        before += rx.flush_stream(j)
+        assert rx._pending is None
+        his[j] = P                          # nothing more for lane j
+    elif scenario == "reset_stream":
+        before += rx.reset_stream(j)
+        assert not rx._pending_touches(j)
+    after = []
+    if scenario == "reset_stream":
+        # lane j starts over at sample 0 of its second stream, the
+        # others go on from P: two schedules, interleaved round by round
+        a = _rounds(rng, rest[:j], lo, his[:j], ragged)
+        b = _rounds(rng, [alt], 0, [len(alt)], ragged)
+        for r in range(max(len(a), len(b))):
+            push = dict(a[r]) if r < len(a) else {}
+            if r < len(b):
+                push[j] = b[r][0]
+            after += rx.push_many(push)
+    else:
+        for push in _rounds(rng, rest, lo, his, ragged):
+            after += rx.push_many({i: x for i, x in push.items()
+                                   if len(x)})
+    after += rx.flush()
+    return before, after
+
+
+def _per_stream(pairs, s):
+    per = [[] for _ in range(s)]
+    for i, fr in pairs:
+        per[i].append(fr)
+    return per
+
+
+def _same_result(a, b) -> bool:
+    return (a.ok == b.ok and a.rate_mbps == b.rate_mbps
+            and a.length_bytes == b.length_bytes
+            and np.array_equal(a.psdu_bits, b.psdu_bits)
+            and a.crc_ok == b.crc_ok)
+
+
+ORACLE = {}
+
+
+def _oracle(streams, alt):
+    """The per-capture oracle (``streaming=False``: every owned window
+    through `rx.receive`) over the whole of every stream, on one
+    device, once a fleet width; and over lane j's second stream."""
+    key = len(streams)
+    if key not in ORACLE:
+        rx = framebatch.MultiStreamReceiver(
+            len(streams), streaming=False, **GEO)
+        out = rx.push_many(list(streams)) + rx.flush()
+        ORACLE[key] = _per_stream(out, len(streams))
+    if "alt" not in ORACLE:
+        ORACLE["alt"], _st = framebatch.receive_stream(
+            alt, streaming=False, **GEO)
+    return ORACLE[key], ORACLE["alt"]
+
+
+def _expected(whole, second, i, j, scenario):
+    """What lane i hands back before lane j's drain point took effect
+    and after, from the oracle of the whole streams: a lane flushed at
+    P keeps the frames before it, a lane reset there those its three
+    launched chunks own and then its second stream's."""
+    if i != j or scenario in ("none", "drain", "checkpoint"):
+        return whole[i], []
+    if scenario == "flush_stream":
+        return [f for f in whole[i] if f.start < P], []
+    return [f for f in whole[i] if f.start < 3 * STRIDE], second
+
+
+def _mesh(placement):
+    if placement == "one device":
+        return None
+    from ziria_tpu.parallel.batch import frame_mesh
+    return frame_mesh(8)
+
+
+#: every fleet x cut x drain point, but for the mesh under ragged pushes,
+#: which keeps the two that say most (none, and a lane reset mid-flight)
+CASES = [(lanes, placement, cut, scenario)
+         for lanes, placement in ((1, "one device"), (S, "one device"),
+                                  (S, "mesh of 8"))
+         for cut in ("lockstep", "ragged") for scenario in SCENARIOS
+         if not (placement == "mesh of 8" and cut == "ragged"
+                 and scenario in ("drain", "checkpoint", "flush_stream"))]
+
+
+@pytest.mark.parametrize("lanes,placement,cut,scenario", CASES)
+def test_emissions_equal_the_oracle_and_the_reference(
+        corpus, monkeypatch, lanes, placement, cut, scenario):
+    all_streams, all_starts, alt, alt_starts = corpus
+    streams, starts = all_streams[:lanes], all_starts[:lanes]
+    j = lanes - 1
+    at_point = None
+    if cut == "lockstep":
+        # nothing counts as ready: only launches advance the pipeline,
+        # so the drain point falls between two steps in flight, lane j
+        # riding in both
+        monkeypatch.setattr(framebatch, "_ready", lambda arrays: False)
+
+        def at_point(rx, lane):
+            assert [st.fronted for st in rx._flight] == [True, False]
+            assert all(lane in st.active for st in rx._flight)
+            assert rx.stats.max_in_flight == 3
+    rx = framebatch.MultiStreamReceiver(lanes, mesh=_mesh(placement),
+                                        **GEO)
+    before, after = _drive(rx, streams, alt, scenario,
+                           cut == "ragged", 7 + lanes, at_point)
+    assert not rx.stats.degraded and rx.stats.overflow_chunks == 0
+    got_b, got_a = _per_stream(before, lanes), _per_stream(after, lanes)
+    whole, second = _oracle(streams, alt)
+
+    # the per-capture oracle: frame for frame, byte for byte, in order
+    # (WHICH call hands a frame back may differ, so only the lane that
+    # was reset is held to what came before its reset and what after)
+    for i in range(lanes):
+        want_b, want_a = _expected(whole, second, i, j, scenario)
+        if i == j and scenario == "reset_stream":
+            pairs = [(got_b[i], want_b), (got_a[i], want_a)]
+        else:
+            pairs = [(got_b[i] + got_a[i], want_b + want_a)]
+        for got, want in pairs:
+            assert [f.start for f in got] \
+                == [f.start for f in want], (i, scenario)
+            for a, b in zip(got, want):
+                assert _same_result(a.result, b.result)
+
+    # every frame sent, exactly once and in order
+    for i in range(lanes):
+        seen = [f.start for f in got_b[i] + got_a[i]]
+        if i == j and scenario == "flush_stream":
+            assert seen == [s for s in starts[i] if s < P]
+        elif i == j and scenario == "reset_stream":
+            old = [f.start for f in got_b[i]]
+            assert old == [s for s in starts[i] if s < 3 * STRIDE]
+            assert [f.start for f in got_a[i]] == alt_starts
+        else:
+            assert seen == starts[i]
+
+    # the plain numpy reference on every emitted frame's own capture
+    for i in range(lanes):
+        for src, frames in ((streams[i], got_b[i]),
+                            (alt if i == j and scenario == "reset_stream"
+                             else streams[i], got_a[i])):
+            for fr in frames:
+                ref = np_receive(src[fr.start: fr.start + FRAME_LEN])
+                assert ref is not None, (i, fr.start)
+                assert fr.result.ok and fr.result.crc_ok is True
+                assert ref.rate_mbps == fr.result.rate_mbps
+                assert ref.length_bytes == fr.result.length_bytes
+                assert np.array_equal(
+                    ref.psdu,
+                    np_bits_to_bytes(np.asarray(fr.result.psdu_bits)))
+
+
+# ------------------------------------------- `_pending`'s contract (WHEN)
+
+
+def _owned_by(host_side, lane, start) -> bool:
+    offs, active, _arrs, _valid, own_lo, own_hi = host_side
+    return lane in active and \
+        offs[lane] + own_lo[lane] <= start < offs[lane] + own_hi[lane]
+
+
+@pytest.fixture(scope="module")
+def closed_loop(corpus):
+    """The benchmark's closed loop through `ServeRuntime`: a stride a
+    session a tick, so every tick after the first launches. After every
+    `step()`: what it returned, and `_pending[:6]` as
+    `benchmark/harness/cell.py`'s `SampledStep` reads it."""
+    streams, _starts, _alt, _as = corpus
+    srv = serve.ServeRuntime(serve.ServeConfig(
+        n_lanes=S, chunk_len=CHUNK, frame_len=FRAME_LEN,
+        max_frames_per_chunk=K, check_fcs=True, shard=False))
+    ticks = []
+    with telemetry.tracing() as tr, telemetry.collect(srv.registry):
+        for i in range(S):
+            assert srv.connect(f"s{i}").admitted
+        lane_of = {sid: ln for ln, sid in srv._lane_sid.items()}
+        pos = 0
+        while pos + STRIDE <= min(len(st) for st in streams):
+            for i, st in enumerate(streams):
+                assert srv.submit(f"s{i}",
+                                  st[pos: pos + STRIDE]).accepted
+            steps = srv._rx.stats.chunk_steps
+            out = srv.step()
+            pend = srv._rx._pending
+            ticks.append((out, srv._rx.stats.chunk_steps - steps,
+                          None if pend is None else tuple(pend[:6]),
+                          srv._rx._pending_step))
+            pos += STRIDE
+        tail = srv._emit(srv._rx.drain_pending())
+        drained = (srv._rx._pending, srv._rx._pending_step)
+    spans = [e for e in tr.events() if e["ph"] == "X"
+             and e["cat"] == "host"]
+    return srv, ticks, tail, drained, lane_of, spans
+
+
+def test_a_launch_hands_back_the_frames_of_the_pending_step(closed_loop):
+    srv, ticks, tail, drained, lane_of, _spans = closed_loop
+    assert sum(n for _o, n, _p, _s in ticks) >= 5
+    named, handed, oldest = None, 0, []
+    for out, launched, pend, step in ticks:
+        if launched:
+            assert launched == 1
+            oldest.append(step)
+        # the frames of exactly the chunk-step `_pending` named after
+        # the call before: SampledStep's rule
+        for sid, fr in out:
+            assert launched and named is not None
+            assert _owned_by(named, lane_of[sid], fr.start)
+            handed += 1
+        named = pend
+    assert handed >= S
+    # the oldest step in flight once launch t has returned: t-1
+    assert oldest == [max(0, t - 1) for t in range(len(oldest))]
+    # the two steps still in flight come back from the drain, and then
+    # nothing is pending
+    assert len(tail) >= 1 and drained == (None, None)
+    assert srv._rx.stats.max_in_flight == 3
+    assert srv.registry.find(telemetry.GAUGE_METRIC,
+                             site="rx.stream_inflight").last == 3
+
+
+def test_a_steps_frames_come_out_two_launches_later(closed_loop):
+    srv, ticks, _tail, _drained, _lane_of, spans = closed_loop
+    stacks = {e["args"]["step"]: e for e in spans
+              if e["name"] == "rx.fleet.stack"}
+    steps = sorted((e["ts"] for e in spans if e["name"] == "serve.step"))
+    assert len(stacks) == srv._rx.stats.chunk_steps
+
+    def tick_of(e):
+        return max(n for n, ts in enumerate(steps) if ts <= e["ts"])
+
+    def named(name):
+        return sorted((e for e in spans if e["name"] == name),
+                      key=lambda e: e["args"]["step"])
+
+    for name, later, left in (("rx.fleet.pull_scan", 1, 1),
+                              ("rx.fleet.classify", 1, 1),
+                              ("rx.fleet.decode", 1, 1),
+                              ("rx.fleet.pull_decode", 2, 2),
+                              ("rx.fleet.emit", 2, 2)):
+        mine = named(name)
+        assert len(mine) >= 3
+        for e in mine:
+            if e["args"]["step"] < len(stacks) - left:   # not the drain's
+                assert tick_of(e) \
+                    == tick_of(stacks[e["args"]["step"]]) + later, name
+
+
+def test_spans_counter_and_gauge_say_how_the_pipeline_ran(closed_loop):
+    srv, _ticks, _tail, _drained, _lane_of, spans = closed_loop
+    steps = srv._rx.stats.chunk_steps
+    for name in ("rx.fleet.pull_scan", "rx.fleet.pull_decode"):
+        mine = [e["args"] for e in spans if e["name"] == name]
+        assert mine and all(a["reads"] == 1 and a["ready"] in (0, 1)
+                            for a in mine)
+    puts = sorted((e["args"] for e in spans
+                   if e["name"] == "rx.fleet.put"),
+                  key=lambda a: a["step"])
+    assert [a["in_flight"] for a in puts] \
+        == [min(3, n + 1) for n in range(steps)]
+    reg = srv.registry
+    by_how = {how: reg.find("rx.pipeline_advances", how=how)
+              for how in ("launch", "ready", "drain")}
+    # two halves a chunk-step; a closed loop runs none of them early,
+    # and the final drain ran three (one front half, two back halves)
+    assert by_how["ready"] is None
+    assert by_how["drain"].value == 3
+    assert by_how["launch"].value == 2 * steps - 3
+
+
+# ------------------------------- a push that launches nothing (the ready path)
+
+
+def _fleet_with_one_step_launched(streams, monkeypatch, ready):
+    monkeypatch.setattr(framebatch, "_ready", ready)
+    rx = framebatch.MultiStreamReceiver(S, **GEO)
+    assert rx.push_many([st[:CHUNK] for st in streams]) == []
+    assert [st.fronted for st in rx._flight] == [False]
+    return rx
+
+
+def test_a_push_that_launches_nothing_never_blocks(corpus, monkeypatch):
+    streams, _starts, _alt, _as = corpus
+    rx = _fleet_with_one_step_launched(streams, monkeypatch,
+                                       lambda arrays: False)
+    monkeypatch.setattr(framebatch, "_pull_chunk", None)   # would raise
+    monkeypatch.setattr(framebatch, "_pull_decode", None)
+    with telemetry.collect() as reg:
+        for n in range(3):
+            assert rx.push(0, streams[0][CHUNK + n: CHUNK + n + 1]) == []
+            assert rx.push_many({}) == []
+    assert [st.fronted for st in rx._flight] == [False]
+    assert reg.find("rx.pipeline_advances", how="ready") is None
+    assert rx.stats.chunk_steps == 1
+
+
+def test_a_push_that_launches_nothing_hands_back_what_is_ready(
+        corpus, monkeypatch):
+    streams, starts, _alt, _as = corpus
+    asked = []
+
+    def ready(arrays):
+        # the scan's nine scalars are; the decode's two are not yet
+        asked.append(len(arrays))
+        return len(arrays) == 9 or decode_done[0]
+
+    decode_done = [False]
+    rx = _fleet_with_one_step_launched(streams, monkeypatch, ready)
+    with telemetry.collect() as reg:
+        # the front half runs (the decode is dispatched), the back does
+        # not: the step stays in flight and nothing comes out
+        assert rx.push(0, streams[0][CHUNK: CHUNK + 1]) == []
+        assert [st.fronted for st in rx._flight] == [True]
+        assert rx._flight[0].dec_out is not None
+        assert reg.find("rx.pipeline_advances", how="ready").value == 1
+        decode_done[0] = True
+        out = rx.push(0, streams[0][CHUNK + 1: CHUNK + 2])
+    assert rx._pending is None
+    assert reg.find("rx.pipeline_advances", how="ready").value == 2
+    assert reg.find("rx.pipeline_advances", how="launch") is None
+    assert set(asked) == {9, 2}
+    # chunk 0 owns the starts below one stride, of every stream
+    per = _per_stream(out, S)
+    for i in range(S):
+        assert [f.start for f in per[i]] \
+            == [s for s in starts[i] if s < STRIDE]
+    assert sum(len(p) for p in per) >= S
+
+
+def test_a_runtime_step_with_nothing_staged_hands_back_what_is_ready(
+        corpus, monkeypatch):
+    streams, starts, _alt, _as = corpus
+    monkeypatch.setattr(framebatch, "_ready", lambda arrays: False)
+    srv = serve.ServeRuntime(serve.ServeConfig(
+        n_lanes=S, chunk_len=CHUNK, frame_len=FRAME_LEN,
+        max_frames_per_chunk=K, check_fcs=True, shard=False))
+    for i in range(S):
+        assert srv.connect(f"s{i}").admitted
+        assert srv.submit(f"s{i}", streams[i][:CHUNK]).accepted
+    assert srv.step() == [] and srv._rx.stats.chunk_steps == 1
+    assert srv.step() == []                 # nothing staged, not ready
+    monkeypatch.setattr(framebatch, "_ready", lambda arrays: True)
+    out = srv.step()                        # nothing staged, ready
+    assert srv._rx._pending is None and srv._rx.stats.chunk_steps == 1
+    lane_of = {sid: ln for ln, sid in srv._lane_sid.items()}
+    got = sorted((lane_of[sid], fr.start) for sid, fr in out)
+    assert got == sorted((i, s) for i in range(S) for s in starts[i]
+                         if s < STRIDE)
+    srv.drain()
+
+
+# ------------------------------------ containment across the split (late)
+
+
+class _Unpullable:
+    """A device handle whose async computation failed: the error
+    surfaces at the host read, a launch after the dispatch."""
+    nbytes = 0
+
+    def __array__(self, *a, **k):
+        raise RuntimeError("UNAVAILABLE: link died mid-execution")
+
+
+def _two_in_flight(streams, monkeypatch):
+    monkeypatch.setattr(framebatch, "_ready", lambda arrays: False)
+    rx = framebatch.MultiStreamReceiver(S, **GEO)
+    out = rx.push_many([st[:CHUNK + STRIDE] for st in streams])
+    assert [st.fronted for st in rx._flight] == [True, False]
+    assert rx._flight[0].dec_out is not None
+    return rx, out
+
+
+def _check_all_frames(out, streams, starts):
+    per = _per_stream(out, S)
+    for i in range(S):
+        assert [f.start for f in per[i]] == starts[i]
+        for fr in per[i]:
+            ref = np_receive(streams[i][fr.start: fr.start + FRAME_LEN])
+            assert ref is not None and fr.result.crc_ok is True
+            assert np.array_equal(
+                ref.psdu,
+                np_bits_to_bytes(np.asarray(fr.result.psdu_bits)))
+
+
+def test_a_decode_pull_that_fails_a_launch_late_is_redispatched_once(
+        corpus, monkeypatch):
+    streams, starts, _alt, _as = corpus
+    with telemetry.collect() as reg:
+        rx, out = _two_in_flight(streams, monkeypatch)
+        dispatched = []
+        real = framebatch._dispatch_decode
+        monkeypatch.setattr(
+            framebatch, "_dispatch_decode",
+            lambda r, st: (dispatched.append(st.step), real(r, st))[1])
+        # the decode in flight loses its device handles AFTER its
+        # dispatch returned: the read, one launch later, finds out
+        lost = rx._flight[0]
+        lost.dec_out = (_Unpullable(), _Unpullable())
+        out += rx.push_many([st[CHUNK + STRIDE:] for st in streams])
+        out += rx.flush()
+    # from the segs and tables the step kept: once, and only that step
+    assert dispatched.count(lost.step) == 1
+    assert reg.snapshot()["resilience.async_rescans"] == 1
+    assert not rx.stats.degraded and rx.stats.lane_blowups == 0
+    _check_all_frames(out, streams, starts)
+
+
+def test_a_decode_lost_twice_degrades_to_the_oracle_and_loses_no_frame(
+        corpus, monkeypatch):
+    streams, starts, _alt, _as = corpus
+    with telemetry.collect() as reg:
+        rx, out = _two_in_flight(streams, monkeypatch)
+
+        real = framebatch._dispatch_decode
+
+        def lost_again(r, st):
+            real(r, st)
+            if st is lost:
+                st.dec_out = (_Unpullable(), _Unpullable())
+
+        lost = rx._flight[0]
+        lost.dec_out = (_Unpullable(), _Unpullable())
+        monkeypatch.setattr(framebatch, "_dispatch_decode", lost_again)
+        out += rx.push_many([st[CHUNK + STRIDE: CHUNK + 2 * STRIDE]
+                             for st in streams])
+        monkeypatch.undo()
+        monkeypatch.setattr(framebatch, "_ready", lambda arrays: False)
+        # degraded: that step through the oracle with its own host
+        # arrays, and every later step too; the step whose decode was
+        # already on the device when the fleet degraded reads it back
+        assert rx.stats.degraded
+        out += rx.push_many([st[CHUNK + 2 * STRIDE:] for st in streams])
+        out += rx.flush()
+    assert reg.snapshot()["resilience.async_rescans"] == 1
+    assert reg.snapshot()["resilience.degraded"] == 1
+    assert rx.stats.lane_blowups == 0
+    _check_all_frames(out, streams, starts)
+
+
+def test_a_scan_lost_behind_a_decode_is_rescanned_from_its_own_arrays(
+        corpus, monkeypatch):
+    streams, starts, _alt, _as = corpus
+    with telemetry.collect() as reg:
+        rx, out = _two_in_flight(streams, monkeypatch)
+        # the NEWER step's scan and the OLDER step's decode both lost
+        rx._flight[1].outs = tuple(_Unpullable() for _ in range(11))
+        rx._flight[0].dec_out = (_Unpullable(), _Unpullable())
+        out += rx.drain_pending()
+        assert rx._pending is None
+        out += rx.push_many([st[CHUNK + STRIDE:] for st in streams])
+        out += rx.flush()
+    assert reg.snapshot()["resilience.async_rescans"] == 2
+    assert not rx.stats.degraded
+    _check_all_frames(out, streams, starts)

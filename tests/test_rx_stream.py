@@ -100,10 +100,10 @@ def test_o_chunks_dispatches_vs_o_frames(corpus):
     assert d_st.counts["rx.stream_chunk_multi"] == st_s.chunks
     assert d_st.counts["rx.stream_decode_multi"] <= st_s.chunks
     assert d_pc.total >= 3 * n + 1, dict(d_pc.counts)
-    # double-buffering really overlapped: chunk i+1 was in flight
-    # before chunk i drained (the utils/dispatch gauge)
-    assert d_st.gauges["rx.stream_inflight"] == 2
-    assert st_s.max_in_flight == 2
+    # the pipeline really overlapped: chunks i+1 and i+2 were in
+    # flight before chunk i drained (the utils/dispatch gauge)
+    assert d_st.gauges["rx.stream_inflight"] == 3
+    assert st_s.max_in_flight == 3
     assert st_s.overflow_chunks == 0
 
 
@@ -318,9 +318,9 @@ def test_stream_receiver_is_a_fleet_of_one(corpus):
         assert _same_result(a.result, b.result)
     for gone in ("chunk", "decode"):     # the fleet's two are `_multi`
         assert not hasattr(rx, "_jit_stream_" + gone)
-    for name in ("_launch", "_scan_dispatch", "_rescan", "_drain",
-                 "_decode_oracle", "_eager_chunk", "_mark_degraded",
-                 "_jit1", "_pending", "_health"):
+    for name in ("_launch", "_scan_dispatch", "_rescan", "_front",
+                 "_drain", "_settle", "_decode_oracle", "_eager_chunk",
+                 "_mark_degraded", "_jit1", "_pending", "_health"):
         assert hasattr(sr.fleet, name) and not hasattr(sr, name), name
     for name in ("_note_emitted", "_runtime_state"):
         assert not hasattr(sr, name), name

@@ -411,9 +411,10 @@ def receive_many_device(x_dev, n_lanes: int, check_fcs: bool = False,
 # (`valid == 0` → the detector caps their positions to nothing), and
 # the all-noise fast path is preserved (a step with zero decodable
 # lanes across the WHOLE fleet skips the decode dispatch entirely).
-# The dispatch loop is double-buffered: step t+1's upload+dispatch is
-# issued BEFORE the host blocks on step t's scalars, so the
-# host<->device transfer hides behind compute (in-flight depth on the
+# The dispatch loop is a pipeline three chunk-steps deep (`_InFlight`):
+# a launch uploads and dispatches scan t, dispatches decode t-1 behind
+# it, and blocks only on decode t-2, so the device holds a scan and a
+# decode while the host stacks the next step (in-flight depth on the
 # `utils/dispatch.record_gauge("rx.stream_inflight")` gauge). The
 # stream axis shards over the dp mesh (`parallel/batch.frame_mesh` /
 # `lane_sharding`, `jax.shard_map` — multihost-ready through
@@ -543,9 +544,10 @@ class _LaneHealth:
         quarantined (valid 0). A dirty chunk resets the clean streak;
         rejoin takes effect from the chunk AFTER the streak fills.
         Blowups are NOT reset here: a chunk's blowups are delivered
-        one drain later than its step (the double buffer), so a
-        per-step reset could never see two in a row — the count
-        accumulates until the lane is poisoned or rejoins."""
+        with its back half, up to two launches later than its step
+        (the pipeline), so a per-step reset could never see two in a
+        row — the count accumulates until the lane is poisoned or
+        rejoins."""
         if dirty:
             self.clean = 0
             return self.quarantined
@@ -649,29 +651,53 @@ def _chunk_scalars(outs):
 
 def _start_pull(arrays) -> None:
     """Send every shard of every array on its way to the host without
-    blocking on any: the reads that follow wait for the slowest device
-    once, not for each transfer in turn (a sharded fleet's nine scan
-    scalars are 36 transfers, its decode's pull 8)."""
+    blocking on any: the read that follows, a launch or two later,
+    finds the bytes on the host, and on a mesh waits for the slowest
+    device once, not for each transfer in turn (a sharded fleet's nine
+    scan scalars are 36 transfers, its decode's pull 8)."""
     for x in arrays:
         x.copy_to_host_async()
 
 
-def _pull_chunk(outs, span, sharded: bool = False):
+def _ready(arrays) -> bool:
+    """Whether a read of every array would return without waiting for
+    the device. What has no ``is_ready`` (a host array of the eager
+    or oracle twin) is ready; one whose answer raises is too, so that
+    the guarded read that follows meets the failure."""
+    try:
+        return all(x.is_ready() for x in arrays
+                   if hasattr(x, "is_ready"))
+    except Exception:        # noqa: BLE001 - the read will say why
+        return True
+
+
+def _host_lanes(x):
+    """A decode output on the host, indexable by lane. Read whole
+    (`np.asarray`), an array that lies over a mesh is built anew on
+    the host and every shard copied into it (8.4 MB a chunk-step at
+    32 lanes, 7 ms of the one thread that paces that fleet); each
+    shard's own host copy, on its way since the dispatch, is read
+    where it lies, and a lane is a row of one of them."""
+    shards = getattr(x, "addressable_shards", ())
+    if len(shards) < 2:
+        return np.asarray(x)
+    # by first lane: in order, and a replica read once
+    first = {sh.index[0].start or 0: sh.data for sh in shards}
+    return [row for at in sorted(first) for row in np.asarray(first[at])]
+
+
+def _pull_chunk(outs, span):
     """Materialize a chunk scan's per-lane scalars on the host. On an
     ASYNC backend a runtime failure mid-execution surfaces HERE, at
     the first host pull, not inside the guarded dispatch — callers
     wrap this and re-run the chunk through the guarded path when it
     throws (the launched results are lost either way). `segs` stays
     device-resident for the decode dispatch. ``span`` (name, args)
-    is opened around the blocking pulls alone. ``sharded``: the
-    arrays lie over a mesh (`_start_pull` first: a no-op for what
-    `_launch` already sent on its way, the whole of it for a rescan)."""
+    is opened around the blocking pulls alone."""
     from ziria_tpu.utils import telemetry
 
     scalars, segs = _chunk_scalars(outs), outs[10]
     with telemetry.span(*span):
-        if sharded:
-            _start_pull(scalars)
         return tuple(np.asarray(x) for x in scalars) + (segs,)
 
 
@@ -686,40 +712,81 @@ def _record_degraded(entered: bool) -> None:
         telemetry.count("resilience.degraded")
 
 
-def _guarded_decode(r, label: str, dec, *args, pull_span):
-    """The guarded decode dispatch + SYNCHRONOUS host pull: an async
-    runtime failure surfaces at the pull, after the dispatch
-    returned, so the pull lives inside the same containment — one
-    guarded re-dispatch, then None, with the receiver marked degraded
-    so the caller (and the rest of the stream) runs the oracle twin.
-    Returns (clear, crc) as host arrays, or None. ``pull_span`` (name,
-    args) is opened around the blocking pull alone, with the pulled
-    ``bytes`` added to its args."""
+def _dispatch_decode(r, st) -> None:
+    """The guarded decode dispatch of a chunk-step's front half, its
+    two outputs sent on their way to the host at once: ``st.dec_out``
+    is (clear, crc) on the device, or None with the receiver marked
+    degraded when the compiled program failed for good (a decode that
+    does not trace or compile raises out of guarded() as itself,
+    `resilience.compile_ahead`: only a RUN-time failure degrades)."""
     from ziria_tpu.runtime import resilience
+
+    try:
+        st.dec_out = resilience.guarded(
+            "rx.stream_decode_multi", st.dec, *st.dec_args,
+            policy=r._policy)
+        _start_pull(st.dec_out)
+    except resilience.DispatchFailed:
+        st.dec_out = None
+        r._mark_degraded(scan=False)
+
+
+def _pull_decode(r, st):
+    """The host read of a chunk-step's decode, in its back half: an
+    async runtime failure surfaces HERE, a launch after the dispatch
+    returned, so the read lives inside the same containment — one
+    guarded re-dispatch from the ``segs`` and tables the step kept,
+    then None, with the receiver marked degraded so the caller (and
+    the rest of the stream) runs the oracle twin. Returns (clear, crc)
+    as host arrays, or None. `rx.fleet.pull_decode` is opened around
+    the blocking read alone."""
     from ziria_tpu.utils import telemetry
 
     for attempt in (0, 1):
+        if st.dec_out is None:       # the dispatch failed for good
+            return None
+        clear, crc = st.dec_out
         try:
-            # a decode that does not trace or compile raises out of
-            # guarded() as itself (resilience.compile_ahead) — only a
-            # RUN-time failure of the compiled program degrades
-            clear, crc = resilience.guarded(label, dec, *args,
-                                            policy=r._policy)
-        except resilience.DispatchFailed:
-            break
-        try:
-            with telemetry.span(pull_span[0], dict(
-                    pull_span[1],
-                    bytes=int(clear.nbytes + crc.nbytes))):
-                if r.mesh is not None:
-                    _start_pull((clear, crc))
-                return np.asarray(clear, np.uint8), np.asarray(crc)
+            with telemetry.span("rx.fleet.pull_decode", {
+                    "step": st.step, "shards": 2 * r._n_devices,
+                    "bytes": int(clear.nbytes + crc.nbytes),
+                    "reads": 1, "ready": int(_ready(st.dec_out))}):
+                return _host_lanes(clear), np.asarray(crc)
         except Exception:        # noqa: BLE001 - async pull loss
             if attempt:
                 break
             telemetry.count("resilience.async_rescans")
+            _dispatch_decode(r, st)
     r._mark_degraded(scan=False)
     return None
+
+
+class _InFlight:
+    """One chunk-step between its launch and its emission. The FRONT
+    half of its drain (`MultiStreamReceiver._front`) reads the scan's
+    scalars, classifies and dispatches the decode; the BACK half
+    (`_drain`) reads the decode and emits. Each half keeps here what
+    the next one, or its containment, needs: the host arrays of the
+    step (a lost scan is rescanned from them, the oracle twin slices
+    its windows out of them), the scan's outputs (`segs` among them,
+    the decode's input and its re-dispatch's), and the decode's
+    tables and outputs. ``step`` tags every span of either half."""
+
+    __slots__ = ("step", "offs", "active", "arrs", "valid", "own_lo",
+                 "own_hi", "outs", "fronted", "allcands", "starts",
+                 "oracle", "emit", "lanes", "slots", "dec", "dec_args",
+                 "dec_out")
+
+    def __init__(self, step, offs, active, arrs, valid, own_lo, own_hi,
+                 outs):
+        self.step, self.offs, self.active = step, offs, active
+        self.arrs, self.valid = arrs, valid
+        self.own_lo, self.own_hi, self.outs = own_lo, own_hi, outs
+        self.fronted = False
+        self.allcands = self.starts = None
+        self.oracle = False
+        self.emit = self.lanes = self.slots = None
+        self.dec = self.dec_args = self.dec_out = None
 
 
 def _gate_finite(arr: np.ndarray, name: str, sanitize: bool,
@@ -783,7 +850,10 @@ class MultiStreamReceiver:
     are bit-identical to that stream received alone — by
     construction. One chunk-step = one stacked (S, chunk_len, 2)
     upload + ONE vmapped scan dispatch (+ ONE flattened decode
-    dispatch when any stream has a decodable frame), double-buffered.
+    dispatch when any stream has a decodable frame), pipelined three
+    deep: a chunk-step's frames come out of the second launch after
+    its own, or of an earlier call that launches nothing and finds
+    them ready; :meth:`drain_pending` hands them over now.
     ``streaming=False`` runs the per-capture oracle over the same
     detected windows in place of the compiled decode. `mesh` shards the
     stream axis over dp (`S % mesh.size == 0`); per-stream carries
@@ -917,13 +987,10 @@ class MultiStreamReceiver:
         self._emitted = [0] * self.s
         self._watermarks = [0] * self.s
         self._seen = [set() for _ in range(self.s)]
-        self._pending = None   # (offsets, active, arrs, valid, outs)
-        # a chunk-step's id is `_chunk_steps` at its launch; it rides
-        # BESIDE the pending tuple (whose positions other code reads)
-        # and tags every span of that step, launch and drain alike
-        self._pending_step = None
-        self._drain_step = None
-        self._inflight = 0
+        # the chunk-steps in flight, oldest first: at most three, of
+        # which the newest alone still waits for its front half once a
+        # launch has returned (`_InFlight`, `_settle`)
+        self._flight: List[_InFlight] = []
         self._chunk_steps = 0
         self._overflow_chunks = 0
         self._max_in_flight = 0
@@ -997,9 +1064,9 @@ class MultiStreamReceiver:
             state=dict(self._lane_state(stream), **rider))
 
     def checkpoint(self, stream: int):
-        """Serialize one fleet lane's live stream state (the in-flight
-        chunk-step is drained first; its fleet-wide emissions return
-        alongside). The blob restores into a lone
+        """Serialize one fleet lane's live stream state (every
+        chunk-step in flight is drained first; their fleet-wide
+        emissions return alongside). The blob restores into a lone
         ``StreamReceiver(checkpoint=...)`` at the same geometry —
         a crashed fleet lane resumes on its own receiver with
         bit-identical subsequent emissions. Returns
@@ -1013,7 +1080,7 @@ class MultiStreamReceiver:
     def checkpoint_fleet(self, lanes=None):
         """Serialize the fleet's live stream state in one pass — the
         serving runtime's automatic-snapshot surface (ISSUE 14): the
-        in-flight chunk-step is drained ONCE (its emissions returned
+        chunk-steps in flight are drained ONCE (their emissions returned
         alongside — they belong to the pre-snapshot past and must
         reach the caller, never be silently dropped), then the lane
         blobs are taken against the now-quiescent state. ``lanes``
@@ -1054,8 +1121,11 @@ class MultiStreamReceiver:
     def push(self, stream: int, samples) -> List:
         """Append samples ((n, 2) float pairs) to one stream; fire
         every chunk-step that completes. Returns the emitted
-        ``(stream, StreamFrame)`` pairs (any stream may emit — a
-        completed step drains the previous step's emissions).
+        ``(stream, StreamFrame)`` pairs (any stream may emit: a
+        launch hands back the frames of the chunk-step two launches
+        before it, and a push that launches nothing those of every
+        older step the device has finished, without waiting for one
+        it has not; :meth:`drain_pending` waits for all of them).
         Malformed slabs and non-finite samples fail loudly at the
         seam, naming the stream (or quarantine under
         ``sanitize=True``; docs/robustness.md)."""
@@ -1068,9 +1138,11 @@ class MultiStreamReceiver:
         """Append one slab per stream (empty slabs fine), THEN pump:
         streams that filled a chunk together ride the same chunk-step
         — the packer's lockstep fast path for synchronized feeds.
-        ``slabs`` is a length-S sequence, or a ``{stream_id: slab}``
-        dict for sparse arrival; an unknown stream id raises a named
-        KeyError."""
+        Frames come out as :meth:`push` says: two launches after their
+        chunk-step's own, or from a call that launches nothing and
+        finds them ready. ``slabs`` is a length-S sequence, or a
+        ``{stream_id: slab}`` dict for sparse arrival; an unknown
+        stream id raises a named KeyError."""
         from ziria_tpu.utils import telemetry
 
         if self._flushed:
@@ -1084,15 +1156,17 @@ class MultiStreamReceiver:
                     f"{self.s} streams need {self.s} slabs, "
                     f"got {len(slabs)}")
             items = list(enumerate(slabs))
-        with telemetry.span("rx.fleet.ingest", {"lanes": len(items)}):
-            for i, s in items:
-                self._ingest(i, s)
+        if items:
+            with telemetry.span("rx.fleet.ingest",
+                                {"lanes": len(items)}):
+                for i, s in items:
+                    self._ingest(i, s)
         return self._pump()
 
     def flush(self) -> List:
         """Close every stream: scan the carried tails (zero-padded to
         the chunk geometry, each stream owning every remaining start)
-        as one final chunk-step, then drain the in-flight step.
+        as one final chunk-step, then drain every step in flight.
         Idempotent."""
         if self._flushed:
             return []
@@ -1102,9 +1176,7 @@ class MultiStreamReceiver:
                   if self._tails[i].shape[0]]
         if active:
             out += self._step(active, flushing=True)
-        if self._pending is not None:
-            out += self._drain(self._swap_pending())
-        return out
+        return out + self.drain_pending()
 
     # -- per-lane lifecycle (the serving runtime's lane recycle) --------
     #
@@ -1114,36 +1186,44 @@ class MultiStreamReceiver:
     # recycled for the next admitted session (`reset_stream`) or a
     # recovering one (`restore_stream`). None of these disturb the
     # other lanes: every piece of stream state is per lane, and the
-    # in-flight chunk-step is drained first only
-    # when the touched lane actually rides in it — an idle lane's
-    # recycle preserves the double buffer.
+    # chunk-steps in flight are drained first only when the touched
+    # lane actually rides in one of them — an idle lane's recycle
+    # preserves the pipeline.
+
+    @property
+    def _pending(self):
+        """The OLDEST chunk-step in flight as ``(offs, active, arrs,
+        valid, own_lo, own_hi, outs)``, None when there is none: the
+        one whose frames the next launch hands back (its first six
+        are what the benchmark's float comparison keeps of it)."""
+        if not self._flight:
+            return None
+        st = self._flight[0]
+        return (st.offs, st.active, st.arrs, st.valid, st.own_lo,
+                st.own_hi, st.outs)
+
+    @property
+    def _pending_step(self):
+        """The id of `_pending`'s chunk-step."""
+        return self._flight[0].step if self._flight else None
 
     def drain_pending(self) -> List:
-        """Block on the in-flight chunk-step (if any) and emit it —
-        the double buffer's explicit drain point. Returns the
-        ``(stream, StreamFrame)`` pairs; safe to call any time."""
-        if self._pending is None:
-            return []
-        return self._drain(self._swap_pending())
-
-    def _swap_pending(self, new=None, step=None):
-        """Take the in-flight chunk-step out, putting ``new`` in its
-        place, and its id with it: the id launched as ``step`` waits
-        in ``_pending_step`` and is ``_drain_step`` while that step
-        drains, one tick later."""
-        pend, self._pending = self._pending, new
-        self._drain_step, self._pending_step = self._pending_step, step
-        return pend
+        """Block on every chunk-step in flight and emit them, oldest
+        first — the pipeline's explicit drain point, and the way to
+        have a step's frames NOW rather than two launches later.
+        Returns the ``(stream, StreamFrame)`` pairs; safe to call any
+        time."""
+        return self._settle(0, 0, "drain")
 
     def _pending_touches(self, stream: int) -> bool:
-        return self._pending is not None and stream in self._pending[1]
+        return any(stream in st.active for st in self._flight)
 
     def flush_stream(self, stream: int) -> List:
         """Close ONE stream: scan its carried tail (zero-padded, the
         lane owning every remaining start — the per-lane twin of
         :meth:`flush`) and drain through it, leaving every other lane
         live. Returns the emitted ``(stream, frame)`` pairs (any lane
-        may emit — the in-flight step drains first). The lane's state
+        may emit — the steps in flight drain first). The lane's state
         is NOT reset; :meth:`reset_stream` recycles it."""
         stream = self._check_stream(stream)
         if self._flushed:
@@ -1159,9 +1239,9 @@ class MultiStreamReceiver:
         tail/dedupe, clean health) so a NEW session can ride it —
         after :meth:`flush_stream` or an eviction's :meth:`checkpoint`.
         Frames the lane emitted stay credited in :attr:`stats` (the
-        ``retired`` accounting). Drains the in-flight step first ONLY
-        when this lane rides in it, so recycling an idle lane never
-        costs the fleet its double-buffer overlap. Returns the drained
+        ``retired`` accounting). Drains the steps in flight first ONLY
+        when this lane rides in one of them, so recycling an idle lane
+        never costs the fleet its overlap. Returns the drained
         ``(stream, frame)`` pairs."""
         stream = self._check_stream(stream)
         out = self.drain_pending() if self._pending_touches(stream) \
@@ -1219,13 +1299,17 @@ class MultiStreamReceiver:
     # -- chunk-step lifecycle -------------------------------------------
 
     def _pump(self) -> List:
+        """Launch every chunk-step the tails hold; a call that holds
+        none hands back what the device has finished meanwhile."""
         out: List = []
+        launched = False
         while True:
             active = [i for i in range(self.s)
                       if self._tails[i].shape[0] >= self.chunk_len]
             if not active:
-                return out
+                return out if launched else self._advance_ready()
             out += self._step(active, flushing=False)
+            launched = True
 
     def _step(self, active, flushing: bool) -> List:
         """Build one stacked chunk-step over the `active` streams
@@ -1293,10 +1377,12 @@ class MultiStreamReceiver:
         return pbatch.shard_batch(self.mesh, x, self.axis)
 
     def _launch(self, arrs, valid, own_lo, own_hi, active, offs) -> List:
-        """Issue the stacked upload + scan dispatch, THEN drain the
-        previous chunk-step — the double buffer: step t's transfer
-        and compute are in flight while the host blocks on step t-1's
-        scalars. Returns step t-1's emissions."""
+        """Issue the stacked upload + scan dispatch of chunk-step t,
+        THEN the front half of t-1 (its decode goes behind scan t) and
+        the back half of t-2, the one read that waits for the device:
+        when this returns, scan t and decode t-1 are queued there and
+        the host stacks step t+1 under them. Returns step t-2's
+        emissions (nothing, where an earlier call found them ready)."""
         from ziria_tpu.utils import dispatch, programs, telemetry
 
         step = self._chunk_steps
@@ -1304,21 +1390,20 @@ class MultiStreamReceiver:
                 "step": step, "bytes": arrs.nbytes + valid.nbytes
                 + own_lo.nbytes + own_hi.nbytes,
                 "locate_rows": self._locate_rows,
-                "devices": self._n_devices, "lanes": self.s}):
+                "devices": self._n_devices, "lanes": self.s,
+                "in_flight": len(self._flight) + 1}):
             chunk_args = (self._put(arrs), self._put(valid),
                           self._put(own_lo), self._put(own_hi))
         programs.note_site("rx.stream_chunk_multi", self._jit1,
                            *chunk_args)
         outs = self._scan_dispatch(chunk_args)
-        if self.mesh is not None:
-            # the scan's scalars leave each device as its scan ends, a
-            # tick before `_drain` reads them
-            _start_pull(_chunk_scalars(outs))
         self._chunk_steps += 1
-        self._inflight += 1
-        self._max_in_flight = max(self._max_in_flight, self._inflight)
+        self._flight.append(_InFlight(
+            step, offs, list(active), arrs, valid.copy(), own_lo.copy(),
+            own_hi.copy(), outs))
+        self._max_in_flight = max(self._max_in_flight, len(self._flight))
         self._max_active = max(self._max_active, len(active))
-        dispatch.record_gauge("rx.stream_inflight", self._inflight)
+        dispatch.record_gauge("rx.stream_inflight", len(self._flight))
         # the fleet-level time series: how many lanes carried real
         # samples this step (idle lanes are the valid-mask riders)
         dispatch.record_gauge("rx.active_streams", len(active))
@@ -1328,69 +1413,131 @@ class MultiStreamReceiver:
         dispatch.record_gauge(
             "rx.degraded_mode",
             1.0 if (self._degraded or self._scan_degraded) else 0.0)
-        pend = self._swap_pending((
-            offs, list(active), arrs, valid.copy(), own_lo.copy(),
-            own_hi.copy(), outs), step)
-        return self._drain(pend) if pend is not None else []
+        return self._settle(1, 2, "launch")
+
+    def _settle(self, scans: int, depth: int, how: str) -> List:
+        """Block, oldest first, until at most ``scans`` chunk-steps
+        still wait for their front half and at most ``depth`` are in
+        flight: (1, 2) behind a launch, (0, 0) at a drain point. Every
+        front half due runs before the first back half, so that each
+        decode is queued on the device before the host waits for an
+        older one. Returns the emissions of the steps that left."""
+        from ziria_tpu.utils import telemetry
+
+        out: List = []
+        halves = 0
+        for st in self._flight[:len(self._flight) - scans]:
+            if not st.fronted:
+                self._front(st)
+                halves += 1
+        while len(self._flight) > depth:
+            out += self._drain(self._flight[0])
+            halves += 1
+        if halves:
+            telemetry.count("rx.pipeline_advances", halves,
+                            labels={"how": how})
+        return out
+
+    def _advance_ready(self) -> List:
+        """Run every half whose arrays the device has finished, in the
+        order a launch would and without waiting for any: the front
+        half of the oldest step that lacks it, the back half of the
+        oldest step. WHICH call hands a frame back depends on timing
+        here and nowhere else; what comes out, and in what order,
+        never does."""
+        from ziria_tpu.utils import telemetry
+
+        out: List = []
+        halves = 0
+        while True:
+            st = next((x for x in self._flight if not x.fronted), None)
+            if st is not None and _ready(_chunk_scalars(st.outs)):
+                self._front(st)
+            elif self._flight and self._flight[0].fronted \
+                    and _ready(self._flight[0].dec_out or ()):
+                out += self._drain(self._flight[0])
+            else:
+                break
+            halves += 1
+        if halves:
+            telemetry.count("rx.pipeline_advances", halves,
+                            labels={"how": "ready"})
+        return out
 
     def _scan_dispatch(self, chunk_args):
         """The ONE guarded fleet-scan dispatch (shared by `_launch`
         and the async-rescan path), degrading to the eager twin when
-        the compiled program fails for good."""
+        the compiled program fails for good. The nine scalars leave
+        each device as its scan ends, a launch before `_front` reads
+        them."""
         from ziria_tpu.runtime import resilience
 
-        if self._scan_degraded:
-            return self._eager_chunk(*chunk_args)
-        try:
-            return resilience.guarded(
-                "rx.stream_chunk_multi", self._jit1, *chunk_args,
-                policy=self._policy)
-        except resilience.DispatchFailed:
-            self._mark_degraded(scan=True)
-            return self._eager_chunk(*chunk_args)
+        outs = None
+        if not self._scan_degraded:
+            try:
+                outs = resilience.guarded(
+                    "rx.stream_chunk_multi", self._jit1, *chunk_args,
+                    policy=self._policy)
+            except resilience.DispatchFailed:
+                self._mark_degraded(scan=True)
+        if outs is None:
+            outs = self._eager_chunk(*chunk_args)
+        _start_pull(_chunk_scalars(outs))
+        return outs
 
-    def _rescan(self, arrs, valid, own_lo, own_hi):
+    def _rescan(self, st):
         """Re-run a chunk-step whose ASYNC results were lost: a
         runtime failure mid-execution surfaces at the host pull in
-        `_drain`, after the guarded dispatch already returned — the
-        launched results are gone, so the step re-dispatches through
-        the same guarded/degraded path (counted as an async rescan)."""
+        `_front`, after the guarded dispatch already returned — the
+        launched results are gone, so the step re-dispatches from its
+        own host arrays through the same guarded/degraded path
+        (counted as an async rescan)."""
         from ziria_tpu.utils import telemetry
 
         telemetry.count("resilience.async_rescans")
         return self._scan_dispatch(
-            (self._put(arrs), self._put(valid), self._put(own_lo),
-             self._put(own_hi)))
+            (self._put(st.arrs), self._put(st.valid),
+             self._put(st.own_lo), self._put(st.own_hi)))
 
-    def _drain(self, pend) -> List:
-        """Block on a launched chunk-step's per-lane scalars, run the
-        host integer decision tree per active stream, and emit —
-        dispatching the step's ONE flattened fleet decode when ANY
-        stream has a decodable lane (the all-noise fast path skips it
-        for the whole fleet). Every span carries the drained step's
-        id (`_swap_pending`)."""
+    def _front(self, st) -> None:
+        """Run the front half of a launched chunk-step's drain
+        (`_scan_to_decode`); one that raises takes its step out of
+        flight with it, as a drain that raised always has."""
+        try:
+            self._scan_to_decode(st)
+        except BaseException:
+            self._flight.remove(st)
+            raise
+        st.fronted = True
+
+    def _scan_to_decode(self, st) -> None:
+        """The front half: block on a launched chunk-step's per-lane
+        scalars (done a launch ago, where the device keeps up), run
+        the host integer decision tree per active stream, and dispatch
+        the step's ONE flattened fleet decode when ANY stream has a
+        decodable lane (the all-noise fast path skips it for the whole
+        fleet), its outputs sent on their way to the host. Every span
+        carries the step's own id."""
         from ziria_tpu.phy.wifi import rx as _rx
-        from ziria_tpu.phy.wifi.params import (N_SERVICE_BITS,
-                                               mixed_trellis_steps)
+        from ziria_tpu.phy.wifi.params import mixed_trellis_steps
         from ziria_tpu.utils import programs, telemetry
 
-        step = self._drain_step
-        offs, active, arrs, valids, own_lo, own_hi, outs = pend
+        step, active = st.step, st.active
 
         def pull(o):
             pulled = _chunk_scalars(o)
             return _pull_chunk(o, ("rx.fleet.pull_scan", {
                 "step": step,
                 "bytes": sum(int(x.nbytes) for x in pulled),
-                "shards": len(pulled) * self._n_devices}),
-                sharded=self.mesh is not None)
+                "shards": len(pulled) * self._n_devices,
+                "reads": 1, "ready": int(_ready(pulled))}))
         try:
             (own, starts, overflow, found, fstart, rb, ln, pk, nv,
-             segs) = pull(outs)
+             segs) = pull(st.outs)
         except Exception:    # noqa: BLE001 - async loss, re-dispatch
+            st.outs = self._rescan(st)
             (own, starts, overflow, found, fstart, rb, ln, pk, nv,
-             segs) = pull(self._rescan(arrs, valids, own_lo, own_hi))
-        self._inflight -= 1
+             segs) = pull(st.outs)
         self._overflow_chunks += int(overflow[active].sum())
 
         # what the scan owned against what its window acquisition found
@@ -1402,67 +1549,79 @@ class MultiStreamReceiver:
                 "acquired": int((owned & found[active]).sum())}):
             allcands = []    # (stream, abs_start, row j) in emit order
             for i in active:
-                off = offs[i]
+                off = st.offs[i]
                 self._watermarks[i] = off
                 self._seen[i], cands = _chunk_candidates(
                     self._seen[i], off, own[i], starts[i], self.k)
                 allcands += [(i, abs_start, j) for abs_start, j in cands]
-            oracle = not self.streaming or self._degraded
-            if not oracle:
-                emit, lanes, slots, tables = self._classify(
-                    allcands, found, fstart, rb, ln, pk, nv)
-        if oracle:
             # the per-capture oracle serves every window: asked for
             # (``streaming=False``), or the compiled fleet decode
             # already failed for good
-            return self._decode_oracle(allcands, starts, arrs, valids)
+            st.oracle = not self.streaming or self._degraded
+            if not st.oracle:
+                st.emit, st.lanes, st.slots, tables = self._classify(
+                    allcands, found, fstart, rb, ln, pk, nv)
+        st.allcands, st.starts = allcands, starts
+        if st.oracle or not st.lanes:
+            return
+        # what the decode is asked for against what it computes:
+        # each of its S x K lanes is gathered at the whole symbol
+        # bucket and runs the bound trellis (the LENGTH field's
+        # longest frame, `params.mixed_trellis_steps`)
+        useful = sum(lane[4] for lane in st.lanes)
+        n_slots = self.s * self.k
+        padded = n_slots * self.n_sym_bucket
+        telemetry.count("rx.decode_symbols", useful,
+                        labels={"kind": "useful"})
+        telemetry.count("rx.decode_symbols", padded,
+                        labels={"kind": "padded"})
+        with telemetry.span("rx.fleet.decode", {
+                "step": step, "lanes": len(st.lanes),
+                "slots": n_slots,
+                "useful_symbols": useful,
+                "padded_symbols": padded,
+                "useful_bits": int(tables[2].sum()),
+                "trellis_steps": n_slots
+                * mixed_trellis_steps(self.n_sym_bucket)}):
+            st.dec = _rx._jit_stream_decode_multi(
+                self.n_sym_bucket, self.viterbi_window,
+                self.viterbi_metric, self.viterbi_radix,
+                self.mesh, self.axis, self.sco_track,
+                self.fused_demap)
+            st.dec_args = (segs,) + tuple(self._put(t) for t in tables)
+            programs.note_site("rx.stream_decode_multi", st.dec,
+                               *st.dec_args)
+            _dispatch_decode(self, st)
 
+    def _drain(self, st) -> List:
+        """The back half of a chunk-step's drain, and the one place its
+        frames come out of: take the oldest chunk-step out of flight,
+        block on its decode's two arrays (on their way to the host
+        since `_front` dispatched it) and emit. A decode that failed
+        for good, at its dispatch or at this read, degrades the WHOLE
+        fleet's decode to the per-capture oracle (bit-identical by the
+        pinned contract), this chunk-step included — healthy lanes
+        keep flowing."""
+        from ziria_tpu.phy.wifi import rx as _rx
+        from ziria_tpu.phy.wifi.params import N_SERVICE_BITS
+        from ziria_tpu.utils import telemetry
+
+        self._flight.remove(st)
+        if st.oracle:
+            return self._decode_oracle(st)
         got = None
-        if lanes:
-            # what the decode is asked for against what it computes:
-            # each of its S x K lanes is gathered at the whole symbol
-            # bucket and runs the bound trellis (the LENGTH field's
-            # longest frame, `params.mixed_trellis_steps`)
-            useful = sum(lane[4] for lane in lanes)
-            n_slots = self.s * self.k
-            padded = n_slots * self.n_sym_bucket
-            telemetry.count("rx.decode_symbols", useful,
-                            labels={"kind": "useful"})
-            telemetry.count("rx.decode_symbols", padded,
-                            labels={"kind": "padded"})
-            with telemetry.span("rx.fleet.decode", {
-                    "step": step, "lanes": len(lanes),
-                    "slots": n_slots,
-                    "useful_symbols": useful,
-                    "padded_symbols": padded,
-                    "useful_bits": int(tables[2].sum()),
-                    "trellis_steps": n_slots
-                    * mixed_trellis_steps(self.n_sym_bucket)}):
-                dec = _rx._jit_stream_decode_multi(
-                    self.n_sym_bucket, self.viterbi_window,
-                    self.viterbi_metric, self.viterbi_radix,
-                    self.mesh, self.axis, self.sco_track,
-                    self.fused_demap)
-                dec_args = (segs,) + tuple(self._put(t) for t in tables)
-                programs.note_site("rx.stream_decode_multi", dec,
-                                   *dec_args)
-                got = _guarded_decode(
-                    self, "rx.stream_decode_multi", dec, *dec_args,
-                    pull_span=("rx.fleet.pull_decode", {
-                        "step": step, "shards": 2 * self._n_devices}))
+        if st.lanes:
+            got = _pull_decode(self, st)
             if got is None:
-                # degrade the WHOLE fleet's decode to the per-capture
-                # oracle (bit-identical by the pinned contract), this
-                # chunk-step included — healthy lanes keep flowing
-                return self._decode_oracle(allcands, starts, arrs,
-                                           valids)
+                return self._decode_oracle(st)
+        emit = st.emit
         with telemetry.span("rx.fleet.emit", {
-                "step": step, "frames": len(emit) + len(lanes)}):
+                "step": st.step, "frames": len(emit) + len(st.lanes)}):
             if got is not None:
                 clear, crc = got
-                for i, sl in slots.items():
+                for i, sl in st.slots.items():
                     for pos, (abs_start, m, lb) in enumerate(sl):
-                        psdu = clear[i, pos][
+                        psdu = clear[i][pos][
                             N_SERVICE_BITS: N_SERVICE_BITS + 8 * lb]
                         emit[(i, abs_start)] = _rx.RxResult(
                             True, m, lb, psdu,
@@ -1518,7 +1677,7 @@ class MultiStreamReceiver:
             npsdu[i, pos] = 8 * lb
         return emit, lanes, slots, (rows, ridx, nbits, npsdu)
 
-    def _decode_oracle(self, allcands, starts, arrs, valids) -> List:
+    def _decode_oracle(self, st) -> List:
         """The per-capture decode twin — the ``streaming=False``
         oracle AND the degraded mode the compiled decode falls back
         to: each owned window sliced from its stream's host chunk and
@@ -1539,12 +1698,12 @@ class MultiStreamReceiver:
                    or self._scan_degraded)
         out: List = []
         with telemetry.span("rx.fleet.emit", {
-                "step": self._drain_step, "frames": len(allcands)}):
-            for i, abs_start, j in sorted(allcands,
+                "step": st.step, "frames": len(st.allcands)}):
+            for i, abs_start, j in sorted(st.allcands,
                                           key=lambda c: (c[0], c[1])):
-                s = int(starts[i, j])
-                win = arrs[i][s: min(s + self.frame_len,
-                                     int(valids[i]))]
+                s = int(st.starts[i, j])
+                win = st.arrs[i][s: min(s + self.frame_len,
+                                        int(st.valid[i]))]
                 try:
                     res = _rx.receive(
                         win, check_fcs=self.check_fcs,

@@ -669,7 +669,12 @@ class ServeRuntime:
         packer (one ``push_many`` — chunk-steps dispatch for
         whichever lanes filled, idle lanes ride the valid-mask).
         Returns the ``(sid, StreamFrame)`` pairs that became
-        decodable this tick."""
+        decodable this tick: a tick that launches hands back the
+        frames of the chunk-step two launches before it, and a tick
+        that launches nothing (nothing staged included) those of
+        every older step the device has finished meanwhile, without
+        waiting for one it has not. :meth:`drain`, :meth:`close`,
+        :meth:`evict` and :meth:`snapshot` wait for all of them."""
         if self._drained:
             raise RuntimeError("step after drain")
         with telemetry.span("serve.step",
@@ -685,8 +690,9 @@ class ServeRuntime:
                                              self.cfg.chunk_len)
                     if take is not None:
                         push[lane] = take
-            if push:
-                out += self._push(push)
+            # an empty push launches nothing and still hands back
+            # what the device has finished
+            out += self._push(push)
             out += self._maybe_snapshot()
             self._gauges()
         return out
