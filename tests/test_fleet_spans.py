@@ -9,6 +9,9 @@ and once with nothing active. The program half lowers the two fleet
 programs (no compile) and reads module names and scopes off the text.
 """
 
+import gc
+import time
+
 import jax
 import numpy as np
 import pytest
@@ -31,8 +34,10 @@ FLEET_SPANS = {"serve.step", "serve.stage", "serve.emit",
                "rx.fleet.pull_scan", "rx.fleet.classify",
                "rx.fleet.decode", "rx.fleet.pull_decode",
                "rx.fleet.emit"}
+#: the two dispatch spans of `resilience.guarded` carry the id too
+DISPATCH_SPANS = {"rx.stream_chunk_multi", "rx.stream_decode_multi"}
 STEP_KEYED = {n for n in FLEET_SPANS if n.startswith("rx.fleet.")} \
-    - {"rx.fleet.ingest"}
+    | DISPATCH_SPANS
 SCAN_SCOPES = ("rx.scan.locate", "rx.scan.window", "rx.scan.acquire",
                "rx.scan.gather")
 DECODE_SCOPES = ("rx.decode.select", "rx.decode.front",
@@ -85,17 +90,21 @@ def runs():
         built.append(1)
         return real()
 
-    # nothing active: no annotation class is even looked up
+    # nothing active: no annotation class is even looked up, no
+    # collection is listened for, no lane's fill is timed
     telemetry._annotation_cls = counting
     try:
-        _srv0, plain = _serve(streams)
+        srv0, plain = _serve(streams)
     finally:
         telemetry._annotation_cls = real
+    idle = {"built": len(built),
+            "gc_callbacks": telemetry._on_gc in gc.callbacks,
+            "full_since": srv0._rx._full_since}
     with telemetry.tracing() as tr:
         srv, traced = _serve(streams)
     spans = [e for e in tr.events() if e["ph"] == "X"
              and e["cat"] == "host"]
-    return srv, spans, traced, plain, len(built)
+    return srv, spans, traced, plain, idle
 
 
 def _named(spans, name):
@@ -107,8 +116,7 @@ def test_every_span_of_the_table_is_recorded(runs):
     assert len(traced) == sum(len(r) for r in RATE_SETS)
     names = {e["name"] for e in spans}
     assert FLEET_SPANS <= names
-    # the dispatch spans of resilience.guarded stay as they were
-    assert {"rx.stream_chunk_multi", "rx.stream_decode_multi"} <= names
+    assert DISPATCH_SPANS <= names
     for e in spans:
         if e["name"] in STEP_KEYED:
             assert isinstance(e["args"]["step"], int), e
@@ -116,7 +124,8 @@ def test_every_span_of_the_table_is_recorded(runs):
             assert "step" not in (e.get("args") or {}), e
     assert all(e["args"] == {"sessions": S}
                for e in _named(spans, "serve.step"))
-    assert all(e["args"] == {"lanes": S}
+    assert all(set(e["args"]) == {"step", "lanes"}
+               and e["args"]["lanes"] == S
                for e in _named(spans, "rx.fleet.ingest"))
 
 
@@ -138,9 +147,10 @@ def test_step_pairs_each_stack_with_one_emit_two_ticks_later(runs):
     # its front half (the decode's dispatch) runs a tick before that
     for e in _named(spans, "rx.fleet.classify")[:-1]:
         assert tick_of(e) == tick_of(stacks[e["args"]["step"]]) + 1
-    # every other step-keyed span of a step lies between the two
+    # every other step-keyed span of a step lies between the two (but
+    # the ingest that fills its lanes, which comes before the stack)
     for e in spans:
-        if e["name"] in STEP_KEYED:
+        if e["name"] in STEP_KEYED - {"rx.fleet.ingest"}:
             st = stacks[e["args"]["step"]]
             assert e["ts"] >= st["ts"]
     for e in _named(spans, "rx.fleet.stack"):
@@ -321,8 +331,10 @@ def test_locate_rows_by_geometry(s, chunk_len, devices, want):
 
 
 def test_with_no_trace_same_frames_and_nothing_built(runs):
-    _srv, _spans, traced, plain, built = runs
-    assert built == 0
+    _srv, _spans, traced, plain, idle = runs
+    assert idle == {"built": 0, "gc_callbacks": False,
+                    "full_since": None}
+    assert telemetry._on_gc not in gc.callbacks     # nor after a trace
     assert [(sid, f.start) for sid, f in plain] \
         == [(sid, f.start) for sid, f in traced]
     for (_s, a), (_t, b) in zip(plain, traced):
@@ -335,8 +347,179 @@ def test_with_no_trace_same_frames_and_nothing_built(runs):
     assert idle.events() == []
 
 
+def test_one_step_id_from_a_samples_arrival_to_its_frames_emission(runs):
+    """ISSUE 41: `rx.fleet.ingest` carries the id of the NEXT launch
+    and the two dispatch spans their chunk-step's, so every span of a
+    flight shares one identifier, in whichever call it runs."""
+    srv, spans, _traced, _plain, _idle = runs
+    steps = srv._rx.stats.chunk_steps
+    by_step = {}
+    for e in spans:
+        if e["name"] in STEP_KEYED:
+            by_step.setdefault(e["args"]["step"], []).append(e)
+    # the ingests after the last launch name a step that never came
+    assert set(range(steps)) <= set(by_step)
+    always = {"rx.fleet.ingest", "rx.fleet.stack", "rx.fleet.put",
+              "rx.stream_chunk_multi", "rx.fleet.pull_scan",
+              "rx.fleet.classify", "rx.fleet.emit"}
+    decoded = {"rx.fleet.decode", "rx.stream_decode_multi",
+               "rx.fleet.pull_decode"}
+    for step in range(steps):
+        evs = sorted(by_step[step], key=lambda e: e["ts"])
+        names = [e["name"] for e in evs]
+        assert always <= set(names), (step, names)
+        assert set(names) - always in (set(), decoded), (step, names)
+        # the ingest that filled the lanes comes first, the emit last
+        assert names[0] == "rx.fleet.ingest"
+        assert names[-1] == "rx.fleet.emit"
+        # and each dispatch span lies inside its step's launch or decode
+        outer = {"rx.stream_chunk_multi": "rx.fleet.stack",
+                 "rx.stream_decode_multi": "rx.fleet.decode"}
+        at = {e["name"]: e for e in evs}
+        for inner, first in outer.items():
+            if inner in at:
+                assert at[inner]["ts"] >= at[first]["ts"]
+
+
+@pytest.fixture(scope="module")
+def busy():
+    """One stream with a frame in every chunk of its first five, the
+    same on every lane of a bare fleet: what the `how` and `ready_ms`
+    cases drive by hand."""
+    rng = np.random.default_rng(20260930)
+    rates = [6, 24, 54] * 3
+    psdus = [rng.integers(0, 256, N_BYTES).astype(np.uint8)
+             for _ in rates]
+    st, _starts = link.stream_many(
+        psdus, rates, gaps=[1500] * (len(rates) - 1), snr_db=30.0,
+        cfo=1e-4, delay=60, seed=41, add_fcs=True, tail=FRAME_LEN)
+    assert len(st) >= CHUNK + 3 * SLAB
+    return st
+
+
+def _fleet():
+    return framebatch.MultiStreamReceiver(
+        n_streams=S, chunk_len=CHUNK, frame_len=FRAME_LEN,
+        max_frames_per_chunk=K, check_fcs=True)
+
+
+def _hows(tr, name):
+    return {e["args"]["step"]: e["args"]["how"] for e in tr.events()
+            if e["name"] == name}
+
+
+def test_a_pull_says_how_its_half_was_reached(busy):
+    """`how` on the two pulls (ISSUE 41): `launch` behind a launch,
+    `ready` from a call that launched nothing, `drain` at a drain
+    point: the value `rx.pipeline_advances` is labelled with."""
+    rx_ = _fleet()
+    cuts = [0, CHUNK, CHUNK + SLAB, CHUNK + 2 * SLAB, CHUNK + 3 * SLAB]
+    with telemetry.tracing() as tr:
+        for a, b in zip(cuts[:3], cuts[1:4]):       # steps 0, 1, 2
+            rx_.push_many([busy[a:b]] * S)
+        # a closed loop: step 0 went through both halves behind
+        # launches, step 1 through its front half
+        assert _hows(tr, "rx.fleet.pull_scan") == {0: "launch",
+                                                   1: "launch"}
+        assert _hows(tr, "rx.fleet.pull_decode") == {0: "launch"}
+        out = rx_.drain_pending()
+        assert out and rx_._pending is None
+        assert _hows(tr, "rx.fleet.pull_scan")[2] == "drain"
+        assert {s: h for s, h in _hows(
+            tr, "rx.fleet.pull_decode").items() if s} \
+            == {1: "drain", 2: "drain"}
+        # a launch, then calls that launch nothing once the device is
+        # done: each half is found ready
+        rx_.push_many([busy[cuts[3]:cuts[4]]] * S)  # step 3
+        jax.block_until_ready(rx_._flight[-1].outs)
+        got = rx_.push_many({})
+        st = rx_._flight[0] if rx_._flight else None
+        if st is not None:      # its decode, dispatched just now
+            jax.block_until_ready(st.dec_out)
+            got += rx_.push_many({})
+        assert got and rx_._pending is None
+        assert _hows(tr, "rx.fleet.pull_scan")[3] == "ready"
+        assert _hows(tr, "rx.fleet.pull_decode")[3] == "ready"
+    adv = {how: sum(1 for e in tr.events() if e["ph"] == "X"
+                    and (e.get("args") or {}).get("how") == how)
+           for how in ("launch", "ready", "drain")}
+    assert adv == {"launch": 3, "ready": 2, "drain": 3}
+
+
+def test_ready_ms_is_the_wait_from_a_full_lane_to_its_launch(busy):
+    """`ready_ms` on `rx.fleet.stack` (ISSUE 41): next to nothing in a
+    lockstep `push_many` (the rest of that call's ingest), and longer
+    by whatever passes between a lane's fill and the push that
+    launches."""
+    rx_ = _fleet()
+    nap = 0.2
+    with telemetry.tracing() as tr:
+        rx_.push_many([busy[:CHUNK]] * S)                   # step 0
+        rx_._ingest(0, busy[CHUNK:CHUNK + SLAB])            # lane 0 full
+        assert rx_._full_since is not None
+        time.sleep(nap)
+        rx_.push_many({i: busy[CHUNK:CHUNK + SLAB]
+                       for i in range(1, S)})               # step 1
+        assert rx_._full_since is None
+        rx_.drain_pending()
+    waits = {e["args"]["step"]: e["args"]["ready_ms"]
+             for e in tr.events() if e["name"] == "rx.fleet.stack"}
+    assert 0.0 <= waits[0] < 1e3 * nap / 2
+    assert waits[1] >= 1e3 * nap > waits[0]
+    # with no trace no lane's fill is timed
+    rx_.push_many([busy[CHUNK + SLAB:CHUNK + 2 * SLAB]] * S)
+    assert rx_._full_since is None
+    rx_.drain_pending()
+
+
+def test_a_collection_inside_a_trace_is_a_span(monkeypatch):
+    """`rx.pause.gc` (ISSUE 41): one `gc.callbacks` entry while any
+    trace is active, gone when the last closes; each collection a span
+    with its `generation` and what it `collected`, and an annotation
+    (the generation alone: it is entered at the start) when the trace
+    annotates the device."""
+    seen = []
+
+    class Ann:
+        def __init__(self, name, **kw):
+            seen.append((name, kw))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(telemetry, "_ANN_CLS", Ann)
+    assert telemetry._on_gc not in gc.callbacks
+    with telemetry.tracing() as outer:
+        with telemetry.tracing(annotate_device=True) as inner:
+            assert gc.callbacks.count(telemetry._on_gc) == 1
+            with telemetry.span("rx.fleet.stack", {"step": 0}):
+                gc.collect()
+        assert gc.callbacks.count(telemetry._on_gc) == 1
+        gc.collect(0)
+    assert telemetry._on_gc not in gc.callbacks
+    full = [e for e in inner.events() if e["name"] == telemetry.GC_SPAN
+            and e["args"]["generation"] == 2]
+    assert len(full) == 1 and full[0]["args"]["collected"] >= 0
+    stack = next(e for e in inner.events()
+                 if e["name"] == "rx.fleet.stack")
+    assert stack["ts"] <= full[0]["ts"] and full[0]["ts"] \
+        + full[0]["dur"] <= stack["ts"] + stack["dur"]
+    assert (telemetry.GC_SPAN, {"generation": 2}) in seen
+    # the outer trace saw both, the inner one only its own
+    gens = [e["args"]["generation"] for e in outer.events()
+            if e["name"] == telemetry.GC_SPAN]
+    assert 2 in gens and gens[-1] == 0
+    assert len([e for e in inner.events()
+                if e["name"] == telemetry.GC_SPAN]) < len(gens)
+
+
 def test_annotation_takes_args_as_keywords(monkeypatch):
     seen = []
+    # every annotation is listed below: none for a collection
+    monkeypatch.setattr(telemetry, "_on_gc", lambda phase, info: None)
 
     class Ann:
         def __init__(self, name, **kw):

@@ -56,13 +56,16 @@ def test_the_cell_reads_what_mtu8_saturated_reads_but_one_and_four_more():
     its operations are counted for all 32 lanes and its kernel time is
     device 0's 8, so it would read four times too high here (left to a
     `benchmark` issue). The four new ones are this cell's alone; the
-    one PR 40 appended behind them (`decode_ready_share`) it shares."""
+    one PR 40 appended behind them (`decode_ready_share`) it shares,
+    and so it does the whole-window readings PR 41 appended."""
     names = [m.name for m in manifest.load_cell(CELL).per_layer]
     mtu8 = [m.name for m in manifest.load_cell("mtu8.saturated").per_layer]
     assert "acs_roofline" in mtu8 and "acs_roofline" not in names
     assert [n for n in names if n not in NEW_METRICS] \
         == [n for n in mtu8 if n != "acs_roofline"]
-    assert names[-5:] == list(NEW_METRICS) + ["decode_ready_share"]
+    at = names.index("decode_ready_share")
+    assert names[at - 4:at + 1] \
+        == list(NEW_METRICS) + ["decode_ready_share"]
     by = {m["name"]: m for m in MAN["per_layer"]}
     for n, (layer, source) in NEW_METRICS.items():
         assert by[n]["workloads"] == [CELL], n

@@ -6,6 +6,7 @@ exposition, the dispatch/gauge/compile emitter wiring, and — because
 the hot paths carry their instrumentation permanently — a pinned
 near-zero-overhead check for the disabled path."""
 
+import gc
 import importlib.util
 import json
 import math
@@ -27,6 +28,22 @@ def _load_trace_report():
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+@pytest.fixture(autouse=True)
+def collector_off():
+    """The tests below count a trace's events exactly, and since
+    ISSUE 41 a collection that strikes inside an active trace is an
+    event of its own (`rx.pause.gc`; tests/test_fleet_spans.py has
+    its test): eight threads appending 400 event dicts are enough to
+    bring one on. So the collector rests while each test runs."""
+    was = gc.isenabled()
+    gc.disable()
+    try:
+        yield
+    finally:
+        if was:
+            gc.enable()
 
 
 # ------------------------------------------------------------------ spans
@@ -113,6 +130,57 @@ def test_overlapping_traces_each_see_their_window():
             pass
     assert [e["name"] for e in a.events()] == ["one", "two", "three"]
     assert [e["name"] for e in b.events()] == ["two"]
+
+
+def test_last_trace_before_inside_and_after_nested(monkeypatch):
+    """`last_trace()` (ISSUE 41): the trace most recently ACTIVATED,
+    still there once its block has closed, so a reader that was not
+    handed the object finds it; None before the first."""
+    monkeypatch.setattr(telemetry, "_LAST_TRACE", None)
+    assert telemetry.last_trace() is None
+    with telemetry.tracing() as a:
+        assert telemetry.last_trace() is a
+        with telemetry.tracing() as b:
+            assert telemetry.last_trace() is b
+        # closing the inner block re-activates nothing
+        assert telemetry.last_trace() is b
+    assert telemetry.last_trace() is b and not telemetry.traced()
+    mine = telemetry.Trace()
+    with telemetry.tracing(trace=mine):
+        assert telemetry.traced()
+    assert telemetry.last_trace() is mine
+    # building a trace activates nothing
+    telemetry.Trace()
+    assert telemetry.last_trace() is mine
+
+
+def test_epoch_lays_a_span_on_perf_counter():
+    """`Trace.epoch` is the `perf_counter` value `ts` is relative to:
+    `epoch + ts / 1e6` is when the span began on that clock."""
+    t_made = time.perf_counter()
+    with telemetry.tracing() as tr:
+        assert t_made <= tr.epoch <= time.perf_counter()
+        time.sleep(0.01)
+        before = time.perf_counter()
+        with telemetry.span("one", {"step": 1}):
+            inside = time.perf_counter()
+        after = time.perf_counter()
+        tr.complete("by_hand", 123.25, 0.5)
+    one, by_hand = tr.events()
+    began = tr.epoch + one["ts"] / 1e6
+    assert before <= began <= inside
+    assert inside <= began + one["dur"] / 1e6 <= after
+    assert tr.epoch + by_hand["ts"] / 1e6 == pytest.approx(123.25,
+                                                            abs=1e-9)
+
+
+def test_track_is_a_counter_sample_in_traces_alone():
+    with telemetry.collect() as reg, telemetry.tracing() as tr:
+        telemetry.track("rx.device_bytes_in_use", 7.0)
+    assert [(e["name"], e["ph"], e["args"]) for e in tr.events()] \
+        == [("rx.device_bytes_in_use", "C", {"value": 7.0})]
+    assert reg.snapshot() == {}
+    telemetry.track("rx.device_bytes_in_use", 8.0)      # nothing active
 
 
 # ------------------------------------------------------------- histograms

@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import collections
 import threading
+import time
 from typing import Any, List, NamedTuple, Optional, Sequence
 
 import numpy as np
@@ -724,7 +725,7 @@ def _dispatch_decode(r, st) -> None:
     try:
         st.dec_out = resilience.guarded(
             "rx.stream_decode_multi", st.dec, *st.dec_args,
-            policy=r._policy)
+            policy=r._policy, span_args={"step": st.step})
         _start_pull(st.dec_out)
     except resilience.DispatchFailed:
         st.dec_out = None
@@ -750,7 +751,8 @@ def _pull_decode(r, st):
             with telemetry.span("rx.fleet.pull_decode", {
                     "step": st.step, "shards": 2 * r._n_devices,
                     "bytes": int(clear.nbytes + crc.nbytes),
-                    "reads": 1, "ready": int(_ready(st.dec_out))}):
+                    "reads": 1, "ready": int(_ready(st.dec_out)),
+                    "how": st.how}):
                 return _host_lanes(clear), np.asarray(crc)
         except Exception:        # noqa: BLE001 - async pull loss
             if attempt:
@@ -770,12 +772,16 @@ class _InFlight:
     step (a lost scan is rescanned from them, the oracle twin slices
     its windows out of them), the scan's outputs (`segs` among them,
     the decode's input and its re-dispatch's), and the decode's
-    tables and outputs. ``step`` tags every span of either half."""
+    tables and outputs. ``step`` tags every span of either half, and
+    ``how`` says which way the half now running was reached (behind a
+    ``"launch"``, by a call that launched nothing and found it
+    ``"ready"``, at a ``"drain"`` point): the two pull spans carry it,
+    so that a read that waited is told from one that did not have to."""
 
     __slots__ = ("step", "offs", "active", "arrs", "valid", "own_lo",
                  "own_hi", "outs", "fronted", "allcands", "starts",
                  "oracle", "emit", "lanes", "slots", "dec", "dec_args",
-                 "dec_out")
+                 "dec_out", "how")
 
     def __init__(self, step, offs, active, arrs, valid, own_lo, own_hi,
                  outs):
@@ -787,6 +793,7 @@ class _InFlight:
         self.oracle = False
         self.emit = self.lanes = self.slots = None
         self.dec = self.dec_args = self.dec_out = None
+        self.how = "launch"
 
 
 def _gate_finite(arr: np.ndarray, name: str, sanitize: bool,
@@ -992,6 +999,10 @@ class MultiStreamReceiver:
         # launch has returned (`_InFlight`, `_settle`)
         self._flight: List[_InFlight] = []
         self._chunk_steps = 0
+        # since when a lane has held a full chunk that no launch has
+        # taken (`perf_counter`; kept only while a trace is active:
+        # `rx.fleet.stack`'s `ready_ms`)
+        self._full_since: Optional[float] = None
         self._overflow_chunks = 0
         self._max_in_flight = 0
         self._max_active = 0
@@ -1104,7 +1115,7 @@ class MultiStreamReceiver:
         """The per-stream push seam: shape gate, chaos corruption
         seam (site ``rx.push.s<i>``), non-finite gate (reject, or
         ``sanitize=True`` zero-and-quarantine), then append."""
-        from ziria_tpu.utils import faults
+        from ziria_tpu.utils import faults, telemetry
 
         name = f"stream {stream}"
         arr = _slab_array(samples, name)
@@ -1117,6 +1128,9 @@ class MultiStreamReceiver:
         if arr.size:
             self._tails[stream] = np.concatenate(
                 [self._tails[stream], arr], axis=0)
+            if telemetry.traced() and self._full_since is None \
+                    and self._tails[stream].shape[0] >= self.chunk_len:
+                self._full_since = time.perf_counter()
 
     def push(self, stream: int, samples) -> List:
         """Append samples ((n, 2) float pairs) to one stream; fire
@@ -1157,8 +1171,8 @@ class MultiStreamReceiver:
                     f"got {len(slabs)}")
             items = list(enumerate(slabs))
         if items:
-            with telemetry.span("rx.fleet.ingest",
-                                {"lanes": len(items)}):
+            with telemetry.span("rx.fleet.ingest", {
+                    "step": self._chunk_steps, "lanes": len(items)}):
                 for i, s in items:
                     self._ingest(i, s)
         return self._pump()
@@ -1317,10 +1331,15 @@ class MultiStreamReceiver:
         advance the active streams' host carries."""
         from ziria_tpu.utils import dispatch, telemetry
 
-        with telemetry.span("rx.fleet.stack", {
-                "step": self._chunk_steps, "active": len(active),
+        args = {"step": self._chunk_steps, "active": len(active),
                 "samples": sum(self._tails[i].shape[0] if flushing
-                               else self.chunk_len for i in active)}):
+                               else self.chunk_len for i in active)}
+        traced = telemetry.traced()
+        full_since, self._full_since = self._full_since, None
+        if traced and full_since is not None:
+            # how long the first lane to fill waited for this launch
+            args["ready_ms"] = 1e3 * (time.perf_counter() - full_since)
+        with telemetry.span("rx.fleet.stack", args):
             arrs = np.zeros((self.s, self.chunk_len, 2), np.float32)
             valid = np.zeros(self.s, np.int32)
             own_lo = np.zeros(self.s, np.int32)
@@ -1358,10 +1377,10 @@ class MultiStreamReceiver:
         for i in active:
             self._tails[i] = self._tails[i][adv[i]:]
             self._offsets[i] += adv[i]
-            # per-stream carry depth: with telemetry active these are
-            # the per-stream counter-track rows next to the aggregate
-            dispatch.record_gauge(f"rx.stream_carry_depth[s{i}]",
-                                  self._tails[i].shape[0])
+        if traced and any(t.shape[0] >= self.chunk_len
+                          for t in self._tails):
+            # a lane this launch left full has waited since it
+            self._full_since = time.perf_counter()
         dispatch.record_gauge("rx.stream_carry_depth",
                               sum(t.shape[0] for t in self._tails))
         return res
@@ -1396,7 +1415,7 @@ class MultiStreamReceiver:
                           self._put(own_lo), self._put(own_hi))
         programs.note_site("rx.stream_chunk_multi", self._jit1,
                            *chunk_args)
-        outs = self._scan_dispatch(chunk_args)
+        outs = self._scan_dispatch(chunk_args, step)
         self._chunk_steps += 1
         self._flight.append(_InFlight(
             step, offs, list(active), arrs, valid.copy(), own_lo.copy(),
@@ -1404,6 +1423,15 @@ class MultiStreamReceiver:
         self._max_in_flight = max(self._max_in_flight, len(self._flight))
         self._max_active = max(self._max_active, len(active))
         dispatch.record_gauge("rx.stream_inflight", len(self._flight))
+        if telemetry.traced():
+            # the stall's one known signature (PERF.md section 7),
+            # with a time beside the spans it struck
+            import jax
+            dev = jax.devices()[0] if self.mesh is None \
+                else self.mesh.devices.flat[0]
+            in_use = (dev.memory_stats() or {}).get("bytes_in_use")
+            if in_use is not None:
+                telemetry.track("rx.device_bytes_in_use", in_use)
         # the fleet-level time series: how many lanes carried real
         # samples this step (idle lanes are the valid-mask riders)
         dispatch.record_gauge("rx.active_streams", len(active))
@@ -1428,9 +1456,11 @@ class MultiStreamReceiver:
         halves = 0
         for st in self._flight[:len(self._flight) - scans]:
             if not st.fronted:
+                st.how = how
                 self._front(st)
                 halves += 1
         while len(self._flight) > depth:
+            self._flight[0].how = how
             out += self._drain(self._flight[0])
             halves += 1
         if halves:
@@ -1452,9 +1482,11 @@ class MultiStreamReceiver:
         while True:
             st = next((x for x in self._flight if not x.fronted), None)
             if st is not None and _ready(_chunk_scalars(st.outs)):
+                st.how = "ready"
                 self._front(st)
             elif self._flight and self._flight[0].fronted \
                     and _ready(self._flight[0].dec_out or ()):
+                self._flight[0].how = "ready"
                 out += self._drain(self._flight[0])
             else:
                 break
@@ -1464,12 +1496,12 @@ class MultiStreamReceiver:
                             labels={"how": "ready"})
         return out
 
-    def _scan_dispatch(self, chunk_args):
+    def _scan_dispatch(self, chunk_args, step: int):
         """The ONE guarded fleet-scan dispatch (shared by `_launch`
         and the async-rescan path), degrading to the eager twin when
         the compiled program fails for good. The nine scalars leave
         each device as its scan ends, a launch before `_front` reads
-        them."""
+        them. Its span carries the chunk-step's id."""
         from ziria_tpu.runtime import resilience
 
         outs = None
@@ -1477,7 +1509,7 @@ class MultiStreamReceiver:
             try:
                 outs = resilience.guarded(
                     "rx.stream_chunk_multi", self._jit1, *chunk_args,
-                    policy=self._policy)
+                    policy=self._policy, span_args={"step": step})
             except resilience.DispatchFailed:
                 self._mark_degraded(scan=True)
         if outs is None:
@@ -1497,7 +1529,7 @@ class MultiStreamReceiver:
         telemetry.count("resilience.async_rescans")
         return self._scan_dispatch(
             (self._put(st.arrs), self._put(st.valid),
-             self._put(st.own_lo), self._put(st.own_hi)))
+             self._put(st.own_lo), self._put(st.own_hi)), st.step)
 
     def _front(self, st) -> None:
         """Run the front half of a launched chunk-step's drain
@@ -1530,7 +1562,8 @@ class MultiStreamReceiver:
                 "step": step,
                 "bytes": sum(int(x.nbytes) for x in pulled),
                 "shards": len(pulled) * self._n_devices,
-                "reads": 1, "ready": int(_ready(pulled))}))
+                "reads": 1, "ready": int(_ready(pulled)),
+                "how": st.how}))
         try:
             (own, starts, overflow, found, fstart, rb, ln, pk, nv,
              segs) = pull(st.outs)

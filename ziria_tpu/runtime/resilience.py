@@ -251,11 +251,13 @@ def compile_ahead(fn: Callable, *args) -> None:
 def guarded(label: str, fn: Callable, *args,
             policy: Optional[FaultPolicy] = None,
             fallback: Optional[Callable[[], Any]] = None,
+            span_args: Optional[dict] = None,
             _sleep: Callable[[float], None] = time.sleep) -> Any:
     """Fire ``fn(*args)`` as a guarded dispatch at site ``label``.
 
-    Every attempt runs inside ``dispatch.timed(label)`` (the
-    per-attempt latency lands in the site's telemetry histogram, and
+    Every attempt runs inside ``dispatch.timed(label, span_args)`` (the
+    per-attempt latency lands in the site's telemetry histogram, its
+    trace span carries ``span_args``, and
     retries count as the extra dispatches they are) behind the chaos
     seam (``faults.maybe_fail(label)``). Transient failures retry up
     to ``policy.max_retries`` times with deterministic-jitter
@@ -273,7 +275,7 @@ def guarded(label: str, fn: Callable, *args,
     attempt = 0
     for attempt in range(policy.max_retries + 1):
         try:
-            with dispatch.timed(label):
+            with dispatch.timed(label, span_args):
                 if policy.timeout_s is not None:
                     def call(abandoned):
                         faults.maybe_fail(label)

@@ -53,8 +53,12 @@ All SLO metrics report through the PR 7 `utils/telemetry` registry —
 ``exposition()``, not a parallel stats path: ``serve.*`` counters
 (admitted/queued/rejected/shed/evicted/restored/closed/frames, shed
 reasons as labels), ``serve.active_sessions`` / ``serve.queue_depth``
-gauges, and the ``serve.chunk_seconds`` latency histogram whose
-p50/p99 are the SLO numbers, next to the per-dispatch
+gauges, and the ``serve.chunk_seconds`` latency histogram (the time
+of the ``push_many`` call a chunk-step was LAUNCHED in: since the
+receiver keeps three steps in flight that is a tick of the loop, not
+the step's flight from its launch to its frames, which spans three
+calls and is the benchmark's ``chunk_flight_ms.window``), next to the
+per-dispatch
 ``ziria_dispatch_seconds{site="rx.stream_chunk_multi"}`` series the
 receiver already emits. Use the runtime as a context manager — it
 activates its registry for its lifetime and drains on exit.

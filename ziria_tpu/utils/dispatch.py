@@ -190,19 +190,20 @@ def record_gauge(label: str, value: float) -> None:
 
 
 @contextmanager
-def timed(label: str = "dispatch"):
+def timed(label: str = "dispatch", args: Optional[dict] = None):
     """``with timed("rx.sync"): ...`` — record ONE dispatch at the
     site plus the wall time of the block. The preferred form for
     instrumented call sites: dispatch *time*, not just count, becomes
     observable per stage (`tools/rx_dispatch_bench.py` stats blocks
     report both). With telemetry active the block is additionally a
-    trace span and a latency-histogram observation — p50/p99 per site
-    for free. Near-free when nothing is collecting (one truthiness
-    check)."""
+    trace span (carrying ``args``, as `telemetry.span` does: the served
+    path's two dispatches give their chunk-step's id) and a
+    latency-histogram observation — p50/p99 per site for free.
+    Near-free when nothing is collecting (one truthiness check)."""
     if _idle():
         yield
         return
-    with _tm.span(label):
+    with _tm.span(label, args):
         t0 = time.perf_counter()
         try:
             yield

@@ -21,6 +21,10 @@ cost is one tuple truthiness check, pinned by
   ``Trace(annotate_device=True)`` passes each span through
   ``jax.profiler.TraceAnnotation`` so host spans line up with device
   traces when a ``jax.profiler`` capture runs concurrently.
+  The trace most recently activated stays reachable
+  (:func:`last_trace`) and says its clock (``Trace.epoch``); while
+  one is active each collection of the interpreter's is a span too
+  (``rx.pause.gc``), so a pause of the host has a name in it.
 - **Metrics** — :func:`collect` activates a :class:`MetricsRegistry`
   of :class:`CounterMetric`\\ s, time-series :class:`Gauge`\\ s (every
   sample kept, not just the high-water mark), and power-of-two
@@ -48,6 +52,7 @@ reentrancy contract as ``dispatch.count_dispatches``).
 
 from __future__ import annotations
 
+import gc
 import json
 import math
 import os
@@ -69,6 +74,13 @@ def active() -> bool:
     """True when any trace or registry is collecting (the slow path of
     every emitter is gated on this)."""
     return bool(_TRACES or _REGISTRIES)
+
+
+def traced() -> bool:
+    """True when a trace is active: the gate of what is recorded for a
+    trace alone (a clock read kept for a span's arg, a counter-track
+    sample), which a registry that always collects must not pay for."""
+    return bool(_TRACES)
 
 
 # ------------------------------------------------------------- histograms
@@ -326,18 +338,24 @@ class Trace:
     monotonic epoch; gauges as counter ("C") tracks; compile events in
     the ``compile`` category. :meth:`export` writes the standard
     ``{"traceEvents": [...]}`` JSON object (Perfetto /
-    ``chrome://tracing`` / ``tools/trace_report.py``)."""
+    ``chrome://tracing`` / ``tools/trace_report.py``).
+
+    ``epoch`` is the ``time.perf_counter()`` value every event's ``ts``
+    is relative to: an event began at ``epoch + ts / 1e6`` seconds on
+    that clock, which is how a reader lays the trace against anything
+    else timed with ``perf_counter`` (the benchmark's window) or, by
+    the spans both hold, against a profiler's trace."""
 
     def __init__(self, annotate_device: bool = False) -> None:
         self.annotate_device = annotate_device
         self._lock = threading.Lock()
         self._events: List[Dict[str, Any]] = []
         self._meta: Dict[str, Any] = {}
-        self._epoch = time.perf_counter()
+        self.epoch = time.perf_counter()
         self._pid = os.getpid()
 
     def _ts(self, t: float) -> float:
-        return (t - self._epoch) * 1e6          # µs, trace-relative
+        return (t - self.epoch) * 1e6           # µs, trace-relative
 
     def add_event(self, ev: Dict[str, Any]) -> None:
         with self._lock:
@@ -456,7 +474,58 @@ def span(name: str, args: Optional[dict] = None):
             t.complete(name, t0, dur, args=args)
 
 
+# ----------------------------------------------------------------- pauses
+
+#: the span a collection of the interpreter's is recorded under: named
+#: as the served path's spans are, so that a reader of device-idle gaps
+#: (which labels a gap by the deepest ``rx.*`` span open in it) names a
+#: gap a collection made
+GC_SPAN = "rx.pause.gc"
+_gc_open: Optional[Tuple[float, Any]] = None    # (start, annotation)
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    """The ``gc.callbacks`` entry of an active trace: each collection
+    becomes one span from its ``start`` to its ``stop`` phase, with
+    ``generation`` and ``collected`` (the annotation, entered at the
+    start, carries the generation alone). Collections do not nest and
+    run under the interpreter lock, so one open slot serves every
+    thread."""
+    global _gc_open
+    traces = _TRACES
+    if phase == "start":
+        ann = None
+        if any(t.annotate_device for t in traces):
+            cls = _annotation_cls()
+            if cls is not None:
+                ann = cls(GC_SPAN, generation=info["generation"])
+                ann.__enter__()
+        _gc_open = (time.perf_counter(), ann)
+    elif _gc_open is not None:
+        (t0, ann), _gc_open = _gc_open, None
+        dur = time.perf_counter() - t0
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        args = {"generation": info["generation"],
+                "collected": info["collected"]}
+        for t in traces:
+            t.complete(GC_SPAN, t0, dur, args=args)
+
+
 # ------------------------------------------------- activation / lifecycle
+
+_LAST_TRACE: Optional[Trace] = None
+
+
+def last_trace() -> Optional[Trace]:
+    """The :class:`Trace` most recently activated by :func:`tracing`
+    in this process, still there after its block has closed (one
+    reference, replaced at the next activation; None before the
+    first): how a reader that was not handed the object gets it — the
+    benchmark's whole-window readers, or an operator after a traced
+    run raised."""
+    return _LAST_TRACE
+
 
 
 def _without_last(sinks: Tuple, x) -> Tuple:
@@ -476,18 +545,28 @@ def tracing(path: Optional[str] = None, annotate_device: bool = False,
     """Activate a :class:`Trace` for the block (a fresh one, or the
     one passed in); on exit deactivate and — when ``path`` is given —
     export the Chrome trace JSON there (export runs even when the
-    block raises: a crashed run's trace is the one you want most)."""
-    global _TRACES
+    block raises: a crashed run's trace is the one you want most).
+    The trace stays reachable as :func:`last_trace`. While any trace
+    is active the interpreter's collections are recorded as
+    ``rx.pause.gc`` spans (one ``gc.callbacks`` entry, installed by
+    the first activation and removed when the last closes)."""
+    global _TRACES, _LAST_TRACE, _gc_open
     t = trace if trace is not None else Trace(
         annotate_device=annotate_device)
     with _LOCK:
+        if not _TRACES:
+            _gc_open = None
+            gc.callbacks.append(_on_gc)
         _TRACES = _TRACES + (t,)
+        _LAST_TRACE = t
     _install_compile_listener()
     try:
         yield t
     finally:
         with _LOCK:
             _TRACES = _without_last(_TRACES, t)
+            if not _TRACES:
+                gc.callbacks.remove(_on_gc)
         if path:
             t.export(path)
 
@@ -551,6 +630,14 @@ def gauge_sample(label: str, value: float) -> None:
         r.gauge(GAUGE_METRIC, site=label).set(value, t)
     for tr in _TRACES:
         tr.counter(label, value)
+
+
+def track(name: str, value: float) -> None:
+    """One counter-track sample in every active trace and nowhere
+    else: a level that is worth a time beside the spans (the device's
+    bytes in use at a launch) and not a series in every registry."""
+    for tr in _TRACES:
+        tr.counter(name, value)
 
 
 def observe(name: str, value: float,
