@@ -10,6 +10,9 @@ and of the plain numpy reference, wherever `drain_pending`,
 `flush_stream`, `reset_stream` or `checkpoint` / `restore_stream` fall
 between two steps in flight. WHEN is pinned where it is a rule: a
 launch hands back the frames of `_pending`'s chunk-step, the oldest.
+And WHAT `_pending` keeps (ISSUE 42): a step's stacked host array comes
+from the receiver's own store and is written again only once nothing
+but that store holds it (the last section).
 """
 
 import numpy as np
@@ -575,3 +578,262 @@ def test_a_scan_lost_behind_a_decode_is_rescanned_from_its_own_arrays(
     assert reg.snapshot()["resilience.async_rescans"] == 2
     assert not rx.stats.degraded
     _check_all_frames(out, streams, starts)
+
+
+# ------------------------- `_pending`'s contract (WHAT): the staging arrays
+#
+# ISSUE 42: a chunk-step's stacked host array comes from the receiver's
+# store (`framebatch._Staging`) and is written again only once nothing
+# but the store holds it. These cases run on the programs the cases
+# above compiled, and the oracle they ran.
+
+ARRAY_BYTES = S * CHUNK * 2 * 4
+
+
+def _assert_same_frames(got, want):
+    """Per stream: the same starts in the same order, equal results."""
+    for i in range(S):
+        assert [f.start for f in got[i]] == [f.start for f in want[i]]
+        for a, b in zip(got[i], want[i]):
+            assert _same_result(a.result, b.result)
+
+
+def _stacks(trace):
+    return sorted((e["args"] for e in trace.events()
+                   if e["ph"] == "X" and e["name"] == "rx.fleet.stack"),
+                  key=lambda a: a["step"])
+
+
+def test_the_store_hands_out_only_what_nobody_holds():
+    store = framebatch._Staging(2, 8)
+    a, stale, fresh = store.take()
+    assert fresh and a.shape == (2, 8, 2) and not a.any() \
+        and not stale.any()
+    ident = id(a)
+    del a, stale
+    b, _stale, fresh = store.take()         # let go: handed out again
+    assert not fresh and id(b) == ident
+    c, _stale, fresh = store.take()         # held: passed over
+    assert fresh and c is not b
+    lane = b[1]                              # a view holds its base
+    del b, c
+    d, _stale, fresh = store.take()
+    assert not fresh and d.base is None and d is not lane.base
+    del d
+    # one array more for every reference somebody keeps, and no more
+    e, _stale, fresh = store.take()
+    assert not fresh and e is not lane.base
+    assert store.nbytes == 2 * lane.base.nbytes
+    del lane, e
+    assert id(store.take()[0]) == ident and len(store._arrays) == 2
+
+
+@pytest.fixture(scope="module")
+def sampled_loop(corpus):
+    """The benchmark's closed loop through `ServeRuntime`, a stride a
+    session a tick, with `benchmark/harness/cell.py`'s `SampledStep`
+    beside it: `_pending[:6]` kept by reference after every `step()`
+    (``last``, replaced every call; ``kept``, the newest whose frames
+    came back), and the first one held to the end. Every reference is
+    paired with the copy taken when it was."""
+    streams, _starts, _alt, _as = corpus
+    srv = serve.ServeRuntime(serve.ServeConfig(
+        n_lanes=S, chunk_len=CHUNK, frame_len=FRAME_LEN,
+        max_frames_per_chunk=K, check_fcs=True, shard=False))
+    # the corpus twice over: one launch and ten further ones
+    streams = [np.concatenate([st, st])[:CHUNK + 10 * STRIDE]
+               for st in streams]
+    first = last = kept = None
+    with telemetry.tracing() as tr, telemetry.collect(srv.registry):
+        for i in range(S):
+            assert srv.connect(f"s{i}").admitted
+        for pos in range(0, len(streams[0]) - STRIDE + 1, STRIDE):
+            for i, st in enumerate(streams):
+                assert srv.submit(f"s{i}",
+                                  st[pos: pos + STRIDE]).accepted
+            out = srv.step()
+            if out and last is not None:
+                kept = last
+            pend = srv._rx._pending
+            last = None if pend is None else (
+                tuple(pend[:6]), pend[2].copy(), srv._rx._pending_step)
+            if first is None:
+                first = last
+        srv._rx.drain_pending()
+    return srv, tr, first, kept
+
+
+def test_an_array_somebody_holds_stays_that_steps_samples(sampled_loop):
+    srv, _tr, first, kept = sampled_loop
+    assert srv._rx.stats.chunk_steps >= 10
+    # the one replaced every call, as `SampledStep.kept` is read after
+    # the window: the samples of the step it was taken from
+    assert kept is not None and kept[1].any()
+    assert np.array_equal(kept[0][2], kept[1])
+    # the one held throughout: eight further launches and more, and a
+    # `drain_pending`
+    assert first[2] == 0 and srv._rx.stats.chunk_steps >= 1 + 8
+    assert kept[2] > first[2]
+    assert np.array_equal(first[0][2], first[1])
+    assert first[0][2] is not kept[0][2]
+
+
+def test_the_store_settles_and_the_counter_and_gauge_say_so(
+        sampled_loop):
+    srv, tr, _first, _kept = sampled_loop
+    rx = srv._rx
+    fresh = [a["fresh"] for a in _stacks(tr)]
+    assert len(fresh) == rx.stats.chunk_steps
+    # two steps in flight as a third is stacked, `kept`, and the first
+    # held to the end: no array is made once each of them has one
+    made = sum(fresh)
+    assert made <= rx.stats.max_in_flight + 2
+    assert fresh == [1] * made + [0] * (len(fresh) - made)
+    assert len(rx._staging._arrays) == made
+    reg = srv.registry
+    assert reg.find("rx.stage_arrays", how="fresh").value == made
+    assert reg.find("rx.stage_arrays", how="reused").value \
+        == len(fresh) - made
+    gauge = reg.find(telemetry.GAUGE_METRIC, site="rx.stage_bytes")
+    assert gauge.last == made * ARRAY_BYTES == rx._staging.nbytes
+
+
+#: lanes that get a stride in each round (every lane holds a chunk
+#: less a stride first): lanes idle, then carried, then idle again,
+#: over more launches than the store has arrays
+ROUNDS = [(0, 1, 2, 3), tuple(range(S)), (4, 5, 6, 7), (0, 2, 4, 6),
+          (1, 3, 5, 7), (0, 7), (1, 2, 3, 4, 5, 6)]
+
+
+def _ragged(rx, streams, checked):
+    """Drive ``rx`` through ROUNDS, the rest of every stream and a
+    flush of ragged tails. Every array put is compared with the array a
+    new `np.zeros` and the tails would have made, and the verdict
+    appended (no reference to a staging array is kept)."""
+    step, launch = rx._step, rx._launch
+    want = []
+
+    def _step(active, flushing):
+        new = np.zeros((S, CHUNK, 2), np.float32)
+        for i in active:
+            t = rx._tails[i]
+            if flushing:
+                new[i, :t.shape[0]] = t
+            else:
+                new[i] = t[:CHUNK]
+        want.append(new)
+        return step(active, flushing)
+
+    def _launch(arrs, *rest):
+        checked.append(np.array_equal(arrs, want.pop()))
+        return launch(arrs, *rest)
+
+    rx._step, rx._launch = _step, _launch
+    at = [CHUNK - STRIDE] * S
+    out = rx.push_many([st[:CHUNK - STRIDE] for st in streams])
+    for lanes in ROUNDS:
+        out += rx.push_many(
+            {i: streams[i][at[i]: at[i] + STRIDE] for i in lanes})
+        for i in lanes:
+            at[i] += STRIDE
+    out += rx.push_many([st[at[i]:] for i, st in enumerate(streams)])
+    return out + rx.flush()
+
+
+@pytest.mark.parametrize("placement", ["one device", "mesh of 8"])
+def test_a_reused_array_reads_as_a_new_one_would(corpus, placement):
+    streams, _starts, alt, _as = corpus
+    rx = framebatch.MultiStreamReceiver(S, mesh=_mesh(placement), **GEO)
+    checked = []
+    with telemetry.tracing() as tr:
+        got = _per_stream(_ragged(rx, streams, checked), S)
+    assert len(checked) == rx.stats.chunk_steps > len(ROUNDS)
+    assert all(checked)
+    # nobody holds an array: the steps in flight have theirs, and
+    # every later one is used again, idle lanes and short tails and all
+    fresh = [a["fresh"] for a in _stacks(tr)]
+    made = sum(fresh)
+    assert made <= rx.stats.max_in_flight + 1 < len(fresh)
+    assert fresh == [1] * made + [0] * (len(fresh) - made)
+    whole, _second = _oracle(streams, alt)
+    _assert_same_frames(got, whole)
+
+
+def test_a_scan_lost_a_launch_ago_is_rescanned_from_intact_samples(
+        corpus, monkeypatch):
+    streams, _starts, alt, _as = corpus
+    monkeypatch.setattr(framebatch, "_ready", lambda arrays: False)
+    rx = framebatch.MultiStreamReceiver(S, **GEO)
+    rescanned = []
+    rescan = rx._rescan
+
+    def _rescan(st):
+        rescanned.append((st.step, np.array_equal(st.arrs, sent)))
+        return rescan(st)
+
+    rx._rescan = _rescan
+    with telemetry.collect() as reg, telemetry.tracing() as tr:
+        out = rx.push_many([st[:CHUNK] for st in streams])
+        # one reference held for the whole run
+        held = rx._pending[2]
+        held_copy = held.copy()
+        # enough launches that every array of the store has been used
+        out += rx.push_many([st[CHUNK: CHUNK + 4 * STRIDE]
+                             for st in streams])
+        lost = rx._flight[-1]
+        assert lost.step == 4 and not lost.fronted
+        sent = lost.arrs.copy()
+        lost.outs = tuple(_Unpullable() for _ in range(11))
+        del lost
+        # the loss is found a launch later, after that launch stacked
+        # and put its own samples
+        out += rx.push_many([st[CHUNK + 4 * STRIDE:] for st in streams])
+        out += rx.flush()
+    assert rescanned == [(4, True)]
+    assert reg.snapshot()["resilience.async_rescans"] == 1
+    assert not rx.stats.degraded
+    # two in flight as a third is stacked, and the one held: one more
+    # than a run in which nobody holds one
+    fresh = [a["fresh"] for a in _stacks(tr)]
+    assert fresh == [1] * 4 + [0] * (len(fresh) - 4) and len(fresh) > 5
+    assert np.array_equal(held, held_copy)
+    got = _per_stream(out, S)
+    whole, _second = _oracle(streams, alt)
+    _assert_same_frames(got, whole)
+
+
+def test_a_step_that_leaves_by_an_exception_gives_its_array_back(
+        corpus, monkeypatch):
+    streams, _starts, _alt, _as = corpus
+    monkeypatch.setattr(framebatch, "_ready", lambda arrays: False)
+    rx = framebatch.MultiStreamReceiver(S, **GEO)
+    real = rx._scan_to_decode
+
+    def _scan_to_decode(st):
+        if st.step == 3:
+            raise RuntimeError("the front half of step 3")
+        return real(st)
+
+    rx._scan_to_decode = _scan_to_decode
+    with telemetry.tracing() as tr:
+        rx.push_many([st[:CHUNK + 3 * STRIDE] for st in streams])
+        assert [st.step for st in rx._flight] == [2, 3]
+        try:
+            rx.push_many([st[CHUNK + 3 * STRIDE: CHUNK + 4 * STRIDE]
+                          for st in streams])
+        except RuntimeError as exc:
+            assert "step 3" in str(exc)
+        else:
+            raise AssertionError("the front half did not raise")
+        # `_front` took step 3 out of flight; nobody released anything
+        assert [st.step for st in rx._flight] == [2, 4]
+        rx.push_many([st[CHUNK + 4 * STRIDE:] for st in streams])
+        rx.flush()
+    # step 3 left before its scan was read: while that scan still runs
+    # the runtime holds the array it reads (on this backend the device
+    # array may be the host's memory), so the launch behind the raise
+    # may find it held and make one; by the launch after it is back
+    fresh = [a["fresh"] for a in _stacks(tr)]
+    assert fresh[:5] == [1, 1, 1, 0, 0] and len(fresh) > 7
+    assert not any(fresh[6:])
+    assert len(rx._staging._arrays) == sum(fresh) <= 4

@@ -37,6 +37,7 @@ variants, not one per group size.
 from __future__ import annotations
 
 import collections
+import sys
 import threading
 import time
 from typing import Any, List, NamedTuple, Optional, Sequence
@@ -416,7 +417,9 @@ def receive_many_device(x_dev, n_lanes: int, check_fcs: bool = False,
 # a launch uploads and dispatches scan t, dispatches decode t-1 behind
 # it, and blocks only on decode t-2, so the device holds a scan and a
 # decode while the host stacks the next step (in-flight depth on the
-# `utils/dispatch.record_gauge("rx.stream_inflight")` gauge). The
+# `utils/dispatch.record_gauge("rx.stream_inflight")` gauge). A step's
+# stacked host array comes from the receiver's own store and is written
+# again only once nothing else holds it (`_Staging`). The
 # stream axis shards over the dp mesh (`parallel/batch.frame_mesh` /
 # `lane_sharding`, `jax.shard_map` — multihost-ready through
 # `parallel/multihost.build_mesh`, dp being the axis with no
@@ -770,9 +773,10 @@ class _InFlight:
     (`_drain`) reads the decode and emits. Each half keeps here what
     the next one, or its containment, needs: the host arrays of the
     step (a lost scan is rescanned from them, the oracle twin slices
-    its windows out of them), the scan's outputs (`segs` among them,
-    the decode's input and its re-dispatch's), and the decode's
-    tables and outputs. ``step`` tags every span of either half, and
+    its windows out of them; ``arrs`` is the store's, `_Staging`, and
+    holding it here is what keeps it this step's), the scan's outputs
+    (`segs` among them, the decode's input and its re-dispatch's), and
+    the decode's tables and outputs. ``step`` tags every span of either half, and
     ``how`` says which way the half now running was reached (behind a
     ``"launch"``, by a call that launched nothing and found it
     ``"ready"``, at a ``"drain"`` point): the two pull spans carry it,
@@ -794,6 +798,58 @@ class _InFlight:
         self.emit = self.lanes = self.slots = None
         self.dec = self.dec_args = self.dec_out = None
         self.how = "launch"
+
+
+def _unheld_refs() -> int:
+    """What `sys.getrefcount` reads of an object that one list holds
+    and nothing else, asked the way `_Staging.take` asks: measured, so
+    that no interpreter's calling convention is assumed."""
+    probe = [object()]
+    return sys.getrefcount(probe[0])
+
+
+class _Staging:
+    """The host arrays a receiver stacks its chunk-steps in: made once,
+    used again. ONE rule says when: an array is written again only when
+    nothing but this store holds it, read from the interpreter's own
+    count of references as a step is stacked. A step in flight holds
+    its array (`_InFlight.arrs`: a lost scan is re-put from it, the
+    oracle twin slices its windows out of it), a put whose copy has not
+    finished holds it (the runtime keeps the array, or the shard views
+    whose base it is, until it is done with the memory), and so does a
+    caller that kept what `_pending` showed it; each lets go by
+    dropping its reference, a step that leaves flight by an exception
+    too, and there is no release call to forget. An array somebody
+    still holds is passed over and stays theirs for as long as they
+    keep it; where every array is held a new one is made, so the store
+    grows to the most that were ever held at once, and one.
+
+    ``stale[i]`` beside an array says lane i still holds samples of
+    the array's last use: `_step` zeroes such a lane where the new step
+    leaves it idle, so idle lanes ride zeros as they do in a new
+    array, and nothing is zeroed where every lane is carried."""
+
+    _UNHELD = _unheld_refs()
+
+    def __init__(self, n_lanes: int, chunk_len: int):
+        self._shape = (n_lanes, chunk_len, 2)
+        self._arrays: List[np.ndarray] = []
+        self._stale: List[np.ndarray] = []
+
+    @property
+    def nbytes(self) -> int:
+        """Host memory the store keeps."""
+        return sum(a.nbytes for a in self._arrays)
+
+    def take(self):
+        """``(array, stale, fresh)`` for the step being stacked: the
+        first array nobody holds, or a new one of zeros (``fresh``)."""
+        for k in range(len(self._arrays)):
+            if sys.getrefcount(self._arrays[k]) == self._UNHELD:
+                return self._arrays[k], self._stale[k], False
+        self._arrays.append(np.zeros(self._shape, np.float32))
+        self._stale.append(np.zeros(self._shape[0], bool))
+        return self._arrays[-1], self._stale[-1], True
 
 
 def _gate_finite(arr: np.ndarray, name: str, sanitize: bool,
@@ -998,6 +1054,7 @@ class MultiStreamReceiver:
         # which the newest alone still waits for its front half once a
         # launch has returned (`_InFlight`, `_settle`)
         self._flight: List[_InFlight] = []
+        self._staging = _Staging(self.s, self.chunk_len)
         self._chunk_steps = 0
         # since when a lane has held a full chunk that no launch has
         # taken (`perf_counter`; kept only while a trace is active:
@@ -1209,7 +1266,9 @@ class MultiStreamReceiver:
         """The OLDEST chunk-step in flight as ``(offs, active, arrs,
         valid, own_lo, own_hi, outs)``, None when there is none: the
         one whose frames the next launch hands back (its first six
-        are what the benchmark's float comparison keeps of it)."""
+        are what the benchmark's float comparison keeps of it: for as
+        long as a caller keeps ``arrs`` it stays this step's samples,
+        `_Staging`)."""
         if not self._flight:
             return None
         st = self._flight[0]
@@ -1327,20 +1386,25 @@ class MultiStreamReceiver:
 
     def _step(self, active, flushing: bool) -> List:
         """Build one stacked chunk-step over the `active` streams
-        (idle lanes ride zeros behind `valid == 0`), launch it, and
-        advance the active streams' host carries."""
+        (idle lanes ride zeros behind `valid == 0`) in a staging array
+        nobody else holds (`_Staging`), launch it, and advance the
+        active streams' host carries."""
         from ziria_tpu.utils import dispatch, telemetry
 
+        arrs, stale, fresh = self._staging.take()
+        telemetry.count("rx.stage_arrays", labels={
+            "how": "fresh" if fresh else "reused"})
+        dispatch.record_gauge("rx.stage_bytes", self._staging.nbytes)
         args = {"step": self._chunk_steps, "active": len(active),
                 "samples": sum(self._tails[i].shape[0] if flushing
-                               else self.chunk_len for i in active)}
+                               else self.chunk_len for i in active),
+                "fresh": int(fresh)}
         traced = telemetry.traced()
         full_since, self._full_since = self._full_since, None
         if traced and full_since is not None:
             # how long the first lane to fill waited for this launch
             args["ready_ms"] = 1e3 * (time.perf_counter() - full_since)
         with telemetry.span("rx.fleet.stack", args):
-            arrs = np.zeros((self.s, self.chunk_len, 2), np.float32)
             valid = np.zeros(self.s, np.int32)
             own_lo = np.zeros(self.s, np.int32)
             own_hi = np.zeros(self.s, np.int32)
@@ -1350,6 +1414,8 @@ class MultiStreamReceiver:
                 if flushing:
                     v = t.shape[0]
                     arrs[i, :v] = t
+                    if stale[i]:
+                        arrs[i, v:] = 0
                     valid[i] = own_hi[i] = v
                     adv[i] = v
                 else:
@@ -1372,6 +1438,12 @@ class MultiStreamReceiver:
                 # clamps); on any later chunk a negative start is the
                 # previous chunk's frame
                 own_lo[i] = -192 if self._offsets[i] == 0 else 0
+            # an idle lane rides zeros: one that still holds the
+            # samples of the array's last use is zeroed, once
+            stale[active] = False
+            arrs[stale] = 0
+            stale[:] = False
+            stale[active] = True
         offs = list(self._offsets)          # snapshot BEFORE advancing
         res = self._launch(arrs, valid, own_lo, own_hi, active, offs)
         for i in active:
