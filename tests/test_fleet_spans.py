@@ -209,6 +209,31 @@ def test_classify_counts_the_lanes_owned_and_those_acquired(runs):
                for e in cls)
 
 
+def test_emit_and_decode_say_what_the_window_held(runs):
+    """ISSUE 43: `rx.fleet.emit` carries the step's `acquired` again
+    beside `truncated` (the frames its windows could not hold: none on
+    this load; tests/test_maxpsdu_deployment.py has some), and
+    `rx.fleet.decode` each frame's samples on air against the whole
+    window every slot was cut at."""
+    srv, spans, _traced, _plain, _built = runs
+    acquired = {e["args"]["step"]: e["args"]["acquired"]
+                for e in _named(spans, "rx.fleet.classify")}
+    emits = _named(spans, "rx.fleet.emit")
+    assert all(set(e["args"]) == {"step", "frames", "acquired",
+                                  "truncated"} for e in emits)
+    assert all(e["args"]["truncated"] == 0
+               and e["args"]["acquired"] == acquired[e["args"]["step"]]
+               == e["args"]["frames"] for e in emits)
+    assert srv._rx.stats.truncated_frames == 0
+    assert srv.registry.find("rx.stream_frames_truncated") is None
+    decodes = _named(spans, "rx.fleet.decode")
+    assert all(e["args"]["window_samples"] == S * K * FRAME_LEN
+               for e in decodes)
+    assert sum(e["args"]["frame_samples"] for e in decodes) \
+        == sum(rx.FRAME_DATA_START + 80 * _n_sym(m)
+               for rates in RATE_SETS for m in rates)
+
+
 def test_window_wider_than_the_acquisition_head_loses_no_lane():
     """The same count where it says something: a window twice
     `rx._acquire_head` (the suite geometry's window IS the head), all

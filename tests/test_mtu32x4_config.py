@@ -45,10 +45,12 @@ def test_the_manifest_is_sound_with_the_four_chip_cell():
     assert cell.traffic["loop"] == "closed"
     assert {m["name"] for m in cell.end_to_end} \
         == {"samples_per_s", "setup_s"}
-    assert len(MAN["workloads"]) == 5
+    assert len(MAN["workloads"]) >= 5
     assert [w["name"] for w in MAN["workloads"] if w["chips"] == 4] \
         == [CELL]
-    assert MAN["workloads"][-1]["name"] == CELL     # appended, not put in
+    # appended behind the four of its day, not put in; later cells
+    # (PR 43's `maxpsdu8.saturated`) follow it
+    assert MAN["workloads"][4]["name"] == CELL
 
 
 def test_the_cell_reads_what_mtu8_saturated_reads_but_one_and_four_more():

@@ -42,6 +42,9 @@ LANES = vp.LANES
 #: the served MTU geometry (chip_smoke.py): the power-of-two capture
 #: bucket holding a 1500-byte PSDU at 6 Mbit/s, its chunk, S = K = 8
 MTU = dict(s=8, k=8, chunk_len=131072, frame_len=65536)
+#: `wifi-a-maxpsdu-8s` (PR 43): the window a 4095-byte PSDU at
+#: 6 Mbit/s needs (109 680 samples), its chunk, K = 16: one full tile
+MAXPSDU = dict(s=8, k=16, chunk_len=262144, frame_len=131072)
 DFLT = dict(s=DEFAULT.n_streams, k=DEFAULT.max_frames_per_chunk,
             chunk_len=DEFAULT.chunk_len, frame_len=DEFAULT.frame_len)
 
@@ -144,7 +147,8 @@ def _chunk_shapes(geo, sharding):
     return chunks, vec, vec, vec
 
 
-@pytest.mark.parametrize("geo", [DFLT, MTU], ids=["default", "mtu"])
+@pytest.mark.parametrize("geo", [DFLT, MTU, MAXPSDU],
+                         ids=["default", "mtu", "maxpsdu"])
 def test_decode_program_compiles_with_both_kernels(one_chip, on_chip,
                                                    geo):
     """Dispatch 2 of the fleet chunk-step at the served geometry: the
@@ -195,6 +199,16 @@ def test_chunk_scan_program_compiles_at_mtu_geometry(one_chip):
     conv over 131072 samples x 8 streams — and 18 s once it asked for
     HIGHEST (PR 22), which is what lets it stay in tier-1."""
     exe = _compile(_chunk_scan(MTU), *_chunk_shapes(MTU, one_chip))
+    assert exe.memory_analysis().temp_size_in_bytes < (8 << 30)
+
+
+def test_chunk_scan_program_compiles_at_maxpsdu_geometry(one_chip):
+    """Dispatch 1 at the one served geometry whose window is not
+    65 536 (PR 43): 512 folded blocks a lane, 128 windows of 131 072
+    gathered at 2048 symbols. 12 s here; 305 MB of temporaries."""
+    assert _sym_bucket(MAXPSDU["frame_len"]) == 2048
+    exe = _compile(_chunk_scan(MAXPSDU),
+                   *_chunk_shapes(MAXPSDU, one_chip))
     assert exe.memory_analysis().temp_size_in_bytes < (8 << 30)
 
 

@@ -591,6 +591,14 @@ def _count_emitted(out, total: int) -> None:
                         labels={"psdu": c})
 
 
+def _truncated(results) -> int:
+    """How many of a chunk-step's results are frames the window could
+    not hold: SIGNAL parsed, and the DATA field it announces runs past
+    the samples the window (or, at a flush, the stream) has left
+    (`rx.ACQ_TRUNCATED`; the one failure that still names its rate)."""
+    return sum(1 for r in results if not r.ok and r.rate_mbps)
+
+
 #: geometry keys that postdate shipped checkpoint blobs, mapped to
 #: the behavior the pre-key code had (see _validate_checkpoint)
 _LEGACY_GEOMETRY_DEFAULTS = {"sco_track": False, "fused_demap": False}
@@ -785,7 +793,7 @@ class _InFlight:
     __slots__ = ("step", "offs", "active", "arrs", "valid", "own_lo",
                  "own_hi", "outs", "fronted", "allcands", "starts",
                  "oracle", "emit", "lanes", "slots", "dec", "dec_args",
-                 "dec_out", "how")
+                 "dec_out", "how", "acquired")
 
     def __init__(self, step, offs, active, arrs, valid, own_lo, own_hi,
                  outs):
@@ -798,6 +806,7 @@ class _InFlight:
         self.emit = self.lanes = self.slots = None
         self.dec = self.dec_args = self.dec_out = None
         self.how = "launch"
+        self.acquired = 0
 
 
 def _unheld_refs() -> int:
@@ -889,6 +898,7 @@ class MultiStreamStats(NamedTuple):
     quarantined_streams: int = 0   # streams quarantined RIGHT NOW
     lane_blowups: int = 0      # per-window oracle blowups caught
     degraded: bool = False     # a compiled fleet program degraded
+    truncated_frames: int = 0  # owned frames longer than the window
 
 
 class MultiStreamReceiver:
@@ -907,7 +917,12 @@ class MultiStreamReceiver:
     frame is decoded exactly once. Up to `max_frames_per_chunk` frames
     are extracted per chunk per stream; more raises the chunk's
     overflow flag (counted in :attr:`stats` — reported, never silently
-    dropped; widen K or shorten the chunk). Each stream steps through
+    dropped; widen K or shorten the chunk). An owned frame LONGER than
+    `frame_len` (or cut by the stream's end at a flush) is reported
+    the same way: emitted where it started as a failed result that
+    names its rate and length, and counted (`stats.truncated_frames`,
+    the counter `rx.stream_frames_truncated`, `truncated` on
+    `rx.fleet.emit`; widen the window). Each stream steps through
     its own chunk boundaries whatever its lane-mates do, and per-lane
     graphs under vmap are the one-stream graphs, so a lane's frames
     are bit-identical to that stream received alone — by
@@ -997,7 +1012,8 @@ class MultiStreamReceiver:
         self.k = int(max_frames_per_chunk)
         # the largest DATA field a frame_len window can hold, bucketed:
         # the fleet's ONE fixed decode geometry (longer frames are
-        # ACQ_TRUNCATED in both paths — the window cannot hold them)
+        # ACQ_TRUNCATED in both paths — the window cannot hold them —
+        # and counted: `_count_truncated`)
         self.n_sym_bucket = geo.sym_bucket(
             max(1, (self.frame_len - _rx.FRAME_DATA_START) // 80))
         self.check_fcs = check_fcs
@@ -1061,6 +1077,7 @@ class MultiStreamReceiver:
         # `rx.fleet.stack`'s `ready_ms`)
         self._full_since: Optional[float] = None
         self._overflow_chunks = 0
+        self._truncated = 0    # owned frames the window could not hold
         self._max_in_flight = 0
         self._max_active = 0
         self._retired = 0      # frames credited to recycled lanes
@@ -1101,7 +1118,7 @@ class MultiStreamReceiver:
             sum(h.quarantines for h in self._health),
             sum(1 for h in self._health if h.quarantined),
             self._lane_blowups,
-            self._degraded or self._scan_degraded)
+            self._degraded or self._scan_degraded, self._truncated)
 
     def quarantined(self, stream: int) -> bool:
         """True while `stream` rides behind the valid-mask (poisoned
@@ -1649,9 +1666,10 @@ class MultiStreamReceiver:
         # there: equal on a clean stream (the acquisition reads only
         # each window's head, `rx._acquire_head`, and loses nothing)
         owned = own[active]
+        st.acquired = int((owned & found[active]).sum())
         with telemetry.span("rx.fleet.classify", {
                 "step": step, "candidates": int(owned.sum()),
-                "acquired": int((owned & found[active]).sum())}):
+                "acquired": st.acquired}):
             allcands = []    # (stream, abs_start, row j) in emit order
             for i in active:
                 off = st.offs[i]
@@ -1672,7 +1690,9 @@ class MultiStreamReceiver:
         # what the decode is asked for against what it computes:
         # each of its S x K lanes is gathered at the whole symbol
         # bucket and runs the bound trellis (the LENGTH field's
-        # longest frame, `params.mixed_trellis_steps`)
+        # longest frame, `params.mixed_trellis_steps`), and each was
+        # cut from the chunk at the whole window whatever its frame's
+        # own length on air
         useful = sum(lane[4] for lane in st.lanes)
         n_slots = self.s * self.k
         padded = n_slots * self.n_sym_bucket
@@ -1687,7 +1707,10 @@ class MultiStreamReceiver:
                 "padded_symbols": padded,
                 "useful_bits": int(tables[2].sum()),
                 "trellis_steps": n_slots
-                * mixed_trellis_steps(self.n_sym_bucket)}):
+                * mixed_trellis_steps(self.n_sym_bucket),
+                "frame_samples": len(st.lanes) * _rx.FRAME_DATA_START
+                + 80 * useful,
+                "window_samples": n_slots * self.frame_len}):
             st.dec = _rx._jit_stream_decode_multi(
                 self.n_sym_bucket, self.viterbi_window,
                 self.viterbi_metric, self.viterbi_radix,
@@ -1720,8 +1743,12 @@ class MultiStreamReceiver:
             if got is None:
                 return self._decode_oracle(st)
         emit = st.emit
+        # final since `_classify`: the frames this step's windows
+        # could not hold, beside those it acquired
+        n_trunc = _truncated(emit.values())
         with telemetry.span("rx.fleet.emit", {
-                "step": st.step, "frames": len(emit) + len(st.lanes)}):
+                "step": st.step, "frames": len(emit) + len(st.lanes),
+                "acquired": st.acquired, "truncated": n_trunc}):
             if got is not None:
                 clear, crc = got
                 for i, sl in st.slots.items():
@@ -1738,7 +1765,20 @@ class MultiStreamReceiver:
                 out.append((i, StreamFrame(abs_start, emit[key])))
                 self._emitted[i] += 1
         _count_emitted(out, sum(self._emitted))
+        self._count_truncated(n_trunc)
         return out
+
+    def _count_truncated(self, n: int) -> None:
+        """`n` more owned frames the window could not hold: the stat,
+        and the registry's view beside `_count_emitted`'s (the running
+        count for the trace's counter track; `telemetry.count` is free
+        when nothing collects)."""
+        from ziria_tpu.utils import telemetry
+
+        self._truncated += n
+        if n:
+            telemetry.count("rx.stream_frames_truncated", n,
+                            total=self._truncated)
 
     def _classify(self, allcands, found, fstart, rb, ln, pk, nv):
         """The host integer decision tree over a chunk-step's owned
@@ -1826,6 +1866,7 @@ class MultiStreamReceiver:
                 out.append((i, StreamFrame(abs_start, res)))
                 self._emitted[i] += 1
         _count_emitted(out, sum(self._emitted))
+        self._count_truncated(_truncated(fr.result for _i, fr in out))
         return out
 
     def _eager_chunk(self, chunks, valid, own_lo, own_hi):

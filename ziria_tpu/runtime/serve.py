@@ -97,15 +97,16 @@ class ServeConfig(NamedTuple):
     inherited from :data:`ziria_tpu.utils.geometry.DEFAULT` —
     admission churn never changes them, so the two fleet programs
     compile once); the rest are host-side protocol bounds. Build
-    from a tuned geometry with :meth:`from_geometry`."""
+    from a tuned geometry with :meth:`from_geometry`. The two ingress
+    bounds left None follow the geometry (:meth:`ingress_bounds`)."""
     n_lanes: int = _GEO.n_streams    # S: concurrent sessions on device
     chunk_len: int = _GEO.chunk_len
     frame_len: int = _GEO.frame_len
     max_frames_per_chunk: int = _GEO.max_frames_per_chunk
     check_fcs: bool = False
     queue_cap: int = 16              # admission queue bound
-    max_slab_samples: int = 1 << 16  # oversized-slab reject bound
-    max_backlog_samples: int = 1 << 18   # per-session staged bound
+    max_slab_samples: Optional[int] = None   # oversized-slab bound
+    max_backlog_samples: Optional[int] = None    # per-session staged
     default_slo_s: Optional[float] = None  # deadline = connect + slo
     retry_after_s: float = 0.05      # base backpressure hint
     sanitize: bool = True            # NaN slabs quarantine, not crash
@@ -137,6 +138,20 @@ class ServeConfig(NamedTuple):
                       max_frames_per_chunk=geo.max_frames_per_chunk)
         fields.update(overrides)
         return cls(**fields)
+
+    def ingress_bounds(self) -> Tuple[int, int]:
+        """``(max_slab_samples, max_backlog_samples)`` as `submit`
+        holds them: the caller's where given; left None, a slab of one
+        stride (``chunk_len - frame_len``, what a session advances by
+        a chunk-step) is always admitted and a session stages two
+        chunks, and never less than the 65 536 / 262 144 samples the
+        two were fixed at while every served window was 65 536."""
+        slab, backlog = self.max_slab_samples, self.max_backlog_samples
+        if slab is None:
+            slab = max(1 << 16, self.chunk_len - self.frame_len)
+        if backlog is None:
+            backlog = max(1 << 18, 2 * self.chunk_len)
+        return slab, backlog
 
 
 class AdmitResult(NamedTuple):
@@ -275,6 +290,7 @@ class ServeRuntime:
         self.cfg = config if config is not None else ServeConfig()
         if self.cfg.n_lanes < 1:
             raise ValueError(f"n_lanes {self.cfg.n_lanes} must be >= 1")
+        self._max_slab, self._max_backlog = self.cfg.ingress_bounds()
         self.clock = clock
         self.registry = registry if registry is not None \
             else telemetry.MetricsRegistry()
@@ -580,11 +596,11 @@ class ServeRuntime:
             self._get_session(sid)     # raises the named KeyError
         arr = _slab(samples, sid)
         n = int(arr.shape[0])
-        if n > self.cfg.max_slab_samples:
+        if n > self._max_slab:
             self._count("serve.rejected_slabs",
                         labels={"reason": "oversized"})
             return SubmitResult(sid, False, 0.0, "oversized")
-        if s.staged_samples + n > self.cfg.max_backlog_samples:
+        if s.staged_samples + n > self._max_backlog:
             self._count("serve.rejected_slabs",
                         labels={"reason": "backlog_full"})
             return SubmitResult(sid, False, self._retry_after(sid),
