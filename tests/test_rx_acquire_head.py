@@ -11,6 +11,14 @@ UNCHANGED `acquire_frame_graph` vmapped over each WHOLE window. Two
 geometries (a toy one whose window is twice the head, and the served
 MTU one), K=16 so a dozen frames fit one chunk; each geometry compiles
 its two scans once and every case re-dispatches them.
+
+Since ISSUE 44 the scan under test cuts no window at all: the head and
+the data region are sliced from the padded chunk. The oracle still
+writes the S x K x `win_len` window array out, and a second set of
+cases at the toy window runs the relation the served geometries have
+(a symbol bucket whose segment is LONGER than the window: 82 320 >
+65 536, 164 240 > 131 072), where a gather that ran on past the
+window's bound would read the chunk's next frame.
 """
 
 import inspect
@@ -32,7 +40,8 @@ OUTPUTS = ("own", "starts", "overflow", "found", "fstart", "eps",
            "rate_bits", "length", "parity_ok", "n_valid", "segs")
 
 
-def _whole_window_scan(chunk, chunk_valid, own_lo, own_hi, win_len):
+def _whole_window_scan(chunk, chunk_valid, own_lo, own_hi, win_len,
+                       bucket=BUCKET):
     """The oracle: `stream_chunk_graph`'s five steps written out, with
     the acquisition over every whole window (the parent's form)."""
     found, starts, overflow = sync.locate_frames(
@@ -47,22 +56,22 @@ def _whole_window_scan(chunk, chunk_valid, own_lo, own_hi, win_len):
     lim = rx._stream_bucket_graph(nv, win_len)
     f2, fstart, eps, rb, ln, pk = jax.vmap(rx.acquire_frame_graph)(
         wins, nv, lim)
-    need_b = rx.FRAME_DATA_START + 80 * BUCKET
+    need_b = rx.FRAME_DATA_START + 80 * bucket
     wins_pad = jnp.pad(wins, ((0, 0), (0, need_b), (0, 0)))
     segs = jax.vmap(lambda xi, s, e, a: rx.gather_segment_graph(
-        xi, s, e, a, BUCKET))(wins_pad, fstart, eps, nv - fstart)
+        xi, s, e, a, bucket))(wins_pad, fstart, eps, nv - fstart)
     return own, starts, overflow, f2, fstart, eps, rb, ln, pk, nv, segs
 
 
 @lru_cache(maxsize=None)
-def _scans(win_len: int):
+def _scans(win_len: int, bucket: int = BUCKET):
     """(the scan under test, the whole-window oracle), jitted once a
     geometry (chunk length retraces by shape)."""
     return (
         jax.jit(lambda c, v, lo, hi: rx.stream_chunk_graph(
-            c, v, lo, hi, K, win_len, BUCKET)),
+            c, v, lo, hi, K, win_len, bucket)),
         jax.jit(lambda c, v, lo, hi: _whole_window_scan(
-            c, v, lo, hi, win_len)))
+            c, v, lo, hi, win_len, bucket)))
 
 
 def _psdus(rng, n, n_bytes=12):
@@ -219,6 +228,114 @@ def test_head_scan_bit_identical_to_whole_window_oracle(case, geo):
         assert [int(b) for b in want[6][own]] == \
             [RATES[m].signal_bits for m in [54, 48, 36, 24] * 3]
     _assert_owned_lanes_identical(got, want)
+
+
+#: a symbol bucket whose segment outruns the toy window, as the served
+#: ones do: 400 + 80 x 32 = 2960 > 2048
+LONG_BUCKET = 32
+
+
+def _chunk_case(name: str):
+    """(stream, valid or None for all of it, own_lo) of a case of the
+    chunk-sliced scan; each is a stream's final chunk."""
+    rng = np.random.default_rng(44)
+    if name == "eight_rates":
+        stream, _n = _eight_rates(30.0, 1e-4, 441)
+        return stream, None, -192
+    if name in ("last_start_within_window", "last_start_within_head"):
+        # the stream ends 1500 (under win_len) or 700 (under the head,
+        # over the 400 `found` asks) samples into its last frame
+        keep = 1500 if name == "last_start_within_window" else 700
+        stream, starts = link.stream_many(
+            _psdus(rng, 3, 100), [24, 54, 6], gaps=[400, 400],
+            snr_db=30.0, cfo=1e-4, delay=60, seed=442, add_fcs=True,
+            tail=2048)
+        return stream[: int(starts[2]) + keep], None, 0
+    if name == "frame_longer_than_window":
+        # 100 bytes + FCS at 6 Mbit/s: 36 symbols, 3280 samples on air,
+        # and a second frame close behind it, inside what a gather of
+        # 2960 samples reaches and outside the 2048-sample window
+        stream, starts = link.stream_many(
+            _psdus(rng, 2, 100), [6, 54], gaps=[10], snr_db=30.0,
+            cfo=1e-4, delay=60, seed=443, add_fcs=True, tail=4096)
+        assert starts[1] - starts[0] > GEOS["toy"][1]
+        return stream, None, 0
+    if name == "first_chunk_head_truncated":
+        full, _starts = link.stream_many(
+            _psdus(rng, 2, 100), [12, 36], gaps=[400], snr_db=30.0,
+            cfo=1e-4, delay=0, seed=444, add_fcs=True, tail=2048)
+        return full[40:], None, -192
+    if name == "idle_lane":
+        # the packer zeroes an idle lane, but the graph may not lean
+        # on that: samples of an earlier step under `valid == 0`
+        stream, _n = _eight_rates(30.0, 1e-4, 445)
+        return stream, 0, 0
+    assert name == "more_than_k_plateaus"
+    rates = [54, 48, 36, 24] * 5
+    stream, starts = link.stream_many(
+        _psdus(rng, 20, 14), rates, gaps=[10] * 19, snr_db=30.0,
+        cfo=1e-4, delay=60, seed=446, add_fcs=True, tail=2048)
+    return stream, None, -192
+
+
+CHUNK_CASES = ("eight_rates", "last_start_within_window",
+               "last_start_within_head", "frame_longer_than_window",
+               "first_chunk_head_truncated", "idle_lane",
+               "more_than_k_plateaus")
+
+
+@pytest.mark.parametrize("case", CHUNK_CASES)
+def test_chunk_sliced_scan_equals_the_window_array_oracle(case):
+    """ISSUE 44: steps 4 and 5 slice the padded chunk where the oracle
+    slices the window array it cut, at a bucket whose segment is longer
+    than the window; `segs` bit for bit."""
+    chunk_len, win_len = GEOS["toy"]
+    head = rx._acquire_head(win_len)
+    need_b = rx.FRAME_DATA_START + 80 * LONG_BUCKET
+    assert head < win_len < need_b
+    stream, valid, own_lo = _chunk_case(case)
+    chunk, n, lo, hi = _scan_args(stream, own_lo, chunk_len)
+    if valid is not None:
+        n = hi = jnp.int32(valid)
+    chunk_scan, window_scan = _scans(win_len, LONG_BUCKET)
+    got = [np.asarray(o) for o in chunk_scan(chunk, n, lo, hi)]
+    want = [np.asarray(o) for o in window_scan(chunk, n, lo, hi)]
+    own, found, fstart, nv, segs = (want[i] for i in (0, 3, 4, 9, 10))
+    assert segs.shape == (K, need_b, 2)
+    _assert_owned_lanes_identical(got, want)      # `segs` bit for bit
+    if case == "idle_lane":
+        # nothing is owned, so hold every lane to the oracle: whatever
+        # the detector's cap leaves in `starts`, no sample comes out
+        assert not own.any() and not want[2] and not nv.any()
+        for name, g, w in zip(OUTPUTS, got, want):
+            np.testing.assert_array_equal(_bits(g), _bits(w),
+                                          err_msg=name)
+        assert not segs.any()
+        return
+    assert found[own].all()
+    if case == "more_than_k_plateaus":
+        assert own.sum() == K and want[2] > 0     # overflow: K is full
+        return
+    assert not want[2]
+    last = np.flatnonzero(own)[-1]
+    if case == "eight_rates":
+        assert own.sum() == 8
+    if case == "last_start_within_window":
+        assert own.sum() == 3 and head < nv[last] == 1500 < win_len
+    if case == "last_start_within_head":
+        assert own.sum() == 3 and 400 <= nv[last] == 700 < head
+    if case == "first_chunk_head_truncated":
+        assert want[1][own][0] == 0              # clamped, and owned
+    if case == "frame_longer_than_window":
+        # the segment ends where the WINDOW does, though the chunk
+        # goes on with the frame's own tail and the next frame
+        first = np.flatnonzero(own)[0]
+        cut = int(nv[first] - fstart[first])
+        assert nv[first] == win_len and cut < need_b
+        a = int(want[1][first]) + win_len
+        assert np.abs(np.asarray(chunk)[a: a + need_b - cut]).max() > 0.1
+        assert got[10][first, cut - 1].any()
+        assert not got[10][first, cut:].any()
 
 
 def test_head_is_derived_from_syncs_alignment_constants(monkeypatch):

@@ -221,6 +221,46 @@ def test_sharded_fleet_on_suite_mesh_bit_identical(corpus):
         assert [f.start for f in res_s[i]] == list(starts[i])
 
 
+@pytest.mark.parametrize("lanes", [8, 32])
+def test_sharded_scan_outputs_equal_unsharded(corpus, lanes):
+    """The chunk scan itself over a dp mesh of four of the suite's
+    devices (`chip_smoke.py --four-chips`' two placements: 8 lanes
+    forced over four, and 32 at 8 a device) against the same program
+    on one device: all eleven outputs, every lane owned or not, bit
+    for bit — `segs` too, which since ISSUE 44 is sliced from a
+    chunk each shard pads for itself, an idle lane (`valid == 0`)
+    and a noise lane among them."""
+    import jax.numpy as jnp
+
+    from ziria_tpu.parallel.batch import frame_mesh
+
+    streams = corpus[0]
+    chunks = np.zeros((lanes, CHUNK, 2), np.float32)
+    valid = np.zeros((lanes,), np.int32)
+    for i in range(lanes):
+        st = streams[i % S]
+        # the fleet's later copies start further into their stream,
+        # so no two lanes of the 32 carry the same chunk
+        st = st[(i // S) * 37:][:CHUNK]
+        chunks[i, :len(st)] = st
+        valid[i] = len(st)
+    assert (valid == 0).sum() == lanes // S and (valid == CHUNK).any()
+    own_lo = np.where(np.arange(lanes) < S, -192, 0).astype(np.int32)
+    args = tuple(jnp.asarray(a) for a in (chunks, valid, own_lo, valid))
+    # the receivers' own compiled scans (`_jit1`: at 8 lanes the
+    # fixture's program, no new compile)
+    one = framebatch.MultiStreamReceiver(lanes, **GEO)._jit1(*args)
+    four = framebatch.MultiStreamReceiver(
+        lanes, mesh=frame_mesh(4), **GEO)._jit1(*args)
+    assert len(one) == len(four) == 11
+    assert np.asarray(one[0]).sum() >= 10 * (lanes // S)     # owned
+    assert len(four[10].sharding.device_set) == 4
+    for i, (a, b) in enumerate(zip(one, four)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.shape == b.shape and a.dtype == b.dtype, i
+        assert a.tobytes() == b.tobytes(), i
+
+
 def test_all_noise_fleet_costs_one_dispatch_per_step(corpus):
     # the noise fast path survives the fleet: a chunk-step with zero
     # decodable lanes across ALL streams skips the decode dispatch

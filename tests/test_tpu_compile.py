@@ -45,6 +45,8 @@ MTU = dict(s=8, k=8, chunk_len=131072, frame_len=65536)
 #: `wifi-a-maxpsdu-8s` (PR 43): the window a 4095-byte PSDU at
 #: 6 Mbit/s needs (109 680 samples), its chunk, K = 16: one full tile
 MAXPSDU = dict(s=8, k=16, chunk_len=262144, frame_len=131072)
+#: `wifi-a-mix-8s` (PR 33): the MTU window at K = 32, 256 slots
+MIX = dict(MTU, k=32)
 DFLT = dict(s=DEFAULT.n_streams, k=DEFAULT.max_frames_per_chunk,
             chunk_len=DEFAULT.chunk_len, frame_len=DEFAULT.frame_len)
 
@@ -197,19 +199,28 @@ def test_chunk_scan_program_compiles_at_mtu_geometry(one_chip):
     on the chip machine) while `ops/sync`'s sliding-window conv ran at
     the TPU's DEFAULT precision — the compiler spent minutes on that
     conv over 131072 samples x 8 streams — and 18 s once it asked for
-    HIGHEST (PR 22), which is what lets it stay in tier-1."""
+    HIGHEST (PR 22), which is what lets it stay in tier-1.
+
+    Its temporaries in HBM: 2 161 152 bytes (the parent of ISSUE 44:
+    2 226 176; at K = 8 the compiler kept that parent's 33.5 MB window
+    array in its fast memory, layout `S(1)`, so the count hardly saw
+    it go; at K = 32, compiled by hand: 305 357 824 -> 86 507 008)."""
     exe = _compile(_chunk_scan(MTU), *_chunk_shapes(MTU, one_chip))
-    assert exe.memory_analysis().temp_size_in_bytes < (8 << 30)
+    assert exe.memory_analysis().temp_size_in_bytes < (8 << 20)
 
 
 def test_chunk_scan_program_compiles_at_maxpsdu_geometry(one_chip):
     """Dispatch 1 at the one served geometry whose window is not
-    65 536 (PR 43): 512 folded blocks a lane, 128 windows of 131 072
-    gathered at 2048 symbols. 12 s here; 305 MB of temporaries."""
+    65 536 (PR 43): 512 folded blocks a lane, 128 candidates' heads
+    and 2048-symbol segments sliced from a 262 144-sample chunk. 11 s
+    here; 86 244 864 bytes of temporaries, where the parent of ISSUE
+    44, which cut 128 windows of 131 072 and padded each by 164 240
+    to gather from, had 304 833 536: under half of that, or a window
+    array is back."""
     assert _sym_bucket(MAXPSDU["frame_len"]) == 2048
     exe = _compile(_chunk_scan(MAXPSDU),
                    *_chunk_shapes(MAXPSDU, one_chip))
-    assert exe.memory_analysis().temp_size_in_bytes < (8 << 30)
+    assert exe.memory_analysis().temp_size_in_bytes < 150_000_000
 
 
 def test_sharded_programs_compile_for_four_chips(topo, one_chip,
@@ -317,6 +328,43 @@ def test_chunk_scan_at_mtu_geometry_acquires_over_the_window_head(s):
     assert folded == [(s * blocks, sync.FOLD_BLOCK),
                       (s * blocks, sync.FOLD_BLOCK),
                       (2 * s * blocks, sync.FOLD_BLOCK)]
+
+
+@pytest.mark.parametrize("geo", [MTU, MIX, MAXPSDU],
+                         ids=["mtu", "mix", "maxpsdu"])
+def test_chunk_scan_cuts_no_window_array(geo):
+    """Steps 3 to 5 of `rx.stream_chunk_graph` used to cut a
+    `win_len`-sample window for every one of S x K candidates, read
+    1024 samples of each, pad every one by the segment's length and
+    gather from that: 134 MB written and 303 MB padded a scan at 256
+    windows of 65 536 or 128 of 131 072, 5.4 and 4.1 ms of the scan on
+    the chip (ledger, PR 43). Every sample both reads keep is a sample
+    of the chunk, so they slice the padded chunk (ISSUE 44): nothing
+    in the traced scan is a window long beside the slots any more, and
+    the only arrays as large as the window array was are the segment
+    batch it hands the decode (lowering only, no compiler)."""
+    import math
+
+    s, k, win = geo["s"], geo["k"], geo["frame_len"]
+    need_b = _rx.FRAME_DATA_START + 80 * _sym_bucket(win)
+    head = _rx._acquire_head(win)
+    text = _chunk_scan(geo).lower(*_chunk_shapes(geo, None)).as_text()
+    shapes = {tuple(int(d) for d in m.group(1).split("x")[:-1])
+              for m in re.finditer(r"tensor<((?:\d+x)+)\w+>", text)}
+    assert (s, geo["chunk_len"], 2) in shapes         # the reader reads
+    assert (s, k, need_b, 2) in shapes                # `segs`
+    assert (s, k, head, 2) in shapes                  # the heads
+    per_lane = k * win * 2
+    long_as_a_window = sorted(
+        sh for sh in shapes if win in sh and math.prod(sh) >= per_lane)
+    assert not long_as_a_window, long_as_a_window
+    as_large = sorted(sh for sh in shapes
+                      if math.prod(sh) >= s * per_lane)
+    assert as_large and all(need_b in sh for sh in as_large), as_large
+    # the one array steps 4 and 5 slice: the chunk and a tail neither
+    # read can run out of, a lane (not a slot)
+    assert (s, geo["chunk_len"] + head + need_b, 2) in shapes
+    assert need_b > win                   # the served relation, both
 
 
 def _while_locations(lowered):

@@ -986,7 +986,8 @@ def _padded_segment(acq: _Acquired, n_sym_bucket: int):
 # The per-chunk device half of `backend/framebatch.receive_stream`:
 # ONE jitted graph turns a long multi-frame chunk into K dense
 # candidate lanes — multi-peak detect (`ops/sync.locate_frames`),
-# per-candidate window extraction at the traced aligned starts, the
+# per-candidate windows at the traced aligned starts (a bound each,
+# sliced from the chunk where they are read and never cut out), the
 # vmapped per-window acquisition (`acquire_frame_graph`, the SAME
 # graph the batched per-capture path runs, so every window decodes
 # bit-identically to `receive` over that window), and the
@@ -1013,8 +1014,8 @@ def _stream_bucket_graph(n_valid, cap: int):
 
 
 def _acquire_head(win_len: int) -> int:
-    """How many samples of a window cut AT an aligned frame start its
-    acquisition reads (`stream_chunk_graph` step 4): the chunk scan
+    """How many samples of a window that begins AT an aligned frame start
+    its acquisition reads (`stream_chunk_graph` step 4): the chunk scan
     found the start already, so `acquire_frame_graph` re-derives it
     from the window's head and everything it computes further in is
     discarded by its own first-crossing `argmax` and local peak mask.
@@ -1059,18 +1060,29 @@ def stream_chunk_graph(chunk, chunk_valid, own_lo, own_hi, k: int,
        owned by the PREVIOUS chunk). Boundary-straddling frames
        re-detect fully inside the NEXT chunk's overlap and are owned
        exactly once.
-    3. per-candidate `win_len`-sample window extraction at the traced
-       starts, clamped to 0 exactly as `locate_frame` clamps
-       (`dynamic_slice` — the window IS the capture the per-capture
-       oracle would see for `stream[max(start,0) : +win_len]`).
+    3. the per-candidate window, as a bound and not as an array: the
+       traced starts clamped to 0 exactly as `locate_frame` clamps,
+       and each candidate's true count `n_valid` = the chunk's real
+       samples from its start on, at most `win_len` (the window IS
+       the capture the per-capture oracle would see for
+       `stream[max(start,0) : +win_len]`; `win_len` bounds what a
+       slot may hold, and the truncation verdict reads that count).
+       Nothing is cut: every sample steps 4 and 5 keep is a sample of
+       the chunk, so they slice the chunk, tail-padded once by what
+       the two reads reach past its end (a window array is S x K x
+       `win_len` samples written and read back a step: 134 MB at 256
+       x 65 536, a third of that scan's time on the chip; PR 44).
     4. the vmapped per-window acquisition (detect gate, LTS timing,
        CFO, SIGNAL decode) with per-lane true counts and own-bucket
        detector caps, over the window's first `_acquire_head` samples
-       — all it reads of a window that starts at its frame; a whole-
-       window scan there located every frame a second time, 36 ms of
-       a chunk-step at 64 windows x 65 536 (chip runs, PR 30) — and
-    5. gather+derotate of every window's data region at the ONE static
-       symbol bucket (garbage on failed lanes, masked host-side).
+       (`dynamic_slice` of the chunk at the start) — all it reads of
+       a window that starts at its frame; a whole-window scan there
+       located every frame a second time, 36 ms of a chunk-step at 64
+       windows x 65 536 (chip runs, PR 30) — and
+    5. gather+derotate of every candidate's data region at the ONE
+       static symbol bucket, sliced from the chunk at start + the
+       frame start step 4 found and zeroed from the window's true
+       count on (garbage on failed lanes, masked host-side).
 
     Returns ``(own, starts, overflow, found, fstart, eps, rate_bits,
     length, parity_ok, n_valid, segs)`` — everything before `segs` is
@@ -1094,29 +1106,32 @@ def stream_chunk_graph(chunk, chunk_valid, own_lo, own_hi, k: int,
     with jax.named_scope("rx.scan.window"):
         own = found & (starts >= own_lo) & (starts < own_hi)
         starts = jnp.where(own, jnp.maximum(starts, 0), starts)
-        # tail-pad before slicing: a final-chunk start may sit within
-        # win_len of the chunk end (the stream genuinely ends there,
-        # so the window's zero tail is exactly the oracle slice's
-        # bucket pad); clamping the slice instead would silently
-        # shift the lane
+        # a candidate's window is chunk[safe : safe + win_len], and it
+        # is never cut: steps 4 and 5 slice what they read of it from
+        # the chunk. Tail-pad once so that neither slice clamps (a
+        # final-chunk start may sit at the chunk's very end, fstart <
+        # head, and the stream genuinely ends there: the zero tail is
+        # exactly the oracle slice's bucket pad); clamping a slice
+        # instead would silently shift the lane
         safe = jnp.clip(starts, 0, chunk.shape[0])
-        chunk_pad = jnp.pad(chunk, ((0, win_len), (0, 0)))
-        wins = jax.vmap(lambda s: jax.lax.dynamic_slice(
-            chunk_pad, (s, jnp.int32(0)), (win_len, 2)))(safe)
+        head = _acquire_head(win_len)
+        need_b = FRAME_DATA_START + 80 * n_sym_bucket
+        chunk_pad = jnp.pad(chunk, ((0, head + need_b), (0, 0)))
         nv = jnp.clip(jnp.asarray(chunk_valid, jnp.int32) - safe,
                       0, win_len).astype(jnp.int32)
         lim = _stream_bucket_graph(nv, win_len)
     with jax.named_scope("rx.scan.acquire"):
         # nv stays the window's true count: `found`'s avail gate reads it
-        head = _acquire_head(win_len)
+        heads = jax.vmap(lambda s: jax.lax.dynamic_slice(
+            chunk_pad, (s, jnp.int32(0)), (head, 2)))(safe)
         f2, fstart, eps, rb, ln, pk = jax.vmap(acquire_frame_graph)(
-            wins[:, :head], nv, jnp.minimum(lim, head))
+            heads, nv, jnp.minimum(lim, head))
     with jax.named_scope("rx.scan.gather"):
-        need_b = FRAME_DATA_START + 80 * n_sym_bucket
-        wins_pad = jnp.pad(wins, ((0, 0), (0, need_b), (0, 0)))
-        segs = jax.vmap(lambda xi, s, e, a: gather_segment_graph(
-            xi, s, e, a, n_sym_bucket))(wins_pad, fstart, eps,
-                                        nv - fstart)
+        # avail <= win_len - fstart, so the segment's mask ends at the
+        # window's bound wherever the chunk goes on past it
+        segs = jax.vmap(lambda s, e, a: gather_segment_graph(
+            chunk_pad, s, e, a, n_sym_bucket))(safe + fstart, eps,
+                                               nv - fstart)
     return own, starts, overflow, f2, fstart, eps, rb, ln, pk, nv, segs
 
 
