@@ -39,7 +39,7 @@ DISPATCH_SPANS = {"rx.stream_chunk_multi", "rx.stream_decode_multi"}
 STEP_KEYED = {n for n in FLEET_SPANS if n.startswith("rx.fleet.")} \
     | DISPATCH_SPANS
 SCAN_SCOPES = ("rx.scan.locate", "rx.scan.window", "rx.scan.acquire",
-               "rx.scan.gather")
+               "rx.scan.gather", "rx.scan.gather.derotate")
 DECODE_SCOPES = ("rx.decode.select", "rx.decode.front",
                  "rx.decode.viterbi", "rx.decode.back")
 
@@ -194,7 +194,8 @@ def test_useful_and_padded_symbols_from_the_frames_sent(runs):
 def test_classify_counts_the_lanes_owned_and_those_acquired(runs):
     _srv, spans, _traced, _plain, _built = runs
     cls = _named(spans, "rx.fleet.classify")
-    assert all(set(e["args"]) == {"step", "candidates", "acquired"}
+    assert all(set(e["args"]) == {"step", "candidates", "acquired",
+                                  "cfo_abs_max_urad", "cfo_abs_sum_urad"}
                and all(isinstance(v, int) for v in e["args"].values())
                for e in cls)
     # a clean load: every lane the scan owned, its window acquired
@@ -207,6 +208,78 @@ def test_classify_counts_the_lanes_owned_and_those_acquired(runs):
              for e in _named(spans, "rx.fleet.decode")}
     assert all(e["args"]["acquired"] == lanes.get(e["args"]["step"], 0)
                for e in cls)
+
+
+def test_classify_says_how_far_off_carrier_the_frames_were(runs):
+    """ISSUE 45: the scan's estimate comes to the host inside the rate
+    word (`rx.pack_rate_word`: to 7.6 micro-radians a sample, in the
+    bytes the pull already had), and `rx.fleet.classify` carries the
+    step's widest and the sum over its acquired frames: every stream
+    here was sent 1e-4 rad/sample off, and the estimators' scatter at
+    30 dB over a 320-sample preamble is under 1e-4. The fleet sets
+    the level a LANE for `scrape()`: S series however many sessions
+    come and go, a sample only when a lane's level moved."""
+    srv, spans, _traced, _plain, _built = runs
+    cls = [e["args"] for e in _named(spans, "rx.fleet.classify")
+           if e["args"]["acquired"]]
+    assert cls
+    for a in cls:
+        assert 20 <= a["cfo_abs_max_urad"] <= 220
+        assert a["cfo_abs_max_urad"] <= a["cfo_abs_sum_urad"] \
+            <= a["acquired"] * a["cfo_abs_max_urad"]
+    mean = sum(a["cfo_abs_sum_urad"] for a in cls) \
+        / sum(a["acquired"] for a in cls)
+    assert 70 <= mean <= 130
+    idle = [e["args"] for e in _named(spans, "rx.fleet.classify")
+            if not e["args"]["acquired"]]
+    assert all(a["cfo_abs_max_urad"] == a["cfo_abs_sum_urad"] == 0
+               for a in idle)
+    lines = [ln for ln in srv.scrape().splitlines()
+             if 'site="rx.stream_cfo_abs_max_urad"' in ln]
+    assert len(lines) == S
+    for i in range(S):
+        (line,) = [ln for ln in lines if f'lane="{i}"' in ln]
+        assert 20 <= float(line.split()[-1]) <= 220
+        assert srv._rx._cfo_urad[i] == int(float(line.split()[-1]))
+
+
+def test_the_offset_gauge_is_a_series_a_lane_and_samples_only_moves():
+    """A registry never drops a series, so the gauge is labelled from
+    a set the configuration bounds (the lane), a level that did not
+    move takes no sample, and a lane whose stream is reset (a session
+    closed or evicted) reads 0 again and not the last radio's."""
+    rcv = object.__new__(framebatch.MultiStreamReceiver)
+    rcv._cfo_urad = [0, 0]
+    reg = telemetry.MetricsRegistry()
+    with telemetry.collect(reg), telemetry.tracing() as tr:
+        for urad in (36600, 36600, 36608, 36608):
+            rcv._note_cfo(1, urad)
+        rcv._note_cfo(0, 0)               # never moved: no series yet
+        rcv._note_cfo(1, 0)               # what `reset_stream` does
+    g = reg.find(telemetry.GAUGE_METRIC,
+                 site="rx.stream_cfo_abs_max_urad", lane="1")
+    assert [v for _t, v in g.samples] == [36600.0, 36608.0, 0.0]
+    assert [k for k, _m in reg.metrics()] == [
+        (telemetry.GAUGE_METRIC,
+         (("lane", "1"), ("site", "rx.stream_cfo_abs_max_urad")))]
+    assert [(e["name"], list(e["args"].values())) for e in tr.events()
+            if e.get("ph") == "C"] \
+        == [("rx.stream_cfo_abs_max_urad[lane=1]", [v])
+            for v in (36600, 36608, 0)]
+
+
+def test_the_rate_word_round_trips_the_offset_to_24_hz():
+    eps = np.array([0.0366, -0.0366, 1e-4, 0.0, 0.19634, -0.3, 0.3],
+                   np.float32)
+    rate = np.arange(7, dtype=np.uint32) + 3
+    word = np.asarray(rx.pack_rate_word(rate, eps))
+    assert word.dtype == np.uint32
+    got_rate, urad = rx.unpack_rate_word(word)
+    assert list(got_rate) == list(rate)
+    # clipped at the int16's ends, +-0.25 rad/sample, past pi / 16
+    want = np.clip(eps.astype(np.float64), -0.25, 0.25 - 2.0 ** -17)
+    assert np.abs(urad - want * 1e6).max() <= 0.5 * 1e6 / 2 ** 17 + 0.5
+    assert 36000 <= urad[0] <= 37200 and urad[1] == -urad[0]
 
 
 def test_emit_and_decode_say_what_the_window_held(runs):
@@ -269,10 +342,13 @@ def test_bytes_on_put_and_pulls_redo_the_shape_arithmetic(runs):
     # (S, chunk, 2) f32 slab + three (S,) int32 vectors
     assert {e["args"]["bytes"] for e in _named(spans, "rx.fleet.put")} \
         == {S * CHUNK * 2 * 4 + 3 * S * 4}
-    # three bool and five int32 (S, K) tables, and overflow (S,) bool
+    # three bool and five int32 (S, K) tables, and overflow (S,) bool:
+    # the benchmark's ceiling, to the byte, with the carrier offset
+    # inside the rate word (ISSUE 45)
+    from benchmark.harness import counts
     assert {e["args"]["bytes"]
             for e in _named(spans, "rx.fleet.pull_scan")} \
-        == {S * K * (3 * 1 + 5 * 4) + S}
+        == {S * K * (3 * 1 + 5 * 4) + S} == {counts.scan_d2h_bytes(S, K)}
     # (S, K, T) uint8 clear bits, T the bound trellis (216 bits a
     # symbol at this bucket: it is under 152 symbols) + (S, K) bool
     assert mixed_trellis_steps(bucket) == bucket * 216

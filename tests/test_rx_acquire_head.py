@@ -60,7 +60,8 @@ def _whole_window_scan(chunk, chunk_valid, own_lo, own_hi, win_len,
     wins_pad = jnp.pad(wins, ((0, 0), (0, need_b), (0, 0)))
     segs = jax.vmap(lambda xi, s, e, a: rx.gather_segment_graph(
         xi, s, e, a, bucket))(wins_pad, fstart, eps, nv - fstart)
-    return own, starts, overflow, f2, fstart, eps, rb, ln, pk, nv, segs
+    return (own, starts, overflow, f2, fstart, eps,
+            rx.pack_rate_word(rb, eps), ln, pk, nv, segs)
 
 
 @lru_cache(maxsize=None)
@@ -202,6 +203,12 @@ def _assert_owned_lanes_identical(got, want):
         else:
             np.testing.assert_array_equal(_bits(g[own]), _bits(w[own]),
                                           err_msg=name)
+    # the rate word (PR 45): the RATE bits, and above them the lane's
+    # own `eps` (output 5) to half a step of 2**-17 rad/sample
+    rate, urad = rx.unpack_rate_word(got[6])
+    assert (rate[own] < 16).all()
+    assert np.abs(urad[own] - got[5][own].astype(np.float64) * 1e6
+                  ).max(initial=0) <= 0.5 * 1e6 / rx.CFO_WORD_SCALE + 0.5
 
 
 @pytest.mark.parametrize("geo", sorted(GEOS))
@@ -225,7 +232,7 @@ def test_head_scan_bit_identical_to_whole_window_oracle(case, geo):
         assert want[1][own][0] == 1140 and want[4][own][0] == 191
     if case == "dozen_back_to_back":
         # each window reads ITS frame's RATE, in the order sent
-        assert [int(b) for b in want[6][own]] == \
+        assert [int(b) for b in rx.unpack_rate_word(want[6])[0][own]] == \
             [RATES[m].signal_bits for m in [54, 48, 36, 24] * 3]
     _assert_owned_lanes_identical(got, want)
 
@@ -380,3 +387,29 @@ def test_a_head_cut_too_short_is_seen(monkeypatch):
     got = [np.asarray(o) for o in short(*args)]
     with pytest.raises(AssertionError):
         _assert_owned_lanes_identical(got, want)
+
+
+@pytest.mark.parametrize("k", [1, 3, 8])
+def test_the_gather_in_groups_is_the_vmap_bit_for_bit(k):
+    """`rx._gather_in_groups` takes a lane's K candidates
+    `rx.GATHER_GROUP` at a time and writes each group's segments in
+    place (PR 45: the mask's and the derotation's temporaries are then
+    a group's, not the batch's): the values are the vmap's over all K,
+    a K the group does not divide and K = 1 included."""
+    rng = np.random.default_rng(45 + k)
+    bucket = 4
+    need_b = rx.FRAME_DATA_START + 80 * bucket
+    x = jnp.asarray(rng.standard_normal((2048 + need_b, 2)),
+                    jnp.float32)
+    args = (jnp.asarray(rng.integers(0, 2048, k), jnp.int32),
+            jnp.asarray(rng.uniform(-0.05, 0.05, k), jnp.float32),
+            jnp.asarray(rng.integers(0, need_b + 50, k), jnp.int32))
+
+    def one(s, e, a):
+        return rx.gather_segment_graph(x, s, e, a, bucket)
+
+    got = jax.jit(lambda *a: rx._gather_in_groups(one, a, k, need_b))(
+        *args)
+    want = jax.jit(jax.vmap(one))(*args)
+    assert got.shape == (k, need_b, 2)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

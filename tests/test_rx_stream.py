@@ -426,6 +426,7 @@ def test_chunk_scan_reads_every_frame_of_a_many_frame_window(corpus):
     assert list(got[own]) == list(starts)
     assert found[own].all() and pk[own].all()
     assert list(fstart[own]) == [0] * len(starts)
-    assert [int(b) for b in rb[own]] == \
+    # the rate word: RATE bits, the lane's CFO estimate above them
+    assert [int(b) for b in rx.unpack_rate_word(rb)[0][own]] == \
         [RATES[m].signal_bits for m in sorted(RATES)]
     assert set(ln[own]) == {N_BYTES + 4}

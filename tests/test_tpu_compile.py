@@ -47,6 +47,8 @@ MTU = dict(s=8, k=8, chunk_len=131072, frame_len=65536)
 MAXPSDU = dict(s=8, k=16, chunk_len=262144, frame_len=131072)
 #: `wifi-a-mix-8s` (PR 33): the MTU window at K = 32, 256 slots
 MIX = dict(MTU, k=32)
+#: `wifi-a-dense54-8s` (PR 45): the MTU window at K = 16, 128 slots
+DENSE54 = dict(MTU, k=16)
 DFLT = dict(s=DEFAULT.n_streams, k=DEFAULT.max_frames_per_chunk,
             chunk_len=DEFAULT.chunk_len, frame_len=DEFAULT.frame_len)
 
@@ -216,11 +218,31 @@ def test_chunk_scan_program_compiles_at_maxpsdu_geometry(one_chip):
     here; 86 244 864 bytes of temporaries, where the parent of ISSUE
     44, which cut 128 windows of 131 072 and padded each by 164 240
     to gather from, had 304 833 536: under half of that, or a window
-    array is back."""
+    array is back. 4 705 280 since PR 45: the gather goes through a
+    lane's K candidates two at a time (`rx._gather_in_groups`), so a
+    step's temporaries are a group's (21 MB each here) and the
+    compiler keeps them in its fast memory; that PR's derotation under
+    a vmap over all K ran on a planar copy of all the masked segments
+    and read 170 522 112 here."""
     assert _sym_bucket(MAXPSDU["frame_len"]) == 2048
     exe = _compile(_chunk_scan(MAXPSDU),
                    *_chunk_shapes(MAXPSDU, one_chip))
     assert exe.memory_analysis().temp_size_in_bytes < 150_000_000
+
+
+def test_chunk_scan_program_compiles_at_dense54_geometry(one_chip):
+    """Dispatch 1 at `wifi-a-dense54-8s`'s geometry (PR 45): the MTU
+    window at K = 16, 128 candidates' heads and 1024-symbol segments,
+    the phase of each segment's derotation formed exactly a sample.
+    9 s here; 3 165 184 bytes of temporaries (the plain product under
+    the vmap over K had 86 539 264), and every float contraction at
+    HIGHEST."""
+    assert _sym_bucket(DENSE54["frame_len"]) == 1024
+    fn = _chunk_scan(DENSE54)
+    exe = _compile(fn, *_chunk_shapes(DENSE54, one_chip))
+    assert exe.memory_analysis().temp_size_in_bytes < (8 << 20)
+    assert not _loose_contractions(
+        fn.lower(*_chunk_shapes(DENSE54, None)).as_text())
 
 
 def test_sharded_programs_compile_for_four_chips(topo, one_chip,
@@ -330,8 +352,8 @@ def test_chunk_scan_at_mtu_geometry_acquires_over_the_window_head(s):
                       (2 * s * blocks, sync.FOLD_BLOCK)]
 
 
-@pytest.mark.parametrize("geo", [MTU, MIX, MAXPSDU],
-                         ids=["mtu", "mix", "maxpsdu"])
+@pytest.mark.parametrize("geo", [MTU, MIX, MAXPSDU, DENSE54],
+                         ids=["mtu", "mix", "maxpsdu", "dense54"])
 def test_chunk_scan_cuts_no_window_array(geo):
     """Steps 3 to 5 of `rx.stream_chunk_graph` used to cut a
     `win_len`-sample window for every one of S x K candidates, read

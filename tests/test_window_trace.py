@@ -257,7 +257,8 @@ def test_the_ten_entries_resolve_and_only_follow_what_was_there():
     assert len(set(names)) == len(names)
     by = {m["name"]: m for m in man["per_layer"]}
     sat = ["mtu8.saturated", "beacon8.saturated", "mix8.saturated",
-           "mtu32x4.saturated", "maxpsdu8.saturated"]
+           "mtu32x4.saturated", "maxpsdu8.saturated",
+           "dense54.saturated"]
     for n in NEW:
         with open(os.path.join(manifest.HERE, "layer_metrics",
                                n + ".json")) as f:

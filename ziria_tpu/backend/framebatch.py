@@ -656,8 +656,9 @@ def _stream_geometry(r) -> dict:
 
 def _chunk_scalars(outs):
     """The nine per-lane arrays the host pulls of a chunk scan's
-    eleven outputs: all but the CFO estimate and the segments, which
-    stay on the device for the decode."""
+    eleven outputs: all but the float CFO estimate and the segments,
+    which stay on the device for the decode (the estimate comes to
+    the host to 24 Hz inside the rate word: `rx.pack_rate_word`)."""
     return outs[:5] + outs[6:10]
 
 
@@ -1066,6 +1067,7 @@ class MultiStreamReceiver:
         self._emitted = [0] * self.s
         self._watermarks = [0] * self.s
         self._seen = [set() for _ in range(self.s)]
+        self._cfo_urad = [0] * self.s
         # the chunk-steps in flight, oldest first: at most three, of
         # which the newest alone still waits for its front half once a
         # launch has returned (`_InFlight`, `_settle`)
@@ -1119,6 +1121,21 @@ class MultiStreamReceiver:
             sum(1 for h in self._health if h.quarantined),
             self._lane_blowups,
             self._degraded or self._scan_degraded, self._truncated)
+
+    def _note_cfo(self, lane: int, urad: int) -> None:
+        """The gauge ``rx.stream_cfo_abs_max_urad{lane}``: the widest
+        carrier offset among the frames of the lane's newest chunk-step
+        that acquired any (micro-radians a sample, to the 7.6 of the
+        scan's rate word; 0 once the lane's stream is reset), to be
+        read against the estimators' ranges, pi / 64 = 49 087 fine and
+        pi / 16 = 196 350 coarse (docs/observability.md). A series a
+        lane, so at most S of them however sessions come and go, and
+        a sample only when the level moved."""
+        from ziria_tpu.utils import telemetry
+        if urad != self._cfo_urad[lane]:
+            self._cfo_urad[lane] = urad
+            telemetry.gauge_sample("rx.stream_cfo_abs_max_urad", urad,
+                                   {"lane": str(lane)})
 
     def quarantined(self, stream: int) -> bool:
         """True while `stream` rides behind the valid-mask (poisoned
@@ -1346,6 +1363,7 @@ class MultiStreamReceiver:
         self._emitted[stream] = 0
         self._watermarks[stream] = 0
         self._seen[stream] = set()
+        self._note_cfo(stream, 0)
         return out
 
     def restore_stream(self, stream: int, checkpoint: bytes) -> List:
@@ -1661,15 +1679,23 @@ class MultiStreamReceiver:
             (own, starts, overflow, found, fstart, rb, ln, pk, nv,
              segs) = pull(st.outs)
         self._overflow_chunks += int(overflow[active].sum())
+        rb, cfo = _rx.unpack_rate_word(rb)
 
         # what the scan owned against what its window acquisition found
         # there: equal on a clean stream (the acquisition reads only
-        # each window's head, `rx._acquire_head`, and loses nothing)
-        owned = own[active]
-        st.acquired = int((owned & found[active]).sum())
+        # each window's head, `rx._acquire_head`, and loses nothing),
+        # and how far off carrier the frames found were
+        got = own & found
+        st.acquired = int(got[active].sum())
+        cfo = np.where(got, np.abs(cfo), 0)
+        for i in active:
+            if got[i].any():
+                self._note_cfo(i, int(cfo[i].max()))
         with telemetry.span("rx.fleet.classify", {
-                "step": step, "candidates": int(owned.sum()),
-                "acquired": st.acquired}):
+                "step": step, "candidates": int(own[active].sum()),
+                "acquired": st.acquired,
+                "cfo_abs_max_urad": int(cfo[active].max(initial=0)),
+                "cfo_abs_sum_urad": int(cfo[active].sum())}):
             allcands = []    # (stream, abs_start, row j) in emit order
             for i in active:
                 off = st.offs[i]

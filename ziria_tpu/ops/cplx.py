@@ -84,6 +84,66 @@ def cangle(p):
     return jnp.arctan2(p[..., 1], p[..., 0])
 
 
+# `cexp_ramp`'s block, and 2*pi in three parts (Cody-Waite): the first
+# has 8 significant bits and the second 11, so a whole number of turns
+# below 2**13 times either is an exact float32 product.
+_RAMP_SHIFT = 9
+RAMP_BLOCK = 1 << _RAMP_SHIFT
+_TURN_1 = 6.28125
+_TURN_2 = 0.0019350051879882812
+_TURN_3 = 3.019916050561733e-07
+
+
+def cexp_ramp(eps, n: int):
+    """Unit phasors e^{j*eps*m} for m = 0..n-1 as an (n, 2) pair array
+    (`eps` a float32 scalar, traced or not): THE carrier rotation, for
+    the receiver's derotation (`ops/sync.correct_cfo`) and the
+    channel's offset (`phy/channel.apply_cfo`) alike.
+
+    The float32 product ``eps * m`` carries half an ulp of its own
+    size, so a ramp formed from it is wrong by 1.2e-4 rad once the
+    phase passes 2048 rad (sample 55 960 at 20 ppm of a 5.8 GHz
+    carrier, 0.0366 rad/sample) and loses a bit more with every
+    doubling. Here the error does not grow with ``m``: with ``m = 512
+    j + i``, the phase of block ``j``, ``j * (512 * eps)`` modulo a
+    turn, is formed without rounding until it is small (``512 * eps``
+    is split into a 12-bit head and its exact remainder, so both
+    products with ``j``, below 2**12, are exact, and the head's whole
+    turns come off in three parts of 2*pi), and ``i * eps``, below
+    ``512 * eps``, is added to it. What is left is the rounding of
+    that last product and sum, an ulp of ``512 * eps``: within 1.5e-6
+    of a float64 ramp by the same float32 `eps` for ``|eps| <= pi /
+    64`` (the fine estimator's range) at every served length
+    (tests/test_derotate_precision.py), within 1.5e-5 for ``|eps| <=
+    pi / 16`` (the coarse estimator's) at any ``n`` up to 2**21. One
+    block and less (``n <= 512``: the acquisition's heads) is the
+    plain product, bit for bit.
+
+    A dozen integer and float operations a sample beside the sine and
+    the cosine, in the same elementwise pass. Not a two-level table
+    (``coarse[j] * fine[i]``, two transcendentals a BLOCK): its blocked
+    product has to be laid out again as the segment is, which costs
+    the scan more than the transcendentals do (PERF.md, PR 45)."""
+    if n > RAMP_BLOCK << 12:
+        raise ValueError(
+            f"cexp_ramp: {n} samples exceed {RAMP_BLOCK << 12}, the "
+            f"longest ramp whose block phases are formed exactly")
+    eps = jnp.asarray(eps, jnp.float32)
+    m = jnp.arange(n, dtype=jnp.int32)
+    j = (m >> _RAMP_SHIFT).astype(jnp.float32)
+    i = (m & (RAMP_BLOCK - 1)).astype(jnp.float32)
+    step = eps * RAMP_BLOCK                     # exact: a power of two
+    head = jax.lax.bitcast_convert_type(
+        jax.lax.bitcast_convert_type(step, jnp.uint32)
+        & jnp.uint32(0xFFFFF000), jnp.float32)
+    tail = step - head                          # exact, 12 bits
+    phase = j * head                            # exact, 24 bits
+    turns = jnp.round(phase * float(0.5 / np.pi))
+    phase = ((phase - turns * _TURN_1) - turns * _TURN_2) \
+        - turns * _TURN_3
+    return cexp(phase + (j * tail + i * eps))
+
+
 # ----------------------------------------------------------------- dft
 
 @lru_cache(maxsize=None)

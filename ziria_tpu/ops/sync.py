@@ -182,11 +182,11 @@ def estimate_cfo_lts(samples):
 
 
 def correct_cfo(samples, eps):
-    """Multiply samples by e^{-j*eps*n}."""
+    """Multiply samples by e^{-j*eps*n}: THE derotation, for a
+    400-sample head and a 164 240-sample segment alike, its error flat
+    in ``n`` (`cplx.cexp_ramp`)."""
     x = jnp.asarray(samples, jnp.float32)
-    n = jnp.arange(x.shape[0], dtype=jnp.float32)
-    rot = cplx.cexp(-eps * n)
-    return cplx.cmul(x, rot)
+    return cplx.cmul(x, cplx.cexp_ramp(-eps, x.shape[0]))
 
 
 def lts_pair_metric(samples, limit=None):

@@ -32,8 +32,7 @@ def awgn(key, samples, snr_db: float) -> jnp.ndarray:
 def apply_cfo(samples, eps: float) -> jnp.ndarray:
     """Rotate samples by e^{+j*eps*n} (eps radians/sample)."""
     x = jnp.asarray(samples, jnp.float32)
-    n = jnp.arange(x.shape[0], dtype=jnp.float32)
-    return cplx.cmul(x, cplx.cexp(eps * n))
+    return cplx.cmul(x, cplx.cexp_ramp(eps, x.shape[0]))
 
 
 def apply_phase(samples, theta: float) -> jnp.ndarray:

@@ -920,7 +920,8 @@ def gather_segment_graph(x, start, eps, avail, n_sym_bucket: int):
     seg = jax.lax.dynamic_slice(x, (start, jnp.int32(0)), (need_b, 2))
     n = jnp.minimum(avail, need_b)
     seg = jnp.where((jnp.arange(need_b) < n)[:, None], seg, 0.0)
-    return sync.correct_cfo(seg, eps)
+    with jax.named_scope("rx.scan.gather.derotate"):
+        return sync.correct_cfo(seg, eps)
 
 
 @lru_cache(maxsize=None)
@@ -1041,6 +1042,64 @@ def _acquire_head(win_len: int) -> int:
     return min(pow2_ceil(max(timing, heads)), win_len)
 
 
+#: A candidate's carrier offset rides to the host in the 16 bits above
+#: its SIGNAL RATE field (four bits of a uint32), so that an operator
+#: can see a session's radio drift toward the estimators' ranges without
+#: a byte more in the scan's pull: an int16 in units of 2**-17
+#: rad/sample (24 Hz at 20 MS/s), range +-0.25, past the coarse
+#: estimator's pi / 16.
+CFO_WORD_SCALE = float(1 << 17)
+
+
+def pack_rate_word(rate_bits, eps):
+    """The scan's rate word: `rate_bits` (uint32, below 16) with `eps`
+    quantized into its upper half (traced; `unpack_rate_word` is the
+    host's reading of it)."""
+    q = jnp.clip(jnp.round(eps * CFO_WORD_SCALE), -32768.0, 32767.0)
+    return rate_bits | (jax.lax.bitcast_convert_type(
+        q.astype(jnp.int32), jnp.uint32) << 16)
+
+
+def unpack_rate_word(word):
+    """Host side of `pack_rate_word`: ``(rate_bits, cfo_urad)`` as
+    int32 arrays, the offset in micro-radians a sample (rounded from
+    the word's 7.6 a step)."""
+    word = np.asarray(word, np.uint32)
+    q = (word >> 16).astype(np.uint16).view(np.int16)
+    return (word & 15).astype(np.int32), np.rint(
+        q * (1e6 / CFO_WORD_SCALE)).astype(np.int32)
+
+
+#: Candidates a lane that `_gather_in_groups` takes at a time. Two: the
+#: loop that slices a group's segments then writes 2 x S rows, which
+#: the chip's compiler lays out as the chunk is (`{1,2,0:T(2,128)}`,
+#: 0.15 ms for 64 slots); one candidate a lane is S rows, a lane each,
+#: which it lays out as planar rows written a sublane at a time (1.27
+#: ms for the same 64: PERF.md, PR 45).
+GATHER_GROUP = 2
+
+
+def _gather_in_groups(one, args, k: int, need_b: int):
+    """``jax.vmap(one)(*args)`` over K candidates, `GATHER_GROUP` at a
+    time, each group's segments written in place into the (K, need_b,
+    2) batch: what the mask and the derotation hold between the slice
+    and the batch is then a group's, which the chip keeps in its fast
+    memory, and not a second array of the batch's size in HBM (168 MB
+    at K = 32, and at K = 16 with the window of 131 072). Values are
+    the vmap's, bit for bit."""
+    g = GATHER_GROUP
+    groups = -(-k // g)
+    args = [jnp.pad(a, (0, groups * g - k)) for a in args]
+
+    def body(j, batch):
+        part = jax.vmap(one)(*[
+            jax.lax.dynamic_slice(a, (j * g,), (g,)) for a in args])
+        return jax.lax.dynamic_update_slice(batch, part, (j * g, 0, 0))
+
+    batch = jnp.zeros((groups * g, need_b, 2), jnp.float32)
+    return jax.lax.fori_loop(0, groups, body, batch)[:k]
+
+
 def stream_chunk_graph(chunk, chunk_valid, own_lo, own_hi, k: int,
                        win_len: int, n_sym_bucket: int,
                        threshold: float = 0.75, min_run: int = 33,
@@ -1084,10 +1143,13 @@ def stream_chunk_graph(chunk, chunk_valid, own_lo, own_hi, k: int,
        frame start step 4 found and zeroed from the window's true
        count on (garbage on failed lanes, masked host-side).
 
-    Returns ``(own, starts, overflow, found, fstart, eps, rate_bits,
+    Returns ``(own, starts, overflow, found, fstart, eps, rate_word,
     length, parity_ok, n_valid, segs)`` — everything before `segs` is
-    K scalars per lane (one host transfer; `starts` already clamped),
-    `segs` stays device-resident for the decode dispatch."""
+    K scalars per lane (one host transfer; `starts` already clamped;
+    `rate_word` is `pack_rate_word`'s: the RATE bits, and `eps` to 24
+    Hz above them for the host, which pulls every scalar but the
+    float `eps`), `segs` stays device-resident for the decode
+    dispatch."""
     # overflow scan cap: the scan sees plateau CROSSING indices, and a
     # frame aligned at start s can cross as late as s + 224 (the
     # alignment window spans [d-32, d+384) and start = peak - 192, so
@@ -1126,12 +1188,14 @@ def stream_chunk_graph(chunk, chunk_valid, own_lo, own_hi, k: int,
             chunk_pad, (s, jnp.int32(0)), (head, 2)))(safe)
         f2, fstart, eps, rb, ln, pk = jax.vmap(acquire_frame_graph)(
             heads, nv, jnp.minimum(lim, head))
+        rb = pack_rate_word(rb, eps)
     with jax.named_scope("rx.scan.gather"):
         # avail <= win_len - fstart, so the segment's mask ends at the
         # window's bound wherever the chunk goes on past it
-        segs = jax.vmap(lambda s, e, a: gather_segment_graph(
-            chunk_pad, s, e, a, n_sym_bucket))(safe + fstart, eps,
-                                               nv - fstart)
+        segs = _gather_in_groups(
+            lambda s, e, a: gather_segment_graph(chunk_pad, s, e, a,
+                                                 n_sym_bucket),
+            (safe + fstart, eps, nv - fstart), k, need_b)
     return own, starts, overflow, f2, fstart, eps, rb, ln, pk, nv, segs
 
 

@@ -619,17 +619,23 @@ def dispatch_event(label: str, n: int = 1,
             r.histogram(DISPATCH_HISTOGRAM, site=label).observe(seconds)
 
 
-def gauge_sample(label: str, value: float) -> None:
+def gauge_sample(label: str, value: float,
+                 labels: Optional[Dict[str, str]] = None) -> None:
     """One level sample: a time-series point in every active registry
     AND a counter-track event in every active trace — the level is
-    plottable over time, not just a high-water mark."""
+    plottable over time, not just a high-water mark. ``labels`` split
+    the series (a level a lane: ``{"lane": "3"}``; from a bounded set,
+    since a registry keeps every series it was ever given), in the
+    registry beside ``site`` and in the trace as a track of its own."""
     if not (_TRACES or _REGISTRIES):
         return
     t = time.perf_counter()
+    labels = labels or {}
     for r in _REGISTRIES:
-        r.gauge(GAUGE_METRIC, site=label).set(value, t)
+        r.gauge(GAUGE_METRIC, site=label, **labels).set(value, t)
+    track_name = label + "".join(f"[{k}={v}]" for k, v in labels.items())
     for tr in _TRACES:
-        tr.counter(label, value)
+        tr.counter(track_name, value)
 
 
 def track(name: str, value: float) -> None:
