@@ -167,24 +167,38 @@ def test_useful_and_padded_symbols_from_the_frames_sent(runs):
     want = sum(_n_sym(m) for rates in RATE_SETS for m in rates)
     assert sum(e["args"]["useful_symbols"] for e in decodes) == want
     bucket = srv._rx.n_sym_bucket
-    assert all(e["args"]["padded_symbols"] == S * K * bucket
-               for e in decodes)
+    # the slots the program fronts for the step's lanes, whole groups
+    # of them, and the lanes of the tiles its trellis runs
+    # (`rx.decode_walk`, the program's own rule; the values and the
+    # trip counts: test_rx_multistream), each at most all S x K
+    walked, decoded = zip(*(
+        [min(w, S * K) for w in rx.decode_walk(e["args"]["lanes"], S * K)]
+        for e in decodes))
+    assert all(e["args"]["lanes"] <= w <= d
+               for e, w, d in zip(decodes, walked, decoded))
+    assert [e["args"]["padded_symbols"] for e in decodes] \
+        == [w * bucket for w in walked]
     assert sum(e["args"]["lanes"] for e in decodes) \
         == sum(len(r) for r in RATE_SETS)
-    # the lanes filled of the S x K the program runs whatever they hold
-    assert all(e["args"]["slots"] == S * K for e in decodes)
+    assert [e["args"]["slots"] for e in decodes] == list(walked)
+    assert all(e["args"]["window_samples"] == S * K * FRAME_LEN
+               for e in decodes)
     # and what the bound trellis ran against the bits that filled it
     assert sum(e["args"]["useful_bits"] for e in decodes) \
         == sum(_n_sym(m) * RATES[m].n_dbps
                for rates in RATE_SETS for m in rates)
-    assert all(e["args"]["trellis_steps"]
-               == S * K * mixed_trellis_steps(bucket) for e in decodes)
-    # the same two counts in the registry, for scrape()
+    assert [e["args"]["trellis_steps"] for e in decodes] \
+        == [d * mixed_trellis_steps(bucket) for d in decoded]
+    # the same counts in the registry, for scrape()
     reg = srv.registry
     assert reg.find("rx.decode_symbols", kind="useful").value == want
     assert reg.find("rx.decode_symbols", kind="padded").value \
-        == len(decodes) * S * K * bucket
+        == sum(walked) * bucket
+    assert reg.find("rx.decode_slots", kind="live").value \
+        == sum(len(r) for r in RATE_SETS)
+    assert reg.find("rx.decode_slots", kind="walked").value == sum(walked)
     assert 'rx_decode_symbols{kind="useful"}' in srv.scrape()
+    assert 'rx_decode_slots{kind="walked"}' in srv.scrape()
     assert sum(e["args"]["frames"]
                for e in _named(spans, "rx.fleet.emit")) \
         == sum(e["args"]["frames"] for e in _named(spans, "serve.emit")) \

@@ -371,3 +371,121 @@ def test_stream_many_multi_synthesizer_contract():
         link.stream_many_multi(pp, [[6]])
     with pytest.raises(ValueError):
         link.stream_many_multi(pp, [[6], [54]], gaps=[[1]])
+
+
+# ---- the decode walks the slots that hold a frame (PR 46) ----------
+#
+# ONE compiled toy program (`rx.stream_decode_graph` with its trip
+# counts as a third output) at 2 x 72 slots of the suite's 8-symbol
+# bucket — two ACS tiles, so its loop turns — and one
+# reference: `decode_data_mixed` + `crc_psdu_many_graph` over the
+# unpacked batch with EVERY slot live, run once. Lane values do not
+# depend on the batch (the pinned `receive_many` contract), so a case
+# is data: which slots of that one table keep their `nbits`.
+
+WS, WK, WBUCKET = 2, 72, 8
+G = rx.DECODE_GROUP
+#: live slots a stream (stream 0, stream 1): the counts ISSUE 46 names
+#: around a group and a tile, and lanes spread unevenly over streams
+WALK_CASES = [(0, 0), (0, 1), (G - 1, 0), (G // 2, G - G // 2), (G, 1),
+              (2 * G, 0), (2 * G, 1), (WK, 0), (0, WK), (WK, 128 - WK),
+              (WK, 129 - WK), (WK, WK - 1), (WK, WK)]
+
+
+@pytest.fixture(scope="module")
+def walk_toy():
+    import jax
+    from ziria_tpu.phy.wifi.params import RATES
+    rng = np.random.default_rng(46)
+    need = rx.FRAME_DATA_START + 80 * WBUCKET
+    segs = rng.standard_normal((WS, WK, need, 2)).astype(np.float32)
+    rows = rng.integers(0, WK, (WS, WK)).astype(np.int32)
+    ridx = rng.integers(0, 8, (WS, WK)).astype(np.int32)   # mixed rates
+    dbps = np.array([RATES[m].n_dbps for m in rx.RATE_MBPS_ORDER])
+    nbits = (rng.integers(1, WBUCKET + 1, (WS, WK))
+             * dbps[ridx]).astype(np.int32)
+    npsdu = ((nbits - 22) // 8 * 8).astype(np.int32)
+    # traced and compiled ONCE: the cases below run the executable,
+    # the last test reads the trace
+    tables = (segs, rows, ridx, nbits, npsdu)
+    traced = jax.jit(
+        lambda *a: rx.stream_decode_graph(*a, WBUCKET)).trace(*tables)
+    walk = traced.lower().compile()
+
+    def ref(frames, r, b, p):
+        clear = rx.decode_data_mixed(frames, r, b, WBUCKET)
+        return clear, rx.crc_psdu_many_graph(clear, p)
+
+    sel = np.stack([segs[i][rows[i]] for i in range(WS)])
+    clear, crc = jax.jit(ref)(
+        sel.reshape(WS * WK, need, 2), ridx.reshape(-1),
+        nbits.reshape(-1), npsdu.reshape(-1))
+    return (walk, tables, np.asarray(clear).reshape(WS, WK, -1),
+            np.asarray(crc).reshape(WS, WK), traced.jaxpr)
+
+
+@pytest.mark.parametrize("counts", WALK_CASES,
+                         ids=[f"live{a + b}of{WS * WK}-{a}+{b}"
+                              for a, b in WALK_CASES])
+def test_decode_walks_the_live_slots_bit_identical(walk_toy, counts):
+    walk, (segs, rows, ridx, nbits, npsdu), want_clear, want_crc, _ = walk_toy
+    # the host's tables: a stream's live lanes first, `nbits` 0 after
+    live = np.arange(WK)[None, :] < np.array(counts)[:, None]
+    clear, crc, trips = walk(segs, rows, ridx,
+                             np.where(live, nbits, 0), npsdu)
+    clear, crc = np.asarray(clear), np.asarray(crc)
+    assert clear.shape == want_clear.shape and crc.shape == (WS, WK)
+    # every live slot: the mixed decode's own bits and FCS flag
+    assert np.array_equal(clear[live], want_clear[live])
+    assert np.array_equal(crc[live], want_crc[live])
+    # a slot that holds no frame is not decoded: zero, both outputs
+    assert not clear[~live].any() and not crc[~live].any()
+    # the slots the program's trips ran are the python rule's
+    n = int(live.sum())
+    fronted, decoded = rx.decode_walk(n, WS * WK)
+    assert tuple(int(t) for t in trips) == (fronted, decoded)
+    # the rule, said again: 128-lane tiles up to the last live slot,
+    # each fronted whole but the last, which fronts one group where
+    # one holds what is left
+    tiles = max(1, -(-n // 128))
+    left = max(n, 1) - 128 * (tiles - 1)
+    assert decoded == 128 * tiles
+    assert fronted == 128 * (tiles - 1) + (G if left <= G else 128) >= n
+
+
+@pytest.mark.parametrize("n_slots, n_live, want", [
+    (256, 78, (128, 128)),        # the mix cell's step: one tile, whole
+    (256, 32, (32, 128)), (256, 129, (160, 256)), (256, 161, (256, 256)),
+    (128, 24, (32, 128)), (128, 95, (128, 128)),     # maxpsdu, dense54
+    (64, 30, (32, 64)), (64, 33, (64, 64)),          # the MTU cells
+    (8, 3, (32, 32)),             # a batch under a group is padded to one
+    (96, 40, (96, 96)), (144, 0, (32, 128))])
+def test_decode_walk_rule(n_slots, n_live, want):
+    assert rx.decode_walk(n_live, n_slots) == want
+    # the same rule over an array of counts, a device each (the
+    # host's account under a mesh)
+    both = rx.decode_walk(np.array([n_live, n_live]), n_slots)
+    assert [w.tolist() for w in both] == [[v, v] for v in want]
+
+
+def test_decode_walk_loops_to_a_bound_that_is_data(walk_toy):
+    """At two ACS tiles the traced program holds ONE loop of its own,
+    over the tiles that hold a live slot, and its bound is data: a
+    `fori_loop` to a static bound traces to a `scan`, one to a traced
+    bound to a `while`; the front inside it is a `cond` (one group, or
+    the whole tile). The Pallas kernels are calls, not loops, here."""
+    import jax
+
+    def eqns(jaxpr):
+        for e in jaxpr.eqns:
+            yield e
+            if e.primitive.name != "pallas_call":
+                for sub in jax.core.jaxprs_in_params(e.params):
+                    yield from eqns(sub)
+
+    loops = [e for e in eqns(walk_toy[4].jaxpr)
+             if e.primitive.name == "while"]
+    assert len(loops) == 1
+    body = loops[0].params["body_jaxpr"].jaxpr
+    assert [e.primitive.name for e in body.eqns].count("cond") == 1
+    assert sum(e.primitive.name == "pallas_call" for e in eqns(body)) >= 2

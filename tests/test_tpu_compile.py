@@ -161,7 +161,17 @@ def test_decode_program_compiles_with_both_kernels(one_chip, on_chip,
     nsb, shapes = _decode_shapes(geo, one_chip)
     dec = _rx._jit_stream_decode_multi.__wrapped__(
         nsb, None, None, 2, None, "dp", False, False)
-    _assert_mosaic(_compile(dec, *shapes), at_least=2)
+    low = dec.lower(*shapes)
+    _assert_mosaic(low.compile(), at_least=2)
+    # the walk over the slots that hold a frame (PR 46): a batch of
+    # more than a group fronts one group or its whole tile by a
+    # conditional on its data; a batch of one tile is no loop (the
+    # loop and its bound: test_rx_multistream's toy of two tiles)
+    n = geo["s"] * geo["k"]
+    assert n <= LANES and not [
+        w for w in _while_locations(low) if "stream_decode_graph" in w]
+    assert low.as_text().count("stablehlo.case") \
+        == (n > _rx.DECODE_GROUP)
 
 
 def test_decode_program_runs_the_bound_trellis_at_mtu(one_chip, on_chip):
@@ -421,6 +431,7 @@ def test_served_decode_at_mtu_geometry_checks_the_fcs_without_a_loop():
     whiles = _while_locations(lowered)
     assert not [w[:200] for w in whiles if "rx.decode.back" in w]
     # what is left is the two Pallas kernels, interpreted on the CPU
+    # (64 slots are one run of the walk, PR 46: no loop of its own)
     assert all("viterbi_pallas" in w for w in whiles), whiles
     assert not _loose_contractions(lowered.as_text())
 

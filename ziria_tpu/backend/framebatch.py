@@ -1713,30 +1713,41 @@ class MultiStreamReceiver:
         st.allcands, st.starts = allcands, starts
         if st.oracle or not st.lanes:
             return
-        # what the decode is asked for against what it computes:
-        # each of its S x K lanes is gathered at the whole symbol
-        # bucket and runs the bound trellis (the LENGTH field's
-        # longest frame, `params.mixed_trellis_steps`), and each was
-        # cut from the chunk at the whole window whatever its frame's
-        # own length on air
+        # what the decode is asked for against what it computes: on
+        # every device the program fronts the slots that hold a frame,
+        # whole groups of them, each gathered at the whole symbol
+        # bucket, and runs the tiles that hold them over the bound
+        # trellis, every lane (the LENGTH field's longest frame,
+        # `params.mixed_trellis_steps`): `rx.decode_walk`, its own
+        # rule, cut at the slots a device has; the scan cut every one
+        # of the S x K from the chunk at the whole window, whatever
+        # it holds
         useful = sum(lane[4] for lane in st.lanes)
-        n_slots = self.s * self.k
+        dev_slots = self.s * self.k // self._n_devices
+        n_slots, decoded = (
+            int(np.minimum(w, dev_slots).sum()) for w in _rx.decode_walk(
+                (tables[2] > 0).reshape(self._n_devices, -1).sum(axis=1),
+                dev_slots))
         padded = n_slots * self.n_sym_bucket
         telemetry.count("rx.decode_symbols", useful,
                         labels={"kind": "useful"})
         telemetry.count("rx.decode_symbols", padded,
                         labels={"kind": "padded"})
+        telemetry.count("rx.decode_slots", len(st.lanes),
+                        labels={"kind": "live"})
+        telemetry.count("rx.decode_slots", n_slots,
+                        labels={"kind": "walked"})
         with telemetry.span("rx.fleet.decode", {
                 "step": step, "lanes": len(st.lanes),
                 "slots": n_slots,
                 "useful_symbols": useful,
                 "padded_symbols": padded,
                 "useful_bits": int(tables[2].sum()),
-                "trellis_steps": n_slots
+                "trellis_steps": decoded
                 * mixed_trellis_steps(self.n_sym_bucket),
                 "frame_samples": len(st.lanes) * _rx.FRAME_DATA_START
                 + 80 * useful,
-                "window_samples": n_slots * self.frame_len}):
+                "window_samples": self.s * self.k * self.frame_len}):
             st.dec = _rx._jit_stream_decode_multi(
                 self.n_sym_bucket, self.viterbi_window,
                 self.viterbi_metric, self.viterbi_radix,

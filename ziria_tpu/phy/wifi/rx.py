@@ -498,23 +498,47 @@ def decode_data_mixed(frames, rate_idx, n_bits_real, n_sym_bucket: int,
     share stays one kernel. Windowed/quantized modes fall back to the
     (bit-identical) unfused front, exactly like the known-rate path.
     """
-    t_max = mixed_trellis_steps(n_sym_bucket)
+    front, trellis, back = _mixed_stages(
+        n_sym_bucket, viterbi_window, viterbi_metric, viterbi_radix,
+        interpret, sco_track, fused_demap)
     rate_idx = jnp.asarray(rate_idx, jnp.int32)
     n_bits_real = jnp.asarray(n_bits_real, jnp.int32)
+    return back(trellis(front(frames, rate_idx, n_bits_real),
+                        rate_idx, n_bits_real))
+
+
+def _mixed_stages(n_sym_bucket: int, viterbi_window, viterbi_metric,
+                  viterbi_radix, interpret, sco_track, fused_demap):
+    """`decode_data_mixed`'s three stages as functions of a batch of
+    lanes, each under its scope: ``front(frames, rate_idx, n_bits_real)``
+    -> what the trellis reads, a tuple of arrays that lead with the
+    lane axis (the depunctured LLRs; under the fused front the
+    equalized symbols and their gains); ``trellis(soft, rate_idx,
+    n_bits_real)`` -> (B, t_max) decoded bits; ``back(bits)`` -> the
+    descrambled rows. Every stage is lane-local, so a lane's values
+    do not depend on the batch it rides in: the mixed decode runs the
+    three over one batch, the streaming decode
+    (`stream_decode_graph`) over the groups and tiles that hold a
+    frame."""
+    t_max = mixed_trellis_steps(n_sym_bucket)
     # `rx.decode.front` / `.viterbi` / `.back` name the stages in the
     # device trace (docs/observability.md): metadata, no program change
     if fused_demap_enabled(fused_demap) \
             and _fused_front_applies(viterbi_window, viterbi_metric):
-        with jax.named_scope("rx.decode.front"):
-            data, gain = jax.vmap(
-                lambda f: _front_symbols(f, n_sym_bucket,
-                                         sco_track))(frames)
-        with jax.named_scope("rx.decode.viterbi"):
-            # the fused kernel still runs the bucket's whole trellis
-            # (ROADMAP S2); its rows past t_max are the same erasures
-            bits = viterbi_pallas.viterbi_decode_mixed_fused(
-                data, gain, rate_idx, n_bits_real, radix=viterbi_radix,
-                interpret=interpret)[:, :t_max]
+        def front(frames, rate_idx, n_bits_real):
+            with jax.named_scope("rx.decode.front"):
+                return jax.vmap(
+                    lambda f: _front_symbols(f, n_sym_bucket,
+                                             sco_track))(frames)
+
+        def trellis(soft, rate_idx, n_bits_real):
+            data, gain = soft
+            with jax.named_scope("rx.decode.viterbi"):
+                # the fused kernel still runs the bucket's whole trellis
+                # (ROADMAP S2); its rows past t_max are the same erasures
+                return viterbi_pallas.viterbi_decode_mixed_fused(
+                    data, gain, rate_idx, n_bits_real,
+                    radix=viterbi_radix, interpret=interpret)[:, :t_max]
     else:
         def _branch(rate):
             n_sym = mixed_branch_symbols(n_sym_bucket, rate)
@@ -530,28 +554,36 @@ def decode_data_mixed(frames, rate_idx, n_bits_real, n_sym_bucket: int,
                 return jnp.pad(dep, ((0, t_max - dep.shape[0]), (0, 0)))
             return f
 
-        with jax.named_scope("rx.decode.front"):
-            branches = [_branch(RATES[m]) for m in RATE_MBPS_ORDER]
-            dep = jax.vmap(
-                lambda f, r: jax.lax.switch(r, branches, f))(
-                    frames, rate_idx)
-            # rows at/after each lane's true bit count become
-            # erasures (covers both the in-rate bucket pad and the
-            # cross-rate pad to MAX_DBPS)
-            t = jnp.arange(t_max)
-            dep = jnp.where(
-                (t[None, :] < n_bits_real[:, None])[..., None], dep, 0.0)
-        with jax.named_scope("rx.decode.viterbi"):
-            bits = viterbi_pallas.viterbi_decode_batch_opt(
-                dep, window=viterbi_window, metric_dtype=viterbi_metric,
-                radix=viterbi_radix, interpret=interpret)
+        def front(frames, rate_idx, n_bits_real):
+            with jax.named_scope("rx.decode.front"):
+                branches = [_branch(RATES[m]) for m in RATE_MBPS_ORDER]
+                dep = jax.vmap(
+                    lambda f, r: jax.lax.switch(r, branches, f))(
+                        frames, rate_idx)
+                # rows at/after each lane's true bit count become
+                # erasures (covers both the in-rate bucket pad and the
+                # cross-rate pad to MAX_DBPS)
+                t = jnp.arange(t_max)
+                return (jnp.where(
+                    (t[None, :] < n_bits_real[:, None])[..., None],
+                    dep, 0.0),)
+
+        def trellis(soft, rate_idx, n_bits_real):
+            with jax.named_scope("rx.decode.viterbi"):
+                return viterbi_pallas.viterbi_decode_batch_opt(
+                    soft[0], window=viterbi_window,
+                    metric_dtype=viterbi_metric, radix=viterbi_radix,
+                    interpret=interpret)
 
     def _descramble(b):
         seed = scramble.recover_seed(b[:7])
         return scramble.descramble_bits(b, seed)
 
-    with jax.named_scope("rx.decode.back"):
-        return jax.vmap(_descramble)(bits)
+    def back(bits):
+        with jax.named_scope("rx.decode.back"):
+            return jax.vmap(_descramble)(bits)
+
+    return front, trellis, back
 
 
 def crc_psdu_many_graph(clear_b, n_psdu_bits):
@@ -1266,6 +1298,138 @@ def _jit_stream_chunk_multi(k: int, win_len: int, n_sym_bucket: int,
         check_vma=False))
 
 
+#: Live slots in a group of the streaming decode's walk (`stream_
+#: decode_graph`): the unit it accounts in, and the least it fronts. A
+#: tile of the walk fronts one group or all of its groups at once,
+#: because on the chip a front is some eighty small ops whose cost
+#: hardly falls under 32 slots (1.7, 2.1, 2.9 ms for 8, 16, 32 at the
+#: 1024-symbol bucket; 6.9 for 128), so one trip at a size that holds
+#: the live slots beats several small ones, and every size is a front
+#: the program compiles (PERF.md, PR 46: the sweep).
+DECODE_GROUP = 32
+
+
+def decode_walk(n_live, n_slots: int):
+    """What the streaming decode computes for `n_live` live slots of a
+    batch of `n_slots`, in slots: ``(fronted, decoded)``. It takes the
+    packed live slots a tile at a time, a tile the ACS kernel's 128
+    lanes or the whole groups of `DECODE_GROUP` that already hold the
+    batch, and stops at the last tile that holds one. `decoded`: the
+    lanes of those tiles, which the ACS, the traceback, the descrambler
+    and the FCS check run whole. `fronted`: the slots it selects and
+    fronts in them: every tile but the last whole, the last ONE group
+    where one holds what is left of the live slots and whole where it
+    does not. A batch with no live slot (no step dispatches one; a
+    warm-up does) walks as a batch with one. ONE rule for the program
+    (`n_live` traced: its loop's bound and each trip's front) and for
+    the host's account of it (ints, or an array of them a device); a
+    batch that is no whole number of groups or tiles is padded to
+    one, and the pad is no slot: the account cuts both at `n_slots`."""
+    tile = min(viterbi_pallas.LANES,
+               -(-n_slots // DECODE_GROUP) * DECODE_GROUP)
+    n = n_live + (n_live == 0)
+    full = (n - 1) // tile * tile        # the tiles before the last
+    whole = n - full > DECODE_GROUP
+    return (full + DECODE_GROUP + whole * (tile - DECODE_GROUP),
+            full + tile)
+
+
+def stream_decode_graph(segs, rows, ridx, nbits, npsdu,
+                        n_sym_bucket: int, viterbi_window: int = None,
+                        viterbi_metric: str = None,
+                        viterbi_radix: int = None,
+                        sco_track: bool = False,
+                        fused_demap: bool = False):
+    """The streaming decode over the slots that hold a frame. `segs`
+    (S, K, need_b, 2) is the scan's segment batch; `rows`, `ridx`,
+    `nbits`, `npsdu` the host's four (S, K) tables (segment row, rate
+    index, data bits, PSDU bits), a stream's decodable lanes first
+    and ``nbits == 0`` in every slot past them. Returns ``(clear (S,
+    K, t_max), crc (S, K), (fronted, decoded))``, the last the slots
+    its trips ran (`decode_walk`'s pair, for the tests).
+
+    A slot is live where ``nbits > 0``. A stable partition packs the
+    live slots of the flattened (S*K) batch to its front, stream
+    order kept, and ONE loop whose trip count is data (`decode_walk`)
+    takes them a tile of 128 at a time and stops at the last tile
+    that holds one: a trip selects the segments of its tile's live
+    slots from `segs` and fronts them (one group of `DECODE_GROUP`
+    where one holds what is left, else the whole tile: a `lax.cond`),
+    then runs the ACS, the traceback, the descrambler and the FCS
+    check on the tile. The stages are `decode_data_mixed`'s own
+    (`_mixed_stages`) and lane-local, so every live slot's `clear`
+    and `crc` are, bit for bit, what the mixed decode over all S*K
+    slots gives it. A
+    slot that holds no frame is not decoded and reads ZERO in both
+    outputs (until PR 46 it came back as a decoded erasure); the host
+    reads neither."""
+    front, trellis, back = _mixed_stages(
+        n_sym_bucket, viterbi_window, viterbi_metric, viterbi_radix,
+        None, sco_track, fused_demap)
+    s, kk = rows.shape
+    n, g = s * kk, DECODE_GROUP
+    # a tile: what one live slot decodes; the packed batch: whole tiles
+    tile, n_buf = decode_walk(1, n)[1], decode_walk(n, n)[1]
+    ridx, nbits, npsdu = (t.reshape(-1) for t in (ridx, nbits, npsdu))
+    with jax.named_scope("rx.decode.select"):
+        live = nbits > 0
+        fronted, decoded = decode_walk(live.sum(dtype=jnp.int32), n)
+        # a live slot's place in the packed order, and the slot at
+        # each place; places past the last live slot keep slot 0,
+        # whose rows are computed with the last group and never read
+        place = jnp.cumsum(live, dtype=jnp.int32) - 1
+        order = jnp.zeros((n_buf,), jnp.int32).at[
+            jnp.where(live, place, n_buf)].set(
+                jnp.arange(n, dtype=jnp.int32), mode="drop")
+        segs = segs.reshape((n,) + segs.shape[2:])
+        src = jnp.arange(n, dtype=jnp.int32) // kk * kk \
+            + rows.reshape(-1)
+
+    def front_rows(size):
+        """The front over a tile's first `size` packed slots, as the
+        tile's rows (zero, an erasure, past them)."""
+        def rows_of(j):
+            idx = jax.lax.dynamic_slice(order, (j * tile,), (size,))
+            with jax.named_scope("rx.decode.select"):
+                # ONE gather a trip: a slice a slot, stacked, cost the
+                # chip's compiler 10.9 GB of temporaries at 128 slots
+                frames = jnp.take(segs, src[idx], axis=0, mode="clip")
+            return tuple(
+                jnp.pad(o, ((0, tile - size),) + ((0, 0),) * (o.ndim - 1))
+                for o in front(frames, ridx[idx], nbits[idx]))
+        return rows_of
+
+    def decode_tile(j, out):
+        # what `decode_walk` fronts of this tile: one group (the
+        # last tile, where one holds what is left) or all of it
+        soft = front_rows(g)(j) if tile == g else jax.lax.cond(
+            fronted - j * tile > g, front_rows(tile), front_rows(g), j)
+        idx = jax.lax.dynamic_slice(order, (j * tile,), (tile,))
+        clear = back(trellis(soft, ridx[idx], nbits[idx]))
+        crc = crc_psdu_many_graph(clear, npsdu[idx])
+        with jax.named_scope("rx.decode.back"):
+            # a tile is a leading index of the packed outputs, so the
+            # write is the tile's own bytes (zero where no trip went)
+            return tuple(jax.lax.dynamic_update_index_in_dim(b, p, j, 0)
+                         for b, p in zip(out, (clear, crc)))
+
+    out = (jnp.zeros((n_buf // tile, tile,
+                      mixed_trellis_steps(n_sym_bucket)), jnp.uint8),
+           jnp.zeros((n_buf // tile, tile), bool))
+    # one tile is no loop: a body that does not read its counter is
+    # hoisted out piecemeal by the chip's compiler, which then ran
+    # the ACS twice
+    clear, crc = (b.reshape((n_buf,) + b.shape[2:]) for b in (
+        decode_tile(0, out) if n_buf == tile
+        else jax.lax.fori_loop(0, decoded // tile, decode_tile, out)))
+    with jax.named_scope("rx.decode.back"):
+        # packed places back to the slots' own
+        clear = jnp.where(live[:, None], clear[place], 0)
+        crc = live & crc[place]
+    return (clear.reshape(s, kk, -1), crc.reshape(s, kk),
+            (fronted, decoded))
+
+
 @lru_cache(maxsize=None)
 def _jit_stream_decode_multi(n_sym_bucket: int, viterbi_window: int = None,
                              viterbi_metric: str = None,
@@ -1273,14 +1437,15 @@ def _jit_stream_decode_multi(n_sym_bucket: int, viterbi_window: int = None,
                              axis: str = "dp",
                              sco_track: bool = False,
                              fused_demap: bool = False):
-    """Dispatch 2 of the chunk-step: per-stream row-select of the
-    decodable lanes (all inside the jit — the still device-resident
-    (S, K, ...) segment batch never re-crosses the host link), then
-    the (S*K)-lane FLATTENED mixed-rate decode + masked CRC — one
-    rate-agnostic Pallas Viterbi batch for the whole fleet, every
-    lane riding the same 128-lane tiles (lane values are batch-
-    independent, the pinned receive_many contract, so each lane is
-    bit-identical to its stream's own K-lane decode). The CRC flags
+    """Dispatch 2 of the chunk-step: the mixed-rate decode + masked
+    CRC of the slots that hold a frame (`stream_decode_graph`: all
+    inside the jit — the still device-resident (S, K, ...) segment
+    batch never re-crosses the host link) — one rate-agnostic Pallas
+    Viterbi for the whole fleet, every live lane riding the same
+    128-lane tiles (lane values are batch-independent, the pinned
+    receive_many contract, so each lane is bit-identical to its
+    stream's own K-lane decode). With a `mesh` each device packs and
+    walks its own streams' slots; no collective. The CRC flags
     are always computed, so one compile serves both `check_fcs`
     modes: two XOR-reductions and a look-up, 0.4 ms at the MTU
     bucket (as a byte-serial scan the check was 35.9 ms of the 83 ms
@@ -1289,16 +1454,10 @@ def _jit_stream_decode_multi(n_sym_bucket: int, viterbi_window: int = None,
     for the R1 lint demo) and the mesh are cache keys, as in every
     jit factory here."""
     def stream_decode_multi(segs, rows, ridx, nbits, npsdu):
-        with jax.named_scope("rx.decode.select"):
-            sel = jax.vmap(lambda sg, r: sg[r])(segs, rows)
-        s, kk = rows.shape
-        clear = decode_data_mixed(
-            sel.reshape((s * kk,) + sel.shape[2:]), ridx.reshape(-1),
-            nbits.reshape(-1), n_sym_bucket, viterbi_window,
-            viterbi_metric, viterbi_radix, sco_track=sco_track,
-            fused_demap=fused_demap)
-        crc = crc_psdu_many_graph(clear, npsdu.reshape(-1))
-        return (clear.reshape(s, kk, -1), crc.reshape(s, kk))
+        return stream_decode_graph(
+            segs, rows, ridx, nbits, npsdu, n_sym_bucket,
+            viterbi_window, viterbi_metric, viterbi_radix,
+            sco_track=sco_track, fused_demap=fused_demap)[:2]
 
     if mesh is None:
         return jax.jit(stream_decode_multi)
