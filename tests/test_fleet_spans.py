@@ -418,7 +418,7 @@ def test_put_and_pulls_say_how_the_pipeline_ran(runs):
 
 def test_put_names_the_batch_the_detector_convolves_over(runs):
     """`locate_rows` (PR 35): lanes a device x the blocks
-    `sync.correlate_valid` cuts a chunk-long row into, from the
+    `sync.ccorrelate_valid` cuts a chunk-long row into, from the
     function that picks the fold; static, so the same on every
     step."""
     from ziria_tpu.ops import sync

@@ -327,39 +327,42 @@ def test_chunk_scan_at_mtu_geometry_acquires_over_the_window_head(s):
     window's head now (`rx._acquire_head`): at the served geometry no
     convolution of the S x K window batch is window-long any more.
 
-    And the chunk-level ones are no longer `[S, 1, 131 072]`, the
+    And the chunk-level one is no longer `[S, 1, 131 072]`, the
     shape that was 420.8 ms of every tick at S = 8 (PERF.md, PR 35):
-    `sync.correlate_valid` cuts each row into blocks of
-    `sync.FOLD_BLOCK` outputs, so every chunk-level contraction of the
+    `sync.ccorrelate_valid` cuts each row into blocks of
+    `sync.FOLD_BLOCK` outputs, so the chunk-level contraction of the
     scan has a batch of at least 256 — for the lone stream (S = 1) as
-    for the fleet — while the head-long ones pass through as they
-    were, and every float contraction is HIGHEST (no compiler)."""
+    for the fleet — while the head-long one passes through as it was.
+
+    Since PR 47 the scan holds TWO convolutions where it held six: the
+    LTS correlation is one of two input channels and two output
+    features (four one-channel products before), once over the chunk
+    and once over the heads, and the 48-sample window sums are
+    shift-adds, no contraction at all (three convolutions with ones
+    before). Every float contraction is HIGHEST (no compiler)."""
     from ziria_tpu.ops import sync
 
     k, chunk = MTU["k"], MTU["chunk_len"]
     geo = dict(MTU, s=s)
     text = _chunk_scan(geo).lower(*_chunk_shapes(geo, None)).as_text()
     assert not _loose_contractions(text)
-    outs = [tuple(int(d) for d in m.groups()) for m in re.finditer(
-        r"stablehlo\.convolution.*-> tensor<(\d+)x1x(\d+)xf32>", text)]
-    assert len(outs) == 6, outs
+    convs = [ln for ln in text.splitlines()
+             if "stablehlo.convolution" in ln]
+    outs = sorted(tuple(int(d) for d in m.groups()) for m in re.finditer(
+        r"stablehlo\.convolution.*-> tensor<(\d+)x2x(\d+)xf32>", text))
+    assert len(convs) == len(outs) == 2, convs
+    assert all("tensor<2x2x64xf32>" in ln for ln in convs)
     head = _rx._acquire_head(MTU["frame_len"])
-    # the acquisition: STS sums over 2 S K and S K rows, LTS over S K
-    # (one private function, called four times), head-long, unfolded
-    # (they were frame_len - 63 and + 63 long before PR 30)
+    # the acquisition: the LTS correlation over S K rows, head-long,
+    # unfolded (frame_len - 63 long before PR 30)
     assert head < MTU["frame_len"] - 128
     assert sync.fold_blocks(head - 63) == 1
-    heads = sorted(o for o in outs if o[1] > sync.FOLD_BLOCK)
-    assert heads == [(s * k, head - 63), (s * k, head - 63),
-                     (2 * s * k, head - 63)]
-    # the chunk scan: the same three, every row cut into 256 blocks
+    # the chunk scan: the same one, every row cut into 256 blocks
     blocks = sync.fold_blocks(chunk - 63)
-    assert blocks == sync.fold_blocks(chunk - 63 - 47) == 256
+    assert blocks == 256
     assert sync.fold_rows(s, chunk) == s * blocks
-    folded = sorted(o for o in outs if o[1] <= sync.FOLD_BLOCK)
-    assert folded == [(s * blocks, sync.FOLD_BLOCK),
-                      (s * blocks, sync.FOLD_BLOCK),
-                      (2 * s * blocks, sync.FOLD_BLOCK)]
+    assert outs == sorted([(s * k, head - 63),
+                           (s * blocks, sync.FOLD_BLOCK)])
 
 
 @pytest.mark.parametrize("geo", [MTU, MIX, MAXPSDU, DENSE54],

@@ -3,9 +3,10 @@
 Counterpart of the reference RX's front half (SURVEY.md §2.3, §3.4):
 packet detect via STS autocorrelation, coarse/fine CFO from STS/LTS
 lag products, channel estimation from the two LTS symbols. All in pair
-representation, all expressed as whole-array ops (short convolutions
-for sliding correlations — see _sliding_sum for why not cumsum) so a
-frame's worth of samples is one fused graph.
+representation, all expressed as whole-array ops (one short
+convolution for the sliding LTS correlation, `ccorrelate_valid`, and
+doubling shift-adds for the window sums — see `_sliding_sum` for why
+not cumsum) so a frame's worth of samples is one fused graph.
 """
 
 from __future__ import annotations
@@ -17,19 +18,22 @@ from ziria_tpu.ops import cplx
 from ziria_tpu.ops.ofdm import LTS_FREQ, N_FFT, lts_time_symbol
 
 
-#: Output samples of one folded block. A one-channel convolution over
-#: few long rows is a shape the TPU crawls on: 64 taps over
-#: [8, 1, 131 072] took 86.9 ms on a v5e (12 M outputs/s) and 0.87 ms
-#: cut into 128 rows or more (1.2 G outputs/s, flat from 16 to 1024
-#: blocks a row); a lone row wants 256 blocks or more. The whole
-#: `locate_frames` at [8, 131 072, 2]: 421.1 ms unfolded, 5.8 ms at
-#: blocks of 128 to 8192 outputs, 6.4 at 1024 (a row of 1024 + 47
-#: tiles badly), 9.6 at 16 384 (chip runs, PR 35: PERF.md section 6).
+#: Output samples of one folded block. A convolution over few long
+#: rows is a shape the TPU crawls on: 64 taps over [8, 1, 131 072] took
+#: 86.9 ms on a v5e (12 M outputs/s) and 0.87 ms cut into 128 rows or
+#: more (1.2 G outputs/s, flat from 16 to 1024 blocks a row); a lone
+#: row wants 256 blocks or more (chip runs, PR 35, of the one-channel
+#: products the LTS correlation then was). Since PR 47 the correlation
+#: is one two-channel convolution over the same blocks, 1.56 ms at
+#: [8, 131 072, 2] where the four products took 4.03, and the whole
+#: `vmap(locate_frames)` 2.44 ms where it took 6.78 (4.00 against
+#: 12.67 at [8, 262 144, 2]; chip run, PR 47: PERF.md section 6 has
+#: every form that was priced beside it).
 FOLD_BLOCK = 512
 
 
 def fold_blocks(n_out: int) -> int:
-    """How many overlapped blocks `correlate_valid` cuts a row of
+    """How many overlapped blocks `ccorrelate_valid` cuts a row of
     ``n_out`` outputs into: as many as `FOLD_BLOCK` goes into it,
     rounded up, and 1 (the row passes through unfolded) where the row
     is at most two blocks long — the acquisition's window heads are
@@ -47,72 +51,88 @@ def fold_rows(rows: int, n: int) -> int:
     return rows * fold_blocks(n - N_FFT + 1)
 
 
-def correlate_valid(x, taps):
-    """``jnp.convolve(x, taps, mode="valid", precision="highest")`` of
-    one f32 row, value for value, in a shape the chip runs well: THE
-    sliding correlator of this module (`_sliding_sum`'s float path and
-    `lts_pair_metric`), so the per-capture oracle and the chunk scan
-    run the same function.
+def ccorrelate_valid(x, ref):
+    """Sliding complex correlation of one row of pairs against ``ref``:
+    ``out[k] = sum_j x[k + j] * conj(ref[j])``, THE sliding correlator
+    of this module (`lts_pair_metric`), so the per-capture oracle and
+    the chunk scan run the same function.
 
-    x: (n,), taps: (w,), n >= w. Returns (n - w + 1,). A long row is
-    cut into `fold_blocks` blocks of `FOLD_BLOCK` outputs, each with
-    the w - 1 samples of halo its last outputs read, the blocks ride
-    the convolution's batch axis (under ``vmap`` the lane axis merges
-    into the same batch) and their outputs are laid end to end again.
-    The same taps meet the same samples in the same order, so no
-    arithmetic is added, removed or reordered, and a value depends on
-    its own w-sample window alone — never on the block, the offset or
-    the array it landed in. "highest": the TPU's default convolution
-    precision is bfloat16."""
+    x: (n, 2), ref: (w, 2), n >= w. Returns (n - w + 1, 2). ONE
+    convolution of two input channels (re, im) and two output features
+    (re, im), where four one-channel convolutions ran, one a real
+    product, each filling a single column of the MXU (4.03 ms at 2048
+    rows against this one's 1.56: chip run, PR 47, PERF.md section
+    6). A long row is cut into `fold_blocks` blocks of `FOLD_BLOCK`
+    outputs, each with the w - 1 samples of halo its last outputs
+    read, the blocks ride the convolution's batch axis (under ``vmap``
+    the lane axis merges into the same batch) and their outputs are
+    laid end to end again. The same taps meet the same samples in the
+    same order in every block, so a value depends on its own w-sample
+    window alone — never on the block, the offset or the array it
+    landed in (`tests/test_sync_fold.py`, and read so on the chip: a
+    banded matmul was twice as fast there and did not, PERF.md).
+    HIGHEST: the TPU's default convolution precision is bfloat16."""
     import jax
 
-    def conv(row):
-        return jnp.convolve(row, taps, mode="valid", precision="highest")
-
-    w = taps.shape[0]
+    w = ref.shape[0]
     n_out = x.shape[0] - w + 1
     blocks = fold_blocks(n_out)
     if blocks == 1:
-        return conv(x)
-    # block b reads x[b * L : (b + 1) * L + w - 1]: its own L samples
-    # and the head of the next block's (w - 1 <= L), zeros past the end
-    size = FOLD_BLOCK
-    body = jnp.pad(x, (0, (blocks + 1) * size - x.shape[0])) \
-        .reshape(blocks + 1, size)
-    rows = jnp.concatenate([body[:-1], body[1:, :w - 1]], axis=1)
-    return jax.vmap(conv)(rows).reshape(-1)[:n_out]
+        rows = x[None]
+    else:
+        # block b reads x[b * L : (b + 1) * L + w - 1]: its own L
+        # samples and the head of the next block's (w - 1 <= L), zeros
+        # past the end
+        size = FOLD_BLOCK
+        body = jnp.pad(x, ((0, (blocks + 1) * size - x.shape[0]), (0, 0))) \
+            .reshape(blocks + 1, size, 2)
+        rows = jnp.concatenate([body[:-1], body[1:, :w - 1]], axis=1)
+    rr, ri = ref[:, 0], ref[:, 1]
+    # [feature, channel, tap]: re = xr rr + xi ri, im = xi rr - xr ri
+    taps = jnp.stack([jnp.stack([rr, ri]), jnp.stack([-ri, rr])])
+    out = jax.lax.conv_general_dilated(
+        rows.transpose(0, 2, 1), taps, (1,), "VALID",
+        dimension_numbers=("NCH", "OIH", "NCH"),
+        precision=jax.lax.Precision.HIGHEST)        # (blocks, 2, L)
+    return out.transpose(0, 2, 1).reshape(-1, 2)[:n_out]
 
 
 def _sliding_sum(x, w: int):
     """Sliding window sums along axis 0: out[k] = sum(x[k:k+w]).
 
-    Computed as a w-tap convolution, NOT a global cumsum difference:
-    prefix sums accumulate f32 rounding along the whole stream and the
-    window value c[k+w]-c[k] is a catastrophic cancellation once the
-    prefix dwarfs the window (measured ~0.2% metric error at 14k
-    samples, and host vs stream-sharded results diverged). The conv
-    accumulates only the w local terms and is position-independent —
-    so `parallel/streampar.sliding_parallel` shards bit-compatibly and
-    `correlate_valid` may cut the row into blocks. It is cheap only
-    in the right shape: 48 taps over 8 rows of 131 072 took 64 ms on
-    a v5e (ledger, PR 34), which is why the rows are folded.
+    NOT a global cumsum difference: prefix sums accumulate f32
+    rounding along the whole stream and the window value c[k+w]-c[k]
+    is a catastrophic cancellation once the prefix dwarfs the window
+    (measured ~0.2% metric error at 14k samples, and host vs
+    stream-sharded results diverged). And no longer a w-tap
+    convolution with ones (48 multiply-adds by 1.0 an output; the
+    three columns of `sts_autocorr` took 3.10 ms at [8, 131 072, 2]
+    and take 1.05 so: chip run, PR 47, PERF.md section 6): the window
+    sums of 2, 4, 8 ... samples by doubling shift-adds, then one add
+    for each further set bit of ``w`` (48 = 32 + 16: six additions an
+    output). Every output is the same tree of f32 additions over its
+    own w terms, so it is position-independent —
+    `parallel/streampar.sliding_parallel` shards bit-compatibly, and a
+    chunk and a capture that hold the same samples read the same
+    values, on the chip too (the one-channel convolution's did not
+    there: its summation order followed its batch).
     """
-    import jax
     x = jnp.asarray(x)
     if not jnp.issubdtype(x.dtype, jnp.inexact):
-        # integer windows: cumsum differences are EXACT (no rounding),
-        # and jnp.convolve would promote to float
+        # integer windows: cumsum differences are EXACT (no rounding)
         c = jnp.cumsum(x, axis=0)
         c = jnp.concatenate([jnp.zeros_like(c[:1]), c], axis=0)
         return c[w:] - c[:-w]
-    k = jnp.ones((w,), x.dtype)
-
-    if x.ndim == 1:
-        return correlate_valid(x, k)
-    flat = x.reshape(x.shape[0], -1)
-    out = jax.vmap(lambda col: correlate_valid(col, k),
-                   in_axes=1, out_axes=1)(flat)
-    return out.reshape((out.shape[0],) + x.shape[1:])
+    n_out = x.shape[0] - w + 1
+    sums, p = {1: x}, 1
+    while 2 * p <= w:
+        sums[2 * p] = sums[p][:-p] + sums[p][p:]
+        p *= 2
+    out, off = sums[p][:n_out], p
+    for q in sorted((q for q in sums if q < p and w & q), reverse=True):
+        out = out + sums[q][off:off + n_out]
+        off += q
+    return out
 
 
 def sts_autocorr(samples, window: int = 48):
@@ -206,14 +226,8 @@ def lts_pair_metric(samples, limit=None):
     x = jnp.asarray(samples, jnp.float32)
     n = x.shape[0]
     lim = n if limit is None else limit
-    lts = jnp.asarray(lts_time_symbol())                # (64, 2)
-    ref = cplx.conj(lts)[::-1]                          # reversed conj
-
-    conv1 = correlate_valid                             # (n-63,) each
-    re = conv1(x[:, 0], ref[:, 0]) - conv1(x[:, 1], ref[:, 1])
-    im = conv1(x[:, 0], ref[:, 1]) + conv1(x[:, 1], ref[:, 0])
-    # valid conv index k = correlation at lag k
-    c = re ** 2 + im ** 2                               # (n-63,)
+    corr = ccorrelate_valid(x, jnp.asarray(lts_time_symbol()))
+    c = cplx.cabs2(corr)                                # (n-63,)
     pair = c[:-64] + c[64:]                             # two-peak sum
     return jnp.where(jnp.arange(pair.shape[0]) < lim - 127, pair, -1.0)
 
