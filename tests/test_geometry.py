@@ -1,23 +1,18 @@
-"""The declarative Geometry object + autotuner (ISSUE 16): the
-default ``Geometry()`` is a NO-OP by construction — zero new compiled
+"""The declarative Geometry object (ISSUE 16): the default
+``Geometry()`` is a NO-OP by construction — zero new compiled
 programs and bit-identical emissions against the legacy per-knob
 arguments at the suite-shared 4096/1024/K=8 streaming geometry —
 while ``resolve()`` folds env knobs exactly once, serialization and
 the checkpoint geometry fingerprint round-trip (legacy blobs missing
-post-format fields included), and the autotuner pipeline
-(cost-prune -> measure -> identity gate -> ledger record ->
-``Geometry.tuned()``) runs deterministically under injected fakes.
+post-format fields included).
 
 Budget discipline: every compiled-path test constructs at the SAME
 4096/1024/K=8 geometry the streaming/batched-acquire/mixed suites
 share, pays its compiles once in a module fixture, and pins the
-geometry-object path under ``dispatch.no_recompile`` against it. The
-autotuner tests never touch jax at all (fakes).
+geometry-object path under ``dispatch.no_recompile`` against it.
 """
 
 import dataclasses
-import json
-import os
 
 import numpy as np
 import pytest
@@ -26,7 +21,7 @@ from ziria_tpu.backend import framebatch
 from ziria_tpu.phy import link
 from ziria_tpu.phy.wifi import rx
 from ziria_tpu.runtime import resilience, serve
-from ziria_tpu.utils import autotune, dispatch, geometry
+from ziria_tpu.utils import dispatch
 from ziria_tpu.utils.geometry import Geometry
 
 N_BYTES = 12
@@ -117,7 +112,7 @@ def test_resolve_env_precedence_and_scoped_restore(monkeypatch):
 
 
 def test_serialization_round_trips_strictly():
-    r = GEO.replace(viterbi_radix=4).resolve()
+    r = dataclasses.replace(GEO, viterbi_radix=4).resolve()
     assert Geometry.from_json(r.to_json()) == r
     assert Geometry.from_dict(r.as_dict()) == r
     with pytest.raises(ValueError, match="warp_factor"):
@@ -133,7 +128,7 @@ def test_serve_config_defaults_derive_from_geometry():
             c.max_frames_per_chunk) == \
         (g.n_streams, g.chunk_len, g.frame_len, g.max_frames_per_chunk)
     t = serve.ServeConfig.from_geometry(
-        g.replace(chunk_len=16384, n_streams=4), queue_cap=3)
+        dataclasses.replace(g, chunk_len=16384, n_streams=4), queue_cap=3)
     assert (t.n_lanes, t.chunk_len, t.queue_cap) == (4, 16384, 3)
     assert t.frame_len == g.frame_len
 
@@ -242,136 +237,5 @@ def test_checkpoint_fingerprint_round_trip(corpus):
     with pytest.raises(resilience.CarryCheckpointError):
         framebatch.StreamReceiver(
             checkpoint=blob, check_fcs=True,
-            geometry=GEO.replace(chunk_len=8192))
+            geometry=dataclasses.replace(GEO, chunk_len=8192))
     del out, drained
-
-
-# ------------------------------------------------------- the autotuner
-
-
-def _fake_cost(costs):
-    """cost_fn keyed on chunk_len (the axis the fake search varies)."""
-    def fn(geo):
-        return dict(costs[geo.chunk_len])
-    return fn
-
-
-def _fake_measure(speeds, fingerprints=None):
-    """measure_fn keyed on chunk_len; same fingerprint everywhere
-    unless a divergent one is injected."""
-    def fn(geo):
-        fp = (fingerprints or {}).get(geo.chunk_len, "identical")
-        return {"sps": float(speeds[geo.chunk_len]), "fps": 1.0,
-                "p50_ms": 1.0, "p99_ms": 2.0, "fingerprint": fp}
-    return fn
-
-
-def _fake_search_space(base):
-    cands = [("half", base.replace(chunk_len=base.chunk_len // 2)),
-             ("double", base.replace(chunk_len=base.chunk_len * 2))]
-    costs = {base.chunk_len: {"bytes_per_sample": 10.0,
-                              "flops_per_sample": 10.0},
-             base.chunk_len // 2: {"bytes_per_sample": 15.0,
-                                   "flops_per_sample": 15.0},
-             base.chunk_len * 2: {"bytes_per_sample": 8.0,
-                                  "flops_per_sample": 8.0}}
-    speeds = {base.chunk_len: 100.0, base.chunk_len // 2: 150.0,
-              base.chunk_len * 2: 130.0}
-    return cands, costs, speeds
-
-
-def test_default_candidates_carry_fused_demap_axis():
-    # ISSUE 20: the rate-switched fused front makes fused_demap a
-    # measured axis on the mixed/stream path — the default search
-    # space must offer the lever alone AND the joint chunk x fused
-    # move (the fused kernel shifts the bytes/flops balance, so the
-    # chunk length that wins unfused need not win fused)
-    base = Geometry().resolve()
-    assert not base.fused_demap
-    cands = dict(autotune.default_candidates(base))
-    assert cands["fused_demap"].fused_demap is True
-    assert cands["fused_demap"].chunk_len == base.chunk_len
-    joint = cands[f"chunk{base.chunk_len * 2}_fused"]
-    assert joint.fused_demap is True
-    assert joint.chunk_len == base.chunk_len * 2
-    # an already-fused base does not re-offer the axis
-    fused_base = base.replace(fused_demap=True)
-    assert not any("fused" in label for label, _ in
-                   autotune.default_candidates(fused_base))
-
-
-def test_autotune_cost_prune_rejects_analytically_worse():
-    base = Geometry().resolve()
-    cands, costs, speeds = _fake_search_space(base)
-    out = autotune.run(base=base, candidates=cands,
-                       cost_fn=_fake_cost(costs),
-                       measure_fn=_fake_measure(speeds),
-                       record=False, device_kind="faketpu",
-                       platform="cpu", log=lambda s: None)
-    # "half" is analytically worse: pruned BEFORE measurement, so its
-    # (faster!) fake measurement can never make it the winner
-    assert [r["label"] for r in out["pruned"]] == ["half"]
-    assert out["winner"] == "double"
-    assert out["speedup"] == pytest.approx(1.3)
-    assert out["sps_tuned"] == pytest.approx(130.0)
-    assert out["baseline_sps"] == pytest.approx(100.0)
-
-
-def test_autotune_identity_gate_rejects_divergent_emissions():
-    base = Geometry().resolve()
-    cands, costs, speeds = _fake_search_space(base)
-    out = autotune.run(
-        base=base, candidates=cands, cost_fn=_fake_cost(costs),
-        measure_fn=_fake_measure(
-            speeds, fingerprints={base.chunk_len * 2: "DIVERGED"}),
-        record=False, device_kind="faketpu", platform="cpu",
-        log=lambda s: None)
-    # the only survivor diverged -> the default wins by default
-    assert out["identity_rejected"] == ["double"]
-    assert out["winner"] == "default"
-    assert out["speedup"] == pytest.approx(1.0)
-
-
-def test_autotune_deterministic_and_tuned_reloads(tmp_path):
-    ledger = str(tmp_path / "traj.jsonl")
-    base = Geometry().resolve()
-    cands, costs, speeds = _fake_search_space(base)
-    kw = dict(base=base, candidates=cands, cost_fn=_fake_cost(costs),
-              measure_fn=_fake_measure(speeds), record=True,
-              path=ledger, device_kind="faketpu", platform="cpu",
-              log=lambda s: None)
-    out1 = autotune.run(**kw)
-    out2 = autotune.run(**kw)
-    # injected fakes -> the whole search is a pure function
-    for k in ("winner", "geometry", "sps_tuned", "baseline_sps",
-              "speedup", "pruned", "identity_rejected"):
-        assert out1[k] == out2[k]
-    # the record landed, keyed by device_kind, and tuned() reloads it
-    recs = [json.loads(ln) for ln in open(ledger)]
-    assert [r["stage"] for r in recs] == ["autotune", "autotune"]
-    assert all(r["device_kind"] == "faketpu" and
-               r["metric"] == "sps_tuned" for r in recs)
-    g = Geometry.tuned("faketpu", path=ledger)
-    assert g == Geometry.from_dict(out1["geometry"])
-    assert g.chunk_len == base.chunk_len * 2
-    # a different device kind falls back to the default, always
-    assert Geometry.tuned("cpu", path=ledger) == Geometry()
-    assert Geometry.tuned("faketpu",
-                          path=str(tmp_path / "absent")) == Geometry()
-
-
-def test_autotune_ledger_honors_bench_trajectory_env(tmp_path,
-                                                     monkeypatch):
-    ledger = str(tmp_path / "override.jsonl")
-    monkeypatch.setenv("BENCH_TRAJECTORY", ledger)
-    base = Geometry().resolve()
-    cands, costs, speeds = _fake_search_space(base)
-    out = autotune.run(base=base, candidates=cands,
-                       cost_fn=_fake_cost(costs),
-                       measure_fn=_fake_measure(speeds), record=True,
-                       device_kind="faketpu", platform="cpu",
-                       log=lambda s: None)
-    assert out["recorded_to"] == ledger and os.path.exists(ledger)
-    # tuned() reads the same override path by default
-    assert Geometry.tuned("faketpu") == \
-        Geometry.from_dict(out["geometry"])

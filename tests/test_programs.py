@@ -276,26 +276,6 @@ def test_observatory_dedupes_geometry_and_counts_calls():
     assert counts == [1, 3]
 
 
-def test_bench_roofline_prefers_cost_and_keeps_hand_crosscheck():
-    # bench.py's _roofline: with an XLA cost dict the achieved numbers
-    # come from the compiled graph and the hand formula stays as the
-    # cross-check column; without one the source says hand_estimate
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(REPO, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    cost = {"flops": 2e12, "bytes_accessed": 819e9 / 2}
-    r = bench._roofline(128, 4720, 54, 8000, 1.0,
-                        device_kind="TPU v5 lite", cost=cost)
-    assert r["source"] == "xla_cost_analysis"
-    assert r["pct_hbm_peak"] == pytest.approx(50.0, rel=1e-3)
-    assert "hand_gbps" in r and "hand_tflops" in r
-    r2 = bench._roofline(128, 4720, 54, 8000, 1.0)
-    assert r2["source"] == "hand_estimate"
-    assert "pct_hbm_peak" not in r2       # no device kind -> no pct
-
-
 # ------------------------------------------------------------ full driver
 
 

@@ -10,11 +10,9 @@ the same compiled dispatch. Compile counts are measured with
 `utils.dispatch.cache_growth` — lru_cache DELTAS, never cache_clear:
 this module runs inside the full suite, and clearing the shared
 bucketed cache would throw away compiled decoders later test files
-reuse (the per-rate/bucket entries are process-wide state). The
-exact O(rates x log lengths) -> O(log lengths) before/after numbers
-are the bench artifact's job (tools/rx_dispatch_bench.py, which owns
-clean caches in its own process); here the contract is the
-cache-growth SHAPE.
+reuse (the per-rate/bucket entries are process-wide state). Here the
+contract is the cache-growth SHAPE, O(log lengths) and not
+O(rates x log lengths).
 """
 
 import numpy as np

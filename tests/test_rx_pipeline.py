@@ -15,6 +15,9 @@ from the receiver's own store and is written again only once nothing
 but that store holds it (the last section).
 """
 
+import os
+import re
+
 import numpy as np
 import pytest
 
@@ -22,7 +25,7 @@ from benchmark.reference.wifi_rx_ref import np_receive
 from ziria_tpu.backend import framebatch
 from ziria_tpu.phy import link
 from ziria_tpu.runtime import serve
-from ziria_tpu.utils import telemetry
+from ziria_tpu.utils import dispatch, telemetry
 from ziria_tpu.utils.bits import np_bits_to_bytes
 
 N_BYTES = 12     # +4 FCS = the suite's standard 16-byte on-air PSDU
@@ -394,6 +397,85 @@ def test_spans_counter_and_gauge_say_how_the_pipeline_ran(closed_loop):
     assert by_how["launch"].value == 2 * steps - 3
 
 
+# --------------------------- the names the benchmark's harness reads (D13)
+
+#: the two files of the benchmark that take the fleet's attributes by
+#: name; a program PR that renames one breaks every cell and has no
+#: other test to tell it (tier-1 never runs `cell.measure`)
+HARNESS = ("benchmark/harness/cell.py", "benchmark/control.py")
+
+
+def _read_by_name(var):
+    """Every attribute the harness reads off the variable ``var``
+    (`rx`: the receiver, `srv`: the runtime, `_rx`: the module
+    `phy/wifi/rx`), from the two files' source; a span's name in a
+    string (`"rx.fleet.put"`) is none."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    names = set()
+    for path in HARNESS:
+        with open(os.path.join(root, path)) as f:
+            names |= set(re.findall(
+                r"(?<![\w.\"'`])" + var + r"\.(\w+)", f.read()))
+    return names
+
+
+def test_the_names_the_harness_reads_are_there_at_the_shapes_it_uses(
+        corpus):
+    """At the smallest twin's geometry (`wifi-a-mtu-8s`'s: this file's),
+    one chunk-step launched and none yet handed back."""
+    from benchmark.harness import cell
+    from ziria_tpu.phy.wifi import rx as _rx
+
+    streams, _starts, _alt, _as = corpus
+    srv = serve.ServeRuntime(serve.ServeConfig(
+        n_lanes=S, chunk_len=CHUNK, frame_len=FRAME_LEN,
+        max_frames_per_chunk=K, check_fcs=True, shard=False))
+    rx = srv._rx
+    of_rx, of_srv, of_mod = (_read_by_name(v)
+                             for v in ("rx", "srv", "_rx"))
+    # the expression still finds what the harness is known to read
+    assert {"_put", "_jit1", "s", "k", "chunk_len", "frame_len",
+            "n_sym_bucket", "stride", "mesh", "axis", "stats", "carry",
+            "drain_pending"} <= of_rx
+    assert {"_rx", "_lane_sid", "_sessions", "step"} <= of_srv
+    assert of_mod == {"_jit_stream_chunk_multi",
+                      "_jit_stream_decode_multi"}
+    assert [n for n in sorted(of_rx) if not hasattr(rx, n)] == []
+    assert [n for n in sorted(of_srv) if not hasattr(srv, n)] == []
+    for name in of_mod:
+        factory = getattr(_rx, name)
+        assert factory.cache_info().currsize >= 0
+        assert callable(factory.__wrapped__)
+    # the shapes: geometry as integers, the two programs' arguments as
+    # `cell.warm` builds them
+    assert (rx.s, rx.k, rx.chunk_len, rx.frame_len, rx.stride) \
+        == (S, K, CHUNK, FRAME_LEN, STRIDE)
+    assert rx.n_sym_bucket == 8 and rx.mesh is None
+    assert [tuple(a.shape) for a in cell.chunk_shapes(rx)] \
+        == [(S, CHUNK, 2), (S,), (S,), (S,)]
+    assert cell.decode_program(rx) is not None
+    assert rx._pending is None and rx._pending_step is None
+    sampled = cell.SampledStep(srv)
+    for i in range(S):
+        assert srv.connect(f"s{i}").admitted
+        assert srv.submit(f"s{i}", streams[i][:CHUNK + 7]).accepted
+    assert sorted(srv._lane_sid) == list(range(S))
+    assert srv.step() == [] and sampled.kept is None
+    assert rx.stats.chunk_steps == 1 and rx._pending_step == 0
+    assert len(rx._pending) == 7
+    offs, active, arrs, valid, own_lo, own_hi = rx._pending[:6]
+    assert len(offs) == S and sorted(active) == list(range(S))
+    assert arrs.shape == (S, CHUNK, 2) and arrs.dtype == np.float32
+    assert valid.shape == own_lo.shape == own_hi.shape == (S,)
+    assert [rx.carry(i).offset for i in range(S)] == [STRIDE] * S
+    # what the open loop's `staged_at_close` sums: the 7 samples a
+    # session that the step left staged
+    assert [s.staged_samples for s in srv._sessions.values()] == [7] * S
+    assert rx.drain_pending()
+    assert rx._pending is None
+    srv.drain()
+
+
 # ------------------------------- a push that launches nothing (the ready path)
 
 
@@ -473,6 +555,73 @@ def test_a_runtime_step_with_nothing_staged_hands_back_what_is_ready(
     assert got == sorted((i, s) for i in range(S) for s in starts[i]
                          if s < STRIDE)
     srv.drain()
+
+
+# ------------------------------- what a counter over a window must allow
+
+
+def _window(streams, closes_on):
+    """Two launches of warm-up, then a counted interval of three
+    launches that closes straight after the last of them
+    (``"launch"``) or after one more `push_many` that launches nothing
+    and finds the newest scan done (``"ready"``: blocked on here, where
+    a chip's timing would decide). Returns the interval's counts and
+    every frame the receiver handed back, the flush's among them."""
+    import jax
+
+    n = min(len(st) for st in streams)
+    cuts = [0, CHUNK] + list(range(CHUNK + STRIDE, n + 1, STRIDE))
+    rounds = [{i: st[a:b] for i, st in enumerate(streams)}
+              for a, b in zip(cuts, cuts[1:])]
+    assert len(rounds) >= 5
+    rx = framebatch.MultiStreamReceiver(S, **GEO)
+    out = []
+    for push in rounds[:2]:
+        out += rx.push_many(push)
+    # the interval opens after a launch: its scan is queued, its front
+    # half (the decode's dispatch) still to come
+    assert [st.fronted for st in rx._flight] == [True, False]
+    with dispatch.count_dispatches() as d:
+        for push in rounds[2:5]:
+            out += rx.push_many(push)
+        if closes_on == "ready":
+            jax.block_until_ready(
+                framebatch._chunk_scalars(rx._flight[-1].outs))
+            out += rx.push_many({})
+            assert rx._flight[-1].fronted
+    assert rx.stats.chunk_steps == 5
+    for push in rounds[5:]:
+        out += rx.push_many(push)
+    out += rx.push_many({i: st[cuts[-1]:]
+                         for i, st in enumerate(streams)})
+    return dict(d.counts), out + rx.flush()
+
+
+def test_a_window_that_closes_on_a_ready_step_counts_one_decode_more(
+        corpus):
+    """What the pipeline does by design (PR 40) and what a counter of
+    dispatches over a window of it must allow: a step's decode is
+    dispatched a launch after its scan, or by an earlier call that
+    launches nothing and finds the scan done, so a window's decodes are
+    its chunk-steps plus the steps unfronted when it opened less those
+    unfronted when it closed. The benchmark's open loop opens after a
+    launch (one unfronted) and closes on whichever call passes its
+    time: `dispatches_per_chunk_step` reads (2n + 1) / n against a
+    limit of 2.0 where that call launched nothing (ROADMAP B2(i))."""
+    streams, starts, _alt, _as = corpus
+    on_launch, a = _window(streams, "launch")
+    on_ready, b = _window(streams, "ready")
+    assert on_launch["rx.stream_chunk_multi"] == 3
+    assert on_launch["rx.stream_decode_multi"] == 3
+    assert on_ready["rx.stream_chunk_multi"] == 3
+    assert on_ready["rx.stream_decode_multi"] == 3 + 1
+    # which call handed a frame back differs; what came out does not
+    per_a, per_b = _per_stream(a, S), _per_stream(b, S)
+    for i in range(S):
+        assert [f.start for f in per_a[i]] == starts[i]
+        assert [f.start for f in per_b[i]] == starts[i]
+        assert all(_same_result(x.result, y.result)
+                   for x, y in zip(per_a[i], per_b[i]))
 
 
 # ------------------------------------ containment across the split (late)

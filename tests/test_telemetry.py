@@ -1,13 +1,12 @@
 """The runtime telemetry layer (utils/telemetry) and its dispatch
 emitters (ISSUE 7): span nesting and thread-safety, histogram
 quantile bounds vs exact sorted percentiles, Chrome trace-event JSON
-schema validity, the trace_report summarizer, the Prometheus-style
-exposition, the dispatch/gauge/compile emitter wiring, and — because
+schema validity, the Prometheus-style exposition, the
+dispatch/gauge/compile emitter wiring, and — because
 the hot paths carry their instrumentation permanently — a pinned
 near-zero-overhead check for the disabled path."""
 
 import gc
-import importlib.util
 import json
 import math
 import os
@@ -18,16 +17,6 @@ import numpy as np
 import pytest
 
 from ziria_tpu.utils import dispatch, telemetry
-
-TOOLS = os.path.join(os.path.dirname(__file__), "..", "tools")
-
-
-def _load_trace_report():
-    spec = importlib.util.spec_from_file_location(
-        "trace_report", os.path.join(TOOLS, "trace_report.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 @pytest.fixture(autouse=True)
@@ -308,36 +297,6 @@ def test_chrome_trace_json_schema(tmp_path):
     assert by_ph["C"][0]["args"]["value"] == 2.0
 
 
-def test_trace_report_summarizes_real_trace(tmp_path):
-    path = tmp_path / "trace.json"
-    with telemetry.tracing(str(path)) as tr:
-        for _ in range(4):
-            with telemetry.span("rx.stream_chunk"):
-                time.sleep(0.001)
-        with telemetry.span("rx.stream_decode"):
-            pass
-        tr.counter("rx.stream_inflight", 2.0)
-        telemetry.record_compile("xla:fake", seconds=0.5)
-        telemetry.record_compile("cache_growth:_jit_x", n=2)
-    tr_mod = _load_trace_report()
-    summary, table = tr_mod.summarize_file(str(path))
-    spans = summary["spans"]
-    assert spans["rx.stream_chunk"]["count"] == 4
-    assert spans["rx.stream_chunk"]["p50_ms"] >= 1.0
-    assert spans["rx.stream_chunk"]["p99_ms"] >= \
-        spans["rx.stream_chunk"]["p50_ms"]
-    assert spans["rx.stream_chunk"]["total_ms"] >= 4.0
-    assert summary["compiles"]["xla:fake"]["total_ms"] == \
-        pytest.approx(500.0, rel=1e-3)
-    assert summary["compile_markers"] == {"cache_growth:_jit_x": 2}
-    assert summary["counters"]["rx.stream_inflight"]["max"] == 2.0
-    for needle in ("rx.stream_chunk", "xla:fake", "p99 ms",
-                   "rx.stream_inflight"):
-        assert needle in table
-    # and the CLI entry point parses the same file
-    assert tr_mod.main([str(path)]) == 0
-
-
 # ------------------------------------------------------ dispatch emitters
 
 
@@ -498,7 +457,6 @@ def test_cli_trace_and_metrics_dump(tmp_path, capsys):
     assert os.environ.get("ZIRIA_TRACE") is None     # scoped, restored
     obj = json.loads(tracef.read_text())
     assert isinstance(obj["traceEvents"], list)
-    _summary, table = _load_trace_report().summarize_file(str(tracef))
     err = capsys.readouterr().err
     assert "telemetry trace written to" in err
     # the exposition dump ran (its marker line always prints; the

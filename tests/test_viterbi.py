@@ -90,7 +90,7 @@ def test_native_simd_acs_bit_exact_with_scalar():
     # the AVX2 ACS (runtime/native/viterbi.c, the SORA-SSE-class
     # baseline kernel) must match the portable scalar path bit-for-bit
     # on random soft values — same op order, same tie-breaks, same
-    # per-step renorm (BASELINE.md r3)
+    # per-step renorm
     import ctypes
 
     from ziria_tpu.runtime.native_lib import load, viterbi_decode_native

@@ -5,8 +5,7 @@ Chip time comes in short windows; each tool banks every finished unit of work
 the next window only on what is missing. One implementation so the
 aging rules cannot diverge between tools (review r5): every entry
 carries its OWN capture time ``_t`` and ages out individually —
-re-banking a new entry must not revive old ones (the same
-chained-resume hazard bench.py's ``captured_t`` guards against).
+re-banking a new entry must not revive old ones.
 """
 
 from __future__ import annotations

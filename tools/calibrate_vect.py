@@ -3,8 +3,8 @@
 VERDICT r1 next-round #5: the model (core/vectorize.py STEP_OVERHEAD /
 VPU_PARALLEL) picked widths no measurement had ever contacted. This
 harness times representative pipelines at W in {pick/4, pick, 4*pick}
-on the real chip using the device-loop marginal method (see bench.py:
-per-call timing measures the host link, not the chip) and reports
+on the real chip using the device-loop marginal method (per-call
+timing measures the host link, not the chip) and reports
 whether the model's pick is within tolerance of the measured best.
 
     python tools/calibrate_vect.py            # needs the TPU reachable
@@ -153,8 +153,8 @@ def main() -> int:
 
     from ziria_tpu.core.vectorize import vectorize
 
-    # per-pipeline resume across window flaps (same idea as bench.py's
-    # stage resume): each finished pipeline is banked in the scratch
+    # per-pipeline resume across window flaps (tools/_bank.py): each
+    # finished pipeline is banked in the scratch
     # dir with its own capture time; a re-entering run on the same
     # platform reuses the still-fresh ones and spends the (possibly
     # short) window on what is missing.

@@ -19,7 +19,7 @@ import sys
 import time
 
 # run as a script from tools/: only tools/ lands on sys.path, the repo
-# root is not — same bootstrap as rx_dispatch_bench.py
+# root is not
 sys.path.insert(0, os.path.join(os.path.dirname(
     os.path.abspath(__file__)), ".."))
 

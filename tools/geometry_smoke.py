@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """jax-free smoke of the declarative geometry layer (ISSUE 16).
 
-Constructs, resolves, serializes, and tuned()-round-trips
+Constructs, resolves and serializes
 `ziria_tpu.utils.geometry.Geometry` WITHOUT importing jax — the same
 through-TPU-probe-hangs discipline as chaos/serve/durability smokes —
 and pins that the default Geometry still resolves to the tree's
@@ -10,15 +10,13 @@ rests on exactly these values; tests/test_geometry.py pins the
 compiled side). Wired into tools/precommit.sh. Sub-second.
 """
 
-import json
+import dataclasses
 import os
 import sys
-import tempfile
 
 sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.abspath(__file__)), ".."))
 
-from ziria_tpu.utils import geometry  # noqa: E402
 from ziria_tpu.utils.geometry import Geometry  # noqa: E402
 
 checks = 0
@@ -71,7 +69,7 @@ def main():
            f"resolve() missed the env knobs: {r}")
         ok(g.viterbi_radix is None,
            "resolve() mutated the source geometry")
-        explicit = g.replace(viterbi_radix=2).resolve()
+        explicit = dataclasses.replace(g, viterbi_radix=2).resolve()
         ok(explicit.viterbi_radix == 2,
            "an explicit field lost to the env default")
     finally:
@@ -94,26 +92,6 @@ def main():
         ok(False, "from_dict accepted an unknown field")
     except ValueError:
         pass
-
-    # tuned(): reconstructs a ledger winner; degrades to default on
-    # any miss (absent ledger, foreign device, malformed record)
-    with tempfile.TemporaryDirectory() as td:
-        ledger = os.path.join(td, "traj.jsonl")
-        ok(Geometry.tuned("v5e", path=ledger) == Geometry(),
-           "tuned() with no ledger is not the default")
-        win = r.replace(chunk_len=16384)
-        with open(ledger, "w") as f:
-            f.write("garbage line\n")
-            f.write(json.dumps({
-                "stage": "autotune", "metric": "sps_tuned",
-                "value": 1.0, "unix": 1.0, "device_kind": "v5e",
-                "geometry": win.as_dict()}) + "\n")
-        ok(Geometry.tuned("v5e", path=ledger) == win,
-           "tuned() did not reconstruct the recorded winner")
-        ok(Geometry.tuned("cpu", path=ledger) == Geometry(),
-           "tuned() served a v5e winner to a cpu device")
-        ok(geometry.latest_tuned_record("cpu", path=ledger) is None,
-           "latest_tuned_record matched across device kinds")
 
     ok("jax" not in sys.modules,
        "a geometry code path imported jax")

@@ -8,7 +8,7 @@ the interpreter oracle.
 Emits one JSON line: platform, per-frame cold/warm wall times, and the
 bit-exactness verdict. Wall times include the host-side control loop
 (the hybrid design point), so they are NOT a throughput claim — the
-throughput metric is bench.py's batched library receiver.
+throughput metric is the benchmark's (`benchmark/run.py`, PERF.md).
 """
 
 from __future__ import annotations

@@ -20,15 +20,14 @@ crash and a ``ServeRuntime.recover``. Gates:
   subsets (quarantine drops, never corrupts); truncate-poisoned
   sessions gate on no-crash only (their stream genuinely differs).
 - **recovery latency SLO**: ``recover()`` wall time per round, gated
-  at p99 (the bench ledger's ``recovery_p99_s``, lower is better).
+  at p99 (``recovery_p99_s``, lower is better).
 - **dispatch budget after recovery**: <= 2 dispatches per chunk-step
   on the recovered fleet, under ``dispatch.no_recompile`` for the
   unchanged-geometry case — recovery must not cost the compiled
   programs their one-compile contract.
 
-``bench.py soak`` rides :func:`soak_stats` (resumable, never-fatal,
-smoke-sized on CPU); ``--child`` is the subprocess serving loop the
-SIGKILL rounds shoot. The jax-free protocol canary is
+:func:`soak_stats` is the campaign (smoke-sized on CPU); ``--child``
+is the subprocess serving loop the SIGKILL rounds shoot. The jax-free protocol canary is
 tools/durability_smoke.py — this harness is the full-device proof.
 """
 
@@ -425,10 +424,10 @@ def soak_stats(n_sessions: int = 3, n_lanes: int = 4,
                recovery_slo_s: float = 30.0,
                tick_sleep: float = 0.05,
                channel_profile: str = "urban") -> dict:
-    """The bench-facing campaign (``bench.py soak``): in-process
+    """The campaign: in-process
     fault rounds (alternating clean-data / dirty-data spec draws) +
     real SIGKILL subprocess rounds, all gated, recovery latencies
-    aggregated to the ledger metric ``recovery_p99_s``. The campaign
+    aggregated to ``recovery_p99_s``. The campaign
     additionally runs ONE multipath-active round (ISSUE 15): every
     client's stream rides the named physical-channel profile
     (phy/profiles; an equalizable tap set, so the oracle is complete)

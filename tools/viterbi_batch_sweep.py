@@ -69,8 +69,8 @@ def main():
     def fence(x):
         np.asarray(x.ravel()[:1])
 
-    # per-point resume across window flaps (same idea as bench.py's
-    # stage resume): finished B points are banked in the scratch dir
+    # per-point resume across window flaps (tools/_bank.py):
+    # finished B points are banked in the scratch dir
     # keyed by platform+T with per-point capture times, so a window
     # that dies after B=256 spends its successor on 512/1024.
     import _bank

@@ -385,8 +385,8 @@ class LintResult:
 def lint_paths(paths: Sequence[str],
                rules: Optional[Sequence[Rule]] = None) -> LintResult:
     """Lint every ``.py`` under ``paths`` (files or directories).
-    The library entry the CLI, the tier-1 gate
-    (tests/test_lint_clean.py), and bench.py's ``lint`` stage share."""
+    The library entry the CLI and the tier-1 gate
+    (tests/test_lint_clean.py) share."""
     findings: List[Finding] = []
     suppressed = 0
     files = iter_py_files(paths)

@@ -24,7 +24,7 @@ Each rule encodes one recurring bug class of the repo's own history
       (chunk_len / K / S / viterbi window / radix / bucket floors) at
       a jit-factory call site, or a literal ``pow2_bucket`` floor,
       bypasses `utils/geometry.Geometry` and forks the compiled
-      geometry from the autotuner's tuned winner (ISSUE 16).
+      geometry from the one its caller was given (ISSUE 16).
 
 Jit factories are DISCOVERED (an ``@lru_cache`` def whose body calls
 ``jax.jit``), never hardcoded, so the rules keep covering factories
@@ -442,8 +442,8 @@ class GeometryHygiene(Rule):
     why = ("a numeric literal for a known tunable at a jit-factory "
            "call site (or a literal pow2_bucket floor) bypasses the "
            "Geometry object: the literal and Geometry's default can "
-           "drift apart, and the autotuner's tuned() winner never "
-           "reaches that surface")
+           "drift apart, and a caller's geometry never reaches that "
+           "surface")
 
     def check(self, ctx: Context) -> None:
         mod = ctx.module

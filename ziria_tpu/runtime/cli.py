@@ -133,11 +133,7 @@ def build_parser() -> argparse.ArgumentParser:
                "observatory (CPU-pinned XLA cost/memory attribution; "
                "docs/observability.md); `python -m ziria_tpu serve "
                "[--sessions N] [--chaos SPEC]` runs the "
-               "continuous-batching serving demo (docs/serving.md); "
-               "`python -m ziria_tpu autotune [--frames N] [--reps N]` "
-               "runs the cost-pruned measured geometry search and "
-               "records the per-device winner in the bench ledger "
-               "(docs/autotune.md)")
+               "continuous-batching serving demo (docs/serving.md)")
     p.add_argument("--prog", help="registered pipeline name")
     p.add_argument("--src", help="Ziria-like source file (.zir) to compile")
     p.add_argument("--list-progs", action="store_true")
@@ -229,8 +225,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "dispatch site, gauge counter track, and "
                         "compile event — to PATH "
                         "(utils/telemetry; load in Perfetto / "
-                        "chrome://tracing or summarize with "
-                        "tools/trace_report.py); also via ZIRIA_TRACE")
+                        "chrome://tracing); also via ZIRIA_TRACE")
     p.add_argument("--metrics-dump", action="store_true",
                    help="print a Prometheus-style text exposition of "
                         "the invocation's metrics registry — dispatch "
@@ -488,12 +483,6 @@ def main(argv=None) -> int:
         # itself, so cost attribution needs no chip.
         from ziria_tpu.utils.programs import main as programs_main
         return programs_main(argv[1:])
-    if argv and argv[0] == "autotune":
-        # geometry autotuner (utils/autotune, docs/autotune.md):
-        # cost-pruned measured search; pre-argparse like `lint` —
-        # the winner lands keyed by device_kind in the bench ledger
-        from ziria_tpu.utils.autotune import main as autotune_main
-        return autotune_main(argv[1:])
     if argv and argv[0] == "serve":
         # continuous-batching serving demo (runtime/serve,
         # docs/serving.md): synthetic many-client load through the
@@ -645,8 +634,7 @@ def _main_run(args) -> int:
         # tracing() exports in its own finally, and the exposition /
         # hint print here so ^C or a failing command still reports
         if tpath:
-            print(f"telemetry trace written to {tpath} "
-                  f"(summarize: python tools/trace_report.py {tpath})",
+            print(f"telemetry trace written to {tpath}",
                   file=sys.stderr)
         if reg is not None:
             print("metrics exposition (utils/telemetry):",

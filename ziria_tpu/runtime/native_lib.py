@@ -2,8 +2,8 @@
 
 The reference's runtime is C (`csrc/` — SURVEY.md §2.2); this module
 holds the framework's native CPU components: currently the K=7 Viterbi
-decoder (SORA-brick analogue), used as the honest C baseline in
-bench.py and as a host-side fallback decoder. Builds on demand with
+decoder (SORA-brick analogue), a host-side fallback decoder and the
+C side of the ops' cross-checks. Builds on demand with
 ``make`` (gcc); everything degrades gracefully to the numpy/jax paths
 if no toolchain is present.
 """

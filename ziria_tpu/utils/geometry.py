@@ -30,18 +30,12 @@ of them into one frozen, hashable dataclass:
   ``ServeConfig``, ``link.loopback_many``, ``rx.receive``) accept a
   ``geometry=`` and derive the exact scalar tuples the ``_jit_*``
   factories cache on. Two geometries that agree on a factory's knobs
-  share its compiled program (a tuned ``chunk_len`` never forks the
+  share its compiled program (another ``chunk_len`` never forks the
   decode caches), and data-dependent buckets (``n_sym_bucket`` from
   an input's length) stay derived-per-call through the bucket *rules*
   this object owns (:meth:`sym_bucket` / :meth:`capture_bucket` /
   :meth:`bit_bucket` — jaxlint R6 flags literal floors at call
   sites).
-- **tuned() loads the measured per-device winner.** The autotuner
-  (:mod:`ziria_tpu.utils.autotune`, ``python -m ziria_tpu autotune``)
-  records winners keyed by ``device_kind`` into the bench trajectory
-  ledger; :meth:`Geometry.tuned` reconstructs the latest matching
-  record, falling back to the default on any miss — an absent ledger,
-  an unknown device, a malformed record (docs/autotune.md).
 
 jax-free by design (like runtime/serve and utils/telemetry): the
 geometry must be constructible, resolvable, and serializable through
@@ -63,10 +57,6 @@ from ziria_tpu.utils.dispatch import pow2_bucket
 VITERBI_METRICS = ("float32", "int16", "int8")
 #: valid Viterbi ACS radixes — ops/viterbi.RADIXES aliases this
 VITERBI_RADIXES = (2, 4)
-
-#: ledger file the autotuner records winners into (repo root; the
-#: BENCH_TRAJECTORY env var overrides, exactly like bench.py)
-TRAJECTORY_BASENAME = "BENCH_TRAJECTORY.jsonl"
 
 
 # --------------------------------------------------- designated env readers
@@ -132,18 +122,6 @@ def env_sco_track() -> bool:
     contract pins the default DATA decode bitwise): pilot phase-ramp
     tracking for sampling-clock offset."""
     return os.environ.get("ZIRIA_RX_SCO_TRACK", "0") == "1"
-
-
-def env_trajectory_path() -> str:
-    """The ONE reading of the BENCH_TRAJECTORY ledger-path override
-    (bench.py and tools/perf_report.py honor the same variable);
-    default: the repo-root ledger next to this package."""
-    p = os.environ.get("BENCH_TRAJECTORY")
-    if p:
-        return p
-    root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    return os.path.join(root, TRAJECTORY_BASENAME)
 
 
 # --------------------------------------------------------------- the object
@@ -223,11 +201,6 @@ class Geometry:
             sco_track=(env_sco_track() if self.sco_track is None
                        else bool(self.sco_track)))
 
-    def replace(self, **changes: Any) -> "Geometry":
-        """`dataclasses.replace` convenience (the autotuner's candidate
-        enumeration is built from these)."""
-        return dataclasses.replace(self, **changes)
-
     # ---------------------------------------------------- serialization
 
     def as_dict(self) -> Dict[str, Any]:
@@ -239,8 +212,8 @@ class Geometry:
     @classmethod
     def from_dict(cls, d: Dict[str, Any]) -> "Geometry":
         """Strict inverse of :meth:`as_dict`: unknown keys raise (a
-        ledger record from a future field set must not silently drop
-        a tunable — :meth:`tuned` catches and falls back)."""
+        record from a future field set must not silently drop a
+        field)."""
         known = {f.name for f in dataclasses.fields(cls)}
         unknown = sorted(set(d) - known)
         if unknown:
@@ -252,75 +225,7 @@ class Geometry:
     def from_json(cls, s: str) -> "Geometry":
         return cls.from_dict(json.loads(s))
 
-    # ------------------------------------------------------ tuned winner
-
-    @classmethod
-    def tuned(cls, device_kind: Optional[str] = None,
-              path: Optional[str] = None) -> "Geometry":
-        """The latest autotuner winner recorded for ``device_kind``
-        (default: this process's jax device kind), reconstructed from
-        the bench trajectory ledger — or the default ``Geometry()``
-        when there is no ledger, no matching record, or a record this
-        build cannot parse. Never raises: the tuned geometry is an
-        optimization, and a stale/foreign ledger must degrade to the
-        hand-picked constants, not crash the receiver."""
-        try:
-            if device_kind is None:
-                device_kind = detect_device_kind()
-            rec = latest_tuned_record(device_kind, path)
-            if rec is None:
-                return cls()
-            return cls.from_dict(rec["geometry"])
-        except Exception:
-            return cls()
-
 
 #: the shared default instance — ctor defaults across framebatch /
 #: serve / link derive from this, so "1 << 13" exists ONCE (above)
 DEFAULT = Geometry()
-
-
-def detect_device_kind() -> Optional[str]:
-    """``jax.devices()[0].device_kind`` — lazily, so this module stays
-    importable (and the smoke runnable) with no jax at all. None when
-    jax or a backend is unavailable."""
-    try:
-        import jax
-
-        return jax.devices()[0].device_kind
-    except Exception:
-        return None
-
-
-def latest_tuned_record(device_kind: Optional[str],
-                        path: Optional[str] = None) -> Optional[Dict]:
-    """Scan the trajectory ledger for the newest ``stage=autotune``
-    record whose ``device_kind`` matches (None matches None: a ledger
-    written where jax could not name the device still serves that same
-    environment). Returns the record dict, or None."""
-    p = path or env_trajectory_path()
-    best = None
-    try:
-        with open(p, "r", encoding="utf-8") as fh:
-            for line in fh:
-                line = line.strip()
-                if not line:
-                    continue
-                try:
-                    rec = json.loads(line)
-                except ValueError:
-                    continue
-                if not isinstance(rec, dict):
-                    continue
-                if rec.get("stage") != "autotune":
-                    continue
-                if "geometry" not in rec:
-                    continue
-                if rec.get("device_kind") != device_kind:
-                    continue
-                if best is None or rec.get("unix", 0) >= best.get(
-                        "unix", 0):
-                    best = rec
-    except OSError:
-        return None
-    return best

@@ -17,7 +17,7 @@ cost is one tuple truthiness check, pinned by
   :func:`span` (and every ``dispatch.timed`` site) records nested,
   per-thread spans with monotonic timestamps. :meth:`Trace.export`
   writes Chrome trace-event JSON, loadable in Perfetto /
-  ``chrome://tracing`` and summarizable with ``tools/trace_report.py``.
+  ``chrome://tracing``.
   ``Trace(annotate_device=True)`` passes each span through
   ``jax.profiler.TraceAnnotation`` so host spans line up with device
   traces when a ``jax.profiler`` capture runs concurrently.
@@ -338,7 +338,7 @@ class Trace:
     monotonic epoch; gauges as counter ("C") tracks; compile events in
     the ``compile`` category. :meth:`export` writes the standard
     ``{"traceEvents": [...]}`` JSON object (Perfetto /
-    ``chrome://tracing`` / ``tools/trace_report.py``).
+    ``chrome://tracing``).
 
     ``epoch`` is the ``time.perf_counter()`` value every event's ``ts``
     is relative to: an event began at ``epoch + ts / 1e6`` seconds on
@@ -350,7 +350,6 @@ class Trace:
         self.annotate_device = annotate_device
         self._lock = threading.Lock()
         self._events: List[Dict[str, Any]] = []
-        self._meta: Dict[str, Any] = {}
         self.epoch = time.perf_counter()
         self._pid = os.getpid()
 
@@ -393,20 +392,8 @@ class Trace:
         with self._lock:
             return list(self._events)
 
-    def set_metadata(self, key: str, value: Any) -> None:
-        """Attach a top-level key to the exported trace object (the
-        Chrome trace format ignores unknown object keys, so riders
-        like the observatory's ``siteCosts`` travel with the events
-        and tools/trace_report.py can join on span labels)."""
-        with self._lock:
-            self._meta[key] = value
-
     def to_json(self) -> Dict[str, Any]:
-        obj: Dict[str, Any] = {"traceEvents": self.events(),
-                               "displayTimeUnit": "ms"}
-        with self._lock:
-            obj.update(self._meta)
-        return obj
+        return {"traceEvents": self.events(), "displayTimeUnit": "ms"}
 
     def export(self, path: Optional[str] = None) -> Dict[str, Any]:
         """The trace as a Chrome trace-event JSON object; written to
