@@ -238,7 +238,8 @@ def measure(args, inspect=None, traffic=None):
         return sum(rx.carry(lane_of[s]).offset for s in sids)
 
     def in_flight() -> list:
-        """Blocks on the chunk-step in flight, if any: its frames."""
+        """Blocks on the chunk-steps in flight, if any: their frames.
+        Both loops call it before the window opens and as it closes."""
         return [(srv._lane_sid[ln], fr) for ln, fr in rx.drain_pending()]
 
     # every XLA compile fires this event; the listener cannot be
@@ -296,7 +297,8 @@ def measure(args, inspect=None, traffic=None):
                         rate, phases[i]) for i in range(len(sids))]
                     win = loop.run_open(srv, sids, laps, arrivals, WARM_TICKS,
                                         args.seconds, stride, chunk_len,
-                                        sleep=time.sleep, **common)
+                                        sleep=time.sleep, drain=in_flight,
+                                        **common)
                 else:
                     raise SystemExit(f"traffic loop {traffic['loop']!r}: "
                                      f"'closed' or 'open'")
@@ -306,18 +308,16 @@ def measure(args, inspect=None, traffic=None):
                     prof.close()
         t_close = win.t_open + win.elapsed_s
         steps = rx.stats.chunk_steps - at_open["chunk_steps"]
+        # both loops have drained the fleet at both edges: these are
+        # the dispatches of the chunk-steps the window launched
         disp = {k: v - at_open["dispatches"].get(k, 0)
                 for k, v in d.counts.items()}
         growth = sum(c.cache_info().currsize for c in jits) \
             - at_open["jit_entries"]
-        # the step the open loop left in flight: its frames belong to
-        # the run (the closed loop has drained its own)
-        tail = [loop.Emitted(win.elapsed_s, session_of(sid), fr)
-                for sid, fr in in_flight()]
         stats = rx.stats
         snap = srv.registry.snapshot()
 
-    emitted = win.emitted + tail
+    emitted = win.emitted
     offsets = [rx.carry(lane_of[s]).offset for s in sids]
     frames = checks.check_frames(emitted, laps, offsets)
     rows = [checks.Compared("frames_attempted", frames.attempted, 1, ">="),
@@ -466,11 +466,11 @@ def measure(args, inspect=None, traffic=None):
         line["breakdown"] = breakdown
     if win.delays_s:
         line["paced"] = paced
-    # every number compared beside its limit: the line's last key, and
-    # the last lines of standard error
-    line["compared"] = {r.name: [checks.plain(r.value), r.limit, r.how]
-                        for r in rows}
-    for r in rows:
+    # why a run is not correct, then every number compared beside its
+    # limit: the line's last key, and the last lines of standard error
+    # (of which the record keeps the end: the failed rows come last)
+    line.update(checks.report(rows))
+    for r in sorted(rows, key=lambda r: not r.ok):
         print(f"compared {r.name} {r.value} {r.how} {r.limit} "
               f"{'ok' if r.ok else 'NOT OK'}", file=sys.stderr)
     sys.stderr.flush()

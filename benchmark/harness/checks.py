@@ -53,6 +53,18 @@ def plain(x):
     return None if x != x else int(x) if x == int(x) else x
 
 
+def report(rows: List["Compared"]) -> Dict[str, dict]:
+    """The result line's last two keys. ``not_ok``: the rows that
+    failed as ``{name: [value, limit]}``, ``{}`` when the run is
+    correct. ``compared``: every number compared beside its limit and
+    its rule, the failed ones first: the ledger kept the first 13 of
+    PR 48's 24 rows, and the one that had refused it was the 16th."""
+    return {"not_ok": {r.name: [plain(r.value), r.limit]
+                       for r in rows if not r.ok},
+            "compared": {r.name: [plain(r.value), r.limit, r.how]
+                         for r in sorted(rows, key=lambda r: r.ok)}}
+
+
 def limits() -> Dict[str, float]:
     with open(os.path.join(os.path.dirname(__file__), "limits.json")) as f:
         return json.load(f)["limits"]
@@ -163,7 +175,13 @@ def check_hidden(stats, counters: Dict[str, int], dispatches: Dict[str, int],
                  chunk_steps: int) -> List[Compared]:
     """Nothing was hidden: no overflow, degrade, quarantine, retry,
     fallback, rescan or compile inside the window, and at most two
-    dispatches per chunk-step, all at the two served sites."""
+    dispatches per chunk-step, all at the two served sites.
+    ``dispatches`` are those of the ``chunk_steps`` the window
+    launched, scan and decode: both loops drain the fleet before they
+    open and before they hand the window back, so a decode is counted
+    with the step it belongs to whichever call dispatched it, and a
+    third dispatch in any step reads over 2.0 where every step
+    decodes."""
     off_path = sum(n for s, n in dispatches.items() if s not in SITES)
     n_disp = sum(dispatches.get(s, 0) for s in SITES)
     rows = [Compared("overflow_chunks", stats.overflow_chunks, 0),

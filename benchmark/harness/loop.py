@@ -6,7 +6,9 @@ Closed loop (``saturated``): each tick submits one slab per session and
 calls ``step()``; input is always waiting, and nothing is in flight
 when the window opens or closes. Open loop (``paced``): slabs
 are submitted when they are due, whatever the server is doing, and
-``step()`` is called whenever something was submitted.
+``step()`` is called whenever something was submitted; it too opens
+and closes on a drained fleet, so that what is counted over it is what
+it launched.
 """
 
 from __future__ import annotations
@@ -114,19 +116,32 @@ def run_open(srv, sids: Sequence, laps, arrivals, warm_ticks: int,
              sleep: Callable[[float], None], rec,
              session_of: Callable[[object], int],
              on_open: Callable[[], None] = lambda: None,
-             on_tick: Optional[Callable[[int, float], None]] = None
+             on_tick: Optional[Callable[[int, float], None]] = None,
+             drain: Callable[[], list] = lambda: []
              ) -> Window:
     """``warm_ticks`` closed-loop ticks of one stride a session
     (set-up: they leave every lane one stride short of its next
-    chunk-step), ``on_open()``, then the open loop from there on in
-    every session's stream. Slab k of session i is due at
+    chunk-step), ``drain()``, ``on_open()``, then the open loop from
+    there on in every session's stream. Slab k of session i is due at
     ``arrivals[i].slab(k)``'s time after the window opens; it is
     submitted at the first loop pass at or after that, and a refused
-    slab stays due. One delay sample per (``step()`` that returns
-    frames, session with frames in it): the step's return time minus
-    the due time of the slab carrying that session's earliest returned
-    frame's ``needed_sample``; frames whose chunk the warm-up filled
-    have no due time and give none."""
+    slab stays due. One delay sample per (``step()`` that
+    returns frames, session with frames in it): the step's return time
+    minus the due time of the slab carrying that session's earliest
+    returned frame's ``needed_sample``; frames whose chunk the warm-up
+    filled have no due time and give none.
+
+    ``drain()`` blocks on every chunk-step in flight and returns its
+    (session, frame) pairs, as ``run_closed``'s. It is called before
+    the window opens and after it has closed (its frames are stamped
+    with the closing time and give no delay sample: the window's time
+    and delays are what they were), so whoever counts dispatches from
+    ``on_open()`` to this function's return counts those of the
+    chunk-steps the window launched, all of them and no others. Until
+    PR 50 a step left unfronted by the warm-up gave the window its
+    decode, and a closing call that launched nothing and found the
+    last scan done did not give one back: (2n + 1) / n dispatches a
+    step with every frame right (PR 48 was refused on it)."""
     n = len(sids)
     nxt = [0] * n
     pos = [0] * n
@@ -135,6 +150,7 @@ def run_open(srv, sids: Sequence, laps, arrivals, warm_ticks: int,
     prefill = min(pos)
     if max(pos) != prefill:
         raise RuntimeError(f"warm-up left the sessions unevenly fed: {pos}")
+    emitted += [Emitted(-1.0, session_of(sid), fr) for sid, fr in drain()]
     delays: List[float] = []
     late: List[float] = []
     refused = ticks = 0
@@ -179,5 +195,7 @@ def run_open(srv, sids: Sequence, laps, arrivals, warm_ticks: int,
             sleep(max(0.0, min(due - now, seconds - now)))
         t = clock() - t_open
         if t >= seconds:
+            emitted += [Emitted(t, session_of(sid), fr)
+                        for sid, fr in drain()]
             return Window(t_open, t, ticks, consumed() - c0, emitted,
                           delays, late, refused)

@@ -52,14 +52,19 @@ def cpu_trace(tmp_path_factory):
     return path
 
 
-def test_annotations_come_back_with_their_stats(cpu_trace):
+MEANT = ("bench.tick", "rx.fleet.stack", "rx.fleet.decode", "serve.stage")
+
+
+def annotations_with_their_stats(cpu_trace, meant):
+    """The traced block's annotations as they were written, in order;
+    ``meant`` picks the ones the case is about out of whatever else the
+    program annotates inside the block."""
     tr = xplane.read(cpu_trace, need_device=False)
     an = annotations.read(cpu_trace, tr)
     assert an.window == tr.window and an.ops == []
     assert an.runs == {"scan": [], "decode": [], "other": []}
-    names = [s.name for s in an.spans]
-    assert names == ["bench.tick", "rx.fleet.stack", "rx.fleet.decode",
-                     "serve.stage"] * 2
+    names = [s.name for s in an.spans if meant(s.name)]
+    assert names == list(MEANT) * 2
     stacks = [s for s in an.spans if s.name == "rx.fleet.stack"]
     assert [s.args for s in stacks] == [
         {"step": 4, "active": 8, "samples": 4096},
@@ -72,6 +77,20 @@ def test_annotations_come_back_with_their_stats(cpu_trace):
     for s in an.spans:
         assert an.window[0] <= s.start <= s.end <= an.window[1]
     assert annotations.chunk_steps(an) == 2
+
+
+def test_annotations_come_back_with_their_stats(cpu_trace):
+    """Pins the exact list of the block's annotations, and fails since
+    PR 41 made a collection inside it one (`rx.pause.gc`):
+    `tests/test_benchmark_harness.py` expects that (KNOWN_FAILURES,
+    strict), and this PR's kind may not edit that file. The case below
+    is this one repaired; the PR that strikes the line there deletes
+    this one."""
+    annotations_with_their_stats(cpu_trace, lambda name: True)
+
+
+def test_the_annotations_it_means_come_back_with_their_stats(cpu_trace):
+    annotations_with_their_stats(cpu_trace, MEANT.__contains__)
 
 
 def test_no_device_op_means_every_reader_reports_nothing(cpu_trace):

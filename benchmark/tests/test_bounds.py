@@ -112,23 +112,22 @@ def test_a_bound_that_parts_from_its_record_is_a_problem():
 NEW = "mtu32x4.saturated"
 
 
-def with_new_cell():
+def with_new_cell(new=NEW, config="wifi-a-mtu-32s-dp4", chips=4):
     """BENCHMARK.json as the next ``model_config`` PR leaves it: one
     more cell, reporting ``samples_per_s`` and ``setup_s``."""
     man = json.loads(json.dumps(MAN))
-    man["workloads"].append({"name": NEW, "config": "wifi-a-mtu-32s-dp4",
-                             "traffic": "saturated", "chips": 4,
+    man["workloads"].append({"name": new, "config": config,
+                             "traffic": "saturated", "chips": chips,
                              "why": "x"})
     next(m for m in man["end_to_end"]
-         if m["name"] == "samples_per_s")["workloads"].append(NEW)
+         if m["name"] == "samples_per_s")["workloads"].append(new)
     return man
 
 
-def test_a_new_cell_needs_no_edit_of_a_file_that_is_there(tmp_path):
+def adding_a_cell(new, man, tmp_path):
     """A PR that adds a cell may add files and entries and may not edit
     a file the benchmark has: with nothing added under ``bounds/`` the
     bounds hold as they are."""
-    man = with_new_cell()
     assert bounds.problems(man) == []
     assert bounds.derive("samples_per_s", REC) \
         == E2E["samples_per_s"]["bound"]
@@ -141,8 +140,8 @@ def test_a_new_cell_needs_no_edit_of_a_file_that_is_there(tmp_path):
     half = 0.5 * E2E["samples_per_s"]["bound"]
     for width, ok in ((0.8 * half, True), (1.6 * half, False)):
         runs = [4.0e7 * (1 + width * (i / 4 - 0.5)) for i in range(5)]
-        with open(os.path.join(there, "cells", NEW + ".json"), "w") as f:
-            json.dump({"cell": NEW, "seeds": [1, 2, 3, 4, 5, 6],
+        with open(os.path.join(there, "cells", new + ".json"), "w") as f:
+            json.dump({"cell": new, "seeds": [1, 2, 3, 4, 5, 6],
                        "metrics": {"samples_per_s": [runs + [3.0e7]] * 2,
                                    "setup_s": [[40.0] * 6] * 2}}, f)
         rec = bounds.load(there)
@@ -150,13 +149,29 @@ def test_a_new_cell_needs_no_edit_of_a_file_that_is_there(tmp_path):
             == E2E["samples_per_s"]["bound"]
         got = bounds.problems(man, rec)
         assert (got == []) == ok
-        assert all(NEW in p and "half" in p for p in got)
+        assert all(new in p and "half" in p for p in got)
     after = {f: open(os.path.join(d, f)).read()
              for d, _s, fs in os.walk(there) for f in fs
-             if f != NEW + ".json"}
+             if f != new + ".json"}
     assert after == before
     # a record of a cell BENCHMARK.json does not have is a problem
-    assert any(NEW in p for p in bounds.problems(MAN, rec))
+    assert any(new in p for p in bounds.problems(MAN, rec))
+
+
+def test_a_new_cell_needs_no_edit_of_a_file_that_is_there(tmp_path):
+    """Names a cell the benchmark has had since PR 37, and fails on it:
+    `tests/test_benchmark_harness.py` expects that (KNOWN_FAILURES,
+    strict), and this PR's kind may not edit that file. The case below
+    is this one repaired; the PR that strikes the line there deletes
+    this one."""
+    adding_a_cell(NEW, with_new_cell(), tmp_path)
+
+
+def test_a_cell_the_benchmark_lacks_needs_no_edit_of_a_file_that_is_there(
+        tmp_path):
+    new = "mtu8.overload"
+    assert new not in [w["name"] for w in MAN["workloads"]]
+    adding_a_cell(new, with_new_cell(new, "wifi-a-mtu-8s", 1), tmp_path)
 
 
 # ------------------------------------- the paced rate and its sweep

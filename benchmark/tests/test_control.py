@@ -69,22 +69,29 @@ def _emit_twice(pairs):
     return pairs + pairs[:1]
 
 
-@pytest.mark.parametrize("breakage,kind", [
-    (_flip_a_bit, "bytes"), (_drop_a_lane, "missing"),
-    (_emit_twice, "delivered twice")])
-def test_broken_timed_path_is_not_correct(monkeypatch, capsys, breakage,
-                                          kind):
+@pytest.mark.parametrize("workload,breakage,kind", [
+    ("mtu8.saturated", _flip_a_bit, "bytes"),
+    ("mtu8.saturated", _drop_a_lane, "missing"),
+    ("mtu8.saturated", _emit_twice, "delivered twice"),
+    ("mtu8.paced", _drop_a_lane, "missing"),
+    ("mtu8.paced", _flip_a_bit, "bytes")])
+def test_broken_timed_path_is_not_correct(monkeypatch, capsys, workload,
+                                          breakage, kind):
     """An answer altered, a part of the batch left out, or a frame
     handed back twice where the receiver produces it: the rest of a run
-    is driven as it is and ``correct`` comes out false."""
+    is driven as it is, in the closed loop and in the open one, and
+    ``correct`` comes out false; the line says which row refused it."""
     from ziria_tpu.backend import framebatch
 
     real = framebatch.MultiStreamReceiver._drain
     monkeypatch.setattr(framebatch.MultiStreamReceiver, "_drain",
                         lambda self, pend: breakage(real(self, pend)))
-    line, _compared = cell.measure(_args("mtu8.saturated", 7))
+    line, _compared = cell.measure(_args(workload, 7))
     assert line["correct"] is False and line["failed"] >= 1
     assert f"'{kind}'" in capsys.readouterr().out
+    assert line["not_ok"]["frames_failed"] == [line["failed"], 0]
+    assert list(line)[-2:] == ["not_ok", "compared"]
+    assert list(line["compared"])[0] == "frames_failed"
 
 
 def test_beacon_cell_refuses_a_frame_that_was_not_sent(monkeypatch):

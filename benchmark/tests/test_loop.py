@@ -1,12 +1,13 @@
 """The window and delay arithmetic on a stub runtime and a fake clock,
 and the traced run's profiled window on the same two loops."""
 
+import types
 from collections import namedtuple
 
 import numpy as np
 import pytest
 
-from benchmark.harness import cell, load, loop, spans
+from benchmark.harness import cell, checks, load, loop, spans
 
 Frame = namedtuple("Frame", "start result")
 Result = namedtuple("Result", "accepted")
@@ -202,12 +203,180 @@ def test_arrivals_are_the_same_for_a_seed_and_look_up_due_times():
     assert a.due_of_sample(first + size) > due
 
 
+def test_steady_arrivals_are_the_formula_to_the_bit():
+    """Slab k is due at phase + first_k / rate, sizes from the seed."""
+    a = load.Arrivals(5000000002, 3, 256, 2048, 4.0e6 / 3, 0.0123)
+    rng = np.random.default_rng([5000000002, 3, 29])
+    first = 0
+    for k in range(2000):
+        assert a.slab(k) == (first, a.size[k],
+                             0.0123 + first / (4.0e6 / 3))
+        assert a.size[k] == int(rng.integers(256, 2048))
+        first += a.size[k]
+
+
 def test_lap_slice_wraps():
     lap = load.Lap(np.arange(20, dtype=np.float32).reshape(10, 2),
                    np.zeros(0, int), [], [])
     got = load.lap_slice(lap, 8, 5)[:, 0]
     assert list(got) == [16, 18, 0, 2, 4]
     assert load.lap_slice(lap, 23, 3)[:, 0].tolist() == [6, 8, 10]
+
+
+# ------------ what a window dispatched: dispatches_per_chunk_step (PR 50)
+
+
+class CountingFleet:
+    """The three-deep pipeline with its dispatches counted: a launch
+    dispatches scan t and then the decode of every older step that
+    lacks one (its front half), and hands back the steps beyond two in
+    flight; a call that launches nothing runs the halves the device
+    has finished, without waiting. One session, a frame a chunk-step.
+    ``twice`` is a step whose decode is dispatched a second time."""
+
+    def __init__(self, clock, scan_s, decode_s, step_s, twice=None):
+        self.clock, self.scan_s, self.decode_s = clock, scan_s, decode_s
+        self.step_s, self.twice = step_s, twice
+        self.fed = self.offset = self.launched = 0
+        self.counts = {"scan": 0, "decode": 0}
+        self.flight = []    # [step, first sample, ready at, fronted]
+
+    def submit(self, sid, slab):
+        self.fed += len(slab)
+        return Result(True)
+
+    def _front(self, st):
+        self.counts["decode"] += 2 if st[0] == self.twice else 1
+        st[2], st[3] = max(self.clock.t, st[2]) + self.decode_s, True
+
+    def _settle(self, scans, depth):
+        for st in self.flight[:len(self.flight) - scans]:
+            if not st[3]:
+                self._front(st)
+        out = []
+        while len(self.flight) > depth:
+            st = self.flight.pop(0)
+            self.clock.t = max(self.clock.t, st[2])
+            out.append((0, Frame(st[1], None)))
+        return out
+
+    def step(self):
+        self.clock.t += self.step_s
+        if self.fed >= CHUNK:
+            self.fed -= STRIDE
+            self.counts["scan"] += 1
+            self.flight.append([self.launched, self.offset,
+                                self.clock.t + self.scan_s, False])
+            self.launched += 1
+            self.offset += STRIDE
+            return self._settle(1, 2)
+        out = []
+        while True:
+            st = next((x for x in self.flight if not x[3]), None)
+            if st is not None and st[2] <= self.clock.t:
+                self._front(st)
+            elif self.flight and self.flight[0][3] \
+                    and self.flight[0][2] <= self.clock.t:
+                out.append((0, Frame(self.flight.pop(0)[1], None)))
+            else:
+                return out
+
+    def drain(self):
+        return self._settle(0, 0)
+
+    def consumed(self):
+        return self.offset
+
+
+def counted_window(seconds, drained, twice=None):
+    """A lane fill every 100 ms against a 30 ms scan: the open loop on
+    a `CountingFleet`, and the row `checks.check_hidden` makes of what
+    was dispatched from `on_open()` to the loop's return."""
+    clock = FakeClock()
+    srv = CountingFleet(clock, scan_s=0.03, decode_s=0.01, step_s=0.001,
+                        twice=twice)
+    arr = [load.Arrivals(11, 0, 5, 6, STRIDE / 0.1, 0.0)]
+    at = {}
+
+    def drain():
+        at["before_tail"] = dict(srv.counts)
+        return srv.drain()
+
+    win = loop.run_open(
+        srv, [0], laps(1), arr, 2, seconds, STRIDE, CHUNK, srv.consumed,
+        clock, clock.sleep, spans.Recorder(), int,
+        on_open=lambda: at.update(counts=dict(srv.counts),
+                                  steps=srv.launched,
+                                  unfronted=sum(not st[3]
+                                                for st in srv.flight)),
+        **({"drain": drain} if drained else {}))
+    steps = srv.launched - at["steps"]
+    disp = {site: srv.counts[k] - at["counts"][k] for site, k in
+            zip(checks.SITES, ("scan", "decode"))}
+    stats = types.SimpleNamespace(
+        overflow_chunks=0, degraded=False, quarantines=0, sanitized=0,
+        lane_blowups=0)
+    row = next(r for r in checks.check_hidden(stats, {}, disp, 0, 0, steps)
+               if r.name == "dispatches_per_chunk_step")
+    return row, steps, at, srv, win
+
+
+def test_a_window_that_closes_on_a_ready_step_reads_two_a_step():
+    """The window's closing call launched nothing and found the newest
+    scan done: its decode is dispatched inside the window, as the
+    decode of the step the warm-up left unfronted was. Until PR 50 the
+    row read (2n + 1) / n there and refused the run (PR 48)."""
+    row, n, at, srv, win = counted_window(2.99, drained=True)
+    assert n == 30 and not srv.flight
+    # nothing was in flight when it opened, and the tail's drain had
+    # nothing to dispatch: the closing call had fronted the last step
+    assert at["unfronted"] == 0
+    assert at["before_tail"] == srv.counts
+    assert (row.value, row.limit, row.how, row.ok) == (2.0, 2.0, "<=", True)
+    # every step's frame came back, the tail's stamped at the close
+    assert len([e for e in win.emitted if e.t >= 0]) == n
+    assert win.delays_s and min(win.delays_s) >= 0.0
+    # the same window as the loop was: one step unfronted at the
+    # opening, none at the close, 2n + 1 dispatches over n steps
+    was, n_was, at_was, _srv, _win = counted_window(2.99, drained=False)
+    assert n_was == n and at_was["unfronted"] == 1
+    assert was.value == pytest.approx((2 * n + 1) / n) and not was.ok
+
+
+def test_a_window_that_closes_before_its_newest_scan_reads_two_too():
+    row, n, at, srv, _win = counted_window(2.96, drained=True)
+    # the newest step's decode was the tail's to dispatch
+    assert srv.counts["decode"] - at["before_tail"]["decode"] == 1
+    assert (row.value, row.ok) == (2.0, True) and n == 30
+
+
+@pytest.mark.parametrize("seconds", [2.99, 2.96])
+def test_a_third_dispatch_in_some_step_is_not_ok(seconds):
+    row, n, _at, _srv, _win = counted_window(seconds, drained=True,
+                                             twice=17)
+    assert row.value == pytest.approx((2 * n + 1) / n)
+    assert row.limit == 2.0 and not row.ok
+
+
+def test_the_rows_that_failed_come_first_in_what_the_line_keeps():
+    rows = [checks.Compared("frames_attempted", 2429, 1, ">="),
+            checks.Compared("frames_failed", 0, 0),
+            checks.Compared("dispatches_per_chunk_step", 1043 / 521, 2.0),
+            checks.Compared("segment_gap_rel", float("nan"), 1.5e-4),
+            checks.Compared("negative_delays", 0, 0)]
+    got = checks.report(rows)
+    # `compared` stays the line's last key; `not_ok` comes before it
+    assert list(got) == ["not_ok", "compared"]
+    assert got["not_ok"] == {
+        "dispatches_per_chunk_step": [1043 / 521, 2.0],
+        "segment_gap_rel": [None, 1.5e-4]}
+    assert list(got["compared"]) == [
+        "dispatches_per_chunk_step", "segment_gap_rel",
+        "frames_attempted", "frames_failed", "negative_delays"]
+    assert got["compared"]["frames_attempted"] == [2429, 1, ">="]
+    sound = checks.report(rows[:2] + rows[4:])
+    assert sound["not_ok"] == {} and list(sound["compared"]) == [
+        "frames_attempted", "frames_failed", "negative_delays"]
 
 
 # ------------------------------------ the profiled window (cell.Profiler)

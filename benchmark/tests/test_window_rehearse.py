@@ -42,3 +42,5 @@ def test_a_traced_rehearsal_reports_the_window_metrics(
     else:
         assert got["launch_wait_ms"]["value"] >= 0.0
         assert got["chunk_flight_ms.window"]["value"] > 0.0
+        assert line["not_ok"] == {}
+        assert list(line)[-2:] == ["not_ok", "compared"]
