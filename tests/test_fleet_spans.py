@@ -124,7 +124,7 @@ def test_every_span_of_the_table_is_recorded(runs):
             assert "step" not in (e.get("args") or {}), e
     assert all(e["args"] == {"sessions": S}
                for e in _named(spans, "serve.step"))
-    assert all(set(e["args"]) == {"step", "lanes"}
+    assert all(set(e["args"]) == {"step", "lanes", "written"}
                and e["args"]["lanes"] == S
                for e in _named(spans, "rx.fleet.ingest"))
 

@@ -833,10 +833,13 @@ def test_the_store_settles_and_the_counter_and_gauge_say_so(
     rx = srv._rx
     fresh = [a["fresh"] for a in _stacks(tr)]
     assert len(fresh) == rx.stats.chunk_steps
-    # two steps in flight as a third is stacked, `kept`, and the first
-    # held to the end: no array is made once each of them has one
+    # two steps in flight as a third is filled, `last`, `kept`, and the
+    # first held to the end: no array is made once each of them has
+    # one. (`last` counts since ISSUE 52: a slab that straddles a chunk
+    # is written on behind the launch it completes, inside the call,
+    # while the caller still holds the step that launch drained.)
     made = sum(fresh)
-    assert made <= rx.stats.max_in_flight + 2
+    assert made <= rx.stats.max_in_flight + 3
     assert fresh == [1] * made + [0] * (len(fresh) - made)
     assert len(rx._staging._arrays) == made
     reg = srv.registry
@@ -857,19 +860,23 @@ ROUNDS = [(0, 1, 2, 3), tuple(range(S)), (4, 5, 6, 7), (0, 2, 4, 6),
 def _ragged(rx, streams, checked):
     """Drive ``rx`` through ROUNDS, the rest of every stream and a
     flush of ragged tails. Every array put is compared with the array a
-    new `np.zeros` and the tails would have made, and the verdict
-    appended (no reference to a staging array is kept)."""
+    new `np.zeros` and the streams would have made, from the samples
+    this function has pushed (``at``) and the chunk-steps it has seen
+    each lane ride (``off``), and the verdict appended (no reference to
+    a staging array is kept)."""
     step, launch = rx._step, rx._launch
     want = []
+    at, off = [0] * S, [0] * S
 
     def _step(active, flushing):
         new = np.zeros((S, CHUNK, 2), np.float32)
         for i in active:
-            t = rx._tails[i]
             if flushing:
-                new[i, :t.shape[0]] = t
+                new[i, :at[i] - off[i]] = streams[i][off[i]: at[i]]
+                off[i] = at[i]
             else:
-                new[i] = t[:CHUNK]
+                new[i] = streams[i][off[i]: off[i] + CHUNK]
+                off[i] += STRIDE
         want.append(new)
         return step(active, flushing)
 
@@ -878,14 +885,16 @@ def _ragged(rx, streams, checked):
         return launch(arrs, *rest)
 
     rx._step, rx._launch = _step, _launch
-    at = [CHUNK - STRIDE] * S
-    out = rx.push_many([st[:CHUNK - STRIDE] for st in streams])
+
+    def push(ends):
+        slabs = {i: streams[i][at[i]: hi] for i, hi in ends.items()}
+        at[:] = [ends.get(i, at[i]) for i in range(S)]
+        return rx.push_many(slabs)
+
+    out = push({i: CHUNK - STRIDE for i in range(S)})
     for lanes in ROUNDS:
-        out += rx.push_many(
-            {i: streams[i][at[i]: at[i] + STRIDE] for i in lanes})
-        for i in lanes:
-            at[i] += STRIDE
-    out += rx.push_many([st[at[i]:] for i, st in enumerate(streams)])
+        out += push({i: at[i] + STRIDE for i in lanes})
+    out += push({i: len(st) for i, st in enumerate(streams)})
     return out + rx.flush()
 
 
@@ -941,10 +950,13 @@ def test_a_scan_lost_a_launch_ago_is_rescanned_from_intact_samples(
     assert rescanned == [(4, True)]
     assert reg.snapshot()["resilience.async_rescans"] == 1
     assert not rx.stats.degraded
-    # two in flight as a third is stacked, and the one held: one more
-    # than a run in which nobody holds one
+    # two in flight as a third is filled, and the one held: one more
+    # than a run in which nobody holds one. The streams end raggedly,
+    # and a step that some lane waits through takes a second array
+    # before its launch (ISSUE 52): one more at most, at the end
     fresh = [a["fresh"] for a in _stacks(tr)]
-    assert fresh == [1] * 4 + [0] * (len(fresh) - 4) and len(fresh) > 5
+    assert fresh[:6] == [1] * 4 + [0] * 2 and len(fresh) > 6
+    assert sum(fresh) == len(rx._staging._arrays) <= 5
     assert np.array_equal(held, held_copy)
     got = _per_stream(out, S)
     whole, _second = _oracle(streams, alt)
@@ -954,6 +966,10 @@ def test_a_scan_lost_a_launch_ago_is_rescanned_from_intact_samples(
 def test_a_step_that_leaves_by_an_exception_gives_its_array_back(
         corpus, monkeypatch):
     streams, _starts, _alt, _as = corpus
+    # of one length: every step carries all the lanes or none (a step
+    # some lane waits through takes a second array: ISSUE 52)
+    n = 2 * min(len(st) for st in streams)
+    streams = [np.concatenate([st, st])[:n] for st in streams]
     monkeypatch.setattr(framebatch, "_ready", lambda arrays: False)
     rx = framebatch.MultiStreamReceiver(S, **GEO)
     real = rx._scan_to_decode
@@ -981,8 +997,236 @@ def test_a_step_that_leaves_by_an_exception_gives_its_array_back(
     # step 3 left before its scan was read: while that scan still runs
     # the runtime holds the array it reads (on this backend the device
     # array may be the host's memory), so the launch behind the raise
-    # may find it held and make one; by the launch after it is back
+    # may find it held and make one; by the launch after it is back.
+    # Step 4 was whole and queued when the raise came: its lanes had
+    # moved on, and nothing launches it a second time (ISSUE 52)
     fresh = [a["fresh"] for a in _stacks(tr)]
-    assert fresh[:5] == [1, 1, 1, 0, 0] and len(fresh) > 7
+    assert fresh[:5] == [1, 1, 1, 0, 0] and len(fresh) > 6
     assert not any(fresh[6:])
+    assert len(fresh) == rx.stats.chunk_steps \
+        == (n - CHUNK) // STRIDE + 2
     assert len(rx._staging._arrays) == sum(fresh) <= 4
+
+
+# ------------------------------------ a sample is written once (ISSUE 52)
+#
+# A lane's pending samples live in the staging array of the step they
+# will ride: a slab is written there as it is pushed, and the
+# `frame_len` overlap is copied forward once, from the array of the
+# launch before. `written` on `rx.fleet.ingest`, `carried` and `moved`
+# on `rx.fleet.stack` and the counter `rx.stage_samples{how}` say how
+# often each happens. These cases run on the programs compiled above.
+
+
+def _launched(rx):
+    """Copies of ``(arrs, active)`` of every launch of ``rx`` from here
+    on, oldest first."""
+    seen, launch = [], rx._launch
+
+    def _launch(arrs, valid, own_lo, own_hi, active, offs):
+        seen.append((arrs.copy(), list(active)))
+        return launch(arrs, valid, own_lo, own_hi, active, offs)
+
+    rx._launch = _launch
+    return seen
+
+
+def _tail_is_the_streams(rx, streams, pushed):
+    for i, st in enumerate(streams):
+        c = rx.carry(i)
+        assert np.array_equal(c.tail, st[c.offset: pushed[i]]), i
+
+
+def test_a_closed_loop_writes_a_sample_once_and_carries_the_overlap_once(
+        closed_loop):
+    srv, _ticks, _tail, _drained, _lane_of, spans = closed_loop
+    steps = srv._rx.stats.chunk_steps
+    written = {}
+    for e in spans:
+        if e["name"] == "rx.fleet.ingest":
+            step = e["args"]["step"]
+            written[step] = written.get(step, 0) + e["args"]["written"]
+    stacks = {e["args"]["step"]: e["args"] for e in spans
+              if e["name"] == "rx.fleet.stack"}
+    assert sorted(stacks) == list(range(steps)) and steps >= 5
+    for step, a in stacks.items():
+        assert a["active"] == S and a["moved"] == 0
+        assert a["carried"] == (S * FRAME_LEN if step else 0)
+        assert written[step] + a["carried"] == S * CHUNK
+    reg = srv.registry
+    assert reg.find("rx.stage_samples", how="written").value \
+        == sum(written.values())
+    assert reg.find("rx.stage_samples", how="carried").value \
+        == (steps - 1) * S * FRAME_LEN
+    assert reg.find("rx.stage_samples", how="moved").value == 0
+    # twice a sample's share of a chunk, where the tails cost four times
+    assert sum(written[s] for s in range(1, steps)) \
+        == (steps - 1) * S * STRIDE
+
+
+#: what each lane but the first holds when the first fills its chunk:
+#: little (they move on to the next array) or much (lane 0 is copied out)
+SPARSE = {"the waiting lanes move on": 100,
+          "the riding lane is copied out": 3000}
+
+
+@pytest.mark.parametrize("regime", list(SPARSE))
+def test_a_sparse_step_carries_zeros_and_loses_no_sample(corpus, regime):
+    streams, _starts, alt, _as = corpus
+    part = SPARSE[regime]
+    rx = framebatch.MultiStreamReceiver(S, **GEO)
+    seen = _launched(rx)
+    pushed = [CHUNK] + [part] * (S - 1)
+    with telemetry.tracing() as tr:
+        out = rx.push_many([st[:n] for st, n in zip(streams, pushed)])
+    arrs, active = seen[0]
+    assert active == [0] and len(seen) == 1
+    assert np.array_equal(arrs[0], streams[0][:CHUNK])
+    assert not arrs[1:].any()
+    (stack,) = _stacks(tr)
+    # the rule: whichever moves fewer samples, by the levels held
+    assert stack["moved"] == min((S - 1) * part, CHUNK)
+    assert stack["active"] == 1 and stack["carried"] == 0
+    _tail_is_the_streams(rx, streams, pushed)
+    # a second sparse step, lane 0's overlap still owed; then the rest
+    out += rx.push_many({0: streams[0][CHUNK: CHUNK + STRIDE]})
+    pushed[0] += STRIDE
+    assert seen[1][1] == [0] and not seen[1][0][1:].any()
+    assert np.array_equal(seen[1][0][0], streams[0][STRIDE: STRIDE + CHUNK])
+    _tail_is_the_streams(rx, streams, pushed)
+    out += rx.push_many([st[n:] for st, n in zip(streams, pushed)])
+    out += rx.flush()
+    whole, _second = _oracle(streams, alt)
+    _assert_same_frames(_per_stream(out, S), whole)
+
+
+def test_a_slab_of_many_chunks_is_consumed_inside_the_call(corpus):
+    streams, _starts, alt, _as = corpus
+    rx = framebatch.MultiStreamReceiver(S, **GEO)
+    slabs = [st.copy() for st in streams]
+    assert min(len(st) for st in slabs) > CHUNK + 3 * STRIDE
+    out = rx.push_many(slabs)
+    assert rx.stats.chunk_steps >= 4 and rx._rest == {}
+    # the caller's buffers are its own again: nothing pending reads them
+    for st in slabs:
+        st[:] = np.nan
+    _tail_is_the_streams(rx, streams, [len(st) for st in streams])
+    out += rx.flush()
+    whole, _second = _oracle(streams, alt)
+    _assert_same_frames(_per_stream(out, S), whole)
+
+
+def test_a_slab_the_gate_refuses_writes_nothing(corpus):
+    streams, _starts, alt, _as = corpus
+    rx = framebatch.MultiStreamReceiver(S, **GEO)
+    cut = CHUNK + 1500
+    out = rx.push_many([st[:cut] for st in streams])
+    was = (rx._fill.copy(), list(rx._level), list(rx._offsets),
+           list(rx._owed))
+    bad = streams[3][cut: cut + 2 * CHUNK].copy()
+    bad[-1, 0] = np.inf
+    with pytest.raises(ValueError, match="stream 3.*non-finite"):
+        rx.push(3, bad)
+    with pytest.raises(ValueError, match="stream 3.*shape"):
+        rx.push(3, np.zeros((4, 3), np.float32))
+    assert np.array_equal(rx._fill, was[0]) and rx._rest == {}
+    assert (rx._level, rx._offsets, rx._owed) == was[1:]
+    out += rx.push_many([st[cut:] for st in streams])
+    out += rx.flush()
+    whole, _second = _oracle(streams, alt)
+    _assert_same_frames(_per_stream(out, S), whole)
+
+
+def test_a_lane_restored_mid_chunk_goes_on_as_the_unbroken_one(corpus):
+    streams, _starts, alt, _as = corpus
+    rx = framebatch.MultiStreamReceiver(S, **GEO)
+    cut = P + 1500                     # an owed overlap and 1500 written
+    j = 2
+    out = rx.push_many([st[:cut] for st in streams])
+    assert rx._owed[j] and rx._level[j] == FRAME_LEN + 1500
+    blob, got = rx.checkpoint(j)
+    out += got
+    tail = rx.carry(j).tail
+    assert np.array_equal(tail, streams[j][3 * STRIDE: cut])
+    out += rx.restore_stream(j, blob)
+    # written as a slab is: no overlap is owed, the level is the tail's
+    assert not rx._owed[j] and rx._level[j] == len(tail)
+    assert np.array_equal(rx.carry(j).tail, tail)
+    # and a lone receiver takes the same blob up where the lane stood
+    lone = framebatch.StreamReceiver(checkpoint=blob, **GEO)
+    alone = lone.push(streams[j][cut:]) + lone.flush()
+    out += rx.push_many([st[cut:] for st in streams])
+    out += rx.flush()
+    whole, _second = _oracle(streams, alt)
+    got = _per_stream(out, S)
+    _assert_same_frames(got, whole)
+    rest = [f for f in whole[j] if f.start >= 3 * STRIDE]
+    assert [f.start for f in alone] == [f.start for f in rest]
+    assert all(_same_result(a.result, b.result)
+               for a, b in zip(alone, rest))
+
+
+def test_flushing_one_lane_leaves_the_part_full_others_intact(corpus):
+    streams, _starts, alt, _as = corpus
+    rx = framebatch.MultiStreamReceiver(S, **GEO)
+    seen = _launched(rx)
+    cut = P + 1500
+    j = S - 1
+    out = rx.push_many([st[:cut] for st in streams])
+    n = len(seen)
+    out += rx.flush_stream(j)
+    arrs, active = seen[n]
+    assert active == [j] and len(seen) == n + 1
+    assert np.array_equal(arrs[j, :cut - 3 * STRIDE],
+                          streams[j][3 * STRIDE: cut])
+    assert not arrs[j, cut - 3 * STRIDE:].any() and not arrs[:j].any()
+    assert rx._level[j] == 0 and rx.carry(j).offset == cut
+    _tail_is_the_streams(rx, streams[:j], [cut] * j)
+    out += rx.push_many({i: streams[i][cut:] for i in range(j)})
+    out += rx.flush()
+    whole, _second = _oracle(streams, alt)
+    got = _per_stream(out, S)
+    for i in range(j):
+        assert [f.start for f in got[i]] == [f.start for f in whole[i]]
+        assert all(_same_result(a.result, b.result)
+                   for a, b in zip(got[i], whole[i]))
+    # lane j: every frame that ended before its stream was cut
+    kept = [f for f in whole[j] if f.start + FRAME_LEN <= cut]
+    assert kept and [f.start for f in got[j]][:len(kept)] \
+        == [f.start for f in kept]
+
+
+def test_the_receiver_keeps_no_array_a_lane_between_calls(corpus):
+    streams, _starts, _alt, _as = corpus
+    rx = framebatch.MultiStreamReceiver(S, **GEO)
+
+    def a_lane_arrays():
+        found = []
+        for name, val in vars(rx).items():
+            items = val.values() if isinstance(val, dict) else \
+                val if isinstance(val, (list, tuple)) else ()
+            found += [name for x in items if isinstance(x, np.ndarray)
+                      and x.ndim == 2 and x.shape[1] == 2]
+        return found
+
+    pushed = [0] * S
+    rng = np.random.default_rng(52)
+    for _ in range(12):
+        ends = {int(i): pushed[i] + int(rng.integers(1, 2 * CHUNK))
+                for i in rng.choice(S, 5, replace=False)}
+        rx.push_many({i: streams[i][pushed[i]: hi]
+                      for i, hi in ends.items()})
+        for i, hi in ends.items():
+            pushed[i] = min(hi, len(streams[i]))
+        assert not hasattr(rx, "_tails") and a_lane_arrays() == []
+        assert rx._rest == {}
+        _tail_is_the_streams(rx, streams, pushed)
+    assert rx.stats.chunk_steps >= 3
+    # what the lanes hold is in ONE array of the store, or still in
+    # the array launched last
+    assert sum(rx._level) == sum(rx.carry(i).tail.shape[0]
+                                 for i in range(S))
+    for held in (rx._fill, rx._prev):
+        assert held is None or any(held is a
+                                   for a in rx._staging._arrays)
+    rx.flush()

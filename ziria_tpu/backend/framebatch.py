@@ -419,7 +419,9 @@ def receive_many_device(x_dev, n_lanes: int, check_fcs: bool = False,
 # decode while the host stacks the next step (in-flight depth on the
 # `utils/dispatch.record_gauge("rx.stream_inflight")` gauge). A step's
 # stacked host array comes from the receiver's own store and is written
-# again only once nothing else holds it (`_Staging`). The
+# again only once nothing else holds it (`_Staging`); a pushed slab is
+# written ONCE, straight into the array of the step it will ride, and
+# the `frame_len` overlap is copied forward once (`_write`, `_step`). The
 # stream axis shards over the dp mesh (`parallel/batch.frame_mesh` /
 # `lane_sharding`, `jax.shard_map` — multihost-ready through
 # `parallel/multihost.build_mesh`, dp being the axis with no
@@ -452,7 +454,8 @@ class StreamFrame(NamedTuple):
 
 class StreamCarry(NamedTuple):
     """The cross-chunk carry the receiver threads internally: the
-    not-yet-owned tail samples, the stream coordinate of their first
+    not-yet-owned tail samples (a copy, gathered from the staging
+    arrays they wait in), the stream coordinate of their first
     sample, the frames emitted so far, and the dedupe watermark (the
     offset below which no future chunk can re-own a start — the
     `_seen` set holds only entries at or above it, O(K) per stream).
@@ -782,7 +785,8 @@ class _InFlight:
     (`_drain`) reads the decode and emits. Each half keeps here what
     the next one, or its containment, needs: the host arrays of the
     step (a lost scan is rescanned from them, the oracle twin slices
-    its windows out of them; ``arrs`` is the store's, `_Staging`, and
+    its windows out of them, the next step's carry reads its lanes'
+    overlap from them; ``arrs`` is the store's, `_Staging`, and
     holding it here is what keeps it this step's), the scan's outputs
     (`segs` among them, the decode's input and its re-dispatch's), and
     the decode's tables and outputs. ``step`` tags every span of either half, and
@@ -822,12 +826,16 @@ class _Staging:
     """The host arrays a receiver stacks its chunk-steps in: made once,
     used again. ONE rule says when: an array is written again only when
     nothing but this store holds it, read from the interpreter's own
-    count of references as a step is stacked. A step in flight holds
-    its array (`_InFlight.arrs`: a lost scan is re-put from it, the
-    oracle twin slices its windows out of it), a put whose copy has not
-    finished holds it (the runtime keeps the array, or the shard views
-    whose base it is, until it is done with the memory), and so does a
-    caller that kept what `_pending` showed it; each lets go by
+    count of references as an array is asked for. The receiver holds
+    the array it is FILLING (`MultiStreamReceiver._fill`: pushed slabs
+    are written into it as they come) and the one it launched last
+    while a lane's overlap still waits in it (`_prev`), a step in
+    flight holds its array (`_InFlight.arrs`: a lost scan is re-put
+    from it, the oracle twin slices its windows out of it), a put whose
+    copy has not finished holds it (the runtime keeps the array, or the
+    shard views whose base it is, until it is done with the memory),
+    and so does a caller that kept what `_pending` showed it; each lets
+    go by
     dropping its reference, a step that leaves flight by an exception
     too, and there is no release call to forget. An array somebody
     still holds is passed over and stays theirs for as long as they
@@ -835,9 +843,10 @@ class _Staging:
     grows to the most that were ever held at once, and one.
 
     ``stale[i]`` beside an array says lane i still holds samples of
-    the array's last use: `_step` zeroes such a lane where the new step
-    leaves it idle, so idle lanes ride zeros as they do in a new
-    array, and nothing is zeroed where every lane is carried."""
+    an earlier use beyond what has been written into it since: `_step`
+    zeroes such a lane where the new step leaves it idle, so idle lanes
+    ride zeros as they do in a new array, and nothing is zeroed where
+    every lane is carried."""
 
     _UNHELD = _unheld_refs()
 
@@ -852,7 +861,7 @@ class _Staging:
         return sum(a.nbytes for a in self._arrays)
 
     def take(self):
-        """``(array, stale, fresh)`` for the step being stacked: the
+        """``(array, stale, fresh)`` for a step to be filled: the
         first array nobody holds, or a new one of zeros (``fresh``)."""
         for k in range(len(self._arrays)):
             if sys.getrefcount(self._arrays[k]) == self._UNHELD:
@@ -1061,8 +1070,21 @@ class MultiStreamReceiver:
         self._lane_blowups = 0
         self._degraded = False        # fleet decode -> oracle twin
         self._scan_degraded = False   # fleet scan -> eager twin
-        self._tails = [np.zeros((0, 2), np.float32)
-                       for _ in range(self.s)]
+        # a lane's pending samples live in the staging array of the
+        # step they will ride: `_level[i]` of them from sample
+        # `_offsets[i]` of the stream on, at `_fill[i, :_level[i]]`
+        # (`_write`), but for the `frame_len` overlap of a lane that
+        # rode the last launch, which waits in `_prev[i, stride:]` until
+        # the next step copies it forward (`_owed[i]`, `_step`). What a
+        # slab holds beyond its lane's chunk waits in `_rest` for the
+        # launch that makes room, inside the call that brought it
+        self._level = [0] * self.s
+        self._owed = [False] * self.s
+        self._fill: Optional[np.ndarray] = None
+        self._fill_stale: Optional[np.ndarray] = None
+        self._fill_fresh = False
+        self._prev: Optional[np.ndarray] = None
+        self._rest: dict = {}
         self._offsets = [0] * self.s
         self._emitted = [0] * self.s
         self._watermarks = [0] * self.s
@@ -1102,9 +1124,39 @@ class MultiStreamReceiver:
         """Stream `stream`'s live :class:`StreamCarry` (tail, offset,
         emitted, dedupe watermark) — read-only observability."""
         stream = self._check_stream(stream)
-        return StreamCarry(self._tails[stream], self._offsets[stream],
+        return StreamCarry(self._tail(stream), self._offsets[stream],
                            self._emitted[stream],
                            self._watermarks[stream])
+
+    def _held(self, lane: int) -> int:
+        """Samples of ``lane`` that lie in the array being filled
+        (its level less an overlap that is still owed)."""
+        return self._level[lane] - (self.frame_len if self._owed[lane]
+                                    else 0)
+
+    def _depth(self) -> int:
+        """Samples the fleet holds for steps to come."""
+        return sum(self._level) + sum(
+            a.shape[0] for slabs in self._rest.values() for a in slabs)
+
+    def _tail(self, lane: int) -> np.ndarray:
+        """A lane's pending samples gathered into an array of their
+        own: what `carry` shows and a checkpoint keeps (a copy, off
+        the push path)."""
+        v = self._level[lane]
+        rest = self._rest.get(lane, ())
+        out = np.empty((v + sum(a.shape[0] for a in rest), 2),
+                       np.float32)
+        lo = 0
+        if self._owed[lane]:
+            lo = self.frame_len
+            out[:lo] = self._prev[lane, self.stride:]
+        if v > lo:
+            out[lo:v] = self._fill[lane, lo:v]
+        for a in rest:
+            out[v:v + a.shape[0]] = a
+            v += a.shape[0]
+        return out
 
     @property
     def carries(self) -> List[StreamCarry]:
@@ -1202,11 +1254,75 @@ class MultiStreamReceiver:
 
     # -- the push surface -----------------------------------------------
 
-    def _ingest(self, stream: int, samples) -> None:
+    def _filling(self) -> np.ndarray:
+        """The staging array being filled, taken from the store when
+        the first sample of a step has to be written: by then the
+        launch before has drained its oldest step, so that step's
+        array is free to be used again."""
+        if self._fill is None:
+            self._fill, self._fill_stale, self._fill_fresh = \
+                self._staging.take()
+        return self._fill
+
+    def _write(self, lane: int, arr: np.ndarray) -> int:
+        """Write a gated slab at the lane's level in the array being
+        filled: the ONE copy a pushed sample gets on the host. What the
+        lane's chunk has no room for waits in `_rest`, a view of the
+        slab, for `_feed`. Returns the samples written."""
+        if lane in self._rest:          # behind what already waits
+            self._rest[lane].append(arr)
+            return 0
+        at = self._level[lane]
+        n = min(self.chunk_len - at, arr.shape[0])
+        if n:
+            self._filling()[lane, at:at + n] = arr[:n]
+            self._level[lane] = at + n
+            if at + n == self.chunk_len and self._full_since is None:
+                from ziria_tpu.utils import telemetry
+                if telemetry.traced():
+                    self._full_since = time.perf_counter()
+        if n < arr.shape[0]:
+            self._rest[lane] = [arr[n:]]
+        return n
+
+    def _feed(self) -> None:
+        """Write on, behind a launch that made room, what the pushed
+        slabs held beyond their lanes' chunks: a lane's level reaches
+        `chunk_len` again for as long as any of it waits, so `_pump`
+        runs until `_rest` is empty."""
+        rest, self._rest = self._rest, {}
+        self._ingest_span(len(rest), (self._write(i, a)
+                                      for i, slabs in rest.items()
+                                      for a in slabs))
+
+    def _ingest_span(self, lanes: int, writes) -> None:
+        """Run ``writes`` (an iterator, each item the samples one write
+        put into staging) under ONE `rx.fleet.ingest` span that names
+        the next launch, and count what they wrote."""
+        from ziria_tpu.utils import telemetry
+
+        args = {"step": self._chunk_steps, "lanes": lanes}
+        with telemetry.span("rx.fleet.ingest", args):
+            # known as the span closes: the trace's event carries it,
+            # the device profile's annotation does not
+            args["written"] = sum(writes)
+        telemetry.count("rx.stage_samples", args["written"],
+                        labels={"how": "written"})
+
+    def _own_rest(self) -> None:
+        """A call that leaves by an exception keeps what it could not
+        write in arrays of the receiver's own: no reference to a
+        caller's buffer outlives the call that brought it."""
+        self._rest = {i: [np.array(a) for a in slabs]
+                      for i, slabs in self._rest.items()}
+
+    def _ingest(self, stream: int, samples) -> int:
         """The per-stream push seam: shape gate, chaos corruption
         seam (site ``rx.push.s<i>``), non-finite gate (reject, or
-        ``sanitize=True`` zero-and-quarantine), then append."""
-        from ziria_tpu.utils import faults, telemetry
+        ``sanitize=True`` zero-and-quarantine), then the write into
+        the staging array (a slab that is refused has written
+        nothing). Returns the samples written."""
+        from ziria_tpu.utils import faults
 
         name = f"stream {stream}"
         arr = _slab_array(samples, name)
@@ -1216,12 +1332,7 @@ class MultiStreamReceiver:
         if n_bad:
             self._sanitized += n_bad
             self._dirty[stream] = True
-        if arr.size:
-            self._tails[stream] = np.concatenate(
-                [self._tails[stream], arr], axis=0)
-            if telemetry.traced() and self._full_since is None \
-                    and self._tails[stream].shape[0] >= self.chunk_len:
-                self._full_since = time.perf_counter()
+        return self._write(stream, arr) if arr.size else 0
 
     def push(self, stream: int, samples) -> List:
         """Append samples ((n, 2) float pairs) to one stream; fire
@@ -1233,11 +1344,21 @@ class MultiStreamReceiver:
         it has not; :meth:`drain_pending` waits for all of them).
         Malformed slabs and non-finite samples fail loudly at the
         seam, naming the stream (or quarantine under
-        ``sanitize=True``; docs/robustness.md)."""
+        ``sanitize=True``; docs/robustness.md). The slab is consumed
+        inside the call: the caller may write its buffer again."""
+        from ziria_tpu.utils import telemetry
+
         if self._flushed:
             raise RuntimeError("push after flush")
-        self._ingest(self._check_stream(stream), samples)
-        return self._pump()
+        try:
+            telemetry.count(
+                "rx.stage_samples",
+                self._ingest(self._check_stream(stream), samples),
+                labels={"how": "written"})
+            return self._pump()
+        except BaseException:
+            self._own_rest()
+            raise
 
     def push_many(self, slabs) -> List:
         """Append one slab per stream (empty slabs fine), THEN pump:
@@ -1247,9 +1368,8 @@ class MultiStreamReceiver:
         chunk-step's own, or from a call that launches nothing and
         finds them ready. ``slabs`` is a length-S sequence, or a
         ``{stream_id: slab}`` dict for sparse arrival; an unknown
-        stream id raises a named KeyError."""
-        from ziria_tpu.utils import telemetry
-
+        stream id raises a named KeyError. Every slab is consumed
+        inside the call, however many chunks it holds."""
         if self._flushed:
             raise RuntimeError("push after flush")
         if isinstance(slabs, dict):
@@ -1261,24 +1381,25 @@ class MultiStreamReceiver:
                     f"{self.s} streams need {self.s} slabs, "
                     f"got {len(slabs)}")
             items = list(enumerate(slabs))
-        if items:
-            with telemetry.span("rx.fleet.ingest", {
-                    "step": self._chunk_steps, "lanes": len(items)}):
-                for i, s in items:
-                    self._ingest(i, s)
-        return self._pump()
+        try:
+            if items:
+                self._ingest_span(len(items), (self._ingest(i, s)
+                                               for i, s in items))
+            return self._pump()
+        except BaseException:
+            self._own_rest()
+            raise
 
     def flush(self) -> List:
-        """Close every stream: scan the carried tails (zero-padded to
-        the chunk geometry, each stream owning every remaining start)
-        as one final chunk-step, then drain every step in flight.
-        Idempotent."""
+        """Close every stream: scan what each still holds (zero-padded
+        to the chunk geometry, each stream owning every remaining
+        start) as one final chunk-step, then drain every step in
+        flight. Idempotent."""
         if self._flushed:
             return []
         out = self._pump()
         self._flushed = True
-        active = [i for i in range(self.s)
-                  if self._tails[i].shape[0]]
+        active = [i for i in range(self.s) if self._level[i]]
         if active:
             out += self._step(active, flushing=True)
         return out + self.drain_pending()
@@ -1326,17 +1447,18 @@ class MultiStreamReceiver:
         return any(stream in st.active for st in self._flight)
 
     def flush_stream(self, stream: int) -> List:
-        """Close ONE stream: scan its carried tail (zero-padded, the
+        """Close ONE stream: scan what it still holds (zero-padded, the
         lane owning every remaining start — the per-lane twin of
         :meth:`flush`) and drain through it, leaving every other lane
-        live. Returns the emitted ``(stream, frame)`` pairs (any lane
+        live, its pending samples where they were or moved on whole
+        (`_step`). Returns the emitted ``(stream, frame)`` pairs (any lane
         may emit — the steps in flight drain first). The lane's state
         is NOT reset; :meth:`reset_stream` recycles it."""
         stream = self._check_stream(stream)
         if self._flushed:
             raise RuntimeError("flush_stream after flush")
         out = self.drain_pending()
-        if self._tails[stream].shape[0]:
+        if self._level[stream]:
             out += self._step([stream], flushing=True)
             out += self.drain_pending()
         return out
@@ -1358,7 +1480,15 @@ class MultiStreamReceiver:
                                            h.rejoin_after)
         self._dirty[stream] = False
         self._retired += self._emitted[stream]
-        self._tails[stream] = np.zeros((0, 2), np.float32)
+        if self._held(stream):
+            # what the lane wrote stays behind in the array: zeroed
+            # where a step leaves the lane idle, written over otherwise
+            self._fill_stale[stream] = True
+        self._level[stream] = 0
+        self._owed[stream] = False
+        self._rest.pop(stream, None)
+        if not any(self._owed):
+            self._prev = None
         self._offsets[stream] = 0
         self._emitted[stream] = 0
         self._watermarks[stream] = 0
@@ -1382,12 +1512,17 @@ class MultiStreamReceiver:
         this fleet's healthy lane-mates with the slow twin. Returns
         the drained ``(stream, frame)`` pairs (the reset's rule)."""
         from ziria_tpu.runtime import resilience
+        from ziria_tpu.utils import telemetry
 
         stream = self._check_stream(stream)
         st = resilience.restore_carry(checkpoint)
         _validate_checkpoint(st, self._geometry())
         out = self.reset_stream(stream)
-        self._tails[stream] = np.asarray(st.tail, np.float32)
+        # the restored tail is written as a slab is (it is the blob's
+        # own array, nobody else's)
+        tail = np.asarray(st.tail, np.float32)
+        telemetry.count("rx.stage_samples", self._write(stream, tail),
+                        labels={"how": "written"})
         self._offsets[stream] = int(st.offset)
         self._emitted[stream] = int(st.emitted)
         # the restored frames were emitted elsewhere: keep this
@@ -1407,33 +1542,71 @@ class MultiStreamReceiver:
     # -- chunk-step lifecycle -------------------------------------------
 
     def _pump(self) -> List:
-        """Launch every chunk-step the tails hold; a call that holds
-        none hands back what the device has finished meanwhile."""
+        """Launch every chunk-step the lanes have filled, writing on
+        behind each launch what the pushed slabs still hold; a call
+        that fills none hands back what the device has finished
+        meanwhile."""
         out: List = []
         launched = False
         while True:
-            active = [i for i in range(self.s)
-                      if self._tails[i].shape[0] >= self.chunk_len]
+            active = [i for i, n in enumerate(self._level)
+                      if n >= self.chunk_len]
             if not active:
                 return out if launched else self._advance_ready()
             out += self._step(active, flushing=False)
             launched = True
+            if self._rest:
+                self._feed()
 
     def _step(self, active, flushing: bool) -> List:
-        """Build one stacked chunk-step over the `active` streams
-        (idle lanes ride zeros behind `valid == 0`) in a staging array
-        nobody else holds (`_Staging`), launch it, and advance the
-        active streams' host carries."""
+        """Make one stacked chunk-step over the `active` streams whole
+        (idle lanes ride zeros behind `valid == 0`), advance the
+        active streams' host carries, and launch it.
+
+        The array launched is the one the pushes filled, AS IT IS:
+        what is left to do is the ONE carry, the last `frame_len`
+        samples of every lane that rode the launch before, copied
+        from that launch's array (`_prev`, still in flight) to the
+        front of this one. Where some lane holds samples and does not
+        ride (a sparse step: paced arrivals, ragged streams,
+        `flush_stream`), it must ride zeros and keep its samples, and
+        either the waiting lanes move on to the next array and are
+        zeroed behind, or the riding lanes are copied out to an array
+        of their own; the step takes whichever moves fewer samples,
+        by the levels it holds. Either way needs a second array of
+        the store before the launch; a step every holding lane rides
+        needs none, and the next is taken once this launch has
+        drained its oldest step (`_filling`)."""
         from ziria_tpu.utils import dispatch, telemetry
 
-        arrs, stale, fresh = self._staging.take()
+        level, owed = self._level, self._owed
+        fl, st = self.frame_len, self.stride
+        riding = [False] * self.s
+        for i in active:
+            riding[i] = True
+        waiting = [i for i, n in enumerate(level) if n and not riding[i]]
+        carried = fl * sum(owed)
+        move_on = sum(self._held(i) for i in waiting)
+        move_out = sum(self._held(i) for i in active)
+        # ``src`` holds the pushed samples, ``arrs`` is launched,
+        # ``nxt`` is filled from here on
+        if waiting and move_on > move_out:
+            src, src_stale = self._fill, self._fill_stale
+            nxt = (src, src_stale, self._fill_fresh)
+            arrs, stale, fresh = self._staging.take()
+        else:
+            src = arrs = self._filling()
+            src_stale = stale = self._fill_stale
+            fresh = self._fill_fresh
+            nxt = self._staging.take() if waiting else (None, None, False)
         telemetry.count("rx.stage_arrays", labels={
             "how": "fresh" if fresh else "reused"})
         dispatch.record_gauge("rx.stage_bytes", self._staging.nbytes)
         args = {"step": self._chunk_steps, "active": len(active),
-                "samples": sum(self._tails[i].shape[0] if flushing
+                "samples": sum(level[i] if flushing
                                else self.chunk_len for i in active),
-                "fresh": int(fresh)}
+                "fresh": int(fresh), "carried": carried,
+                "moved": min(move_on, move_out) if waiting else 0}
         traced = telemetry.traced()
         full_since, self._full_since = self._full_since, None
         if traced and full_since is not None:
@@ -1443,21 +1616,22 @@ class MultiStreamReceiver:
             valid = np.zeros(self.s, np.int32)
             own_lo = np.zeros(self.s, np.int32)
             own_hi = np.zeros(self.s, np.int32)
-            adv = {}
+            prev = self._prev
             for i in active:
-                t = self._tails[i]
+                v, lo = level[i], 0
+                if owed[i]:
+                    arrs[i, :fl] = prev[i, st:]
+                    lo = fl
+                if arrs is not src and v > lo:
+                    arrs[i, lo:v] = src[i, lo:v]
+                    src_stale[i] = True
                 if flushing:
-                    v = t.shape[0]
-                    arrs[i, :v] = t
                     if stale[i]:
                         arrs[i, v:] = 0
                     valid[i] = own_hi[i] = v
-                    adv[i] = v
                 else:
-                    arrs[i] = t[:self.chunk_len]
                     valid[i] = self.chunk_len
-                    own_hi[i] = self.stride
-                    adv[i] = self.stride
+                    own_hi[i] = st
                 # a quarantined stream rides behind the existing
                 # valid-mask: its chunk advances (samples consumed)
                 # but the detector sees zero valid samples — healthy
@@ -1473,23 +1647,38 @@ class MultiStreamReceiver:
                 # clamps); on any later chunk a negative start is the
                 # previous chunk's frame
                 own_lo[i] = -192 if self._offsets[i] == 0 else 0
+            dst = nxt[0]
+            for i in waiting:
+                v, lo = level[i], 0
+                if owed[i]:
+                    dst[i, :fl] = prev[i, st:]
+                    lo = fl
+                if dst is not src and v > lo:
+                    dst[i, lo:v] = src[i, lo:v]
+                    if not stale[i]:
+                        src[i, lo:v] = 0
             # an idle lane rides zeros: one that still holds the
-            # samples of the array's last use is zeroed, once
+            # samples of an earlier use is zeroed, once
             stale[active] = False
             arrs[stale] = 0
             stale[:] = False
             stale[active] = True
-        offs = list(self._offsets)          # snapshot BEFORE advancing
-        res = self._launch(arrs, valid, own_lo, own_hi, active, offs)
+        telemetry.count("rx.stage_samples", carried,
+                        labels={"how": "carried"})
+        telemetry.count("rx.stage_samples", args["moved"],
+                        labels={"how": "moved"})
+        # the step is whole: the receiver's state is the next step's
+        # from here on, whatever becomes of the launch (one that raises
+        # once the scan is queued has its step in flight all the same)
+        offs = list(self._offsets)
+        owed[:] = [r and not flushing for r in riding]
         for i in active:
-            self._tails[i] = self._tails[i][adv[i]:]
-            self._offsets[i] += adv[i]
-        if traced and any(t.shape[0] >= self.chunk_len
-                          for t in self._tails):
-            # a lane this launch left full has waited since it
-            self._full_since = time.perf_counter()
-        dispatch.record_gauge("rx.stream_carry_depth",
-                              sum(t.shape[0] for t in self._tails))
+            self._offsets[i] += level[i] if flushing else st
+            level[i] = 0 if flushing else fl
+        self._prev = None if flushing else arrs
+        self._fill, self._fill_stale, self._fill_fresh = nxt
+        res = self._launch(arrs, valid, own_lo, own_hi, active, offs)
+        dispatch.record_gauge("rx.stream_carry_depth", self._depth())
         return res
 
     def _put(self, x):
