@@ -285,6 +285,75 @@ def check_reference(clients, frames, cfg) -> None:
         rates=",".join(str(m) for m in rates))
 
 
+#: the bounded decode's check: (rate, PSDU + FCS bytes) a lane. An ACK,
+#: a TCP ACK and MTU frames ride ONE tile (the mix cell's lengths), so
+#: the short lanes are erasures for most of what the tile runs
+BOUND_LANES = ((24, 14), (12, 76), (54, 1504), (6, 1504), (36, 1504),
+               (6, 14))
+TINY_BOUND_LANES = ((24, 14), (12, 16), (54, 50), (6, 16), (36, 40),
+                    (6, 14))
+
+
+def check_bounded_decode(cfg, lanes) -> None:
+    """PR 53: one tile of mixed lengths through the decode's own stages
+    (`rx._mixed_stages`) at the served symbol bucket, its ACS and
+    traceback stopped at the tile's longest frame (`rx.decode_bound`)
+    and run whole: every lane's bits before its own count, its PSDU and
+    its FCS flag are the same. On the chip both are Mosaic kernels (the
+    tests run the interpreter)."""
+    import jax
+    import numpy as np
+
+    from ziria_tpu.phy.wifi import rx, tx
+    from ziria_tpu.phy.wifi.params import (N_SERVICE_BITS, RATES,
+                                           mixed_trellis_steps, n_symbols)
+    from ziria_tpu.utils.geometry import DEFAULT
+
+    nsb = DEFAULT.sym_bucket(
+        max(1, (cfg.frame_len - rx.FRAME_DATA_START) // 80))
+    need = rx.FRAME_DATA_START + 80 * nsb
+    rng = np.random.default_rng(SEED + 53)
+    frames, sent = [], []
+    for m, n in lanes:
+        body = rng.integers(0, 256, n - 4).astype(np.uint8)
+        s = np.asarray(tx.encode_frame(body, m, add_fcs=True), np.float32)
+        s = s + rng.normal(0, 0.02, s.shape).astype(np.float32)
+        frames.append(np.pad(s, ((0, need - s.shape[0]), (0, 0))))
+        sent.append(np.asarray(tx._host_psdu_bits(body, add_fcs=True)))
+    ridx = np.array([rx.RATE_INDEX[m] for m, _n in lanes], np.int32)
+    nbits = np.array([n_symbols(n, RATES[m]) * RATES[m].n_dbps
+                      for m, n in lanes], np.int32)
+    npsdu = np.array([8 * n for _m, n in lanes], np.int32)
+    front, trellis, back = rx._mixed_stages(nsb, None, None, None, None,
+                                            False, False)
+
+    def decode(fr, r, n, p, blocks=None):
+        clear = back(trellis(front(fr, r, n), r, n, blocks))
+        return clear, rx.crc_psdu_many_graph(clear, p)
+
+    blocks, steps = rx.decode_bound(int(nbits.max()),
+                                    mixed_trellis_steps(nsb))
+    check(steps < mixed_trellis_steps(nsb),
+          f"the tile's bound ({steps} steps) is the whole trellis")
+    args = (np.stack(frames), ridx, nbits, npsdu)
+    whole = [np.asarray(o) for o in jax.jit(decode)(*args)]
+    got = [np.asarray(o) for o in jax.jit(decode)(
+        *args, np.full((1,), blocks, np.int32))]
+    for i, (n, p) in enumerate(zip(nbits, npsdu)):
+        check(np.array_equal(got[0][i, :n], whole[0][i, :n]),
+              f"bounded decode, lane {i} {lanes[i]}: a bit before its "
+              f"{n} differs from the whole trellis's")
+        check(np.array_equal(
+            got[0][i, N_SERVICE_BITS: N_SERVICE_BITS + p], sent[i]),
+            f"bounded decode, lane {i} {lanes[i]}: not the PSDU sent")
+    check(got[1].all() and whole[1].all(),
+          f"bounded decode: FCS flags {got[1].tolist()} "
+          f"(whole: {whole[1].tolist()})")
+    say("bounded-decode", lanes=len(lanes), symbol_bucket=nsb,
+        steps_run=int(steps), steps_whole=mixed_trellis_steps(nsb),
+        same_bits_psdu_and_fcs=True)
+
+
 def same_frames(a, b) -> None:
     import numpy as np
 
@@ -415,6 +484,8 @@ def main(argv=None) -> int:
               f"queued behind the step the host waited for")
         check_frames(frames, sent_frames(load), clients, cfg, "")
         check_reference(clients, frames, cfg)
+        check_bounded_decode(
+            cfg, TINY_BOUND_LANES if args.rehearse else BOUND_LANES)
 
     ms = dev.memory_stats() or {}
     say("memory", peak_device_bytes=ms.get("peak_bytes_in_use", "n/a"),

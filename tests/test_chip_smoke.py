@@ -48,7 +48,14 @@ def test_rehearsal_passes_every_phase_and_prints_no_result():
     lines = r.stdout.strip().splitlines()
     phases = [ln.split("]")[0].lstrip("[") for ln in lines[:-1]]
     assert phases == ["device", "load", "compile", "served", "frames",
-                      "reference", "memory"]
+                      "reference", "bounded-decode", "memory"]
+    # PR 53: a tile of mixed lengths decodes to the same bits with its
+    # kernels stopped at its longest frame and run whole
+    bound = [ln for ln in lines if ln.startswith("[bounded-decode]")][0]
+    assert "same_bits_psdu_and_fcs=True" in bound
+    run, whole = (int(bound.split(f"{k}=")[1].split()[0])
+                  for k in ("steps_run", "steps_whole"))
+    assert 0 < run < whole
     assert lines[-1].startswith('{"rehearsal": true')
     assert '"ok"' not in r.stdout
 

@@ -382,6 +382,12 @@ def test_stream_many_multi_synthesizer_contract():
 # unpacked batch with EVERY slot live, run once. Lane values do not
 # depend on the batch (the pinned `receive_many` contract), so a case
 # is data: which slots of that one table keep their `nbits`.
+#
+# Since PR 53 a tile's ACS and traceback stop where the tile's longest
+# frame stops (`rx.decode_bound`), so a live slot equals the reference
+# BEFORE ITS OWN `nbits` (the PSDU, the tail, the pad of its last
+# symbol) and in its FCS flag; from there to its tile's bound it holds
+# a decode of erasures, and past the bound the descrambled zeros.
 
 WS, WK, WBUCKET = 2, 72, 8
 G = rx.DECODE_GROUP
@@ -389,7 +395,24 @@ G = rx.DECODE_GROUP
 #: around a group and a tile, and lanes spread unevenly over streams
 WALK_CASES = [(0, 0), (0, 1), (G - 1, 0), (G // 2, G - G // 2), (G, 1),
               (2 * G, 0), (2 * G, 1), (WK, 0), (0, WK), (WK, 128 - WK),
-              (WK, 129 - WK), (WK, WK - 1), (WK, WK)]
+              (WK, 129 - WK), (WK, WK - 1), (WK, WK),
+              # PR 53, by name (`_live`): two tiles whose longest lanes
+              # differ, and one live lane that ends ON its bound
+              "tiles-differ", "one-lane-on-its-bound"]
+
+
+def _live(case, nbits):
+    """The (WS, WK) mask of a case's live slots: counts a stream (the
+    host's tables: a stream's live lanes first), or a pattern by name
+    (the program packs whatever slots state ``nbits > 0``)."""
+    if case == "tiles-differ":
+        # the first tile mixed, the second short lanes alone
+        flat = np.arange(WS * WK).reshape(WS, WK)
+        return (flat < 128) | (nbits <= 7 * 64)
+    if case == "one-lane-on-its-bound":
+        on = (nbits % 64 == 0) & (nbits < nbits.max())
+        return np.arange(WS * WK).reshape(WS, WK) == np.flatnonzero(on)[0]
+    return np.arange(WK)[None, :] < np.array(case)[:, None]
 
 
 @pytest.fixture(scope="module")
@@ -413,37 +436,74 @@ def walk_toy():
     walk = traced.lower().compile()
 
     def ref(frames, r, b, p):
-        clear = rx.decode_data_mixed(frames, r, b, WBUCKET)
-        return clear, rx.crc_psdu_many_graph(clear, p)
+        # `decode_data_mixed`, its three stages apart: the decoded row
+        # is also put through the back with every bit past its first
+        # seven (the seed's) ZERO: the descrambled zeros
+        front, trellis, back = rx._mixed_stages(
+            WBUCKET, None, None, None, None, False, False)
+        raw = trellis(front(frames, r, b), r, b)
+        clear = back(raw)
+        return (clear, rx.crc_psdu_many_graph(clear, p),
+                back(raw.at[:, 7:].set(0)))
 
     sel = np.stack([segs[i][rows[i]] for i in range(WS)])
-    clear, crc = jax.jit(ref)(
+    clear, crc, zeros = jax.jit(ref)(
         sel.reshape(WS * WK, need, 2), ridx.reshape(-1),
         nbits.reshape(-1), npsdu.reshape(-1))
     return (walk, tables, np.asarray(clear).reshape(WS, WK, -1),
-            np.asarray(crc).reshape(WS, WK), traced.jaxpr)
+            np.asarray(crc).reshape(WS, WK), traced.jaxpr,
+            np.asarray(zeros).reshape(WS, WK, -1))
 
 
 @pytest.mark.parametrize("counts", WALK_CASES,
-                         ids=[f"live{a + b}of{WS * WK}-{a}+{b}"
-                              for a, b in WALK_CASES])
+                         ids=[c if isinstance(c, str) else
+                              f"live{c[0] + c[1]}of{WS * WK}-{c[0]}+{c[1]}"
+                              for c in WALK_CASES])
 def test_decode_walks_the_live_slots_bit_identical(walk_toy, counts):
-    walk, (segs, rows, ridx, nbits, npsdu), want_clear, want_crc, _ = walk_toy
+    (walk, (segs, rows, ridx, nbits, npsdu), want_clear, want_crc, _,
+     zeros) = walk_toy
     # the host's tables: a stream's live lanes first, `nbits` 0 after
-    live = np.arange(WK)[None, :] < np.array(counts)[:, None]
-    clear, crc, trips = walk(segs, rows, ridx,
-                             np.where(live, nbits, 0), npsdu)
+    live = _live(counts, nbits)
+    table = np.where(live, nbits, 0)
+    clear, crc, trips = walk(segs, rows, ridx, table, npsdu)
     clear, crc = np.asarray(clear), np.asarray(crc)
     assert clear.shape == want_clear.shape and crc.shape == (WS, WK)
-    # every live slot: the mixed decode's own bits and FCS flag
-    assert np.array_equal(clear[live], want_clear[live])
+    # every live slot: the whole-trellis mixed decode's own bits before
+    # its `nbits`, and its FCS flag
+    real = np.arange(clear.shape[-1]) < table[..., None]
+    assert np.array_equal(clear[real], want_clear[real])
     assert np.array_equal(crc[live], want_crc[live])
     # a slot that holds no frame is not decoded: zero, both outputs
     assert not clear[~live].any() and not crc[~live].any()
-    # the slots the program's trips ran are the python rule's
+    # the slots the program's trips ran are the python rule's, and so
+    # are the trellis steps each tile's kernels ran: the bound of the
+    # tile's longest LIVE lane, in packed stream order
     n = int(live.sum())
     fronted, decoded = rx.decode_walk(n, WS * WK)
-    assert tuple(int(t) for t in trips) == (fronted, decoded)
+    assert tuple(int(t) for t in trips[:2]) == (fronted, decoded)
+    steps = np.asarray(trips[2])
+    packed = np.zeros(steps.size * 128, np.int32)
+    packed[:n] = table[live]
+    want_steps = rx.decode_bound(packed.reshape(-1, 128).max(axis=1),
+                                 clear.shape[-1])[1]
+    want_steps[decoded // 128:] = 0          # a tile no trip went to
+    assert steps.tolist() == want_steps.tolist()
+    if counts == "tiles-differ":
+        assert steps[0] == clear.shape[-1] and 0 < steps[1] <= 7 * 64
+    if counts == "one-lane-on-its-bound":
+        assert steps.tolist() == [table.max(), 0]
+    assert rx.decode_steps(table.reshape(1, -1), WBUCKET) == int(
+        (np.minimum(np.arange(128, decoded + 1, 128), WS * WK)
+         - np.arange(0, decoded, 128)) @ steps[:decoded // 128])
+    # (b) a row at or past its tile's bound: the descrambled zeros (no
+    # block the kernels left unwritten reaches an output), the same
+    # bytes in a second run
+    place = np.cumsum(live.reshape(-1)) - 1
+    bound = steps[place // 128].reshape(WS, WK)
+    past = live[..., None] & (np.arange(clear.shape[-1]) >= bound[..., None])
+    assert np.array_equal(clear[past], zeros[past])
+    again = np.asarray(walk(segs, rows, ridx, table, npsdu)[0])
+    assert np.array_equal(again, clear)
     # the rule, said again: 128-lane tiles up to the last live slot,
     # each fronted whole but the last, which fronts one group where
     # one holds what is left

@@ -133,6 +133,24 @@ def test_traceback_kernel_at_mtu_trellis(one_chip):
                             interpret=False))
 
 
+def test_bounded_decode_kernels_take_a_prefetched_bound(one_chip):
+    """PR 53: the ACS and the traceback under a bound that is data
+    (`_decode_tiles` with `n_blocks`, one int32 a tile) compile as
+    Mosaic kernels at the served trellis, and each takes the count as
+    its first operand (the scalar prefetch): what the decode program
+    above runs a tile."""
+    llr = jax.ShapeDtypeStruct((1, T_MTU, 2, LANES), jnp.float32,
+                               sharding=one_chip)
+    n_blocks = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    low = vp._decode_tiles.lower(llr, interpret=False, n_blocks=n_blocks)
+    calls = [ln for ln in low.as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    assert len(calls) == 2 and all(
+        "(tensor<1xi32>, " in ln.split(" : ")[-1] for ln in calls), \
+        [ln[-300:] for ln in calls]
+    _assert_mosaic(low.compile(), at_least=2)
+
+
 def _decode_shapes(geo, sharding):
     nsb = _sym_bucket(geo["frame_len"])
     need = _rx.FRAME_DATA_START + 80 * nsb

@@ -77,3 +77,108 @@ def test_multi_tile_batch():
         llrs.append((2.0 * coded - 1.0).reshape(-1, 2))
     got = np.asarray(viterbi_pallas.viterbi_decode_batch(np.stack(llrs)))
     np.testing.assert_array_equal(got, np.stack(msgs))
+
+
+# ---------------- the sweeps stop at a bound that is data (ISSUE 53)
+#
+# `n_blocks` (a count a 128-lane tile, traced) stops a tile's ACS and
+# traceback after that many blocks of UNROLL steps. Where every lane of
+# the tile is an erasure from there on, each lane's bits before its own
+# last real row are the whole trellis's; past the bound they read zero.
+
+U = viterbi_pallas.UNROLL
+BOUND_T = 6 * U
+#: two tiles (130 lanes): tile 0's longest lane ends inside block 3,
+#: tile 1 (lanes 128, 129) inside block 5 and ON block 2's last step
+BOUND_REAL = np.r_[np.tile([17, 3 * U - 2, U, 2 * U + 1], 32),
+                   [4 * U + 9, 2 * U]]
+BOUND_KERNELS = [("float32", 2), ("float32", 4), ("int16", 2)]
+
+
+@pytest.fixture(scope="module")
+def bound_llrs():
+    rng = np.random.default_rng(53)
+    llr = rng.normal(0, 2.0, (BOUND_REAL.size, BOUND_T, 2))
+    real = np.arange(BOUND_T)[None, :] < BOUND_REAL[:, None]
+    return np.where(real[..., None], llr, 0).astype(np.float32)
+
+
+@pytest.fixture(scope="module", params=BOUND_KERNELS,
+                ids=[f"{m}-radix{r}" for m, r in BOUND_KERNELS])
+def bound_kernel(request, bound_llrs):
+    """(the bounded decode jitted once: the count is an argument; the
+    whole trellis's bits)."""
+    import jax
+    md, rdx = request.param
+    bounded = jax.jit(lambda x, n: viterbi_pallas.viterbi_decode_batch(
+        x, metric_dtype=md, radix=rdx, n_blocks=n))
+    whole = np.asarray(viterbi_pallas.viterbi_decode_batch(
+        bound_llrs, metric_dtype=md, radix=rdx))
+    return bounded, whole
+
+
+@pytest.mark.parametrize("n_blocks, runs", [
+    ((3, 5), (3, 5)),        # each tile to its own longest lane
+    ((3, 6), (3, 6)), ((6, 6), (6, 6)),   # to the grid's last block
+    ((4, 40), (4, 6)),       # a count past the trellis is the trellis
+    ((5, 5), (5, 5))],
+    ids=lambda v: "-".join(map(str, v)))
+def test_bounded_sweeps_give_the_whole_trellis_on_real_bits(
+        bound_llrs, bound_kernel, n_blocks, runs):
+    bounded, whole = bound_kernel
+    got = np.asarray(bounded(bound_llrs, np.asarray(n_blocks, np.int32)))
+    assert got.shape == whole.shape == (BOUND_REAL.size, BOUND_T)
+    tile = np.arange(BOUND_REAL.size) // viterbi_pallas.LANES
+    for lane, n in enumerate(BOUND_REAL):
+        np.testing.assert_array_equal(got[lane, :n], whole[lane, :n])
+        assert not got[lane, runs[tile[lane]] * U:].any()
+
+
+def test_a_bound_under_one_block_runs_one(bound_llrs, bound_kernel):
+    """The count is held to [1, blocks]: 0 runs the first block (and
+    hands over metrics the kernel wrote), so a tile whose lanes all end
+    inside it still decodes."""
+    bounded, whole = bound_kernel
+    short = np.where(
+        (np.arange(BOUND_T) < 17)[None, :, None], bound_llrs, 0)
+    got = np.asarray(bounded(short, np.zeros(2, np.int32)))
+    want = np.asarray(bounded(short, np.full(2, 6, np.int32)))
+    np.testing.assert_array_equal(got[:, :17], want[:, :17])
+    assert not got[:, U:].any()
+
+
+def test_without_a_bound_the_decode_traces_the_program_it_always_did():
+    """`n_blocks` absent: a static grid and no prefetch operand (what
+    `decode_data_mixed`, `decode_data_bucketed` and the tools trace),
+    and the lowered text is, to the byte, what the parent of PR 53
+    lowered (its digest, taken on that commit in this container; the
+    interpreter's lowering: Mosaic's serialized body carries source
+    lines)."""
+    import hashlib
+    import jax
+    llr = jax.ShapeDtypeStruct((3, 200, 2), np.float32)
+    pinned = {("float32", 2): "b043d2e962c047ec",
+              ("float32", 4): "3c7234d13d2c4674",
+              ("int16", 2): "89569d97a681c168"}
+    for (md, rdx), want in pinned.items():
+        traced = jax.jit(lambda x: viterbi_pallas.viterbi_decode_batch(
+            x, interpret=True, metric_dtype=md, radix=rdx)).trace(llr)
+        calls = [e for e in _eqns(traced.jaxpr.jaxpr)
+                 if e.primitive.name == "pallas_call"]
+        assert len(calls) == 2
+        for e in calls:
+            gm = e.params["grid_mapping"]
+            assert gm.num_index_operands == 0
+            assert all(isinstance(g, int) for g in gm.grid)
+        text = traced.lower().as_text()
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] == want, \
+            (md, rdx)
+
+
+def _eqns(jaxpr):
+    import jax
+    for e in jaxpr.eqns:
+        yield e
+        if e.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from _eqns(sub)

@@ -1847,7 +1847,6 @@ class MultiStreamReceiver:
         fleet), its outputs sent on their way to the host. Every span
         carries the step's own id."""
         from ziria_tpu.phy.wifi import rx as _rx
-        from ziria_tpu.phy.wifi.params import mixed_trellis_steps
         from ziria_tpu.utils import programs, telemetry
 
         step, active = st.step, st.active
@@ -1905,18 +1904,19 @@ class MultiStreamReceiver:
         # what the decode is asked for against what it computes: on
         # every device the program fronts the slots that hold a frame,
         # whole groups of them, each gathered at the whole symbol
-        # bucket, and runs the tiles that hold them over the bound
-        # trellis, every lane (the LENGTH field's longest frame,
-        # `params.mixed_trellis_steps`): `rx.decode_walk`, its own
-        # rule, cut at the slots a device has; the scan cut every one
-        # of the S x K from the chunk at the whole window, whatever
-        # it holds
+        # bucket (`rx.decode_walk`, its own rule, cut at the slots a
+        # device has), and runs the tiles that hold them, every lane,
+        # as far into the trellis as the tile's longest frame reaches
+        # (`rx.decode_steps`, over `rx.decode_bound`, the kernels' own
+        # rule); the scan cut every one of the S x K from the chunk at
+        # the whole window, whatever it holds
         useful = sum(lane[4] for lane in st.lanes)
         dev_slots = self.s * self.k // self._n_devices
-        n_slots, decoded = (
-            int(np.minimum(w, dev_slots).sum()) for w in _rx.decode_walk(
-                (tables[2] > 0).reshape(self._n_devices, -1).sum(axis=1),
-                dev_slots))
+        dev_nbits = tables[2].reshape(self._n_devices, -1)
+        n_slots = int(np.minimum(_rx.decode_walk(
+            (dev_nbits > 0).sum(axis=1), dev_slots)[0], dev_slots).sum())
+        bounded = _rx.trellis_takes_bound(
+            self.viterbi_window, self.viterbi_metric, self.fused_demap)
         padded = n_slots * self.n_sym_bucket
         telemetry.count("rx.decode_symbols", useful,
                         labels={"kind": "useful"})
@@ -1932,8 +1932,8 @@ class MultiStreamReceiver:
                 "useful_symbols": useful,
                 "padded_symbols": padded,
                 "useful_bits": int(tables[2].sum()),
-                "trellis_steps": decoded
-                * mixed_trellis_steps(self.n_sym_bucket),
+                "trellis_steps": _rx.decode_steps(
+                    dev_nbits, self.n_sym_bucket, bounded),
                 "frame_samples": len(st.lanes) * _rx.FRAME_DATA_START
                 + 80 * useful,
                 "window_samples": self.s * self.k * self.frame_len}):

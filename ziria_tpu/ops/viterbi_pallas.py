@@ -330,9 +330,80 @@ def _acs_pair_lut_int(m, la1, lb1, la2, lb2, sels16, pack):
 
 
 # ------------------------------------------------------------ ACS kernels
+#
+# A sweep is a grid (lane tiles, blocks of UNROLL steps). WHOLE, it runs
+# every block of the trellis it is given. BOUNDED, it runs the blocks a
+# tile's longest frame reaches, by a count that is data: `n_blocks`,
+# int32 a tile, prefetched to scalar memory ahead of the grid
+# (`_sweep`). A row at or past a lane's last data bit is a zero
+# LLR pair and adds no likelihood, so past a tile's longest lane every
+# row is an erasure and the survivor through those rows leads back to
+# the best state at that lane's last step: stopping there and tracing
+# back from the metrics as they stand gives every bit before a lane's
+# `n_bits_real` as the whole trellis gives it (the argument that rests
+# the trellis on clause 18's longest frame, `rx.decode_data_mixed`,
+# taken to the longest frame the TILE holds).
 
 
-def _acs_kernel(llr_ref, dec_ref, metrics_out_ref, m_ref):
+def _sweep_step(at):
+    """``(t, last)`` of a sweep's kernel: the grid step it runs and the
+    step after which an ACS hands over its metrics. WHOLE (``at`` None)
+    they are the grid's own; a bounded sweep hands them in, read outside
+    the conditional it runs the kernel under."""
+    return (pl.program_id(1), pl.num_programs(1) - 1) if at is None else at
+
+
+def _bounded(kernel):
+    """`kernel` for a bounded sweep: the tile's block count leads its
+    refs (the prefetched scalar), a grid step at or past it runs
+    nothing, and the count's last block is the sweep's last."""
+    @functools.wraps(kernel)
+    def bounded(n_blocks_ref, *refs):
+        t, n = pl.program_id(1), n_blocks_ref[pl.program_id(0)]
+
+        @pl.when(t < n)
+        def _run():
+            kernel(*refs, at=(t, n - 1))
+
+    return bounded
+
+
+def _block_maps(walk, blocks: int, bounded: bool):
+    """The two index maps of a sweep's specs: a (1, UNROLL, ., 128)
+    block of the tile's trellis, and a tile's one (1, ., 128) block.
+    ``walk(t, n)`` gives the block grid step `t` visits among a tile's
+    `n` (the ACS `t`, the traceback `n - 1 - t`). Under a bound `n` is
+    the tile's own count and the step is held inside it, so that a
+    skipped step names the block the last real one did: no LLR block is
+    fetched and no decision or bit block written for it (the pipeline
+    moves a block only when its index moves)."""
+    if not bounded:
+        return (lambda b, t: (b, walk(t, blocks), 0, 0),
+                lambda b, t: (b, 0, 0))
+    return (lambda b, t, n: (b, jnp.clip(walk(t, n[b]), 0, n[b] - 1), 0, 0),
+            lambda b, t, n: (b, 0, 0))
+
+
+def _sweep(kernel, n_blocks, grid, in_specs, out_specs, scratch_shapes,
+           **call):
+    """The `pallas_call` of a sweep over ``grid`` = (tiles, blocks):
+    whole (``n_blocks`` None: a static grid and no prefetch operand,
+    the program it always was) or bounded (``n_blocks`` (tiles,) int32,
+    traced, held to [1, blocks], prefetched; the specs' index maps are
+    `_block_maps`' bounded pair)."""
+    if n_blocks is None:
+        return pl.pallas_call(
+            kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes, **call)
+    return functools.partial(pl.pallas_call(
+        _bounded(kernel),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch_shapes),
+        **call), jnp.clip(n_blocks.astype(jnp.int32), 1, grid[1]))
+
+
+def _acs_kernel(llr_ref, dec_ref, metrics_out_ref, m_ref, at=None):
     """UNROLL trellis time-steps for one batch tile (f32, radix 2 —
     the oracle kernel).
 
@@ -342,7 +413,7 @@ def _acs_kernel(llr_ref, dec_ref, metrics_out_ref, m_ref):
     metrics_out_ref: (64, 128) f32 — final metrics (last write wins).
     m_ref: (64, 128) f32 VMEM scratch — path metrics across the sweep.
     """
-    t = pl.program_id(1)
+    t, last = _sweep_step(at)
 
     @pl.when(t == 0)
     def _init():
@@ -364,17 +435,17 @@ def _acs_kernel(llr_ref, dec_ref, metrics_out_ref, m_ref):
     m = m - jnp.max(m, axis=0, keepdims=True)
     m_ref[:] = m
 
-    @pl.when(t == pl.num_programs(1) - 1)
+    @pl.when(t == last)
     def _flush():
         metrics_out_ref[0] = m_ref[:]
 
 
-def _acs_kernel_r4(llr_ref, dec_ref, metrics_out_ref, m_ref):
+def _acs_kernel_r4(llr_ref, dec_ref, metrics_out_ref, m_ref, at=None):
     """Radix-4 f32 ACS sweep: UNROLL trellis steps as UNROLL/2
     butterfly pairs — bit-identical to `_acs_kernel` (the pair body
     derives it) with HALF the sequential m -> m fan-out/renorm
     structure per trellis step and one packing matmul per pair."""
-    t = pl.program_id(1)
+    t, last = _sweep_step(at)
 
     @pl.when(t == 0)
     def _init():
@@ -397,12 +468,13 @@ def _acs_kernel_r4(llr_ref, dec_ref, metrics_out_ref, m_ref):
     m = m - jnp.max(m, axis=0, keepdims=True)
     m_ref[:] = m
 
-    @pl.when(t == pl.num_programs(1) - 1)
+    @pl.when(t == last)
     def _flush():
         metrics_out_ref[0] = m_ref[:]
 
 
-def _acs_kernel_i16(llr_ref, dec_ref, metrics_out_ref, m_ref):
+def _acs_kernel_i16(llr_ref, dec_ref, metrics_out_ref, m_ref,
+                    at=None):
     """int16 saturating-metric ACS sweep — the SORA trade (SURVEY.md
     §2.2: the reference brick ran 16-bit path metrics across SSE
     lanes). Same trellis walk and packed decision format as
@@ -423,7 +495,7 @@ def _acs_kernel_i16(llr_ref, dec_ref, metrics_out_ref, m_ref):
     path (docs/quantized_viterbi.md has the bound), so the decode
     matches the f32 kernel bit-for-bit on the same quantized inputs.
     """
-    t = pl.program_id(1)
+    t, last = _sweep_step(at)
 
     @pl.when(t == 0)
     def _init():
@@ -442,7 +514,7 @@ def _acs_kernel_i16(llr_ref, dec_ref, metrics_out_ref, m_ref):
     m = m - jnp.max(m, axis=0, keepdims=True)
     m_ref[:] = jnp.clip(m, I16_MIN, I16_MAX).astype(jnp.int16)
 
-    @pl.when(t == pl.num_programs(1) - 1)
+    @pl.when(t == last)
     def _flush():
         metrics_out_ref[0] = m_ref[:].astype(jnp.int32)
 
@@ -457,8 +529,8 @@ def _make_acs_kernel_int_lut(radix: int, lo: int, hi: int, sdtype):
     int8 the rail is shallow and the contract is the BER envelope
     (docs/quantized_viterbi.md §int8)."""
 
-    def kernel(llr_ref, dec_ref, metrics_out_ref, m_ref):
-        t = pl.program_id(1)
+    def kernel(llr_ref, dec_ref, metrics_out_ref, m_ref, at=None):
+        t, last = _sweep_step(at)
 
         @pl.when(t == 0)
         def _init():
@@ -491,7 +563,7 @@ def _make_acs_kernel_int_lut(radix: int, lo: int, hi: int, sdtype):
         m = m - jnp.max(m, axis=0, keepdims=True)
         m_ref[:] = jnp.clip(m, lo, hi).astype(sdtype)
 
-        @pl.when(t == pl.num_programs(1) - 1)
+        @pl.when(t == last)
         def _flush():
             metrics_out_ref[0] = m_ref[:].astype(jnp.int32)
 
@@ -534,8 +606,8 @@ def _make_traceback_kernel(unroll: int):
       tile-aligned).
     s_ref: (8, 128) int32 scratch — row 0 is the current state per lane.
     """
-    def kernel(dec_ref, metrics_ref, bits_ref, s_ref):
-        t = pl.program_id(1)
+    def kernel(dec_ref, metrics_ref, bits_ref, s_ref, at=None):
+        t, _last = _sweep_step(at)
 
         @pl.when(t == 0)
         def _init():
@@ -570,23 +642,29 @@ def _interpret_default() -> bool:
 @functools.partial(jax.jit,
                    static_argnames=("interpret", "metric_dtype", "radix"))
 def _acs_tiles(llrs, interpret: bool, metric_dtype: str = "float32",
-               radix: int = 2):
+               radix: int = 2, n_blocks=None):
     """ACS sweep alone: (nb, Tp, 2, 128) lane tiles (Tp already a
     multiple of UNROLL) -> (packed decision planes, final metrics).
     Split from `_decode_tiles` so the bench breakdown can time the two
     kernels separately (tools/rx_dispatch_bench.viterbi_breakdown —
-    the `bench.py:722` "dependency-chain-bound, but WHERE?" answer)."""
+    the `bench.py:722` "dependency-chain-bound, but WHERE?" answer).
+
+    ``n_blocks`` (nb,) int32, traced: the bound. Tile `b` runs its
+    first ``n_blocks[b]`` blocks of UNROLL steps and hands over the
+    metrics as they stand after them; its decision planes past them are
+    not written (and hold whatever the buffer held). Exact where every
+    lane of the tile is an erasure from there on: see the note above
+    `_sweep_step`."""
     i_in = metric_dtype in ("int16", "int8")
     nb, Tp = llrs.shape[0], llrs.shape[1]
     TB = Tp // UNROLL                       # grid blocks per trellis
-    return pl.pallas_call(
-        _ACS_KERNELS[(metric_dtype, radix)],
-        grid=(nb, TB),
-        in_specs=[pl.BlockSpec((1, UNROLL, 2, LANES),
-                               lambda b, t: (b, t, 0, 0))],
+    block, tile = _block_maps(lambda t, n: t, TB, n_blocks is not None)
+    return _sweep(
+        _ACS_KERNELS[(metric_dtype, radix)], n_blocks, (nb, TB),
+        in_specs=[pl.BlockSpec((1, UNROLL, 2, LANES), block)],
         out_specs=[
-            pl.BlockSpec((1, UNROLL, 8, LANES), lambda b, t: (b, t, 0, 0)),
-            pl.BlockSpec((1, N_STATES, LANES), lambda b, t: (b, 0, 0)),
+            pl.BlockSpec((1, UNROLL, 8, LANES), block),
+            pl.BlockSpec((1, N_STATES, LANES), tile),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((nb, Tp, 8, LANES), jnp.uint8),
@@ -600,22 +678,26 @@ def _acs_tiles(llrs, interpret: bool, metric_dtype: str = "float32",
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _traceback_tiles(dec, metrics, interpret: bool):
+def _traceback_tiles(dec, metrics, interpret: bool, n_blocks=None):
     """Traceback alone over UNROLL-step blocks: packed decision planes
     + final metrics -> (nb, Tp, 8, 128) bit planes (row 0 carries the
-    decoded bit)."""
+    decoded bit).
+
+    ``n_blocks`` (nb,) int32, traced: the bound the ACS ran to. Tile
+    `b` starts from the metrics at its block ``n_blocks[b] - 1`` and
+    walks down to block 0; the bit planes past the bound are not
+    written: `_decode_tiles` zeroes them."""
     nb, Tp = dec.shape[0], dec.shape[1]
     TB = Tp // UNROLL
-    return pl.pallas_call(
-        _make_traceback_kernel(UNROLL),
-        grid=(nb, TB),
+    block, tile = _block_maps(lambda t, n: n - 1 - t, TB,
+                              n_blocks is not None)
+    return _sweep(
+        _make_traceback_kernel(UNROLL), n_blocks, (nb, TB),
         in_specs=[
-            pl.BlockSpec((1, UNROLL, 8, LANES),
-                         lambda b, t, _n=TB: (b, _n - 1 - t, 0, 0)),
-            pl.BlockSpec((1, N_STATES, LANES), lambda b, t: (b, 0, 0)),
+            pl.BlockSpec((1, UNROLL, 8, LANES), block),
+            pl.BlockSpec((1, N_STATES, LANES), tile),
         ],
-        out_specs=pl.BlockSpec((1, UNROLL, 8, LANES),
-                               lambda b, t, _n=TB: (b, _n - 1 - t, 0, 0)),
+        out_specs=pl.BlockSpec((1, UNROLL, 8, LANES), block),
         out_shape=jax.ShapeDtypeStruct((nb, Tp, 8, LANES), jnp.int32),
         scratch_shapes=[pltpu.VMEM((8, LANES), jnp.int32)],
         interpret=interpret,
@@ -625,12 +707,15 @@ def _traceback_tiles(dec, metrics, interpret: bool):
 @functools.partial(jax.jit,
                    static_argnames=("interpret", "metric_dtype", "radix"))
 def _decode_tiles(llrs, interpret: bool, metric_dtype: str = "float32",
-                  radix: int = 2):
+                  radix: int = 2, n_blocks=None):
     """(nb, T, 2, 128) f32|int16 -> (nb, T, 128) uint8 decoded bit
     planes. ``metric_dtype`` picks the ACS kernel ("float32" the
     oracle, "int16"/"int8" the quantized saturating paths — quantized
     llr tiles either way); ``radix`` picks 1 or 2 trellis steps per
-    kernel iteration (bit-identical at float32/int16)."""
+    kernel iteration (bit-identical at float32/int16). ``n_blocks``
+    (nb,) int32, traced, bounds both sweeps a tile (`_acs_tiles`); a
+    bit at or past a tile's bound reads ZERO, so no block the kernels
+    left unwritten reaches an output."""
     nb, T = llrs.shape[0], llrs.shape[1]
     # pad the trellis to a multiple of UNROLL with zero LLRs (erasures:
     # they add no likelihood, so the surviving path over the real prefix
@@ -638,9 +723,16 @@ def _decode_tiles(llrs, interpret: bool, metric_dtype: str = "float32",
     Tp = -(-T // UNROLL) * UNROLL
     if Tp != T:
         llrs = jnp.pad(llrs, ((0, 0), (0, Tp - T), (0, 0), (0, 0)))
-    dec, metrics = _acs_tiles(llrs, interpret, metric_dtype, radix)
-    bits = _traceback_tiles(dec, metrics, interpret)
-    return bits[:, :T, 0, :].astype(jnp.uint8)
+    if n_blocks is not None:
+        n_blocks = jnp.clip(n_blocks.astype(jnp.int32), 1, Tp // UNROLL)
+    dec, metrics = _acs_tiles(llrs, interpret, metric_dtype, radix,
+                              n_blocks)
+    bits = _traceback_tiles(dec, metrics, interpret, n_blocks)
+    bits = bits[:, :T, 0, :].astype(jnp.uint8)
+    if n_blocks is not None:
+        ran = (jnp.arange(T)[None, :] < n_blocks[:, None] * UNROLL)
+        bits = jnp.where(ran[..., None], bits, jnp.uint8(0))
+    return bits
 
 
 def _to_tiles(llrs):
@@ -677,7 +769,8 @@ def _quantize_for(md: str, llrs):
 
 
 def viterbi_decode_batch(llrs, n_bits: int = None, interpret: bool = None,
-                         metric_dtype: str = None, radix: int = None):
+                         metric_dtype: str = None, radix: int = None,
+                         n_blocks=None):
     """Batched soft decode: llrs (B, T, 2) or (B, 2T) -> (B, T) bits.
 
     Same contract as ops.viterbi.viterbi_decode but over a whole batch of
@@ -699,6 +792,13 @@ def viterbi_decode_batch(llrs, n_bits: int = None, interpret: bool = None,
     ``radix=4`` runs the two-steps-per-iteration ACS — bit-identical
     to radix 2 at float32 and int16 (and to the int8 radix-2 kernel on
     the same quantized inputs), half the sequential dependency chain.
+
+    ``n_blocks`` (ceil(B / 128),) int32, traced: a bound for each tile
+    of 128 lanes, in blocks of UNROLL steps. The tile's sweeps stop
+    there and its bits from there on read zero; every lane of the tile
+    has to be an erasure from there on, and then every bit before a
+    lane's last real row is the whole trellis's (`_acs_tiles`). Absent,
+    the whole trellis: the program this has always traced.
     """
     if interpret is None:
         interpret = _interpret_default()
@@ -712,7 +812,7 @@ def viterbi_decode_batch(llrs, n_bits: int = None, interpret: bool = None,
     else:
         llrs = _quantize_for(md, llrs)                # int16 (B, T, 2)
     x, B = _to_tiles(llrs)
-    bits = _decode_tiles(x, interpret, md, radix)     # (nb, T, 128)
+    bits = _decode_tiles(x, interpret, md, radix, n_blocks)  # (nb, T, 128)
     bits = bits.transpose(0, 2, 1).reshape(-1, llrs.shape[1])[:B]
     if n_bits is not None:
         bits = bits[:, :n_bits]
@@ -726,19 +826,23 @@ def viterbi_decode_batch_opt(llrs, n_bits: int = None,
                              window: int = None,
                              interpret: bool = None,
                              metric_dtype: str = None,
-                             radix: int = None):
+                             radix: int = None, n_blocks=None):
     """ONE dispatch for the batch decode's window/metric/radix options
     (review r5: the if/else was copied at every call site):
     ``window=None/0`` runs the exact kernel, ``window=N`` the
     sliding-window parallel decode below; ``metric_dtype`` selects the
     f32 oracle or a quantized saturating kernel and ``radix`` the
-    steps-per-iteration either way."""
+    steps-per-iteration either way. ``n_blocks`` bounds the exact
+    kernel's sweeps a tile (`viterbi_decode_batch`); the windowed
+    decode, whose windows ride as lanes of their own, keeps its whole
+    trellis."""
     if window:
         return viterbi_decode_batch_windowed(
             llrs, n_bits=n_bits, window=window, interpret=interpret,
             metric_dtype=metric_dtype, radix=radix)
     return viterbi_decode_batch(llrs, n_bits=n_bits, interpret=interpret,
-                                metric_dtype=metric_dtype, radix=radix)
+                                metric_dtype=metric_dtype, radix=radix,
+                                n_blocks=n_blocks)
 
 
 def viterbi_decode_batch_windowed(llrs, n_bits: int = None,

@@ -226,6 +226,19 @@ def _fused_front_applies(viterbi_window, viterbi_metric) -> bool:
     return not viterbi_window and (viterbi_metric or "float32") == "float32"
 
 
+def trellis_takes_bound(viterbi_window, viterbi_metric,
+                        fused_demap) -> bool:
+    """Whether the mixed decode's kernels stop at a bound that is data
+    (`_mixed_stages`): the exact lane-tile ACS and traceback do, at
+    every metric and radix; the windowed decode and the fused-demap
+    kernels run their whole trellis (no served configuration runs
+    them: ROADMAP S2, S6). One reading for the program and for the
+    host's account of it (`decode_steps`)."""
+    return not viterbi_window and not (
+        fused_demap_enabled(fused_demap)
+        and _fused_front_applies(viterbi_window, viterbi_metric))
+
+
 def _decode_back(bits, n_psdu_bits: int):
     """Decoded bits -> (psdu_bits, descrambled service bits)."""
     seed = scramble.recover_seed(bits[:7])
@@ -514,12 +527,26 @@ def _mixed_stages(n_sym_bucket: int, viterbi_window, viterbi_metric,
     -> what the trellis reads, a tuple of arrays that lead with the
     lane axis (the depunctured LLRs; under the fused front the
     equalized symbols and their gains); ``trellis(soft, rate_idx,
-    n_bits_real)`` -> (B, t_max) decoded bits; ``back(bits)`` -> the
-    descrambled rows. Every stage is lane-local, so a lane's values
-    do not depend on the batch it rides in: the mixed decode runs the
-    three over one batch, the streaming decode
+    n_bits_real, n_blocks=None)`` -> (B, t_max) decoded bits;
+    ``back(bits)`` -> the descrambled rows. Every stage is lane-local,
+    so a lane's values do not depend on the batch it rides in: the
+    mixed decode runs the three over one batch, the streaming decode
     (`stream_decode_graph`) over the groups and tiles that hold a
-    frame."""
+    frame.
+
+    ``n_blocks`` is a BOUND on the trellis, one count for each 128
+    lanes of the batch, traced (`decode_bound`'s first value): the ACS
+    and the traceback run that many blocks of `viterbi_pallas.UNROLL`
+    steps and a lane's bits from there on read zero. The caller states
+    that every lane under it is an erasure from there on (no
+    ``n_bits_real`` of the 128 passes it); then every bit before a
+    lane's ``n_bits_real`` is what the whole trellis gives, because the
+    rows left out add no likelihood and the survivor through them
+    leads back to the best state at the bound (`decode_data_mixed`'s
+    argument for stopping at clause 18's longest frame, taken to the
+    longest frame the tile holds). Absent, the whole trellis: the
+    program the mixed decode has always traced. The windowed and the
+    fused-demap kernels take no bound and run theirs whole."""
     t_max = mixed_trellis_steps(n_sym_bucket)
     # `rx.decode.front` / `.viterbi` / `.back` name the stages in the
     # device trace (docs/observability.md): metadata, no program change
@@ -531,7 +558,7 @@ def _mixed_stages(n_sym_bucket: int, viterbi_window, viterbi_metric,
                     lambda f: _front_symbols(f, n_sym_bucket,
                                              sco_track))(frames)
 
-        def trellis(soft, rate_idx, n_bits_real):
+        def trellis(soft, rate_idx, n_bits_real, n_blocks=None):
             data, gain = soft
             with jax.named_scope("rx.decode.viterbi"):
                 # the fused kernel still runs the bucket's whole trellis
@@ -568,12 +595,12 @@ def _mixed_stages(n_sym_bucket: int, viterbi_window, viterbi_metric,
                     (t[None, :] < n_bits_real[:, None])[..., None],
                     dep, 0.0),)
 
-        def trellis(soft, rate_idx, n_bits_real):
+        def trellis(soft, rate_idx, n_bits_real, n_blocks=None):
             with jax.named_scope("rx.decode.viterbi"):
                 return viterbi_pallas.viterbi_decode_batch_opt(
                     soft[0], window=viterbi_window,
                     metric_dtype=viterbi_metric, radix=viterbi_radix,
-                    interpret=interpret)
+                    interpret=interpret, n_blocks=n_blocks)
 
     def _descramble(b):
         seed = scramble.recover_seed(b[:7])
@@ -1334,6 +1361,49 @@ def decode_walk(n_live, n_slots: int):
             full + tile)
 
 
+def decode_bound(longest, t_max: int):
+    """What the streaming decode runs of the trellis for a tile whose
+    longest lane holds `longest` data bits: ``(blocks, steps)``. The
+    ACS and the traceback take the trellis in blocks of
+    `viterbi_pallas.UNROLL` steps and stop after the block that holds
+    that lane's last bit: at least one block, at most the `t_max`
+    steps there are (`params.mixed_trellis_steps`). Every row past it
+    is an erasure in every lane of the tile, so the bits before each
+    lane's own count are the whole trellis's (`_mixed_stages`). ONE
+    rule for the program (`longest` traced: the kernels' prefetched
+    count) and for the host's account of it (ints, or an array of them
+    a tile: `decode_steps`)."""
+    unroll = viterbi_pallas.UNROLL
+    blocks = -(-longest // unroll)
+    blocks = blocks + (blocks == 0)
+    steps = blocks * unroll
+    steps = steps - (steps > t_max) * (steps - t_max)
+    return -(-steps // unroll), steps
+
+
+def decode_steps(nbits, n_sym_bucket: int, bounded: bool = True) -> int:
+    """The host's account of the walk: the trellis steps the ACS and
+    the traceback run for the (devices, slots) table `nbits` (data
+    bits a slot in stream order, zero where a slot holds no frame),
+    lanes x steps summed over the tiles `decode_walk` says each device
+    decodes. The program packs a device's live slots in that order a
+    tile at a time, a tile runs all its lanes (cut at the slots there
+    are) to `decode_bound`'s steps for its longest (``bounded`` False,
+    a decode mode whose kernels take no bound, `trellis_takes_bound`:
+    the whole trellis), and that is what this counts: plain ints on
+    the host (a few hundred at most: quicker than arrays), no device."""
+    nbits = np.atleast_2d(nbits)
+    n, t_max = nbits.shape[1], mixed_trellis_steps(n_sym_bucket)
+    tile = decode_walk(1, n)[1]
+    total = 0
+    for row in nbits.tolist():
+        live = [b for b in row if b]
+        for j in range(0, decode_walk(len(live), n)[1], tile):
+            longest = max(live[j:j + tile], default=0) if bounded else t_max
+            total += (min(j + tile, n) - j) * decode_bound(longest, t_max)[1]
+    return total
+
+
 def stream_decode_graph(segs, rows, ridx, nbits, npsdu,
                         n_sym_bucket: int, viterbi_window: int = None,
                         viterbi_metric: str = None,
@@ -1345,8 +1415,10 @@ def stream_decode_graph(segs, rows, ridx, nbits, npsdu,
     `nbits`, `npsdu` the host's four (S, K) tables (segment row, rate
     index, data bits, PSDU bits), a stream's decodable lanes first
     and ``nbits == 0`` in every slot past them. Returns ``(clear (S,
-    K, t_max), crc (S, K), (fronted, decoded))``, the last the slots
-    its trips ran (`decode_walk`'s pair, for the tests).
+    K, t_max), crc (S, K), (fronted, decoded, steps))``, the last the
+    slots its trips ran (`decode_walk`'s pair) and the trellis steps
+    each tile's kernels ran (`decode_bound`'s, zero for a tile no trip
+    went to), for the tests.
 
     A slot is live where ``nbits > 0``. A stable partition packs the
     live slots of the flattened (S*K) batch to its front, stream
@@ -1356,24 +1428,36 @@ def stream_decode_graph(segs, rows, ridx, nbits, npsdu,
     slots from `segs` and fronts them (one group of `DECODE_GROUP`
     where one holds what is left, else the whole tile: a `lax.cond`),
     then runs the ACS, the traceback, the descrambler and the FCS
-    check on the tile. The stages are `decode_data_mixed`'s own
-    (`_mixed_stages`) and lane-local, so every live slot's `clear`
-    and `crc` are, bit for bit, what the mixed decode over all S*K
-    slots gives it. A
-    slot that holds no frame is not decoded and reads ZERO in both
-    outputs (until PR 46 it came back as a decoded erasure); the host
-    reads neither."""
+    check on the tile. The two kernels run the trellis as far as the
+    tile's LONGEST frame reaches and no further (PR 53): the bound is
+    `decode_bound` of the largest ``nbits`` among the tile's live
+    places, a traced count a tile (under a mesh each device bounds its
+    own tiles; no collective), and a tile of 1500-byte frames runs 189
+    of the 513 blocks that clause 18's longest frame fills. The stages
+    are `decode_data_mixed`'s own (`_mixed_stages`) and lane-local, and
+    the rows a bound leaves out are erasures in every lane under it,
+    so every live slot's `crc` and its `clear` BEFORE ITS OWN
+    ``nbits`` are, bit for bit, what the mixed decode over all S*K
+    slots and the whole trellis gives it. From a slot's ``nbits`` on
+    its `clear` held the decode of erasures, and holds that up to its
+    tile's bound and the descrambled zeros past it; a slot that holds
+    no frame is not decoded and reads ZERO in both outputs (until PR
+    46 it came back as a decoded erasure). The host reads neither."""
     front, trellis, back = _mixed_stages(
         n_sym_bucket, viterbi_window, viterbi_metric, viterbi_radix,
         None, sco_track, fused_demap)
+    bounded = trellis_takes_bound(viterbi_window, viterbi_metric,
+                                  fused_demap)
     s, kk = rows.shape
     n, g = s * kk, DECODE_GROUP
+    t_max = mixed_trellis_steps(n_sym_bucket)
     # a tile: what one live slot decodes; the packed batch: whole tiles
     tile, n_buf = decode_walk(1, n)[1], decode_walk(n, n)[1]
     ridx, nbits, npsdu = (t.reshape(-1) for t in (ridx, nbits, npsdu))
     with jax.named_scope("rx.decode.select"):
         live = nbits > 0
-        fronted, decoded = decode_walk(live.sum(dtype=jnp.int32), n)
+        n_live = live.sum(dtype=jnp.int32)
+        fronted, decoded = decode_walk(n_live, n)
         # a live slot's place in the packed order, and the slot at
         # each place; places past the last live slot keep slot 0,
         # whose rows are computed with the last group and never read
@@ -1405,29 +1489,38 @@ def stream_decode_graph(segs, rows, ridx, nbits, npsdu,
         soft = front_rows(g)(j) if tile == g else jax.lax.cond(
             fronted - j * tile > g, front_rows(tile), front_rows(g), j)
         idx = jax.lax.dynamic_slice(order, (j * tile,), (tile,))
-        clear = back(trellis(soft, ridx[idx], nbits[idx]))
+        with jax.named_scope("rx.decode.select"):
+            # the tile's bound: its longest frame, over its LIVE places
+            # (a place past the last live slot reads slot 0's tables)
+            here = j * tile + jnp.arange(tile, dtype=jnp.int32) < n_live
+            blocks, steps = decode_bound(
+                jnp.max(jnp.where(here, nbits[idx], 0)) if bounded
+                else jnp.int32(t_max), t_max)
+        clear = back(trellis(soft, ridx[idx], nbits[idx],
+                             blocks.reshape(1) if bounded else None))
         crc = crc_psdu_many_graph(clear, npsdu[idx])
         with jax.named_scope("rx.decode.back"):
             # a tile is a leading index of the packed outputs, so the
             # write is the tile's own bytes (zero where no trip went)
             return tuple(jax.lax.dynamic_update_index_in_dim(b, p, j, 0)
-                         for b, p in zip(out, (clear, crc)))
+                         for b, p in zip(out, (clear, crc, steps)))
 
-    out = (jnp.zeros((n_buf // tile, tile,
-                      mixed_trellis_steps(n_sym_bucket)), jnp.uint8),
-           jnp.zeros((n_buf // tile, tile), bool))
+    out = (jnp.zeros((n_buf // tile, tile, t_max), jnp.uint8),
+           jnp.zeros((n_buf // tile, tile), bool),
+           jnp.zeros((n_buf // tile,), jnp.int32))
     # one tile is no loop: a body that does not read its counter is
     # hoisted out piecemeal by the chip's compiler, which then ran
     # the ACS twice
-    clear, crc = (b.reshape((n_buf,) + b.shape[2:]) for b in (
+    clear, crc, steps = (
         decode_tile(0, out) if n_buf == tile
-        else jax.lax.fori_loop(0, decoded // tile, decode_tile, out)))
+        else jax.lax.fori_loop(0, decoded // tile, decode_tile, out))
+    clear, crc = (b.reshape((n_buf,) + b.shape[2:]) for b in (clear, crc))
     with jax.named_scope("rx.decode.back"):
         # packed places back to the slots' own
         clear = jnp.where(live[:, None], clear[place], 0)
         crc = live & crc[place]
     return (clear.reshape(s, kk, -1), crc.reshape(s, kk),
-            (fronted, decoded))
+            (fronted, decoded, steps))
 
 
 @lru_cache(maxsize=None)
